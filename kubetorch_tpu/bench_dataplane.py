@@ -72,6 +72,8 @@ class _Store:
              "kubetorch_tpu.data_store.store_server",
              "--host", "127.0.0.1", "--port", str(self.port),
              "--root", str(root)],
+            # host-only child of a parent that may hold the chip
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
             stdout=subprocess.DEVNULL, stderr=subprocess.STDOUT)
         self.url = f"http://127.0.0.1:{self.port}"
         for _ in range(100):

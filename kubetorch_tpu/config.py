@@ -278,8 +278,10 @@ _knob("KT_DEBUG_PORT", "int", 5678,
 _knob("KT_JAX_COORD_PORT", "int", 8476,
       "Port of the JAX distributed coordinator.", "serving")
 _knob("KT_JAX_CACHE_DIR", "str", "/tmp/kt-jax-cache",
-      "Persistent JAX compilation cache dir (mount a volume to "
-      "survive pod reschedules).", "serving")
+      "JAX compilation cache dir the K8s manifests inject into pods as "
+      "JAX_COMPILATION_CACHE_DIR (mount a volume there to survive pod "
+      "reschedules). Local processes use compile_cache_dir() instead.",
+      "serving")
 _knob("KT_TPU_HOSTNAME_PATTERN", "str", None,
       "Format string for TPU worker hostnames ({slice}, {host}).", "serving")
 _knob("KT_TPU_HOSTS_PER_SLICE", "int", None,
@@ -749,6 +751,22 @@ _ACCESSORS = {"str": env_str, "int": env_int, "float": env_float,
 def env_value(name: str) -> Any:
     """Read a knob with the accessor matching its declared type."""
     return _ACCESSORS[KNOBS[name].type](name)
+
+
+def compile_cache_dir() -> str:
+    """Where this process keeps JAX's persistent compilation cache.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it — nothing
+    in code overrides that — otherwise ``<checkout>/.jax_cache``. Never a
+    temp name, pid or time: the directory is part of the cache's key, so
+    one that moves never hits. Every process that compiles exports it
+    BEFORE importing jax::
+
+        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                              compile_cache_dir())
+    """
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(Path(__file__).resolve().parents[1] / ".jax_cache"))
 
 
 def iter_knobs() -> Iterator[Knob]:

@@ -103,9 +103,7 @@ def device_get_chunked(leaves, chunk_bytes: int = 256 << 20):
 
     Each ``jax.device_get`` pays a per-call fixed cost (dispatch +
     transfer setup); a param tree has hundreds of leaves, so per-leaf
-    fetches turn the staging hop into n_leaves × fixed-cost — on a
-    remote-dispatch link (the measured r4 weight-sync regression) that
-    fixed cost is ~100 ms/call and dominates end to end. Packing leaves
+    fetches turn the staging hop into n_leaves × fixed-cost. Packing leaves
     (grouped by dtype) into ≤``chunk_bytes`` on-device buffers cuts the
     call count to a handful; the on-device concatenate is an HBM copy,
     orders of magnitude faster than any host link. Multi-device-sharded

@@ -1,20 +1,27 @@
 """ctypes binding for the native content hasher (kthash.cpp).
 
-Builds on first use with g++ (cached next to the source); callers fall back
-to hashlib when no toolchain exists (see ``sync.file_hash``).
+Built from the source on first use with g++ and kept next to it, named by
+the source's digest: a checkout carries no binary, and an edited source
+never loads a stale one (file times do not survive a copy of the tree).
+Callers fall back to hashlib when no toolchain exists (see
+``sync.file_hash``).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
+import os
 import subprocess
 import threading
 from pathlib import Path
 from typing import Optional
 
+logger = logging.getLogger(__name__)
+
 _DIR = Path(__file__).parent
 _SRC = _DIR / "kthash.cpp"
-_LIB = _DIR / "libkthash.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -28,17 +35,26 @@ def _ensure_lib() -> ctypes.CDLL:
             return _lib
         if _build_failed:
             raise RuntimeError("native hasher build previously failed")
-        if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
+        digest = hashlib.sha1(_SRC.read_bytes()).hexdigest()[:12]
+        built = _DIR / f"libkthash-{digest}.so"
+        if not built.exists():
+            # other processes (pods sharing this checkout) may build at
+            # the same moment: each writes its own file, then renames
+            tmp = built.with_suffix(f".{os.getpid()}.tmp")
             try:
                 # ktlint: disable=KT008 -- build-once barrier: the lock exists precisely so every contender waits for the one g++ build; nothing can use the lib before it exists
                 subprocess.run(
                     ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     str(_SRC), "-o", str(_LIB)],
+                     str(_SRC), "-o", str(tmp)],
                     check=True, capture_output=True, timeout=120)
-            except (subprocess.SubprocessError, FileNotFoundError) as exc:
+                os.replace(tmp, built)
+            except (subprocess.SubprocessError, OSError) as exc:
                 _build_failed = True
+                tmp.unlink(missing_ok=True)
+                logger.warning("native hasher build failed (%s); callers "
+                               "hash with hashlib instead", exc)
                 raise RuntimeError(f"native hasher build failed: {exc}")
-        lib = ctypes.CDLL(str(_LIB))
+        lib = ctypes.CDLL(str(built))
         lib.kt_hash_file.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
                                      ctypes.c_int]
         lib.kt_hash_file.restype = ctypes.c_int

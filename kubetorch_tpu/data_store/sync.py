@@ -38,18 +38,21 @@ def _excluded(rel: str, excludes: Iterable[str]) -> bool:
 
 
 def file_hash(path: Path) -> str:
-    """Content hash; native scanner when available (xxh64-style), else
-    blake2b-128."""
-    try:
-        from kubetorch_tpu.data_store.native import hash_file
+    """Content hash; native scanner (xxh64-style), or blake2b-128 on a
+    machine with no toolchain to build it. The two never compare equal, so
+    a tree hashed one way re-syncs in full against one hashed the other:
+    the failed build is logged where it happens, once."""
+    from kubetorch_tpu.data_store import native
 
-        return hash_file(str(path))
-    except Exception:
-        h = hashlib.blake2b(digest_size=16)
-        with open(path, "rb") as f:
-            for chunk in iter(lambda: f.read(1 << 20), b""):
-                h.update(chunk)
-        return h.hexdigest()
+    try:
+        return native.hash_file(str(path))
+    except RuntimeError:             # the build failed; nothing else
+        pass
+    h = hashlib.blake2b(digest_size=16)
+    with open(path, "rb") as f:
+        for chunk in iter(lambda: f.read(1 << 20), b""):
+            h.update(chunk)
+    return h.hexdigest()
 
 
 def scan_tree(

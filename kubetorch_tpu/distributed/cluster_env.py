@@ -2,19 +2,19 @@
 contract.
 
 The launcher injects ``JAX_COORDINATOR_ADDRESS`` / ``JAX_NUM_PROCESSES`` /
-``JAX_PROCESS_ID`` (+ optional ``JAX_LOCAL_DEVICE_IDS``) per worker
-(``serving/frameworks.py`` JaxProcess — the TPU-first analogue of the
-reference's ``serving/spmd/jax_process.py:8``). Current JAX only reads the
-coordinator address and local-device ids from env; process count/id must
-come from a registered ``ClusterEnv``. This module registers one keyed on
+``JAX_PROCESS_ID`` per worker (``serving/frameworks.py`` JaxProcess — the
+TPU-first analogue of the reference's ``serving/spmd/jax_process.py:8``;
+which chips a process may open is libtpu's ``TPU_VISIBLE_CHIPS`` contract,
+``resources/compute/topology.py::chip_env``). JAX only reads the
+coordinator address from env; process count/id must come from a
+registered ``ClusterEnv``. This module registers one keyed on
 exactly those variables, so user code inside a ``.distribute("jax")``
 workload needs no arguments — the same UX torch users get from
 ``MASTER_ADDR``/``RANK`` env in ``dist.init_process_group``.
 
 Importing the module performs the registration (JAX auto-detects
-``ClusterEnv`` subclasses on definition). ``initialize()`` is the
-explicit-args fallback that works even if the private registration API
-drifts.
+``ClusterEnv`` subclasses on definition). ``initialize()`` passes the same
+contract as explicit arguments.
 """
 
 from __future__ import annotations
@@ -26,15 +26,12 @@ __all__ = ["initialize", "register"]
 _REGISTERED = False
 
 
-def register() -> bool:
-    """Define + auto-register the ClusterEnv subclass. Returns success."""
+def register() -> None:
+    """Define + auto-register the ClusterEnv subclass (idempotent)."""
     global _REGISTERED
     if _REGISTERED:
-        return True
-    try:
-        from jax._src import clusters
-    except ImportError:  # private API moved; explicit initialize() still works
-        return False
+        return
+    from jax._src import clusters
 
     class KubetorchCluster(clusters.ClusterEnv):
         """Bootstraps from the env the kubetorch launcher injects."""
@@ -63,27 +60,30 @@ def register() -> bool:
         def get_process_id(cls) -> int:
             return int(os.environ["JAX_PROCESS_ID"])
 
+    # First in line: JAX takes the first detector whose environment is
+    # present, and its own come first. On a TPU host the chip variables
+    # the launcher sets (TPU_PROCESS_ADDRESSES) look like GKE to JAX's
+    # detector, which then asks a metadata server for the process count —
+    # the launcher's explicit contract must win over that sniffing.
+    types = clusters.ClusterEnv._cluster_types
+    types.remove(KubetorchCluster)
+    types.insert(0, KubetorchCluster)
     _REGISTERED = True
-    return True
 
 
 def initialize(**kwargs) -> None:
     """Explicit ``jax.distributed.initialize`` from the kubetorch env
     contract; idempotent. Use when you want initialization independent of
-    JAX's cluster auto-detection (any JAX version)."""
+    JAX's cluster auto-detection."""
     import jax
 
-    state = jax.distributed.global_state
-    if getattr(state, "client", None) is not None:  # already initialized
+    if jax.distributed.is_initialized():
         return
     args = dict(
         coordinator_address=os.environ.get("JAX_COORDINATOR_ADDRESS"),
         num_processes=_int_env("JAX_NUM_PROCESSES"),
         process_id=_int_env("JAX_PROCESS_ID"),
     )
-    ids = os.environ.get("JAX_LOCAL_DEVICE_IDS")
-    if ids:
-        args["local_device_ids"] = [int(i) for i in ids.split(",")]
     args.update(kwargs)
     jax.distributed.initialize(**args)
 

@@ -21,7 +21,6 @@ import numpy as np
 
 from kubetorch_tpu.models import llama
 from kubetorch_tpu.models.configs import LlamaConfig
-from kubetorch_tpu.parallel.mesh import use_mesh
 from kubetorch_tpu.parallel.sharding import ShardingRules
 
 POOLINGS = ("mean", "last", "first")
@@ -87,7 +86,7 @@ class Embedder:
         toks = np.full((B, P), self.pad_id, np.int32)
         for i, p in enumerate(prompts):
             toks[i, :len(p)] = p
-        ctx = (use_mesh(self.mesh) if self.mesh is not None
+        ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
         with ctx:
             out = self._fn(self.params, jnp.asarray(toks),

@@ -29,7 +29,6 @@ import numpy as np
 
 from kubetorch_tpu.models import llama
 from kubetorch_tpu.models.configs import LlamaConfig
-from kubetorch_tpu.parallel.mesh import use_mesh
 from kubetorch_tpu.parallel.sharding import ShardingRules
 
 
@@ -83,7 +82,7 @@ class Generator:
     ...                    temperature=0.8, top_p=0.9, eos_id=2, seed=0)
 
     Works under a device mesh: pass ``mesh`` (and optionally ``rules``) and
-    call inside or outside ``use_mesh`` — params keep their shardings and XLA
+    call inside or outside ``jax.set_mesh`` — params keep their shardings and XLA
     propagates them into the cache.
     """
 
@@ -237,7 +236,7 @@ class Generator:
 
         import contextlib
 
-        ctx = (use_mesh(self.mesh) if self.mesh is not None
+        ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
         W = 64
         win0 = np.full((B, W), -1, np.int32)

@@ -318,9 +318,7 @@ def _block(x, layer, sin, cos, cfg: LlamaConfig, rules: ShardingRules,
             impl = "flash" if (S >= 2048 and S % 512 == 0
                                and D % 128 == 0) else "xla"
         if impl == "flash" and segment_ids is None:
-            from kubetorch_tpu.ops.flash_attention import flash_attention
-
-            attn = flash_attention(q, k, v, causal=True)
+            attn = _flash_per_shard(q, k, v, rules)
         else:
             attn = dot_product_attention(q, k, v, causal=True,
                                          segment_ids=segment_ids)
@@ -330,6 +328,37 @@ def _block(x, layer, sin, cos, cfg: LlamaConfig, rules: ShardingRules,
 
     x = x + _mlp(x, layer, cfg, rules)
     return shard_constraint(x, rules, "batch", "seq", None)
+
+
+def _flash_per_shard(q, k, v, rules: ShardingRules):
+    """Causal flash attention under whatever mesh is active. XLA cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"), so on a
+    multi-device mesh the kernel runs once per shard: attention is
+    independent across batch and (kv-)heads, which is all these layouts
+    shard here (sequence parallelism takes the ring path). Mosaic wants
+    EVERY mesh axis manual, size one or not; axes an enclosing shard_map
+    already made manual stay as they are."""
+    from jax.sharding import PartitionSpec
+
+    from kubetorch_tpu.ops.flash_attention import flash_attention
+
+    mesh = jax.sharding.get_abstract_mesh()
+    split = {a for a in mesh.axis_names if a not in mesh.manual_axes}
+    if mesh.empty or mesh.size == 1 or not split:
+        return flash_attention(q, k, v, causal=True)
+
+    def spec(heads: str) -> PartitionSpec:
+        dims = []
+        for entry in rules.pspec("batch", None, heads, None):
+            names = (entry,) if isinstance(entry, str) else (entry or ())
+            dims.append(tuple(a for a in names if a in split) or None)
+        return PartitionSpec(*dims)
+
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        in_specs=(spec("heads"), spec("kv_heads"), spec("kv_heads")),
+        out_specs=spec("heads"), axis_names=split, check_vma=False,
+    )(q, k, v)
 
 
 def _mlp(x, layer, cfg: LlamaConfig, rules: ShardingRules, lctx=None):

@@ -233,7 +233,7 @@ def dequantize_params(params: Dict[str, Any],
 
 def init_quantized(key: jax.Array, cfg,
                    keys: Sequence[str] = QUANT_KEYS,
-                   fuse: bool = False) -> Dict[str, Any]:
+                   fuse: bool = False, shardings=None) -> Dict[str, Any]:
     """Random params initialized *directly* in int8-quantized form.
 
     For serving-scale benchmarks and smoke tests of models whose bf16 tree
@@ -245,6 +245,10 @@ def init_quantized(key: jax.Array, cfg,
     dense init) so logits land in a realistic range for the sampling path.
     The unembedding stays bf16 — int8 there is measured slower (see
     :func:`quantize_params`).
+
+    ``shardings``: a tree of shardings matching the output (built from
+    :func:`quantized_logical_axes`) — the tree is then made directly
+    sharded over the mesh, never whole on one device.
     """
     pdt = cfg.storage_dtype
     L, E, H, Hkv, D, M, V = (cfg.n_layers, cfg.embed_dim, cfg.n_heads,
@@ -297,4 +301,4 @@ def init_quantized(key: jax.Array, cfg,
             out["layers"] = fuse_decode_layers(out["layers"])
         return out
 
-    return jax.jit(build)(key)
+    return jax.jit(build, out_shardings=shardings)(key)

@@ -17,9 +17,8 @@ TPU shape discipline + dispatch discipline:
 - All decode state (cache, pending logits, depths, active mask) lives on
   device between calls; the host holds only bookkeeping. Each
   :meth:`step` is ONE jit call running ``steps_per_call`` tokens through a
-  ``lax.scan`` and ONE host sync for the emitted block — per-token Python
-  dispatch is what made naive rolling 8× slower than a static scan on a
-  remote-attached TPU, and chunking amortizes it away. Requests finish
+  ``lax.scan`` and ONE host sync for the emitted block — chunking
+  amortizes the per-token Python dispatch of a naive rolling loop. Requests finish
   mid-chunk: their surplus tokens are trimmed on the host and their slot
   frees at the chunk boundary (≤ ``steps_per_call − 1`` wasted
   slot-tokens), which is the latency/throughput knob.
@@ -298,9 +297,9 @@ class RollingGenerator:
         self._devstats = devstats.ExecutableCosts()
         self._devstats_peaks: Any = "unset"
 
-        # Donation matters doubly here: the cache grid is the largest
-        # buffer in the server and every call rewrites it — aliasing
-        # in/out keeps updates in place (and off any remote-dispatch wire).
+        # Donation matters here: the cache grid is the largest buffer in
+        # the server and every call rewrites it — aliasing in/out keeps
+        # updates in place.
         self._prefill = jax.jit(
             partial(self._prefill_impl, cfg=cfg, rules=self.rules),
             static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4))
@@ -1116,9 +1115,7 @@ class RollingGenerator:
     def _mesh_ctx(self):
         import contextlib
 
-        from kubetorch_tpu.parallel.mesh import use_mesh
-
-        return (use_mesh(self.mesh) if self.mesh is not None
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
 
     def _decode_chunk(self) -> List[Tuple[int, List[int], bool]]:
@@ -1161,10 +1158,9 @@ class RollingGenerator:
         chunk-mode dispatch, and an all-collapsed batch (every row at
         k = 1) dispatches the width-1 forward, i.e. plain decode."""
         # STICKY sampling flag: the first sampled request upgrades the
-        # dispatch to the sampling executable and it stays there —
-        # flapping between the greedy and sampling executables per
-        # occupancy mix would pay an executable swap per flip on
-        # remote-dispatch links
+        # dispatch to the sampling executable and it stays there rather
+        # than flapping between the greedy and sampling executables per
+        # occupancy mix
         if not self._spec_sampling and any(
                 self._slots[s].temperature > 0 for s in self._slots):
             self._spec_sampling = True
@@ -1259,10 +1255,8 @@ class RollingGenerator:
 
         FIXED-shape mask update, never a variable-length index
         scatter: `.at[freed].set` compiles a fresh executable per
-        distinct len(freed), and on a remote-dispatch link each of
-        those tiny compiles costs seconds — speculative drains
-        (scattered finish times) measured 7-14 s spikes per new
-        freed-count until this was masked."""
+        distinct len(freed), and speculative drains (scattered finish
+        times) reach a new freed-count mid-traffic."""
         mask = np.zeros(self.max_slots, bool)
         mask[freed] = True
         mask = jnp.asarray(mask)
@@ -1702,8 +1696,7 @@ class RollingDecoder:
     JSON-able values, and ``step()`` is safe to pipeline at depth ≥ 2 —
     the channel executes calls FIFO per connection, so chunk N+1 is
     serialized + shipped while chunk N is still on device, hiding the
-    per-call dispatch tax the POST path pays (BENCH_r05: ~144 ms/chunk
-    through the tunnel).
+    per-call dispatch tax the POST path pays.
 
     >>> remote = kt.cls(MyDecoderFactory)(...).to(compute)
     >>> chan = remote.channel(depth=2)

@@ -63,7 +63,6 @@ from kubetorch_tpu.lookahead import LookaheadState  # noqa: F401
 #   engine can import it — but spec callers reach it from here)
 from kubetorch_tpu.models import llama
 from kubetorch_tpu.models.configs import LlamaConfig
-from kubetorch_tpu.parallel.mesh import use_mesh
 from kubetorch_tpu.parallel.sharding import ShardingRules
 
 
@@ -386,7 +385,7 @@ class SpeculativeGenerator:
             toks[i, :len(p)] = p
             ctx0[i, :len(p)] = p
 
-        ctx = (use_mesh(self.mesh) if self.mesh is not None
+        ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
                else contextlib.nullcontext())
         with ctx:
             first_logits, cache = self._prefill(

@@ -40,8 +40,8 @@ from typing import Any, Dict, Optional, Tuple
 # (peak dense FLOP/s in the serving dtype (bf16), peak HBM bytes/s).
 # Sources: published TPU spec sheets; the v5e bandwidth matches the
 # 819e9 constant the serving bench has always used for its roofline.
-# Unknown kinds (CPU hosts, interop backends) map to None — the engine
-# then publishes *no* MFU/MBU gauge rather than a made-up one, the
+# Unknown kinds (CPU hosts, unrecognized accelerators) map to None — the
+# engine then publishes *no* MFU/MBU gauge rather than a made-up one, the
 # same absent-not-zero semantics as ``kv_blocks_free``.
 _PEAKS: Tuple[Tuple[str, Tuple[float, float]], ...] = (
     ("v5 lite", (197e12, 819e9)),
@@ -114,6 +114,30 @@ def hbm_stats() -> Optional[Dict[str, float]]:
     # ktlint: disable=KT004 -- metrics introspection must never raise into the serving path
     except Exception:  # noqa: BLE001
         return None
+
+
+def watch_compiles() -> Dict[str, float]:
+    """Count this process's XLA compiles from here on, off
+    ``jax.monitoring``: seconds spent in the backend compile step (a
+    persistent-cache hit spends only its retrieval there) and the
+    persistent cache's hits and misses. Returns the live counter dict."""
+    import jax
+
+    stats = {"backend_compile_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+
+    def on_duration(event: str, seconds: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            stats["backend_compile_s"] += seconds
+
+    def on_event(event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            stats["cache_hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            stats["cache_misses"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    return stats
 
 
 def cost_from_analysis(analysis: Any) -> Tuple[float, float]:

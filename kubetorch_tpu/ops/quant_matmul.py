@@ -114,12 +114,7 @@ def decode_matmul_viable(x: jax.Array, w: jax.Array, scale) -> bool:
         return False  # compute-bound regime: MXU-friendly einsum wins
     if jax.default_backend() == "cpu":
         return False
-    try:
-        from jax.sharding import get_abstract_mesh
-
-        mesh = get_abstract_mesh()
-        if mesh is not None and not mesh.empty and mesh.size > 1:
-            return False
-    except ImportError:  # older jax: no ambient-mesh API → be conservative
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and mesh.size > 1:
         return False
     return pick_block_n(tokens, x.shape[-1], w.shape[-1]) is not None

@@ -12,7 +12,6 @@ from kubetorch_tpu.parallel.mesh import (
     MeshSpec,
     best_spec_for,
     local_mesh,
-    use_mesh,
 )
 from kubetorch_tpu.parallel.sharding import (
     LOGICAL_AXIS_RULES,
@@ -27,7 +26,6 @@ __all__ = [
     "MeshSpec",
     "best_spec_for",
     "local_mesh",
-    "use_mesh",
     "ShardingRules",
     "LOGICAL_AXIS_RULES",
     "logical_to_pspec",

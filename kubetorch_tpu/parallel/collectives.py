@@ -40,17 +40,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubetorch_tpu.config import env_int, env_str
-from kubetorch_tpu.parallel.mesh import shard_map_check_kwargs
-
-try:  # moved out of experimental upstream
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
-_NOCHECK = shard_map_check_kwargs(shard_map, disable_on_new=True)
 
 DCN_AXIS = "dcn"
 
@@ -182,9 +175,9 @@ def dcn_ring_allreduce(stacked, mesh: Mesh, *, block: int = 256,
         return out.reshape(-1)
 
     spec_other = other if other else None
-    ring = shard_map(body, mesh,
+    ring = shard_map(body, mesh=mesh,
                      in_specs=(P(DCN_AXIS, spec_other), P()),
-                     out_specs=P(spec_other), **_NOCHECK)
+                     out_specs=P(spec_other), check_vma=False)
     summed = ring(vec, seed_arr)[:n_elems]
     out, off = [], 0
     for shape, dt in zip(shapes, dtypes):

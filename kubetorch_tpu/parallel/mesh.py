@@ -135,58 +135,6 @@ def best_spec_for(
     return MeshSpec(pp=pp, tp=tp, sp=sp, ep=ep, fsdp=remaining)
 
 
-def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for PartitionSpec-based constraints.
-
-    Compat shim: ``jax.sharding.use_mesh`` (<=0.8) vs ``jax.sharding.set_mesh``
-    (0.9+, context-manager capable) vs the Mesh object itself (jax<=0.4
-    ships neither, but ``with mesh:`` activates it).
-    """
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis inside shard_map.
-
-    Compat shim: ``jax.lax.axis_size`` (0.6+) vs ``jax.core.axis_frame``
-    (0.4.x, where it returns the size directly as an int). Both are
-    STATIC — usable in ``range()``/ppermute permutation construction.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
-
-
-def pcast_varying(tree, mesh_axes):
-    """VMA-typing compat: ``jax.lax.pcast(..., to="varying")`` where it
-    exists (shard_map varying-manual-axes typing, 0.8+); a no-op on older
-    jax, which has no VMA typing for the cast to satisfy."""
-    if mesh_axes and hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(tree, mesh_axes, to="varying")
-    return tree
-
-
-def shard_map_check_kwargs(shard_map_fn, disable_on_new: bool) -> dict:
-    """kwargs for shard_map's per-shard consistency checker across its
-    renames (``check_rep`` → ``check_vma``), resolved once at import.
-
-    On pre-VMA jax the old ``check_rep`` checker lacks replication rules
-    for several modern primitives, so it is ALWAYS disabled there. On
-    VMA-era jax, ``disable_on_new`` says whether the caller needs
-    ``check_vma=False`` (e.g. pallas interpret-mode bodies trip the
-    checker) or keeps it on (pcast handles the typing)."""
-    import inspect
-
-    if "check_vma" in inspect.signature(shard_map_fn).parameters:
-        return {"check_vma": False} if disable_on_new else {}
-    return {"check_rep": False}
-
-
 def local_mesh(spec: Optional[MeshSpec] = None) -> Mesh:
     """Mesh over this process's addressable devices (single-host path)."""
     devs = jax.local_devices()

@@ -28,22 +28,8 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from kubetorch_tpu.parallel.mesh import (
-    axis_size as _axis_size,
-    pcast_varying as _pcast_varying,
-    shard_map_check_kwargs,
-)
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# VMA-era jax keeps its checker on (pcast handles the carry typing);
-# pre-VMA check_rep is disabled (see mesh.shard_map_check_kwargs)
-_COMPAT_KW = shard_map_check_kwargs(shard_map, disable_on_new=False)
 
 
 def _spec_axes(spec) -> Tuple[str, ...]:
@@ -80,7 +66,7 @@ def _pipeline_body(params, x, *, axis_name: str, n_micro: int,
     """Inside shard_map. ``params`` leaves: [1(stage), ...] local slice (weight
     dims possibly still fsdp-sharded); ``x``: [B_local, ...] this shard's
     batch rows."""
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     local_params = jax.tree.map(lambda a: a[0], params)
     if param_specs is not None:
@@ -111,8 +97,10 @@ def _pipeline_body(params, x, *, axis_name: str, n_micro: int,
     inflight0 = jnp.zeros(mb_shape, xs.dtype)
     outputs0 = jnp.zeros((n_micro,) + mb_shape, xs.dtype)
     # VMA typing: carries become device-varying (over pp and any batch/
-    # weight-sharded axes) inside the scan. No-op on pre-VMA jax.
-    inflight0, outputs0 = _pcast_varying((inflight0, outputs0), mesh_axes)
+    # weight-sharded axes) inside the scan.
+    if mesh_axes:
+        inflight0, outputs0 = jax.lax.pcast(
+            (inflight0, outputs0), mesh_axes, to="varying")
     (_, outputs), _ = jax.lax.scan(
         tick, (inflight0, outputs0), jnp.arange(n_micro + pp - 1))
     # outputs live on the last stage only; replicate via psum.
@@ -174,5 +162,5 @@ def pipeline_apply(
     return shard_map(
         body, mesh=mesh,
         in_specs=(param_specs_in, x_spec),
-        out_specs=x_spec, **_COMPAT_KW,
+        out_specs=x_spec,
     )(stage_params, x)

@@ -27,13 +27,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubetorch_tpu.parallel.mesh import (
-    axis_size as _axis_size,
-    pcast_varying as _pcast_varying,
-    shard_map_check_kwargs,
-)
 from kubetorch_tpu.ops.flash_attention import (
     _STATS,
     _flash_backward,
@@ -43,14 +39,8 @@ from kubetorch_tpu.ops.flash_attention import (
     flash_tileable,
 )
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# flash bodies (pallas interpret mode) trip the VMA checker — disable it
-# on every jax generation (see mesh.shard_map_check_kwargs)
-_NOCHECK = shard_map_check_kwargs(shard_map, disable_on_new=True)
+# flash bodies (pallas interpret mode) trip the VMA checker
+_NOCHECK = {"check_vma": False}
 
 _NEG_INF = -1e30
 
@@ -80,7 +70,7 @@ def _chunk_scores(q, k, v, q_off, k_off, scale, causal):
 def _ring_body(q, k, v, *, axis_name: str, scale: float, causal: bool,
                mesh_axes: tuple = ()):
     """Runs inside shard_map: q/k/v are local [B, S_local, H(,kv), D]."""
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, S, H, D = q.shape
     s_local = S
@@ -90,8 +80,9 @@ def _ring_body(q, k, v, *, axis_name: str, scale: float, causal: bool,
     l = jnp.zeros((B, S, H), jnp.float32)
     # shard_map VMA typing: scan carries must enter as 'varying' over the
     # same axes as the inputs, since the loop body makes them
-    # device-varying (ppermute / axis_index). No-op on pre-VMA jax.
-    acc, m, l = _pcast_varying((acc, m, l), mesh_axes)
+    # device-varying (ppermute / axis_index).
+    if mesh_axes:
+        acc, m, l = jax.lax.pcast((acc, m, l), mesh_axes, to="varying")
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
     def step(i, carry):
@@ -161,7 +152,7 @@ def _merge(o, lse, o_c, lse_c):
 
 def _ring_fwd_flash(q, k, v, *, axis_name, scale, interpret, causal):
     """Forward ring pass with flash chunks. Returns (out, lse)."""
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, S, H, D = q.shape
     perm = [(i, (i + 1) % sp) for i in range(sp)]
@@ -190,7 +181,7 @@ def _ring_bwd_flash(q, k, v, out, lse, g, *, axis_name, scale, interpret,
     accumulate while the chunk travels and arrive home after the full
     rotation (sp steps of shift-by-1 = identity).
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 

@@ -24,11 +24,10 @@ import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
-from kubetorch_tpu.config import get_config
+from kubetorch_tpu.config import env_path, env_str, get_config
 from kubetorch_tpu.exceptions import ServiceTimeoutError, StartupError
+from kubetorch_tpu.resources.compute.topology import chip_env
 from kubetorch_tpu.serving import http_client
-
-from kubetorch_tpu.config import env_path, env_str
 
 _LOCAL_ROOT = env_path("KT_LOCAL_STATE")
 
@@ -98,28 +97,7 @@ class LocalBackend:
         ports = [free_port() for _ in range(num_pods)]
         local_ips = ",".join(f"127.0.0.1:{p}" for p in ports)
 
-        # The pod-server subprocess must be able to import this package even
-        # when the client was launched from elsewhere.
-        pkg_root = str(Path(__file__).resolve().parents[2])
-        python_path = os.environ.get("PYTHONPATH", "")
-        if pkg_root not in python_path.split(os.pathsep):
-            python_path = (f"{pkg_root}{os.pathsep}{python_path}"
-                           if python_path else pkg_root)
-
-        # Workers must not inherit the client's TPU/accelerator platform
-        # config unless the compute asked for TPUs: a remote-TPU tunnel
-        # (JAX_PLATFORMS pointing at a proxy backend) is usually
-        # single-tenancy, so CPU-compute pods pin themselves to cpu.
-        base_env = dict(os.environ)
-        wants_tpu = bool(compute_dict.get("tpus"))
-        if not wants_tpu:
-            base_env["JAX_PLATFORMS"] = "cpu"
-            # Shadow any site-level accelerator-plugin import (costs ~2 s
-            # per interpreter — pod server AND each spawned worker): cold
-            # dispatch is a headline metric and these pods are CPU-only.
-            stub = str(Path(__file__).resolve().parent / "_cpu_site")
-            if stub not in python_path.split(os.pathsep):
-                python_path = f"{stub}{os.pathsep}{python_path}"
+        base_env = _pod_base_env(compute_dict)
 
         # TPU-slice env emulation: a GKE TPU pod gets TPU_WORKER_ID from
         # the device plugin and MEGASCALE_SLICE_ID from its JobSet job
@@ -139,46 +117,29 @@ class LocalBackend:
         hosts_per_slice = tpu_spec.num_hosts if tpu_spec else 1
         n_slices = max(1, num_pods // hosts_per_slice) if tpu_spec else 1
 
+        chips_per_pod = (compute_obj.tpu_spec.chips_per_pod
+                         if compute_obj.tpu_spec else 0)
+        taken = self._chips_taken() if chips_per_pod else set()
         pods = []
         for index, port in enumerate(ports):
-            env = {
-                **base_env,
-                **module_env,
-                "PYTHONPATH": python_path,
-                "KT_SERVICE_NAME": service_name,
-                "KT_SERVER_PORT": str(port),
-                "KT_REPLICA_INDEX": str(index),
-                "KT_POD_NAME": f"{service_name}-{index}",
-                "KT_LAUNCH_ID": launch_id,
-                "LOCAL_IPS": local_ips,
-            }
+            extra = {}
             if tpu_spec is not None:
                 # Assign the computed identity EXPLICITLY: setdefault
                 # would let a TPU_WORKER_ID inherited from the client's
                 # own environment give every pod the same identity. An
                 # explicit module_env (user override) still wins.
-                slice_env = {
-                    "TPU_WORKER_ID": str(index % hosts_per_slice),
-                }
+                extra["TPU_WORKER_ID"] = str(index % hosts_per_slice)
                 if n_slices > 1:
-                    slice_env.update({
+                    extra.update({
                         "MEGASCALE_SLICE_ID": str(index // hosts_per_slice),
                         "MEGASCALE_NUM_SLICES": str(n_slices),
                         "MEGASCALE_COORDINATOR_ADDRESS": "127.0.0.1",
                     })
-                for k, v in slice_env.items():
-                    if k not in module_env:
-                        env[k] = v
-            log_path = service_dir / f"pod-{index}.log"
-            log_file = open(log_path, "ab")
-            proc = subprocess.Popen(
-                [sys.executable, "-m", "kubetorch_tpu.serving.server",
-                 "--host", "127.0.0.1", "--port", str(port)],
-                env=env, stdout=log_file, stderr=subprocess.STDOUT,
-                start_new_session=True)
-            log_file.close()
-            pods.append({"pid": proc.pid, "port": port, "index": index,
-                         "log": str(log_path)})
+            pods.append(_spawn_pod(
+                service_name, service_dir, index, port,
+                _claim_chips(taken, chips_per_pod),
+                base_env={**base_env, **extra}, module_env=module_env,
+                launch_id=launch_id, local_ips=local_ips))
 
         record = ServiceRecord({
             "service_name": service_name,
@@ -349,17 +310,10 @@ class LocalBackend:
             # same re-injection as restart(): the scaler runs inside the
             # controller, whose own env has no KT_CONTROLLER_URL
             module_env.setdefault("KT_CONTROLLER_URL", controller_url)
-        pkg_root = str(Path(__file__).resolve().parents[2])
-        python_path = os.environ.get("PYTHONPATH", "")
-        if pkg_root not in python_path.split(os.pathsep):
-            python_path = (f"{pkg_root}{os.pathsep}{python_path}"
-                           if python_path else pkg_root)
-        base_env = dict(os.environ)
-        if not compute_dict.get("tpus"):
-            base_env["JAX_PLATFORMS"] = "cpu"
-            stub = str(Path(__file__).resolve().parent / "_cpu_site")
-            if stub not in python_path.split(os.pathsep):
-                python_path = f"{stub}{os.pathsep}{python_path}"
+        base_env = _pod_base_env(compute_dict)
+        tpu_spec = Compute.from_dict(compute_dict).tpu_spec
+        chips_per_pod = tpu_spec.chips_per_pod if tpu_spec else 0
+        taken = self._chips_taken() if chips_per_pod else set()
         next_index = max((p["index"] for p in pods), default=-1) + 1
         launch_id = record.get("launch_id", "")
         new_ports = [free_port() for _ in range(replicas - current)]
@@ -368,28 +322,11 @@ class LocalBackend:
         ) or ",".join(f"127.0.0.1:{p}" for p in new_ports)
         new_pods = []
         for offset, port in enumerate(new_ports):
-            index = next_index + offset
-            env = {
-                **base_env,
-                **module_env,
-                "PYTHONPATH": python_path,
-                "KT_SERVICE_NAME": service_name,
-                "KT_SERVER_PORT": str(port),
-                "KT_REPLICA_INDEX": str(index),
-                "KT_POD_NAME": f"{service_name}-{index}",
-                "KT_LAUNCH_ID": launch_id,
-                "LOCAL_IPS": local_ips,
-            }
-            log_path = service_dir / f"pod-{index}.log"
-            log_file = open(log_path, "ab")
-            proc = subprocess.Popen(
-                [sys.executable, "-m", "kubetorch_tpu.serving.server",
-                 "--host", "127.0.0.1", "--port", str(port)],
-                env=env, stdout=log_file, stderr=subprocess.STDOUT,
-                start_new_session=True)
-            log_file.close()
-            new_pods.append({"pid": proc.pid, "port": port, "index": index,
-                             "log": str(log_path)})
+            new_pods.append(_spawn_pod(
+                service_name, service_dir, next_index + offset, port,
+                _claim_chips(taken, chips_per_pod),
+                base_env=base_env, module_env=module_env,
+                launch_id=launch_id, local_ips=local_ips))
         record["pods"] = pods + new_pods
         self._record_path(service_name).write_text(
             json.dumps(record, indent=2))
@@ -397,6 +334,19 @@ class LocalBackend:
             ServiceRecord({"service_name": service_name, "pods": new_pods}),
             launch_timeout, launch_id)
         return {"replicas": replicas, "launched": len(new_pods)}
+
+    def _chips_taken(self) -> set:
+        """Chip ids held by the live pods of every local service (a
+        relaunch has torn its own down by now; a scale-up keeps its own).
+        A chip belongs to one process at a time, so a new pod is given ids
+        no live pod was given; an id the host does not have makes libtpu
+        fail the pod's start-up, which surfaces as the launch's error."""
+        taken: set = set()
+        for record in self.list_services():
+            for pod in record.get("pods") or []:
+                if _pid_alive(pod["pid"]):
+                    taken.update(pod.get("chips") or [])
+        return taken
 
     def teardown(self, service_name: str, quiet: bool = False) -> bool:
         record = self.lookup(service_name)
@@ -430,30 +380,133 @@ class LocalBackend:
 
 
 def _pid_alive(pid: int) -> bool:
+    return _alive(pid, group=False)
+
+
+def _pod_base_env(compute_dict: Dict[str, Any]) -> Dict[str, str]:
+    """The client's environment as every pod of a service inherits it:
+    this package importable even when the client was launched from
+    elsewhere, and the platform pinned to what the compute asked for.
+
+    A ``tpus=`` pod must come up on the chip or not at all: with the
+    platform named, JAX raises at start-up when it is missing, and that
+    surfaces as the launch's ``StartupError`` instead of a worker quietly
+    computing on the CPU. Every other pod is pinned to the CPU so none can
+    reach for a chip another process holds. The compute's own ``env``
+    overlays this (an emulated slice in a test says ``JAX_PLATFORMS=cpu``
+    itself)."""
+    pkg_root = str(Path(__file__).resolve().parents[2])
+    python_path = os.environ.get("PYTHONPATH", "")
+    if pkg_root not in python_path.split(os.pathsep):
+        python_path = (f"{pkg_root}{os.pathsep}{python_path}"
+                       if python_path else pkg_root)
+    return {**os.environ, "PYTHONPATH": python_path,
+            "JAX_PLATFORMS": "tpu" if compute_dict.get("tpus") else "cpu"}
+
+
+def _claim_chips(taken: set, count: int) -> List[int]:
+    """The ``count`` lowest chip ids not in ``taken`` (marked taken)."""
+    chips: List[int] = []
+    candidate = 0
+    while len(chips) < count:
+        if candidate not in taken:
+            chips.append(candidate)
+            taken.add(candidate)
+        candidate += 1
+    return chips
+
+
+def _spawn_pod(service_name: str, service_dir: Path, index: int, port: int,
+               chips: List[int], *, base_env: Dict[str, str],
+               module_env: Dict[str, str], launch_id: str,
+               local_ips: str) -> Dict[str, Any]:
+    """Start one pod-server subprocess, confined to ``chips`` when it has
+    any; returns its service-record row."""
+    env = {
+        **base_env,
+        **(chip_env(chips, [free_port()]) if chips else {}),
+        **module_env,
+        "PYTHONPATH": base_env["PYTHONPATH"],
+        "KT_SERVICE_NAME": service_name,
+        "KT_SERVER_PORT": str(port),
+        "KT_REPLICA_INDEX": str(index),
+        "KT_POD_NAME": f"{service_name}-{index}",
+        "KT_LAUNCH_ID": launch_id,
+        "LOCAL_IPS": local_ips,
+    }
+    log_path = service_dir / f"pod-{index}.log"
+    with open(log_path, "ab") as log_file:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "kubetorch_tpu.serving.server",
+             "--host", "127.0.0.1", "--port", str(port)],
+            env=env, stdout=log_file, stderr=subprocess.STDOUT,
+            start_new_session=True)
+    return {"pid": proc.pid, "port": port, "index": index,
+            "log": str(log_path), "chips": chips}
+
+
+def _alive(pid: int, group: bool) -> bool:
+    """Whether ``pid`` — or, with ``group``, any process of the group it
+    leads — still runs. The dead do not count: a zombie answers signal 0
+    until its parent collects it (an orphaned worker waits on init for
+    that), but it holds nothing, the chip included."""
     try:
-        os.kill(pid, 0)
-        return True
-    except (ProcessLookupError, PermissionError):
+        os.waitpid(pid, os.WNOHANG)     # collect our own dead pod server
+    except ChildProcessError:
+        pass
+    try:
+        (os.killpg if group else os.kill)(pid, 0)
+    except ProcessLookupError:
         return False
+    except PermissionError:
+        return True
+    try:
+        entries = ([e for e in os.listdir("/proc") if e.isdigit()]
+                   if group else [str(pid)])
+    except OSError:
+        return True                     # no /proc to tell the dead apart
+    for entry in entries:
+        try:
+            # "pid (comm) state ppid pgrp ..."; comm may hold anything
+            state, _, pgrp = Path(f"/proc/{entry}/stat").read_text(
+                ).rsplit(")", 1)[1].split()[:3]
+        except (OSError, ValueError, IndexError):
+            continue                    # exited while we looked
+        if state not in "ZX" and (not group or int(pgrp) == pid):
+            return True
+    return False
+
+
+def _gone(pid: int, timeout: float, group: bool) -> bool:
+    deadline = time.time() + timeout
+    while _alive(pid, group):
+        if time.time() >= deadline:
+            return False
+        time.sleep(0.02)
+    return True
 
 
 def _kill_tree(pid: int):
-    """SIGTERM the pod server's process group (it leads a session)."""
+    """Stop a pod and return once every process of it is gone — the worker
+    among them, which is the one that holds the chip.
+
+    SIGTERM goes to the pod server alone first: its SIGTERM sequence (drain,
+    emergency checkpoint, report) asks the workers to save, and a worker
+    signalled at the same moment is a corpse the server then waits on for
+    its whole budget. The rest of its process group (it leads a session)
+    follows, and SIGKILL for whatever is left."""
     try:
-        os.killpg(pid, signal.SIGTERM)
+        os.kill(pid, signal.SIGTERM)
+        _gone(pid, 3.0, group=False)
     except (ProcessLookupError, PermissionError):
+        pass                # the server is gone already; its group may not be
+    for sig, wait in ((signal.SIGTERM, 2.0), (signal.SIGKILL, 5.0)):
         try:
-            os.kill(pid, signal.SIGTERM)
+            os.killpg(pid, sig)
         except (ProcessLookupError, PermissionError):
             return
-    deadline = time.time() + 3.0
-    while time.time() < deadline and _pid_alive(pid):
-        time.sleep(0.1)
-    if _pid_alive(pid):
-        try:
-            os.killpg(pid, signal.SIGKILL)
-        except (ProcessLookupError, PermissionError):
-            pass
+        if _gone(pid, wait, group=True):
+            return
 
 
 def _log_tail(log_path: str, lines: int = 60) -> str:

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+from kubetorch_tpu.config import env_str
 from kubetorch_tpu.resources.compute.compute import (
     KUEUE_QUEUE_LABEL,
     Compute,
@@ -140,6 +141,7 @@ def build_pod_template(
     env = {**compute.env, **(env or {})}
     env.setdefault("KT_SERVICE_NAME", service_name)
     env.setdefault("KT_SERVER_PORT", str(SERVER_PORT))
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", env_str("KT_JAX_CACHE_DIR"))
     env_list = [{"name": k, "value": str(v)} for k, v in sorted(env.items())]
     # Downward-API-free pod identity (reference: http_server.py:146-185
     # derives identity without it; we inject the cheap fields anyway).

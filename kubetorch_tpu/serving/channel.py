@@ -1,10 +1,9 @@
 """Persistent, multiplexed call channel to a pod server.
 
 The per-call POST path pays one connection + header negotiation + two
-full serialize/deserialize hops per call — BENCH_r05 measured that fixed
-cost at ~103 ms/call on the serving staging path, which is the whole gap
-between on-device rolling decode (6,850 tok/s) and the tunnel-wall rate
-(4,168 tok/s). This channel removes the per-call share of that cost:
+full serialize/deserialize hops per call, a fixed cost a client-driven
+decode loop pays on every chunk. This channel removes the per-call share
+of that cost:
 
 - **one long-lived WebSocket** (``GET /_channel`` on the pod server)
   carries every call — connection and header cost amortize to zero;
@@ -46,7 +45,7 @@ reconnects and the written/unwritten distinction exact.
 Every call handle carries a latency decomposition (client serialize,
 wire, server queue, worker dispatch, device) — the same stages the
 Prometheus histograms in ``observability/prometheus.py`` record — so the
-tunnel-wall vs device gap stays a measured number.
+gap between the call path's wall and device time stays a measured number.
 
 The channel owns a private event-loop thread; ``submit``/``result`` are
 called from ordinary (sync) code. Wire format: one WebSocket binary
@@ -231,7 +230,7 @@ class ChannelCall:
         self._exc = exc
         # record=False: a transport failure's wall time (which can be
         # the whole pending duration) is not a round trip — it would
-        # poison the wire histogram the tunnel decomposition is built on
+        # poison the wire histogram the latency decomposition is built on
         self._finish({}, record=False)
 
     def _finish(self, server_t: Dict[str, float], record: bool = True):

@@ -1,12 +1,10 @@
 """Server-resident continuous-batching decode engine.
 
-BENCH_r05 measured rolling decode at 6,850 tok/s device-side but only
-4,168 tok/s through the tunnel: the client still *drove* every 8-step
-chunk over the channel, paying ~144 ms of dispatch per chunk, and the
-Poisson phase lost another 182 ms per admission because admission
-swapped whole rolling batches. Both taxes have the same root cause —
-the generation loop lived on the wrong side of the wire. This module
-moves it server-side:
+A client that *drives* every decode chunk over the channel pays a call
+round trip per chunk, and admission that swaps whole rolling batches
+stalls every live row. Both taxes have the same root cause — the
+generation loop living on the wrong side of the wire. This module moves
+it server-side:
 
 - the client submits ONE **generation program** — prompt(s), stopping
   criteria, sampling params, an optional deadline — as a single
@@ -1522,6 +1520,12 @@ class DecodeEngine:
                         except Exception:  # noqa: BLE001
                             pass
                         self._release_locked(rid)
+            # Hand the lock over. This thread takes it again at once and
+            # Python's locks are not fair: a submit, park or stats call
+            # waiting on it otherwise starves until the batch drains (a
+            # park then finds its row already finished). Yielding the
+            # GIL lets the thread the release just woke run first.
+            time.sleep(0)
 
     def _tick_locked(self) -> None:
         eng = self.engine

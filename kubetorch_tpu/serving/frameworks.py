@@ -2,7 +2,7 @@
 
 The JAX path is primary (reference had it as an afterthought:
 ``serving/spmd/jax_process.py:8`` sets JAX_COORDINATOR_ADDRESS / PROCESS_ID /
-NUM_PROCESSES / LOCAL_DEVICE_IDS; torch at ``spmd/pytorch_process.py:19`` sets
+NUM_PROCESSES; torch at ``spmd/pytorch_process.py:19`` sets
 MASTER_ADDR/PORT). Ranks are assigned ICI-topology-aware when TPU slice
 metadata is present: workers of one slice are ordered by
 ``TPU_WORKER_HOSTNAMES``/``TPU_WORKER_ID`` so the jax.distributed process ids
@@ -118,16 +118,17 @@ class JaxProcess(FrameworkProcess):
         for key, value in os.environ.items():
             if key.startswith("MEGASCALE_"):
                 env.setdefault(key, value)
-        if self.num_procs > 1:
-            # Multiple jax processes on one host must split local chips.
-            env["JAX_LOCAL_DEVICE_IDS"] = str(local_rank)
-        # Persistent compilation cache: reload-heavy iteration (the
-        # kubetorch UX) recompiles identical programs on every worker
-        # restart; caching cuts warm-deploy first-call latency from tens of
-        # seconds to ~none. Point KT_JAX_CACHE_DIR at a mounted volume to
-        # survive pod reschedules.
-        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-            env["JAX_COMPILATION_CACHE_DIR"] = env_str("KT_JAX_CACHE_DIR")
+        visible = os.environ.get("TPU_VISIBLE_CHIPS")
+        if self.num_procs > 1 and visible:
+            # Several jax processes in a pod the launcher confined to its
+            # own chips (LocalBackend) split them evenly through libtpu's
+            # per-process contract; left alone, the first to initialise
+            # opens them all and the rest fail or hang.
+            from kubetorch_tpu.resources.compute.topology import chip_env
+
+            ports = [self.port + 1 + i for i in range(self.num_procs)]
+            env.update(chip_env([int(c) for c in visible.split(",")],
+                                ports, task=local_rank))
         return env
 
     @staticmethod
@@ -145,7 +146,7 @@ class JaxProcess(FrameworkProcess):
 
     def cleanup_env(self) -> List[str]:
         return ["JAX_COORDINATOR_ADDRESS", "JAX_NUM_PROCESSES",
-                "JAX_PROCESS_ID", "JAX_LOCAL_DEVICE_IDS"]
+                "JAX_PROCESS_ID"]
 
 
 class PyTorchProcess(FrameworkProcess):

@@ -587,6 +587,10 @@ def worker_main(request_q, response_q, env: Dict[str, str]):
     """Entrypoint of the spawned process."""
     for key, value in env.items():
         os.environ[key] = str(value)
+    # before user code can import jax: this is the process that compiles
+    from kubetorch_tpu.config import compile_cache_dir
+
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
     # before any lock is created: a KT_SAN=1 session instruments the
     # worker too (engine scheduler locks live HERE) — its graph
     # piggybacks to the pod on call responses (_attach_worker_metrics)

@@ -24,7 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.models import llama
 from kubetorch_tpu.parallel import collectives
-from kubetorch_tpu.parallel.mesh import use_mesh
 from kubetorch_tpu.parallel.sharding import ShardingRules, named_sharding
 
 TrainState = Dict[str, Any]
@@ -117,7 +116,7 @@ def make_train_step(
     mesh: Optional[Mesh] = None,
     accum_steps: int = 1,
 ) -> Callable[[TrainState, Dict[str, jax.Array]], tuple]:
-    """Build the jitted train step. Call under ``use_mesh(mesh)``
+    """Build the jitted train step. Call under ``jax.set_mesh(mesh)``
     (the Trainer does this) so PartitionSpec constraints resolve.
 
     ``accum_steps > 1`` splits the batch's leading dim into that many
@@ -243,7 +242,7 @@ class Trainer:
         self._store_key: Optional[str] = None
         self._ckpt_every = 0
         self._step_count = 0
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.state = init_train_state(
                 jax.random.key(seed), cfg, mesh, self.optimizer, self.rules,
                 init_fn=init_fn)
@@ -345,7 +344,7 @@ class Trainer:
             # the local dir survived: the emergency path writes the
             # blocking local save at the same step it pushes, so a
             # surviving dir is never behind the store copy
-            with use_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 self.state = self.checkpoint.restore(self.state)
             step, source = int(latest), "local"
         else:
@@ -386,7 +385,7 @@ class Trainer:
             # next jitted step
             return NamedSharding(self.mesh, PartitionSpec())
 
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.state = jax.tree.map(
                 lambda cur, new: jax.device_put(new, _placement(cur)),
                 self.state, fetched["state"])
@@ -410,7 +409,7 @@ class Trainer:
                               self._step_count, store_key=self._store_key)
 
     def step(self, batch: Dict[str, jax.Array]):
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.state, metrics = self._step(self.state, batch)
         self._step_count += 1
         if self._coll_stats is not None:
@@ -429,21 +428,17 @@ class Trainer:
 
     def benchmark(self, batch: Dict[str, jax.Array], n_steps: int = 10,
                   warmup: int = 2) -> Dict[str, float]:
-        """Steady-state step time + tokens/sec (excludes compile).
-
-        The timed region is closed with a host fetch of the last step's loss
-        (which depends on the whole step chain) — ``block_until_ready`` alone
-        is not trusted because remote/relayed TPU backends have been observed
-        to return from it without forcing execution.
-        """
+        """Steady-state step time + tokens/sec (excludes compile). The
+        timed region ends when the last step's loss — which depends on the
+        whole step chain — is ready."""
         for _ in range(warmup):
             metrics = self.step(batch)
         if warmup:
-            float(jax.device_get(metrics["loss"]))
+            jax.block_until_ready(metrics["loss"])
         t0 = time.perf_counter()
         for _ in range(n_steps):
             metrics = self.step(batch)
-        loss = float(jax.device_get(metrics["loss"]))
+        loss = float(jax.block_until_ready(metrics["loss"]))
         dt = (time.perf_counter() - t0) / n_steps
         tokens = int(batch["inputs"].shape[0] * batch["inputs"].shape[1])
         return {

@@ -57,7 +57,7 @@ def test_checkpoint_save_restore_sharded(tmp_path):
     import optax
 
     from kubetorch_tpu.models import LlamaConfig
-    from kubetorch_tpu.parallel import MeshSpec, use_mesh
+    from kubetorch_tpu.parallel import MeshSpec
     from kubetorch_tpu.training import Trainer
     from kubetorch_tpu.training.checkpoint import CheckpointManager
 

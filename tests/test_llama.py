@@ -7,7 +7,7 @@ import optax
 import pytest
 
 from kubetorch_tpu.models import LlamaConfig, llama
-from kubetorch_tpu.parallel import MeshSpec, ShardingRules, use_mesh
+from kubetorch_tpu.parallel import MeshSpec, ShardingRules
 from kubetorch_tpu.training import Trainer, cross_entropy_loss
 
 
@@ -95,7 +95,7 @@ def test_sharded_forward_matches_single_device(tiny_cfg, mesh):
     from kubetorch_tpu.training.trainer import param_shardings
     shardings = param_shardings(tiny_cfg, mesh, rules)
     sharded_params = jax.device_put(params, shardings)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda p, t: llama.forward(p, t, tiny_cfg, rules)
         )(sharded_params, batch["inputs"])
@@ -183,7 +183,7 @@ def test_moe_capacity_sharded_matches_unsharded():
     rules = ShardingRules.default()
     from kubetorch_tpu.training.trainer import param_shardings
     sharded = jax.device_put(params, param_shardings(cfg, mesh, rules))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, t: llama.forward(p, t, cfg, rules))(
             sharded, batch["inputs"])
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
@@ -199,7 +199,7 @@ def test_moe_sharded_matches_unsharded():
     rules = ShardingRules.default()
     from kubetorch_tpu.training.trainer import param_shardings
     sharded = jax.device_put(params, param_shardings(cfg, mesh, rules))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, t: llama.forward(p, t, cfg, rules))(
             sharded, batch["inputs"])
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),

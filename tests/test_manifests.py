@@ -159,12 +159,21 @@ def test_jax_process_multislice_global_ids(monkeypatch):
     assert env["JAX_PROCESS_ID"] == "3"
     # coordinator = worker 0's hostname (process 0), not pod_ips[0]
     assert env["JAX_COORDINATOR_ADDRESS"] == "h0.svc:8476"
-    # persistent compile cache on by default (overridable via env)
-    assert env["JAX_COMPILATION_CACHE_DIR"] == "/tmp/kt-jax-cache"
+    # the compile cache is placed from outside, never by the rank env:
+    # the K8s pod template injects it, local processes use the helper
+    assert "JAX_COMPILATION_CACHE_DIR" not in env
+
+    def pod_cache_dir(compute):
+        template = build_deployment_manifest("svc", compute)[
+            "spec"]["template"]
+        return {e["name"]: e.get("value") for e in template["spec"][
+            "containers"][0]["env"]}["JAX_COMPILATION_CACHE_DIR"]
+
+    assert pod_cache_dir(kt.Compute(cpus="1")) == "/tmp/kt-jax-cache"
     monkeypatch.setenv("KT_JAX_CACHE_DIR", "/ktfs/cache/jax")
-    env = proc.rank_env(node_rank=0, local_rank=0, num_nodes=1,
-                        pod_ips=["10.0.0.1"])
-    assert env["JAX_COMPILATION_CACHE_DIR"] == "/ktfs/cache/jax"
+    assert pod_cache_dir(kt.Compute(cpus="1")) == "/ktfs/cache/jax"
+    assert pod_cache_dir(kt.Compute(cpus="1", env={
+        "JAX_COMPILATION_CACHE_DIR": "/mine"})) == "/mine"
 
 
 def test_knative_manifest_with_autoscaling():

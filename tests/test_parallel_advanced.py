@@ -136,6 +136,45 @@ def test_flash_attention_grads_under_jit_and_mixed_blocks():
                                rtol=2e-3, atol=2e-3)
 
 
+def test_flash_step_under_a_mesh_runs_per_shard(monkeypatch):
+    """A Mosaic kernel cannot be partitioned by XLA: on the chip a sharded
+    train step died with "wrap the call in a shard_map", and interpret
+    mode never shows it. Under a multi-device mesh the model runs the
+    flash kernels per shard, over batch (fsdp) and heads (tp): the step's
+    loss is the single-device one, and — lowered for the TPU from this
+    host, the kernels NOT interpreted — Mosaic accepts it."""
+    import optax
+
+    import kubetorch_tpu.ops.flash_attention as fa
+    from kubetorch_tpu.models import LlamaConfig
+    from kubetorch_tpu.parallel import MeshSpec
+    from kubetorch_tpu.training import Trainer, make_train_step
+
+    cfg = LlamaConfig.tiny(head_dim=128, n_heads=4, n_kv_heads=2,
+                           attn_impl="flash", max_seq_len=128)
+    toks = np.random.default_rng(0).integers(0, cfg.vocab_size, (4, 129))
+    data = {"inputs": jnp.asarray(toks[:, :-1], jnp.int32),
+            "targets": jnp.asarray(toks[:, 1:], jnp.int32)}
+    single = Trainer(cfg, MeshSpec().build(jax.devices()[:1]),
+                     optax.adamw(1e-3), seed=0)
+    sharded = Trainer(cfg, MeshSpec(fsdp=2, tp=2).build(jax.devices()[:4]),
+                      optax.adamw(1e-3), seed=0)
+    abstract = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding), sharded.state)
+    np.testing.assert_allclose(float(sharded.step(data)["loss"]),
+                               float(single.step(data)["loss"]), rtol=1e-5)
+
+    # a fresh jit of the same step, its kernels not interpreted
+    compiled_kernel = fa.flash_attention
+    monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw:
+                        compiled_kernel(*a, **{**kw, "interpret": False}))
+    with jax.set_mesh(sharded.mesh):
+        for_tpu = make_train_step(
+            cfg, sharded.optimizer, mesh=sharded.mesh).trace(
+                abstract, data).lower(lowering_platforms=("tpu",)).as_text()
+    assert for_tpu.count("tpu_custom_call") >= 3        # fwd, dq, dk/dv
+
+
 def test_flash_attention_fallback_on_odd_shapes():
     q, k, v = _qkv(S=100, D=16)  # not tileable -> XLA path
     ref = dot_product_attention(q, k, v, causal=True)
@@ -207,7 +246,7 @@ def test_no_involuntary_remat_in_sharded_train_steps(capfd):
     round 1's pipeline entry resharded every layer param this way)."""
     import optax
 
-    from kubetorch_tpu.parallel import ShardingRules, use_mesh
+    from kubetorch_tpu.parallel import ShardingRules
     from kubetorch_tpu.training import (
         cross_entropy_loss,
         init_train_state,
@@ -241,7 +280,7 @@ def test_no_involuntary_remat_in_sharded_train_steps(capfd):
         capfd.readouterr()
         for mesh, rules, loss_fn, label in layouts:
             optimizer = optax.adamw(1e-3)
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = init_train_state(
                     jax.random.key(0), cfg, mesh, optimizer, rules)
                 step = make_train_step(cfg, optimizer, rules,

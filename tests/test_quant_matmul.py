@@ -93,10 +93,10 @@ def test_viability_gate():
 def test_viability_gate_rejects_live_mesh():
     """Under a >1-device mesh the einsum path must win (an unpartitioned
     pallas call would force operand all-gathers)."""
-    from kubetorch_tpu.parallel.mesh import MeshSpec, use_mesh
+    from kubetorch_tpu.parallel.mesh import MeshSpec
 
     x = jnp.zeros((2, 1, 64), jnp.bfloat16)
     w8 = jnp.zeros((64, 128), jnp.int8)
     s = jnp.zeros((128,), jnp.bfloat16)
-    with use_mesh(MeshSpec(fsdp=-1).build()):
+    with jax.set_mesh(MeshSpec(fsdp=-1).build()):
         assert not quant_matmul.decode_matmul_viable(x, w8, s)

@@ -140,7 +140,7 @@ def test_rolling_under_tp_mesh(model):
     import jax as _jax
 
     from kubetorch_tpu.models import llama as _llama
-    from kubetorch_tpu.parallel import MeshSpec, use_mesh
+    from kubetorch_tpu.parallel import MeshSpec
     from kubetorch_tpu.parallel.sharding import (
         ShardingRules,
         named_sharding,

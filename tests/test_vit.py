@@ -8,7 +8,7 @@ import pytest
 
 from kubetorch_tpu.models import ViTConfig
 from kubetorch_tpu.models import vit
-from kubetorch_tpu.parallel import MeshSpec, ShardingRules, named_sharding, use_mesh
+from kubetorch_tpu.parallel import MeshSpec, ShardingRules, named_sharding
 
 
 @pytest.fixture(scope="module")
@@ -54,7 +54,7 @@ def test_sharded_forward_matches(cfg):
         lambda ax: named_sharding(mesh, rules, *ax), axes,
         is_leaf=lambda x: isinstance(x, tuple))
     sharded = jax.device_put(params, shardings)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda p, x: vit.forward(p, x, cfg, rules))(
             sharded, images)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
