@@ -29,6 +29,7 @@ Greedy rolling decode is token-identical to isolated ``Generator`` runs
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -43,6 +44,22 @@ from kubetorch_tpu.models import llama
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.models.generate import filter_logits
 from kubetorch_tpu.parallel.sharding import ShardingRules
+
+
+def _named(impl, **fixed):
+    """``partial(impl, **fixed)`` under the implementation's own name:
+    ``jax.jit`` names the executable for the function it is given, and a
+    bare ``partial`` has none, so a profiler trace would list every
+    engine executable as ``jit__unknown``."""
+    fn = partial(impl, **fixed)
+    fn.__name__ = impl.__name__
+    fn.__qualname__ = impl.__qualname__
+    return fn
+
+
+def _ctx_admit_impl(ctx, valid, rows, slots):
+    return (ctx.at[slots].set(rows, mode="drop"),
+            valid.at[slots].set(False, mode="drop"))
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -296,26 +313,33 @@ class RollingGenerator:
         # MFU/MBU gauges.
         self._devstats = devstats.ExecutableCosts()
         self._devstats_peaks: Any = "unset"
+        # ``tick_phase(name)`` -> context manager. The serving engine
+        # installs its phase timer here so that a decode chunk reports
+        # its own halves (``decode_dispatch`` up to the return of the
+        # jitted call, ``decode_sync`` the one blocking read) and its
+        # host bookkeeping (``route``) where they happen. Hand-driven
+        # (or warmed before an engine exists) every phase is a no-op.
+        self.tick_phase = contextlib.nullcontext
 
         # Donation matters here: the cache grid is the largest buffer in
         # the server and every call rewrites it — aliasing in/out keeps
         # updates in place.
         self._prefill = jax.jit(
-            partial(self._prefill_impl, cfg=cfg, rules=self.rules),
+            _named(self._prefill_impl, cfg=cfg, rules=self.rules),
             static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4))
         self._decode = jax.jit(
-            partial(self._decode_impl, cfg=cfg, rules=self.rules),
+            _named(self._decode_impl, cfg=cfg, rules=self.rules),
             static_argnames=("top_k", "top_p", "n_steps"),
             donate_argnums=(1, 2, 3))
         self._prefix_fill = jax.jit(
-            partial(self._prefix_fill_impl, cfg=cfg, rules=self.rules,
-                    quantized=self.kv_quantized),
+            _named(self._prefix_fill_impl, cfg=cfg, rules=self.rules,
+                   quantized=self.kv_quantized),
             static_argnames=("p_pad",))
         self._prefill_px = jax.jit(
-            partial(self._prefill_px_impl, cfg=cfg, rules=self.rules),
+            _named(self._prefill_px_impl, cfg=cfg, rules=self.rules),
             static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4))
         self._prefill_ext = jax.jit(
-            partial(self._prefill_extend_impl, cfg=cfg, rules=self.rules),
+            _named(self._prefill_extend_impl, cfg=cfg, rules=self.rules),
             static_argnames=("C",), donate_argnums=(1, 2, 3, 4))
         if self.adapters is not None:
             # hot-load: write ONE adapter's factors into a slot of the
@@ -335,15 +359,12 @@ class RollingGenerator:
                                           donate_argnums=(0,))
         if self.spec:
             self._decode_sp = jax.jit(
-                partial(self._decode_spec_impl, cfg=cfg, rules=self.rules),
+                _named(self._decode_spec_impl, cfg=cfg, rules=self.rules),
                 static_argnames=("k", "ngram", "n_rounds", "top_k",
                                  "top_p", "sampling"),
                 donate_argnums=(1, 3, 5, 6, 7))
-            self._ctx_admit = jax.jit(
-                lambda ctx, valid, rows, slots: (
-                    ctx.at[slots].set(rows, mode="drop"),
-                    valid.at[slots].set(False, mode="drop")),
-                donate_argnums=(0, 1))
+            self._ctx_admit = jax.jit(_ctx_admit_impl,
+                                      donate_argnums=(0, 1))
 
     def _check_adapter_id(self, adapter_id: int) -> None:
         if adapter_id >= 0 and self.adapters is None:
@@ -524,7 +545,7 @@ class RollingGenerator:
         self.prefill_step()
         return self.decode_step()
 
-    def admit(self, max_rows: Optional[int] = None) -> int:
+    def admit(self, max_rows: Optional[int] = None) -> List[int]:
         """Row-granular admission: move queued requests into free rows of
         the LIVE batch (at most ``max_rows`` this wave). Short prompts
         take the grouped private-cache prefill + splice path
@@ -532,19 +553,20 @@ class RollingGenerator:
         ``prefill_chunk`` enter CHUNKED prefill — their row is claimed
         now but fills one :meth:`prefill_step` chunk at a time, so a long
         prompt never blocks the decode cadence of the rows around it.
-        Returns the number of rows claimed.
+        Returns the rids that left the queue, in admission order (as
+        :meth:`prefill_step` returns the rids it activated).
 
         Batched admission: all same-(bucket, prefix) arrivals prefill in
         ONE call (a per-call dispatch costs more than the prefill compute
         for short prompts; grouping cuts admission dispatches
         ~max_slots×)."""
-        admitted = 0
+        admitted: List[int] = []
         by_key: Dict[tuple, List[Request]] = {}
         while self._free and self._queue and (
-                max_rows is None or admitted < max_rows):
+                max_rows is None or len(admitted) < max_rows):
             req = self._queue.pop(0)
             req.slot = self._free.pop(0)
-            admitted += 1
+            admitted.append(req.rid)
             if (self.prefill_chunk is not None
                     and req.prefix_id is None
                     and len(req.prompt) > self.prefill_chunk):
@@ -1113,36 +1135,36 @@ class RollingGenerator:
                 "scale": float(self.adapter_scale)}
 
     def _mesh_ctx(self):
-        import contextlib
-
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
 
     def _decode_chunk(self) -> List[Tuple[int, List[int], bool]]:
-        self._rng, key = jax.random.split(self._rng)
-        with self._mesh_ctx():
-            (self.cache, self._logits, self._dpos,
-             toks) = self._devstats.call(
-                "decode", self.steps_per_call, self._decode,
-                self.params, self.cache, self._logits, self._dpos,
-                self._dactive, jnp.asarray(self._temps),
-                jnp.asarray(self._penalties), jnp.asarray(self._win), key,
-                self._lora(self._slot_adapter),
-                top_k=self.top_k, top_p=self.top_p,
-                n_steps=self.steps_per_call)
-        toks = np.asarray(toks)                       # [K, B] — the one sync
-        # roll the host-side penalty windows by this chunk's tokens
-        K = toks.shape[0]
-        W = self._win.shape[1]
-        if K >= W:
-            self._win[:] = toks[-W:].T
-        else:
-            self._win[:, :-K] = self._win[:, K:]
-            self._win[:, -K:] = toks.T
-
-        return self._finish_events(
-            {slot: [int(t) for t in toks[:, slot]]
-             for slot in self._slots})
+        with self.tick_phase("decode_dispatch"):
+            self._rng, key = jax.random.split(self._rng)
+            with self._mesh_ctx():
+                (self.cache, self._logits, self._dpos,
+                 toks) = self._devstats.call(
+                    "decode", self.steps_per_call, self._decode,
+                    self.params, self.cache, self._logits, self._dpos,
+                    self._dactive, jnp.asarray(self._temps),
+                    jnp.asarray(self._penalties), jnp.asarray(self._win),
+                    key, self._lora(self._slot_adapter),
+                    top_k=self.top_k, top_p=self.top_p,
+                    n_steps=self.steps_per_call)
+        with self.tick_phase("decode_sync"):
+            toks = np.asarray(toks)                   # [K, B] — the one sync
+        with self.tick_phase("route"):
+            # roll the host-side penalty windows by this chunk's tokens
+            K = toks.shape[0]
+            W = self._win.shape[1]
+            if K >= W:
+                self._win[:] = toks[-W:].T
+            else:
+                self._win[:, :-K] = self._win[:, K:]
+                self._win[:, -K:] = toks.T
+            return self._finish_events(
+                {slot: [int(t) for t in toks[:, slot]]
+                 for slot in self._slots})
 
     def _decode_spec_chunk(self) -> List[Tuple[int, List[int], bool]]:
         """One dispatch = ``steps_per_call`` verify rounds; each round
@@ -1157,61 +1179,64 @@ class RollingGenerator:
         the shared forward — rows at different ``k`` coexist in one
         chunk-mode dispatch, and an all-collapsed batch (every row at
         k = 1) dispatches the width-1 forward, i.e. plain decode."""
-        # STICKY sampling flag: the first sampled request upgrades the
-        # dispatch to the sampling executable and it stays there rather
-        # than flapping between the greedy and sampling executables per
-        # occupancy mix
-        if not self._spec_sampling and any(
-                self._slots[s].temperature > 0 for s in self._slots):
-            self._spec_sampling = True
-        kk = np.ones(self.max_slots, np.int32)
-        for slot in self._slots:
-            st = self._spec_state.get(slot)
-            if st is None:      # imported/hand-driven rows late-create
-                st = self._spec_state[slot] = LookaheadState(
-                    self.spec_k, self.spec_cap)
-            kk[slot] = st.k
-        k_widest = max((int(kk[s]) for s in self._slots), default=1)
-        kd = 1
-        while kd < k_widest:
-            kd *= 2
-        kd = max(1, min(kd, self.spec_k))
-        self._rng, key = jax.random.split(self._rng)
-        with self._mesh_ctx():
-            (self.cache, self._dpos, self._ctx, self._dnt,
-             self._dnt_valid, toks, emits) = self._devstats.call(
-                "decode_spec", (kd, self._spec_sampling), self._decode_sp,
-                self.params, self.cache, self._logits, self._dpos,
-                self._dactive, self._ctx, self._dnt, self._dnt_valid,
-                jnp.asarray(self._temps), jnp.asarray(kk), key,
-                self._lora(self._slot_adapter),
-                k=kd, ngram=self.spec_ngram,
-                n_rounds=self.steps_per_call,
-                top_k=self.top_k, top_p=self.top_p,
-                sampling=self._spec_sampling)
-        toks = np.asarray(toks)                # [R, B, kd] — the one sync
-        emits = np.asarray(emits)              # [R, B]
-        R = toks.shape[0]
-        new_by_slot: Dict[int, List[int]] = {}
-        for slot in self._slots:
-            new: List[int] = []
-            for r in range(R):
-                e = int(emits[r, slot])
-                if e:
-                    new.extend(int(t) for t in toks[r, slot, :e])
-            new_by_slot[slot] = new
-            self._spec_rounds += R
-            self._spec_emitted += len(new)
-            # fold this chunk's acceptance into the row's EMA, then one
-            # adaptation move (grow/shrink/probe) for the next chunk
-            st = self._spec_state[slot]
-            k_used = int(kk[slot])
-            self._spec_drafted += R * (k_used - 1)
-            for r in range(R):
-                st.observe(int(emits[r, slot]), k_used,
-                           alpha=self.spec_ema_alpha)
-            st.adapt(self.spec_k, self.spec_cap)
-        return self._finish_events(new_by_slot)
+        with self.tick_phase("decode_dispatch"):
+            # STICKY sampling flag: the first sampled request upgrades the
+            # dispatch to the sampling executable and it stays there rather
+            # than flapping between the greedy and sampling executables per
+            # occupancy mix
+            if not self._spec_sampling and any(
+                    self._slots[s].temperature > 0 for s in self._slots):
+                self._spec_sampling = True
+            kk = np.ones(self.max_slots, np.int32)
+            for slot in self._slots:
+                st = self._spec_state.get(slot)
+                if st is None:      # imported/hand-driven rows late-create
+                    st = self._spec_state[slot] = LookaheadState(
+                        self.spec_k, self.spec_cap)
+                kk[slot] = st.k
+            k_widest = max((int(kk[s]) for s in self._slots), default=1)
+            kd = 1
+            while kd < k_widest:
+                kd *= 2
+            kd = max(1, min(kd, self.spec_k))
+            self._rng, key = jax.random.split(self._rng)
+            with self._mesh_ctx():
+                (self.cache, self._dpos, self._ctx, self._dnt,
+                 self._dnt_valid, toks, emits) = self._devstats.call(
+                    "decode_spec", (kd, self._spec_sampling), self._decode_sp,
+                    self.params, self.cache, self._logits, self._dpos,
+                    self._dactive, self._ctx, self._dnt, self._dnt_valid,
+                    jnp.asarray(self._temps), jnp.asarray(kk), key,
+                    self._lora(self._slot_adapter),
+                    k=kd, ngram=self.spec_ngram,
+                    n_rounds=self.steps_per_call,
+                    top_k=self.top_k, top_p=self.top_p,
+                    sampling=self._spec_sampling)
+        with self.tick_phase("decode_sync"):
+            toks = np.asarray(toks)            # [R, B, kd] — the one sync
+            emits = np.asarray(emits)          # [R, B]
+        with self.tick_phase("route"):
+            R = toks.shape[0]
+            new_by_slot: Dict[int, List[int]] = {}
+            for slot in self._slots:
+                new: List[int] = []
+                for r in range(R):
+                    e = int(emits[r, slot])
+                    if e:
+                        new.extend(int(t) for t in toks[r, slot, :e])
+                new_by_slot[slot] = new
+                self._spec_rounds += R
+                self._spec_emitted += len(new)
+                # fold this chunk's acceptance into the row's EMA, then one
+                # adaptation move (grow/shrink/probe) for the next chunk
+                st = self._spec_state[slot]
+                k_used = int(kk[slot])
+                self._spec_drafted += R * (k_used - 1)
+                for r in range(R):
+                    st.observe(int(emits[r, slot]), k_used,
+                               alpha=self.spec_ema_alpha)
+                st.adapt(self.spec_k, self.spec_cap)
+            return self._finish_events(new_by_slot)
 
     def _finish_events(self, new_by_slot: Dict[int, List[int]]
                        ) -> List[Tuple[int, List[int], bool]]:

@@ -176,7 +176,9 @@ _m("engine_tick_errors_total", "counter",
    "Engine-loop ticks that raised (streams failed typed, loop "
    "survived).", "engine")
 _m("engine_device_seconds_total", "counter",
-   "Summed decode-chunk wall time in the engine process.", "engine")
+   "Host wall of the decode chunks' dispatch + blocking read (tick "
+   "phases decode_dispatch + decode_sync), summed; NOT device time: "
+   "the device also works off what admission queued.", "engine")
 _m("engine_queue_depth", "gauge",
    "Programs queued ahead of admission.", "engine")
 _m("engine_active_rows", "gauge", "Rows decoding.", "engine")
@@ -184,8 +186,19 @@ _m("engine_free_rows", "gauge", "Rows free for admission.", "engine")
 _m("engine_prefilling_rows", "gauge",
    "Rows mid-chunked-prefill.", "engine")
 _m("engine_ttft_seconds", "histogram",
-   "Submit-to-first-token latency per generation program; buckets "
-   "carry trace exemplars for the slowest calls.", "engine")
+   "Time from generate()'s entry (before the scheduler lock) to the "
+   "row's first frame; = lock wait + queue wait + admit-to-first. "
+   "Buckets carry trace exemplars for the slowest calls.", "engine")
+_m("engine_lock_wait_seconds", "histogram",
+   "generate()'s entry to the request queued in the generator: the "
+   "wait for the scheduler lock the driver holds through a tick (and "
+   "a session restore's or handoff import's store fetch).", "engine")
+_m("engine_queue_wait_seconds", "histogram",
+   "Queued in the generator until the tick that admits the row "
+   "starts its admission (0 for restored / imported rows).", "engine")
+_m("engine_admit_to_first_seconds", "histogram",
+   "Start of the row's admission to its first frame: the prefill, "
+   "the decode chunk after it, and routing.", "engine")
 _m("kv_blocks_used", "gauge",
    "KV blocks held by row reservations + cached prefixes.", "engine")
 _m("kv_blocks_free", "gauge",
@@ -256,13 +269,15 @@ _m("engine_row_eta_seconds", "gauge",
    "the decode-tier routing currency.", "engine")
 _m("engine_mfu", "gauge",
    "Model FLOPs utilization over the last gauge window: compiled-"
-   "executable FLOPs (cost_analysis) over measured dispatch wall x "
-   "peak FLOP/s. Only published when the chip's peaks are known.",
-   "engine")
+   "executable FLOPs (cost_analysis) over the host's dispatch wall "
+   "(engine_device_seconds_total + prefill dispatch, not device "
+   "time) x peak FLOP/s. Only published when the chip's peaks are "
+   "known.", "engine")
 _m("engine_mbu", "gauge",
    "HBM-bandwidth utilization over the last gauge window: executable "
-   "bytes-accessed over measured dispatch wall x peak HBM bytes/s. "
-   "Only published when the chip's peaks are known.", "engine")
+   "bytes-accessed over the host's dispatch wall (as engine_mfu) x "
+   "peak HBM bytes/s. Only published when the chip's peaks are "
+   "known.", "engine")
 _m("hbm_used_bytes", "gauge",
    "Accelerator memory in use, summed over this engine's local "
    "devices (absent on CPU-only pods — absent, not zero).", "engine")

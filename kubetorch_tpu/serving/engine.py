@@ -72,7 +72,12 @@ depth, active/free rows, steps, sheds — the signal the autoscaler will
 consume) plus the KV manager's ``kv_*``/``prefix_*`` set, and
 ``engine.step`` / ``engine.admit`` / ``engine.prefill`` /
 ``engine.prefix_fill`` / ``kv.offload`` / ``kv.restore`` spans into the
-worker's trace ring. Clients poll the snapshot without touching the
+worker's trace ring. The driver times its own ticks and requests
+(:class:`_TickTimer`, :class:`_ReqTimes`): tick phases and request
+lifecycle stamps from one set of ``perf_counter`` stamps, read as flat
+counters in ``stats()``, as those spans, and as ``kt.tick.<phase>``
+host events in any ``jax.profiler`` trace of the process, on the device
+events' clock. Clients poll the snapshot without touching the
 device via a channel **control frame**
 (``CallChannel.control("stats")`` — answered by the pod server
 out-of-band, no worker hop).
@@ -85,9 +90,11 @@ the host-only twin the CPU bench/tests drive the scheduler with.
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import hashlib
 import queue as _queue
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -143,6 +150,177 @@ _SPEC_K_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0)
 # engine_phase gauge encoding (fleet-mergeable: the controller routes
 # on the by-pod values, so the mapping is part of the wire contract)
 _PHASE_CODE = {"prefill": 0, "decode": 1, "mixed": 2}
+
+# The driver thread's timeline, cut into named phases. A tick runs
+# evict .. publish in this order; ``idle`` is the wait for work and
+# ``handover`` the rest of the time between two ticks (lock release, the
+# yield, taking the lock and the GIL back). A ``*_sync`` phase is a wait
+# for a device value: host work and waiting never share a counter.
+_TICK_PHASES = ("evict", "evict_sync", "admit", "prefill", "handoff",
+                "handoff_sync", "decode_dispatch", "decode_sync", "route",
+                "publish", "idle", "handover")
+_IDLE = _TICK_PHASES.index("idle")
+# a tick is slow when its wall (with the handover before it) passes this
+# many running medians; the median is over the last _WALL_RING ticks and
+# nothing is judged before _WALL_MIN of them
+_SLOW_FACTOR = 4.0
+_WALL_RING = 33
+_WALL_MIN = 8
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _no_annotation(name: str, **_kwargs):
+    return _NO_ANNOTATION
+
+
+class _Phase:
+    """One phase of :class:`_TickTimer`: a reusable (not re-entrant)
+    context manager. One pair of ``perf_counter`` stamps feeds the
+    phase's counters and the profiler annotation of the same stretch."""
+
+    __slots__ = ("timer", "index", "label", "t0", "last_s", "_child_s",
+                 "_parent", "_ann")
+
+    def __init__(self, timer: "_TickTimer", index: int):
+        self.timer = timer
+        self.index = index
+        self.label = "kt.tick." + _TICK_PHASES[index]
+        self.t0 = self.last_s = self._child_s = 0.0
+        self._parent: Optional["_Phase"] = None
+        self._ann = _NO_ANNOTATION
+
+    def __enter__(self) -> "_Phase":
+        timer = self.timer
+        self._parent = timer.open
+        timer.open = self
+        self._child_s = 0.0
+        self._ann = timer.annotate(self.label)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.last_s = dt = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        timer = self.timer
+        # exclusive time: what a nested phase took is the nested one's
+        timer.seconds[self.index] += dt - self._child_s
+        timer.calls[self.index] += 1
+        parent = timer.open = self._parent
+        if parent is not None:
+            parent._child_s += dt
+
+
+class _TickTimer:
+    """Times the engine's driver: ``timer(name)`` is the context manager
+    of one phase, ``with timer:`` one whole tick.
+
+    The same stamps are read three ways: as counters (``seconds`` /
+    ``calls`` by phase, flat ``tick_<phase>_s`` / ``_n`` in
+    ``DecodeEngine.stats()``), as spans in the ``tracing`` recorder
+    (:meth:`span`, and one ``engine.slow_tick`` per slow tick with that
+    tick's phase split), and as ``kt.tick`` / ``kt.tick.<phase>`` host
+    events inside any ``jax.profiler`` trace of the process, on the
+    clock the device events carry. Always on; with no jax in the
+    process the annotations are no-ops."""
+
+    def __init__(self):
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self.annotate = (profiler.TraceAnnotation if profiler is not None
+                         else _no_annotation)
+        self._annotate_step = (profiler.StepTraceAnnotation
+                               if profiler is not None else _no_annotation)
+        self.seconds = [0.0] * len(_TICK_PHASES)
+        self.calls = [0] * len(_TICK_PHASES)
+        self.open: Optional[_Phase] = None
+        self._phases = {name: _Phase(self, i)
+                        for i, name in enumerate(_TICK_PHASES)}
+        self.started = 0             # ticks begun: the step number
+        self.slow_ticks = 0
+        self.t0 = 0.0                # start of the current tick
+        self._t_end = time.perf_counter()   # end of the last tick
+        # ``seconds`` as they stood when the last tick ended: the split
+        # of the current tick with the handover (and idle) before it
+        self._base = list(self.seconds)
+        self._walls: List[float] = []
+        self._step = _NO_ANNOTATION
+
+    def __call__(self, name: str) -> _Phase:
+        return self._phases[name]
+
+    def __enter__(self) -> "_TickTimer":
+        self._step = self._annotate_step("kt.tick", step_num=self.started)
+        self._step.__enter__()
+        self.started += 1
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._step.__exit__(*exc)
+        now = time.perf_counter()
+        seconds, base = self.seconds, self._base
+        wall = now - self._t_end - (seconds[_IDLE] - base[_IDLE])
+        self._t_end = now
+        walls = self._walls
+        if (len(walls) >= _WALL_MIN
+                and wall > _SLOW_FACTOR * sorted(walls)[len(walls) // 2]):
+            self.slow_ticks += 1
+            if tracing.enabled():
+                attrs = {name: round(seconds[i] - base[i], 6)
+                         for i, name in enumerate(_TICK_PHASES)
+                         if seconds[i] > base[i]}
+                attrs["tick"] = self.started - 1
+                tracing.record_span("engine.slow_tick", wall, attrs=attrs)
+        if len(walls) < _WALL_RING:
+            walls.append(wall)
+        else:
+            walls[self.started % _WALL_RING] = wall
+        base[:] = seconds
+
+    def span(self, name: str, dur_s: float, keys: Tuple[str, ...],
+             *values) -> None:
+        """A span in the ``tracing`` recorder from a phase's own
+        stamps; its attrs dict exists only if the recorder is on."""
+        if tracing.enabled():
+            tracing.record_span(name, dur_s, attrs=dict(zip(keys, values)))
+
+    def mark(self, name: str, rid: int) -> None:
+        """An instant event of one request in the profiler's trace."""
+        with self.annotate(name, rid=rid):
+            pass
+
+    def stats(self) -> Dict[str, Any]:
+        out: Dict[str, Any] = {}
+        for i, name in enumerate(_TICK_PHASES):
+            out[f"tick_{name}_s"] = self.seconds[i]
+            out[f"tick_{name}_n"] = self.calls[i]
+        out["slow_ticks"] = self.slow_ticks
+        return out
+
+
+class _ReqTimes:
+    """A request's ``perf_counter`` stamps inside the engine, each set
+    where the thing happens, and the submitting call's trace id (the
+    first frame and every later tick run in the driver thread, which has
+    no ambient span)."""
+
+    __slots__ = ("t_submit", "t_queued", "t_admit", "t_first", "trace")
+
+    def __init__(self, t_submit: float, t_queued: float,
+                 trace: Optional[str]):
+        self.t_submit = t_submit
+        self.t_queued = t_queued
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.trace = trace
+
+
+# the request-lifecycle histograms (``record_hist`` families), each also
+# a flat ``<name>_sum`` / ``<name>_count`` pair in ``stats()``
+_LIFE_HISTS = ("engine_lock_wait_seconds", "engine_queue_wait_seconds",
+               "engine_admit_to_first_seconds", "engine_ttft_seconds")
+
 
 
 class GenerationProgram:
@@ -368,7 +546,11 @@ class DecodeEngine:
 
     The wrapped ``engine`` needs the :class:`RollingGenerator` driving
     surface: ``submit/admit/prefill_step/decode_step/evict`` plus the
-    ``queued/free_rows/active_rows/prefilling_rows/pending`` counts.
+    ``queued/free_rows/active_rows/prefilling_rows/pending`` counts;
+    ``admit`` and ``prefill_step`` return the rids they admitted /
+    activated, and ``decode_step`` enters the ``tick_phase`` the engine
+    installs on it around its dispatch, its blocking read and its
+    bookkeeping (``decode_dispatch`` / ``decode_sync`` / ``route``).
     Prefix sharing additionally uses ``register_prefix/drop_prefix`` and
     the ``prefill_tokens`` counter; session park/restore uses
     ``export_row/import_row``; speculative engines (``engine.spec``)
@@ -498,17 +680,17 @@ class DecodeEngine:
         self._wake = threading.Condition()
         self._sinks: Dict[int, "_queue.SimpleQueue"] = {}
         self._deadlines: Dict[int, float] = {}
-        self._submit_t: Dict[int, float] = {}   # rid -> submit stamp,
-        #                           popped at first token (feeds the
-        #                           TTFT EMA below)
-        # rid -> submitting call's trace id: the first-token tick runs
-        # in the driver thread (no ambient span), so the TTFT histogram
-        # exemplar is captured at submit and carried to the observation
-        self._submit_trace: Dict[int, Optional[str]] = {}
-        # rid -> trace id for the row's WHOLE residency (the TTFT map
-        # above is consumed at first token): the flight recorder stamps
-        # each tick with the trace ids live in the batch
-        self._row_trace: Dict[int, Optional[str]] = {}
+        # rid -> lifecycle stamps + the submitting call's trace id, for
+        # the row's whole residency (dropped in _forget_locked): feeds
+        # the lifecycle histograms at the first frame, the TTFT EMA, and
+        # the flight recorder's trace ids live in the batch
+        self._req: Dict[int, _ReqTimes] = {}
+        # histogram name -> [sum, count] of what this engine observed
+        self._life = {name: [0.0, 0] for name in _LIFE_HISTS}
+        # the driver's phase timer; the generator reports the halves of
+        # its decode chunk through it
+        self._timer = _TickTimer()
+        engine.tick_phase = self._timer
         self._exec_counts: Dict[str, int] = {}
         # seconds-per-row-freed EMA — the admission estimate's clock
         # (same role the session's ema_exec_s plays for call shedding)
@@ -570,6 +752,7 @@ class DecodeEngine:
         whose id has parked KV in the store restores it through the
         streaming path into a free row and resumes mid-generation —
         its ``prompt`` is ignored (the parked state is the program)."""
+        t_submit = time.perf_counter()
         prog = GenerationProgram.from_wire(program)
         if self._phase == "prefill" and prog.handoff is None:
             raise ValueError(
@@ -611,14 +794,11 @@ class DecodeEngine:
             deadline = (time.time() + prog.deadline_s
                         if prog.deadline_s is not None else None)
             rids: List[int] = []
-            now = time.perf_counter()
             if restored is not None:
                 rid = self._restore_locked(prog, restored)
                 rids.append(rid)
                 self._sinks[rid] = sink
-                self._submit_t[rid] = now
-                self._submit_trace[rid] = submit_trace
-                self._row_trace[rid] = submit_trace
+                self._imported_locked(rid, t_submit, submit_trace)
                 if deadline is not None:
                     self._deadlines[rid] = deadline
                 self._restores += 1
@@ -629,9 +809,7 @@ class DecodeEngine:
                                            handoff=True)
                 rids.append(rid)
                 self._sinks[rid] = sink
-                self._submit_t[rid] = now
-                self._submit_trace[rid] = submit_trace
-                self._row_trace[rid] = submit_trace
+                self._imported_locked(rid, t_submit, submit_trace)
                 if deadline is not None:
                     self._deadlines[rid] = deadline
                 self._handoff_imports += 1
@@ -711,9 +889,8 @@ class DecodeEngine:
                         rid = self.engine.submit(suffix, **kwargs)
                         rids.append(rid)
                         self._sinks[rid] = sink
-                        self._submit_t[rid] = now
-                        self._submit_trace[rid] = submit_trace
-                        self._row_trace[rid] = submit_trace
+                        self._req[rid] = _ReqTimes(
+                            t_submit, time.perf_counter(), submit_trace)
                         if deadline is not None:
                             self._deadlines[rid] = deadline
                         # prefix_pid=pid covers explicit prefix_ids too:
@@ -897,6 +1074,8 @@ class DecodeEngine:
             "pending": int(eng.pending),
             "steps": self._steps,
             "tokens": self._tokens,
+            # the HOST's wall of decode dispatch + blocking read (tick
+            # phases decode_dispatch + decode_sync), not device time
             "device_s": round(self._device_s, 6),
             "prefill_chunks": self._prefill_chunks,
             "admitted_rows": self._admitted,
@@ -924,7 +1103,14 @@ class DecodeEngine:
             # pool carries no counters of its own)
             "kv_offloads": self._parks,
             "kv_restores": self._restores,
+            # the driver's timeline by phase (seconds, calls), and ticks
+            # that dispatched a decode chunk
+            **self._timer.stats(),
+            "ticks": self._steps,
         }
+        for name, (total, count) in self._life.items():
+            out[f"{name}_sum"] = total
+            out[f"{name}_count"] = count
         # device-truth utilization + flight-recorder state (both
         # conditional — absent means "plane not active here")
         if self._mfu is not None:
@@ -1085,24 +1271,46 @@ class DecodeEngine:
                 sink.put((rid, None))
         return parked
 
-    def _record_ttft(self, ttft_s: float, rid: int,
-                     adapter: Optional[str] = None) -> None:
-        """One TTFT observation into the named-histogram family (with
-        the submit-time trace id as exemplar), behind the same
-        must-never-raise guard as the counters. Named-adapter rows
-        ALSO land in their per-adapter family — the per-tenant p99 the
-        adapter SLO objectives burn against."""
+    def _imported_locked(self, rid: int, t_submit: float,
+                         trace: Optional[str]) -> None:
+        """Lifecycle record of a row spliced in by a session restore or
+        a handoff import: it never sat in the generator's queue, so the
+        import is its admission."""
+        now = time.perf_counter()
+        req = self._req[rid] = _ReqTimes(t_submit, now, trace)
+        req.t_admit = now
+        self._timer.mark("kt.req.admit", rid)
+
+    def _first_frame_locked(self, rid: int, req: _ReqTimes, now: float,
+                            adapter: Optional[str] = None) -> None:
+        """The rid's first tokens go to its sink at ``now``: split its
+        time to first token at the record's stamps and observe each part
+        in its named-histogram family (fleet-mergeable buckets; the
+        submitting call's trace id is the exemplar, so a slow bucket is
+        one click from ``ktpu trace``), behind the same must-never-raise
+        guard as the counters. Named-adapter rows ALSO land in their
+        per-adapter TTFT family — the per-tenant p99 the adapter SLO
+        objectives burn against."""
+        req.t_first = now
+        t_admit = req.t_admit if req.t_admit is not None else now
+        ttft = now - req.t_submit
+        self._ema_ttft_s = 0.8 * self._ema_ttft_s + 0.2 * ttft
+        self._timer.mark("kt.req.first_frame", rid)
         try:
             from kubetorch_tpu.observability.prometheus import (
                 adapter_series,
                 record_hist,
             )
 
-            record_hist("engine_ttft_seconds", ttft_s,
-                        trace_id=self._submit_trace.pop(rid, None))
+            for name, value in zip(_LIFE_HISTS, (
+                    req.t_queued - req.t_submit, t_admit - req.t_queued,
+                    now - t_admit, ttft)):
+                acc = self._life[name]
+                acc[0] += value
+                acc[1] += 1
+                record_hist(name, value, trace_id=req.trace)
             if adapter is not None:
-                record_hist(adapter_series(adapter, "ttft_seconds"),
-                            ttft_s)
+                record_hist(adapter_series(adapter, "ttft_seconds"), ttft)
         # ktlint: disable=KT004 -- metrics must never break the driver tick
         except Exception:  # noqa: BLE001
             pass
@@ -1111,9 +1319,7 @@ class DecodeEngine:
     def _forget_locked(self, rid: int) -> None:
         self._sinks.pop(rid, None)
         self._deadlines.pop(rid, None)
-        self._submit_t.pop(rid, None)
-        self._submit_trace.pop(rid, None)
-        self._row_trace.pop(rid, None)
+        self._req.pop(rid, None)
 
     def _check_session_free_locked(self, session_id: str) -> None:
         if session_id in self._live_sessions:
@@ -1494,10 +1700,12 @@ class DecodeEngine:
         return bool(self.engine.pending)
 
     def _drive(self) -> None:
-        while True:
-            with self._wake:
+        timer = self._timer
+        with self._wake:
+            while True:
                 while not self._stop and not self._work_pending_locked():
-                    self._wake.wait(timeout=self._poll_s)
+                    with timer("idle"):
+                        self._wake.wait(timeout=self._poll_s)
                 if self._stop:
                     return
                 try:
@@ -1520,16 +1728,23 @@ class DecodeEngine:
                         except Exception:  # noqa: BLE001
                             pass
                         self._release_locked(rid)
-            # Hand the lock over. This thread takes it again at once and
-            # Python's locks are not fair: a submit, park or stats call
-            # waiting on it otherwise starves until the batch drains (a
-            # park then finds its row already finished). Yielding the
-            # GIL lets the thread the release just woke run first.
-            time.sleep(0)
+                # Hand the lock over. This thread takes it again at once
+                # and Python's locks are not fair: a submit, park or
+                # stats call waiting on it otherwise starves until the
+                # batch drains (a park then finds its row already
+                # finished). Yielding the GIL lets the thread the release
+                # just woke run first. Timed as its own phase: what the
+                # driver pays to get the lock and the GIL back.
+                with timer("handover"):
+                    self._wake.release()
+                    try:
+                        # ktlint: disable=KT008 -- the lock is released around the yield
+                        time.sleep(0)
+                    finally:
+                        self._wake.acquire()
 
     def _tick_locked(self) -> None:
         eng = self.engine
-        tick_t0 = time.perf_counter()
         # flight-record baseline: per-tick deltas of the cumulative
         # scheduler counters (cheap tuple of ints, taken before any
         # tick work so the record covers exactly this tick)
@@ -1538,8 +1753,81 @@ class DecodeEngine:
                    self._sheds, getattr(eng, "prefill_tokens", 0),
                    getattr(eng, "_spec_rounds", 0),
                    getattr(eng, "_spec_emitted", 0))
+        with self._timer as timer:
+            with timer("evict") as phase:
+                self._evict_expired_locked()
+                # cold-adapter installs (finished background fetches)
+                installed = (self._adapter_pool.admit_ready()
+                             if self._adapter_pool is not None else None)
+            if installed:
+                timer.span("engine.adapter_admit", phase.last_s,
+                           ("adapters",), len(installed))
+            # ---- per-row admission into the live batch ---------------
+            if eng.queued and eng.free_rows:
+                with timer("admit") as phase:
+                    rids = eng.admit(self._admit_rows or None)
+                for rid in rids:
+                    req = self._req.get(rid)
+                    if req is not None:
+                        # the phase's start: when the rid left the queue
+                        req.t_admit = phase.t0
+                        timer.mark("kt.req.admit", rid)
+                if rids:
+                    self._admitted += len(rids)
+                    _record_engine("admit", len(rids))
+                    timer.span("engine.admit", phase.last_s, ("rows",),
+                               len(rids))
+            # ---- one chunked-prefill dispatch, interleaved -----------
+            prefill_dt = 0.0
+            if eng.prefilling_rows:
+                with timer("prefill") as phase:
+                    eng.prefill_step()
+                prefill_dt = phase.last_s
+                self._prefill_s += prefill_dt
+                self._prefill_chunks += 1
+                _record_engine("prefill_chunk")
+                timer.span("engine.prefill", prefill_dt, ("rows",),
+                           eng.prefilling_rows)
+            # ---- handoff exports (disaggregated prefill tier) --------
+            # BEFORE the decode step: a handoff row must ship with zero
+            # locally-emitted tokens, and the export-publish runs in the
+            # background so row N's wire time overlaps row N+1's prefill
+            with timer("handoff"):
+                self._handoff_scan_locked()
+            # ---- one decode chunk ------------------------------------
+            # the generator times its own halves through ``tick_phase``:
+            # the dispatch, the one blocking read, and its share of
+            # ``route`` (trimming the chunk into events, freeing rows)
+            events = eng.decode_step() if self._phase != "prefill" else []
+            with timer("route"):
+                device_dt = 0.0
+                decode_tokens = sum(len(t) for _, t, _ in events)
+                if events:
+                    # the host's wall of dispatch + sync, not device
+                    # time: the device also works off what admission
+                    # left in its queue, and the host's own dispatch
+                    device_dt = (timer("decode_dispatch").last_s
+                                 + timer("decode_sync").last_s)
+                    self._steps += 1
+                    self._device_s += device_dt
+                    _record_engine("step")
+                    _record_engine("device_seconds", device_dt)
+                    timer.span("engine.step", device_dt,
+                               ("rows", "tokens"), len(events),
+                               decode_tokens)
+                self._route_locked(events)
+            # ---- the instrumentation's own bill ----------------------
+            with timer("publish"):
+                self._spec_tick_locked()
+                self._publish_gauges()
+                self._flight_append_locked(
+                    timer.t0, fl_prev, prefill_dt + device_dt,
+                    decode_tokens)
+
+    def _evict_expired_locked(self) -> None:
+        """Deadline eviction, row-granular."""
+        eng = self.engine
         now = time.time()
-        # ---- deadline eviction (row-granular) ------------------------
         for rid, dl in list(self._deadlines.items()):
             if now > dl:
                 meta = self._rid_meta.get(rid) or {}
@@ -1553,8 +1841,9 @@ class DecodeEngine:
                     # client knows the budget passed; a resume with the
                     # same session_id picks up where the deadline hit
                     try:
-                        state = eng.export_row(
-                            rid, block_tokens=self._kv.block_tokens)
+                        with self._timer("evict_sync"):
+                            state = eng.export_row(
+                                rid, block_tokens=self._kv.block_tokens)
                     except (KeyError, ValueError):
                         state = None
                 if state is not None and meta.get("adapter") is not None:
@@ -1579,54 +1868,10 @@ class DecodeEngine:
                         + (f" (session {session} parking in background)"
                            if state is not None else ""),
                         deadline=dl)))
-        # ---- cold-adapter installs (finished background fetches) -----
-        if self._adapter_pool is not None:
-            t0 = time.perf_counter()
-            installed = self._adapter_pool.admit_ready()
-            if installed:
-                tracing.record_span(
-                    "engine.adapter_admit", time.perf_counter() - t0,
-                    attrs={"adapters": len(installed)})
-        # ---- per-row admission into the live batch -------------------
-        t0 = time.perf_counter()
-        admitted = eng.admit(self._admit_rows or None)
-        if admitted:
-            self._admitted += admitted
-            _record_engine("admit", admitted)
-            tracing.record_span(
-                "engine.admit", time.perf_counter() - t0,
-                attrs={"rows": admitted})
-        # ---- one chunked-prefill dispatch, interleaved ---------------
-        t0 = time.perf_counter()
-        prefill_dt = 0.0
-        if eng.prefilling_rows:
-            eng.prefill_step()
-            prefill_dt = time.perf_counter() - t0
-            self._prefill_s += prefill_dt
-            self._prefill_chunks += 1
-            _record_engine("prefill_chunk")
-            tracing.record_span(
-                "engine.prefill", prefill_dt,
-                attrs={"rows": eng.prefilling_rows})
-        # ---- handoff exports (disaggregated prefill tier) ------------
-        # BEFORE the decode step: a handoff row must ship with zero
-        # locally-emitted tokens, and the export-publish runs in the
-        # background so row N's wire time overlaps row N+1's prefill
-        self._handoff_scan_locked()
-        # ---- one decode chunk ----------------------------------------
-        t0 = time.perf_counter()
-        events = eng.decode_step() if self._phase != "prefill" else []
-        dt = time.perf_counter() - t0
-        if events:
-            self._steps += 1
-            self._device_s += dt
-            _record_engine("step")
-            _record_engine("device_seconds", dt)
-            tracing.record_span(
-                "engine.step", dt,
-                attrs={"rows": len(events),
-                       "tokens": sum(len(t) for _, t, _ in events)})
-        # ---- route frames + row-free accounting ----------------------
+
+    def _route_locked(self, events) -> None:
+        """Frames to their sinks, and the row-free accounting."""
+        eng = self.engine
         freed = 0
         blocks_freed = 0
         tnow = time.perf_counter()
@@ -1639,17 +1884,9 @@ class DecodeEngine:
                     # per-tenant throughput: the fleet plane rolls the
                     # name-keyed counter into an adapter tok/s series
                     _record_adapter(aname, "tokens", len(toks))
-                t_sub = self._submit_t.pop(rid, None)
-                if t_sub is not None:  # this rid's FIRST tokens
-                    ttft = tnow - t_sub
-                    self._ema_ttft_s = (0.8 * self._ema_ttft_s
-                                        + 0.2 * ttft)
-                    # fleet-queryable TTFT distribution: buckets merge
-                    # across replicas at the controller (p99 becomes a
-                    # FLEET number); the submitting call's trace id is
-                    # the bucket exemplar — a slow bucket is one click
-                    # from `ktpu trace`
-                    self._record_ttft(ttft, rid, adapter=aname)
+                req = self._req.get(rid)
+                if req is not None and req.t_first is None:
+                    self._first_frame_locked(rid, req, tnow, aname)
             sink = self._sinks.get(rid)
             if sink is not None:
                 sink.put((rid, ([int(t) for t in toks], bool(done))))
@@ -1689,11 +1926,6 @@ class DecodeEngine:
             # lull measured as a minutes-long est_delay → spurious
             # sheds on the next burst)
             self._last_free_t = None
-        self._spec_tick_locked()
-        self._publish_gauges()
-        self._flight_append_locked(
-            tick_t0, fl_prev, prefill_dt + (dt if events else 0.0),
-            sum(len(t) for _, t, _ in events))
 
     def _flight_append_locked(self, tick_t0: float, prev: tuple,
                               device_dt: float,
@@ -1711,7 +1943,7 @@ class DecodeEngine:
             a0, p0, e0, k0, h0, s0, pt0, sr0, se0 = prev
             tick_s = time.perf_counter() - tick_t0
             trace_ids = tuple(sorted(
-                {t for t in self._row_trace.values() if t}))[:8]
+                {r.trace for r in self._req.values() if r.trace}))[:8]
             fl.append(
                 time.time(), time.monotonic(), tick_s, device_dt,
                 max(0.0, tick_s - device_dt),
@@ -1882,8 +2114,9 @@ class DecodeEngine:
             if not ho:
                 continue
             try:
-                state = self.engine.export_row(
-                    rid, block_tokens=self._kv.block_tokens)
+                with self._timer("handoff_sync"):
+                    state = self.engine.export_row(
+                        rid, block_tokens=self._kv.block_tokens)
             except (KeyError, ValueError):
                 continue          # queued / mid-prefill — next tick
             if meta.get("adapter") is not None:
@@ -2114,6 +2347,9 @@ class SimRollingEngine:
         self.peak_flops = 100e12
         self.peak_bw = 1.0e12
         self._devstats = devstats.AnalyticCosts()
+        # the serving engine installs its phase timer here, as on
+        # RollingGenerator; hand-driven, every phase is a no-op
+        self.tick_phase = contextlib.nullcontext
 
     # -------------------------------------------------------- interface
     @staticmethod
@@ -2182,13 +2418,13 @@ class SimRollingEngine:
                             "suffix": len(prompt), "slot": None})
         return rid
 
-    def admit(self, max_rows: Optional[int] = None) -> int:
-        admitted = 0
+    def admit(self, max_rows: Optional[int] = None) -> List[int]:
+        admitted: List[int] = []
         while self._free and self._queue and (
-                max_rows is None or admitted < max_rows):
+                max_rows is None or len(admitted) < max_rows):
             req = self._queue.pop(0)
             req["slot"] = self._free.pop(0)
-            admitted += 1
+            admitted.append(req["rid"])
             self.prefill_tokens += req.get("suffix", len(req["prompt"]))
             # a prefixed row's head is already "computed" — only the
             # suffix consumes prefill chunks
@@ -2225,8 +2461,18 @@ class SimRollingEngine:
     def decode_step(self):
         if not self._rows:
             return []
-        if self.step_s:
-            time.sleep(self.step_s)
+        # the real generator's three stretches of a chunk: the dispatch
+        # (nothing to do here), the blocking read (the modelled device
+        # time), and trimming the chunk into events
+        with self.tick_phase("decode_dispatch"):
+            pass
+        with self.tick_phase("decode_sync"):
+            if self.step_s:
+                time.sleep(self.step_s)
+        with self.tick_phase("route"):
+            return self._emit_events()
+
+    def _emit_events(self):
         events = []
         for rid, req in list(self._rows.items()):
             if self.spec:
