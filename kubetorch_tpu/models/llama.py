@@ -767,7 +767,16 @@ def _cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask, emask,
     int8 path's scale folding (scores·ks after the QK contraction,
     p·vs before the PV one) applied to the grid half only — one softmax
     spans both sources, so rolling decode can run the serving grid at
-    half the cache bytes and residency."""
+    half the cache bytes and residency.
+
+    Which path runs when: this einsum pair (with ``_cached_attn_merged``
+    for a float grid) contracts against all ``M`` positions of every slot
+    and masks afterwards. It serves every chunk-mode forward with more
+    than one query position (chunked prefill, speculative verify), every
+    backend but the TPU, and a grid sharded over a mesh; it is also the
+    numerics ORACLE of ``_cached_attn_ragged``, which single-position
+    decode on one TPU device takes instead (tests/test_decode_attention.py
+    holds the two together)."""
     B, T, H, D = q.shape
     Hkv = gk.shape[2]
     G = H // Hkv
@@ -793,12 +802,56 @@ def _cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask, emask,
     return out.reshape(B, T, H, D).astype(q.dtype)
 
 
+def _cached_attn_ragged(q, gk_all, gv_all, gks_all, gvs_all, li, items,
+                        ek, ev, emask, cfg: LlamaConfig):
+    """Merged grid+chunk attention for ONE query position a row, the grid
+    half read only to each row's depth.
+
+    ``gk_all``/``gv_all`` are the STACKED planes [L,B,M,Hkv,D] (never a
+    sliced layer: handed to a custom call that is a copy of the layer),
+    ``gks_all``/``gvs_all`` their scales or None, ``items`` the kernel's
+    work list ``decode_attention.plan(depth, M)`` for ``depth`` [B], the
+    grid mask as a length (``m < depth[b]``; 0 for a row that is not
+    decoding). The Pallas kernel (``ops/decode_attention.py``) returns
+    the grid half un-normalised with its running max and sum; the chunk's
+    few columns are scored here in XLA and the halves join by the
+    log-sum-exp rule, so one softmax spans both exactly as in
+    ``_cached_attn_merged_q`` / ``_cached_attn_merged``, whose operand
+    dtypes this keeps (bf16 operands over an int8 or bf16 grid, f32
+    accumulation). A row at depth 0 with its chunk masked too comes out
+    finite and meaningless, as it does there."""
+    from kubetorch_tpu.ops.decode_attention import ragged_decode_attention
+
+    B, _, H, D = q.shape
+    Hkv = ek.shape[2]
+    G = H // Hkv
+    acc_g, m_g, l_g = ragged_decode_attention(
+        q[:, 0], gk_all, gv_all, gks_all, gvs_all, li, items,
+        interpret=jax.default_backend() != "tpu")
+    odt = jnp.float32 if gk_all.dtype == jnp.float32 else jnp.bfloat16
+    qg = q.reshape(B, Hkv, G, D).astype(odt)
+    se = jnp.einsum("bkgd,bckd->bkgc", qg, ek.astype(odt),
+                    preferred_element_type=jnp.float32) * (D ** -0.5)
+    se = jnp.where(emask[:, 0, None, None, :], se, -1e30)
+    m_g, l_g = m_g.reshape(B, Hkv, G), l_g.reshape(B, Hkv, G)
+    m = jnp.maximum(m_g, jnp.max(se, axis=-1))
+    pe = jnp.exp(se - m[..., None])
+    wg = jnp.exp(m_g - m)
+    out = (wg[..., None] * acc_g.reshape(B, Hkv, G, D)
+           + jnp.einsum("bkgc,bckd->bkgd", pe.astype(odt), ev.astype(odt),
+                        preferred_element_type=jnp.float32))
+    out = out / (wg * l_g + jnp.sum(pe, axis=-1))[..., None]
+    return out.reshape(B, 1, H, D).astype(q.dtype)
+
+
 def _block_cached_chunk_q(x, layer, li, sin, cos, gk_all, gv_all, gks_all,
                           gvs_all, ek_all, ev_all, col, gmask, emask,
                           cfg: LlamaConfig, rules: ShardingRules,
-                          lctx=None):
+                          lctx=None, items=None):
     """Chunk-mode decoder block over a QUANTIZED read-only grid; the
-    step's K/V land bf16 at uniform chunk column ``col``."""
+    step's K/V land bf16 at uniform chunk column ``col``. ``items``
+    (``gmask`` as the ragged kernel's work list) selects that kernel for
+    the grid half."""
     dt = cfg.compute_dtype
     B, T, _ = x.shape
     H, D = cfg.n_heads, cfg.head_dim
@@ -809,15 +862,19 @@ def _block_cached_chunk_q(x, layer, li, sin, cos, gk_all, gv_all, gks_all,
         ek_all, k.astype(cdt)[None], (li, 0, col, 0, 0))
     ev_all = jax.lax.dynamic_update_slice(
         ev_all, v.astype(cdt)[None], (li, 0, col, 0, 0))
-    gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
-    gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
-    gks = jax.lax.dynamic_index_in_dim(gks_all, li, 0, keepdims=False)
-    gvs = jax.lax.dynamic_index_in_dim(gvs_all, li, 0, keepdims=False)
     ek = jax.lax.dynamic_index_in_dim(ek_all, li, 0, keepdims=False)
     ev = jax.lax.dynamic_index_in_dim(ev_all, li, 0, keepdims=False)
-
-    attn = _cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask,
-                                 emask, cfg).reshape(B, T, H * D)
+    if items is not None:
+        attn = _cached_attn_ragged(q, gk_all, gv_all, gks_all, gvs_all, li,
+                                   items, ek, ev, emask, cfg)
+    else:
+        gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
+        gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
+        gks = jax.lax.dynamic_index_in_dim(gks_all, li, 0, keepdims=False)
+        gvs = jax.lax.dynamic_index_in_dim(gvs_all, li, 0, keepdims=False)
+        attn = _cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask,
+                                     emask, cfg)
+    attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
     x = x + _mlp(x, layer, cfg, rules, lctx)
@@ -826,11 +883,12 @@ def _block_cached_chunk_q(x, layer, li, sin, cos, gk_all, gv_all, gks_all,
 
 def _block_cached_chunk(x, layer, li, sin, cos, gk_all, gv_all, ek_all,
                         ev_all, col, gmask, emask, cfg: LlamaConfig,
-                        rules: ShardingRules, lctx=None):
+                        rules: ShardingRules, lctx=None, items=None):
     """Chunk-mode decoder block: the stacked grid caches are READ-ONLY;
     this step's K/V lands at uniform column ``col`` of the small stacked
     chunk caches (a plain dynamic-update-slice — no per-sequence offsets,
-    so no full-layer rewrite), and attention merges grid + chunk."""
+    so no full-layer rewrite), and attention merges grid + chunk.
+    ``items`` as in ``_block_cached_chunk_q``."""
     dt = cfg.compute_dtype
     B, T, _ = x.shape
     H, D = cfg.n_heads, cfg.head_dim
@@ -841,13 +899,16 @@ def _block_cached_chunk(x, layer, li, sin, cos, gk_all, gv_all, ek_all,
         ek_all, k.astype(cdt)[None], (li, 0, col, 0, 0))
     ev_all = jax.lax.dynamic_update_slice(
         ev_all, v.astype(cdt)[None], (li, 0, col, 0, 0))
-    gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
-    gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
     ek = jax.lax.dynamic_index_in_dim(ek_all, li, 0, keepdims=False)
     ev = jax.lax.dynamic_index_in_dim(ev_all, li, 0, keepdims=False)
-
-    attn = _cached_attn_merged(q, gk, gv, ek, ev, gmask, emask,
-                               cfg).reshape(B, T, H * D)
+    if items is not None:
+        attn = _cached_attn_ragged(q, gk_all, gv_all, None, None, li, items,
+                                   ek, ev, emask, cfg)
+    else:
+        gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
+        gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
+        attn = _cached_attn_merged(q, gk, gv, ek, ev, gmask, emask, cfg)
+    attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
     x = x + _mlp(x, layer, cfg, rules, lctx)
@@ -1026,6 +1087,7 @@ def forward_cached(
     chunk_col=None,                                 # scalar: uniform column
     chunk_mask: Optional[jax.Array] = None,         # [B, T, K] bool
     lora: Optional[Dict[str, Any]] = None,          # multi-adapter serving
+    grid_depth: Optional[jax.Array] = None,         # [B]: mask as a length
 ):
     """Forward with KV cache → (logits [B, T, V] float32, new cache).
 
@@ -1042,6 +1104,19 @@ def forward_cached(
     CHUNK, not the grid — the caller merges it into the grid once per
     decode chunk (``RollingGenerator._decode_impl``). This exists because
     per-sequence grid writes rewrite whole cache layers every step.
+
+    Chunk mode has two implementations of one attention. A caller whose
+    ``mask`` is a plain prefix mask says so by passing it as a length too:
+    ``grid_depth`` [B] with ``mask[b, 0, m] == (m < grid_depth[b])``. With
+    it, one query position (``T == 1``), the TPU backend, the grid on one
+    device and a ``max_len`` a key block divides
+    (``ops.decode_attention.engages``), the grid half runs in the ragged
+    Pallas kernel, which reads each row's K/V only to its depth
+    (``_cached_attn_ragged``). Everything else — chunked prefill and
+    speculative verify (``T > 1``), CPU, a mesh — runs the einsum pair
+    over all ``max_len`` positions (``_cached_attn_merged_q`` /
+    ``_cached_attn_merged``), which is also the kernel's oracle. The shape
+    decides; there is no switch.
     """
     rules = rules or ShardingRules.default()
     dt = cfg.compute_dtype
@@ -1054,6 +1129,16 @@ def forward_cached(
     # _lora_apply gathers the per-slot delta at every adapted
     # projection (select cost independent of n).
     ltree = lora["adapters"] if lora is not None else None
+    from kubetorch_tpu.ops import decode_attention
+
+    # the ragged kernel's work list, made once for all layers; None: the
+    # einsum pair, under ``mask``
+    items = None
+    if chunk is not None and grid_depth is not None and \
+            decode_attention.engages(
+                tokens.shape[1], cache["k"].shape[2], cfg.n_kv_heads,
+                cfg.head_dim, cache["k"].dtype):
+        items = decode_attention.plan(grid_depth, cache["k"].shape[2])
 
     def lctx_of(lslice):
         if lora is None:
@@ -1072,7 +1157,7 @@ def forward_cached(
             x, ek_all, ev_all = _block_cached_chunk_q(
                 x, layer, li, sin, cos, grid_k, grid_v, grid_ks, grid_vs,
                 ek_all, ev_all, chunk_col, mask, chunk_mask, cfg, rules,
-                lctx_of(lslice))
+                lctx_of(lslice), items)
             return (x, ek_all, ev_all), None
 
         (x, new_k, new_v), _ = jax.lax.scan(
@@ -1116,7 +1201,8 @@ def forward_cached(
             layer, li, lslice = inp
             x, ek_all, ev_all = _block_cached_chunk(
                 x, layer, li, sin, cos, grid_k, grid_v, ek_all, ev_all,
-                chunk_col, mask, chunk_mask, cfg, rules, lctx_of(lslice))
+                chunk_col, mask, chunk_mask, cfg, rules, lctx_of(lslice),
+                items)
             return (x, ek_all, ev_all), None
 
         (x, new_k, new_v), _ = jax.lax.scan(

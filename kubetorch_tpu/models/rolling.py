@@ -43,6 +43,7 @@ from kubetorch_tpu.observability import devstats
 from kubetorch_tpu.models import llama
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.models.generate import filter_logits
+from kubetorch_tpu.ops import decode_attention
 from kubetorch_tpu.parallel.sharding import ShardingRules
 
 
@@ -304,6 +305,21 @@ class RollingGenerator:
         # at register_prefix) — the numerator of the serving engine's
         # prefix-sharing savings ratio
         self.prefill_tokens = 0
+        # What decode attention reads of the grid, summed per decode chunk
+        # on the host (every step of a chunk reads as many): positions
+        # below the decoding rows' depths (``live``), positions the
+        # attention implementation fetches for them (``read``: live rounded
+        # up to the ragged kernel's key blocks, or the whole grid where the
+        # einsum pair runs) and the grid's own size. ``_depth`` mirrors
+        # ``_dpos`` for decoding rows, so none of it waits for the device.
+        self._depth = np.zeros(max_slots, np.int64)
+        self._kv_positions = {"live": 0, "read": 0, "grid": 0}
+        with self._mesh_ctx():
+            self._ragged_block = (
+                decode_attention.block_for(self.max_len)
+                if not self.spec and decode_attention.engages(
+                    1, self.max_len, cfg.n_kv_heads, cfg.head_dim,
+                    self.cache["k"].dtype) else None)
 
         # Device-truth utilization accounting: every jitted dispatch
         # below routes through this accumulator, which captures each
@@ -390,6 +406,24 @@ class RollingGenerator:
     @property
     def free_rows(self) -> int:
         return len(self._free)
+
+    def stats(self) -> Dict[str, int]:
+        """Host-only counters of this generator (no device read): what
+        decode attention read of the KV grid. ``read / grid`` is 1.0 where
+        the einsum pair runs and the live share, rounded up to key blocks,
+        where the ragged kernel does."""
+        return {f"decode_kv_positions_{k}": int(v)
+                for k, v in self._kv_positions.items()}
+
+    def _count_kv_read(self) -> None:
+        """Account one decode chunk, from the depths it starts at."""
+        live = self._depth[list(self._slots)]
+        grid = self.max_slots * self.max_len
+        block = self._ragged_block
+        self._kv_positions["live"] += int(live.sum())
+        self._kv_positions["read"] += (
+            grid if block is None else int((-(-live // block) * block).sum()))
+        self._kv_positions["grid"] += grid
 
     def devstats_snapshot(self) -> Dict[str, float]:
         """Cumulative compiler-truth dispatch costs (FLOPs / HBM bytes
@@ -629,6 +663,7 @@ class RollingGenerator:
             if req.repetition_penalty != 1.0 and tail:
                 self._win[req.slot, -len(tail):] = tail
             self._slots[req.slot] = req
+            self._depth[req.slot] = len(req.prompt)
             activated.append(req.rid)
         if self.spec and done_reqs:
             # the chunked-prefill × speculation composition: the draft
@@ -971,6 +1006,7 @@ class RollingGenerator:
         self._win[slot] = np.asarray(state["win"], np.int32)
         self._slot_adapter[slot] = adapter_id
         self._slots[slot] = req
+        self._depth[slot] = dpos
         if self.spec:
             Lctx = self._ctx.shape[1]
             ctx_row = np.zeros(Lctx, np.int32)
@@ -1088,6 +1124,9 @@ class RollingGenerator:
             if req.repetition_penalty != 1.0 and tail:
                 self._win[req.slot, -len(tail):] = tail
             self._slots[req.slot] = req
+            self._depth[req.slot] = len(req.prompt) + (
+                self._prefixes[prefix_id]["len"] if prefix_id is not None
+                else 0)
             self.prefill_tokens += len(req.prompt)
         with self._mesh_ctx():
             if prefix_id is None:
@@ -1140,6 +1179,8 @@ class RollingGenerator:
 
     def _decode_chunk(self) -> List[Tuple[int, List[int], bool]]:
         with self.tick_phase("decode_dispatch"):
+            self._count_kv_read()
+            self._depth[list(self._slots)] += self.steps_per_call
             self._rng, key = jax.random.split(self._rng)
             with self._mesh_ctx():
                 (self.cache, self._logits, self._dpos,
@@ -1199,6 +1240,7 @@ class RollingGenerator:
             while kd < k_widest:
                 kd *= 2
             kd = max(1, min(kd, self.spec_k))
+            self._count_kv_read()
             self._rng, key = jax.random.split(self._rng)
             with self._mesh_ctx():
                 (self.cache, self._dpos, self._ctx, self._dnt,
@@ -1225,6 +1267,7 @@ class RollingGenerator:
                     if e:
                         new.extend(int(t) for t in toks[r, slot, :e])
                 new_by_slot[slot] = new
+                self._depth[slot] += int(emits[:, slot].sum())
                 self._spec_rounds += R
                 self._spec_emitted += len(new)
                 # fold this chunk's acceptance into the row's EMA, then one
@@ -1288,6 +1331,7 @@ class RollingGenerator:
         self._dactive = jnp.where(mask, False, self._dactive)
         self._dpos = jnp.where(mask, 0, self._dpos)
         self._slot_adapter[freed] = -1
+        self._depth[freed] = 0
         for slot in freed:
             self._win[slot] = -1
             self._penalties[slot] = 1.0
@@ -1485,11 +1529,20 @@ class RollingGenerator:
         Deferred cache merge: inside the scan each step's K/V lands at the
         step-index column of a small [L, B, n_steps] *chunk* cache (a
         uniform-offset write, like the static decoder's), and attention
-        merges the read-only grid with the chunk
-        (``llama._cached_attn_merged``). The grid is rewritten ONCE after
-        the scan — per-sequence offsets force a full-layer rewrite, and
-        doing that every step measured ~2× the whole step at 8B serving
-        scale (38 → ~20 ms/step at B=96).
+        merges the read-only grid with the chunk. The grid is rewritten
+        ONCE after the scan — per-sequence offsets force a full-layer
+        rewrite, and doing that every step measured ~2× the whole step at
+        8B serving scale (38 → ~20 ms/step at B=96).
+
+        Which attention reads the grid: this is the one chunk-mode caller
+        with a single query position, and it hands the grid mask down as
+        a length too (``grid_depth``), so on one TPU device the grid half
+        runs in the ragged Pallas kernel, which fetches each row's K/V
+        only to its depth (``llama._cached_attn_ragged``). On CPU, under
+        a tp mesh, or with a ``max_len`` no key block divides, the same
+        call runs the einsum pair over all ``max_len`` positions
+        (``llama._cached_attn_merged_q`` / ``_cached_attn_merged``), the
+        kernel's oracle. ``stats()`` counts what was read either way.
 
         ``window`` [B, W] holds each slot's recent token ids (−1 = empty);
         ``penalties`` [B] apply HF-style repetition penalty to those ids
@@ -1505,6 +1558,8 @@ class RollingGenerator:
         # chunk cache. So the grid mask is loop-invariant.
         gmask = ((jnp.arange(M)[None, None, :] < pos0[:, None, None])
                  & active[:, None, None])
+        # the same mask as a length: what the ragged kernel reads to
+        depth0 = jnp.where(active, pos0, 0)
         cdt = (jnp.bfloat16 if "ks" in cache else cache["k"].dtype)
         chunk0 = {
             "k": jnp.zeros((L, B, n_steps, Hkv, D), cdt),
@@ -1545,7 +1600,7 @@ class RollingGenerator:
             out, chunk = llama.forward_cached(
                 params, tok[:, None], positions, cache, None, gmask, cfg,
                 rules, chunk=chunk, chunk_col=j, chunk_mask=emask,
-                lora=lora)
+                lora=lora, grid_depth=depth0)
             return (chunk, out[:, 0], pos + 1, win), tok
 
         (chunk, logits, pos, _), toks = jax.lax.scan(

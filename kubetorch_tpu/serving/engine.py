@@ -1118,6 +1118,11 @@ class DecodeEngine:
             out["mbu"] = round(self._mbu, 4)
         if self._flight is not None:
             out["flight_seq"] = self._flight.seq
+        # the generator's own host counters (decode_kv_positions_*: what
+        # decode attention read of the grid); a sim engine has none
+        gen_stats = getattr(eng, "stats", None)
+        if gen_stats is not None:
+            out.update(gen_stats())
         snap_fn = getattr(eng, "devstats_snapshot", None)
         if snap_fn is not None:
             try:

@@ -1,0 +1,204 @@
+"""The ragged decode-attention kernel (ops/decode_attention.py) against the
+einsum pair it stands in for (``llama._cached_attn_merged_q`` /
+``_cached_attn_merged``), in interpret mode at toy widths.
+
+Tolerance. Both sides accumulate in f32. Over an int8 grid both feed the
+MXU bf16 operands, but they round DIFFERENT numbers to bf16 before PV: the
+einsum pair rounds the normalised probabilities times ``vs``, the kernel the
+un-normalised ``exp(s - running max)`` times ``vs`` and divides afterwards.
+Each rounding is at most 2^-9 relative per element, and the output is a
+convex mix of the values, so the two can differ by at most
+2 x 2^-9 x max|v| = 2^-8 x max|v| in any element (seen: a third of that).
+Over a float grid the oracle computes in f32 throughout and the kernel keeps
+f32 operands for an f32 grid, so only the order of f32 sums differs:
+1e-5 x max|v|.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kubetorch_tpu.models import llama
+from kubetorch_tpu.ops import decode_attention
+
+BLOCK = 128                         # (serving picks 512 of [512, 256, 128])
+M = 4 * BLOCK                       # four key blocks
+HKV, G, D = 4, 4, 128               # GQA 4:1
+H = HKV * G
+L, B, K = 2, 4, 8                   # layers, rows, chunk columns
+LAYER, COL = 1, 2                   # the layer read; chunk columns 0..COL live
+
+DEPTHS = (0, 1, BLOCK - 1, BLOCK, BLOCK + 1, M - 8)
+PATTERNS = ("all_active", "one_inactive", "mixed_depths")
+TOL = {"int8": 2.0 ** -8, "bf16": 1e-5}   # x max|v|; "bf16" = float grid
+
+
+def _planes(kv: str, key):
+    """A stacked grid [L, B, M, HKV, D] (+ scales for int8), a chunk and a
+    query, all seeded."""
+    kk, kv_, kq, ke = jax.random.split(key, 4)
+    shape = (L, B, M, HKV, D)
+    if kv == "int8":
+        k, sk = llama._kv_quantize(
+            jax.random.normal(kk, shape, jnp.float32).reshape(-1, *shape[2:]))
+        v, sv = llama._kv_quantize(
+            jax.random.normal(kv_, shape, jnp.float32).reshape(-1, *shape[2:]))
+        grid = (k.reshape(shape), v.reshape(shape),
+                sk.reshape(shape[:-1]), sv.reshape(shape[:-1]))
+    else:
+        grid = (jax.random.normal(kk, shape, jnp.float32),
+                jax.random.normal(kv_, shape, jnp.float32), None, None)
+    q = jax.random.normal(kq, (B, 1, H, D), jnp.float32)
+    ek, ev = jax.random.normal(ke, (2, B, K, HKV, D), jnp.float32)
+    return grid, q, ek, ev
+
+
+def _rows(pattern: str, depth: int):
+    """(pos0 [B], active [B]) of one case."""
+    pos0 = np.full(B, depth, np.int32)
+    active = np.ones(B, bool)
+    if pattern == "one_inactive":
+        active[1] = False
+    elif pattern == "mixed_depths":
+        pos0 = np.array([depth, 5, BLOCK + 3, M - 8], np.int32)
+    return jnp.asarray(pos0), jnp.asarray(active)
+
+
+def _both(grid, q, ek, ev, pos0, active):
+    """(oracle, kernel path) outputs [B, 1, H, D] for layer LAYER, and the
+    largest |v| either could have mixed."""
+    gk, gv, gks, gvs = grid
+    gmask = ((jnp.arange(M)[None, None, :] < pos0[:, None, None])
+             & active[:, None, None])
+    emask = ((jnp.arange(K)[None, None, :] <= COL) & active[:, None, None])
+    if gks is not None:
+        want = llama._cached_attn_merged_q(
+            q, gk[LAYER], gv[LAYER], gks[LAYER], gvs[LAYER], ek, ev, gmask,
+            emask, None)
+    else:
+        want = llama._cached_attn_merged(q, gk[LAYER], gv[LAYER], ek, ev,
+                                         gmask, emask, None)
+    got = llama._cached_attn_ragged(
+        q, gk, gv, gks, gvs, jnp.int32(LAYER),
+        decode_attention.plan(jnp.where(active, pos0, 0), M, BLOCK), ek, ev,
+        emask, None)
+    vmax = float(max(jnp.abs(ev).max(), jnp.abs(
+        gv[LAYER] * (1.0 if gvs is None else gvs[LAYER][..., None])).max()))
+    return np.asarray(want), np.asarray(got), vmax
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("pattern", PATTERNS)
+@pytest.mark.parametrize("depth", DEPTHS)
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+def test_kernel_matches_einsum_pair(kv, depth, pattern):
+    grid, q, ek, ev = _planes(kv, jax.random.key(7))
+    pos0, active = _rows(pattern, depth)
+    want, got, vmax = _both(grid, q, ek, ev, pos0, active)
+    rows = np.asarray(active)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want)[rows].max() <= TOL[kv] * vmax, (
+        np.abs(got - want)[rows].max(), vmax)
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+def test_dead_positions_are_not_read_into_the_result(kv):
+    """Poison every grid position at or past each row's depth — NaN in the
+    float planes (the int8 planes take their extreme value), inf in the
+    scales — in EVERY layer: the output must stay finite and unchanged."""
+    grid, q, ek, ev = _planes(kv, jax.random.key(11))
+    pos0 = jnp.asarray([0, 1, BLOCK, M - 8], jnp.int32)
+    active = jnp.asarray([False, True, True, True])
+    depth = jnp.where(active, pos0, 0)
+    emask = ((jnp.arange(K)[None, None, :] <= COL) & active[:, None, None])
+
+    def run(planes):
+        return np.asarray(llama._cached_attn_ragged(
+            q, *planes, jnp.int32(LAYER),
+            decode_attention.plan(depth, M, BLOCK), ek, ev, emask, None))
+
+    dead = (jnp.arange(M)[None, :] >= depth[:, None])[None, :, :, None]
+    gk, gv, gks, gvs = grid
+    if kv == "int8":
+        bad = (jnp.where(dead[..., None], jnp.int8(-128), gk),
+               jnp.where(dead[..., None], jnp.int8(127), gv),
+               jnp.where(dead, jnp.inf, gks), jnp.where(dead, jnp.inf, gvs))
+    else:
+        bad = (jnp.where(dead[..., None], jnp.nan, gk),
+               jnp.where(dead[..., None], jnp.nan, gv), None, None)
+    clean, poisoned = run(grid), run(bad)
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(poisoned, clean)
+
+
+@pytest.mark.level("unit")
+def test_engages_only_where_the_kernel_covers_the_shape(monkeypatch):
+    """On this backend (CPU) nothing engages; with the test hook the shape
+    rules decide."""
+    args = (M, HKV, D, jnp.int8)
+    assert not decode_attention.engages(1, *args)
+    monkeypatch.setattr(decode_attention, "_FORCE_INTERPRET", True)
+    assert decode_attention.engages(1, *args)
+    assert not decode_attention.engages(2, *args)            # T > 1
+    assert not decode_attention.engages(1, M + 8, HKV, D, jnp.int8)
+    assert not decode_attention.engages(1, M, HKV, 64, jnp.int8)
+    assert not decode_attention.engages(1, M, 2, D, jnp.int8)  # 4 a word
+    assert decode_attention.engages(1, M, 2, D, jnp.bfloat16)
+
+
+# ---- compiled for the chip, without the chip (no time, no result: what the
+# TPU's compiler accepts, and which layouts it gives the planes)
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("kv", ["int8", "bf16"])
+def test_kernel_compiles_for_v5e_with_the_planes_as_stored(kv, v5e_chip):
+    """Mistral-7B widths (32 heads, 8 KV heads x 128), 32 slots x 2048:
+    Mosaic takes the kernel, and the module around it neither copies nor
+    transposes anything the size of a plane — the stacked K/V go into the
+    custom call as they lie, the scales through a bitcast."""
+    layers, b, m, hkv, h, d = 4, 32, 2048, 8, 32, 128
+    dt = jnp.int8 if kv == "int8" else jnp.bfloat16
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    scales = spec((layers, b, m, hkv), jnp.float32) if kv == "int8" else None
+
+    def attend(q, k, v, ks, vs, layer, depth):
+        return decode_attention.ragged_decode_attention(
+            q, k, v, ks, vs, layer, decode_attention.plan(depth, m))
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(attend).lower(
+            spec((b, h, d), jnp.bfloat16), spec((layers, b, m, hkv, d), dt),
+            spec((layers, b, m, hkv, d), dt), scales, scales,
+            spec((), jnp.int32), spec((b,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    assert text.count("tpu_custom_call") == 1
+    plane = re.compile(rf"\[{layers},{b},(2048,8|8,2048)[,\]]")
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if plane.search(line.split("=", 1)[-1].split("(", 1)[0])
+             and re.search(r"\b(copy|transpose|fusion)\(", line)]
+    assert not moved, moved

@@ -946,3 +946,74 @@ def test_chunked_prefill_composes_with_spec(model):
     assert spec.spec_stats["rounds"] > 0
     with pytest.raises(ValueError):
         RollingGenerator(params, cfg, max_slots=2, prefill_chunk=0)
+
+
+def _drive_three_chunks(params, cfg, kvd):
+    """Three decode chunks over rows at different depths, one row freed
+    after the first chunk and its slot re-admitted: (tokens by rid, the
+    dequantized merged grid, the engine)."""
+    eng = RollingGenerator(params, cfg, max_slots=4, max_len=384,
+                           steps_per_call=4, kv_dtype=kvd)
+    prompts = [[(7 * i) % 250 + 1 for i in range(n)] for n in (5, 140, 250)]
+    rids = [eng.submit(p, max_new_tokens=12) for p in prompts]
+    rids.append(eng.submit([3, 1, 4, 1, 5], max_new_tokens=4))   # one chunk
+    toks = {r: [] for r in rids}
+    for chunk in range(3):
+        if chunk == 1:      # the short row is done: its slot is free again
+            assert eng.free_rows == 1
+            rids.append(eng.submit([2, 7, 1, 8, 2, 8], max_new_tokens=12))
+            toks[rids[-1]] = []
+        for rid, new, _ in eng.step():
+            toks[rid].extend(new)
+        # the host's mirror of the decoding rows' depths is the device's
+        for slot in eng._slots:
+            assert eng._depth[slot] == int(eng._dpos[slot])
+    grid = {k: np.asarray(v, np.float32) for k, v in eng.cache.items()}
+    if "ks" in grid:
+        grid = {"k": grid["k"] * grid["ks"][..., None],
+                "v": grid["v"] * grid["vs"][..., None]}
+    return toks, grid, eng
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("kvd", ["bf16", "int8"])
+def test_ragged_decode_kernel_matches_einsum_engine(kvd, monkeypatch):
+    """ISSUE 25: single-position decode through the ragged Pallas kernel
+    (forced on here, in interpret mode, by the ops module's test hook) is
+    the engine the einsum pair gives: same greedy tokens, same merged
+    grid, and ``stats()`` says how much of the grid each read."""
+    from kubetorch_tpu.ops import decode_attention
+
+    cfg = LlamaConfig(vocab_size=256, embed_dim=64, n_layers=2, n_heads=8,
+                      n_kv_heads=4, head_dim=128, mlp_dim=128, remat=False,
+                      dtype="float32", param_dtype="float32",
+                      max_seq_len=384)
+    params = llama.init(jax.random.key(0), cfg)
+    want_toks, want_grid, ref = _drive_three_chunks(params, cfg, kvd)
+    assert ref._ragged_block is None
+    monkeypatch.setattr(decode_attention, "_FORCE_INTERPRET", True)
+    got_toks, got_grid, eng = _drive_three_chunks(params, cfg, kvd)
+    assert eng._ragged_block == 128            # 384 = 3 key blocks
+    assert got_toks == want_toks
+    # f32 grid: f32 operands both ways, only the order of sums differs.
+    # int8 grid: bf16 rounding of different numbers (see
+    # tests/test_decode_attention.py) moves layer 1's K/V by ~2^-8
+    # relative, which may step a quantized value by one level (1/127).
+    tol = 1e-4 if kvd == "bf16" else 2.0 / 127
+    for name in ("k", "v"):
+        scale = np.abs(want_grid[name]).max()
+        assert np.abs(got_grid[name] - want_grid[name]).max() <= tol * scale
+
+    s_ref, s_eng = ref.stats(), eng.stats()
+    for s in (s_ref, s_eng):
+        assert (0 < s["decode_kv_positions_live"]
+                <= s["decode_kv_positions_read"]
+                <= s["decode_kv_positions_grid"])
+    assert s_ref["decode_kv_positions_grid"] == 3 * 4 * 384
+    assert s_ref["decode_kv_positions_read"] == \
+        s_ref["decode_kv_positions_grid"]                  # exactly 1.0
+    assert s_eng["decode_kv_positions_live"] == \
+        s_ref["decode_kv_positions_live"]
+    assert s_eng["decode_kv_positions_read"] < \
+        s_eng["decode_kv_positions_grid"]
+    assert s_eng["decode_kv_positions_read"] % 128 == 0
