@@ -114,7 +114,10 @@ def check(manifest: dict = None) -> list:
         if not 0 < x["bound"] <= 0.1:
             bad(f"{x['name']}: bound {x['bound']} outside (0, 0.1]")
     # configurations and cells against their files
+    from benchmark import families
+
     configs = {c["name"]: c for c in m["configs"]}
+    resolved = {}       # config -> its file's body, where the family loads
     for c in m["configs"]:
         path = REPO / c["file"]
         if not path.is_file():
@@ -130,6 +133,11 @@ def check(manifest: dict = None) -> list:
                 bad(f"config {c['name']}: file lacks {key!r}")
         if not any(w["config"] == c["name"] for w in m["workloads"]):
             bad(f"config {c['name']}: used by no cell")
+        try:
+            families.load(body)
+            resolved[c["name"]] = body
+        except LookupError as exc:
+            bad(f"config {c['name']}: {exc}")
     pairs = set()
     for w in m["workloads"]:
         if (w["config"], w["traffic"]) in pairs:
@@ -151,6 +159,19 @@ def check(manifest: dict = None) -> list:
                 bad(f"cell {w['name']}: {key} differs from its file")
         if not (ROOT / "traffic" / f"{w['traffic']}.json").is_file():
             bad(f"cell {w['name']}: no traffic file {w['traffic']}.json")
+        if w["config"] not in resolved:
+            continue
+        try:
+            known = families.load(resolved[w["config"]],
+                                  body.get("kind")).controls()
+        except LookupError as exc:
+            bad(f"cell {w['name']}: {exc}, which a {body.get('kind')} cell "
+                f"calls")
+            continue
+        for lower in body.get("controls", []):
+            if lower not in known:
+                bad(f"cell {w['name']}: control {lower!r} is not one of its "
+                    f"family's {list(known)}")
     four = sum(w["chips"] == 4 for w in m["workloads"])
     if four > max(1, len(cells) // 4):
         bad(f"{four} of {len(cells)} cells ask for four chips "

@@ -1,7 +1,8 @@
-"""Shares of the chip's published peaks: least work from shapes
-(``benchmark/opcounts``) over measured time, against ``peaks.json``."""
+"""Shares of the chip's published peaks: the least work of the run's
+architecture (its family's counts, ``benchmark/families``) over measured
+time, against ``peaks.json``."""
 
-from benchmark.opcounts import llama_dense as ops
+from benchmark import families
 from benchmark.readers import device
 
 
@@ -11,10 +12,9 @@ def decode_hbm_roofline(ctx):
     bandwidth. Decode is bandwidth-bound; the grid the program reads beyond
     the live positions is its own cost and lowers this share."""
     step_ms = device.decode_step_dev_ms(ctx)
-    live = (ctx.get("trace_live") or {}).get("positions")
-    if not step_ms or live is None:
+    need = step_ms and families.load(ctx["config"]).decode_step_bytes(ctx)
+    if not need:
         return None
-    need = ops.decode_step_bytes(ctx["dims"], ctx["config"]["kv_dtype"], live)
     return 100.0 * need / (step_ms / 1e3) / ctx["peaks"]["hbm_bytes_per_s"]
 
 
@@ -23,14 +23,9 @@ def prefill_mfu(ctx):
     device time of the prefill executables / bf16 peak. Padded slots and
     chunk positions past a prompt's end are waste and show here."""
     mods = device._modules(ctx, ("prefill",))
-    d = ctx.get("trace_stats_delta") or {}
-    toks = d.get("prefill_tokens_executed", 0)
-    if not mods or not toks:
+    flops = mods and families.load(ctx["config"]).prefill_flops(ctx)
+    if not flops:
         return None
-    # attention of the traced tokens at the mix's mean prompt length: a
-    # chunk at depth p attends p positions, the mean over a prompt is n/2
-    mean_len = ctx.get("mean_prompt_len") or 0.0
-    flops = ops.prefill_flops(ctx["dims"], toks, toks * mean_len)
     secs = sum(m["total_s"] for m in mods)
     return 100.0 * flops / secs / ctx["peaks"]["bf16_flops"]
 
@@ -41,5 +36,5 @@ def trainer_mfu(ctx):
     rate = ctx.get("train_tok_s_chip")
     if not rate:
         return None
-    per_tok = ops.train_flops_per_token(ctx["dims"], ctx["seq"])
+    per_tok = families.load(ctx["config"]).train_flops_per_token(ctx)
     return 100.0 * per_tok * rate / ctx["peaks"]["bf16_flops"]

@@ -5,8 +5,10 @@
 The job names the configuration file, the seed, and a sample of finished
 requests (prompt + the tokens the engine served). For each request the
 reference runs once over prompt + served tokens, layer by layer, the layer's
-weights rebuilt from the seed; where the engine chose token ``s`` the number
-compared is ``max(logits) - logits[s]`` in units of logits (0 = the
+weights rebuilt from the seed by the configuration's family
+(``benchmark/families``; one compilation a KIND of layer, so a stack whose
+layers differ in shape passes through); where the engine chose token ``s``
+the number compared is ``max(logits) - logits[s]`` in units of logits (0 = the
 reference's own greedy token). With ``"controls"`` in the job the same pass
 is made in lower precision and the gap of the token THAT puts first is read
 at every position: the readings the limit is set against.
@@ -33,29 +35,30 @@ def score(job: dict) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import weights
-    from benchmark.reference import model
+    from benchmark import families, weights
 
-    d = weights.dims(json.load(open(job["config_file"])))
+    config = json.load(open(job["config_file"]))
+    fam = families.load(config, "serve")
+    d = fam.dims(config)
+    kinds = fam.layer_kinds(d)
     seed = job["seed"]
     dev = jax.devices()[0]
     if job.get("need_platform") and dev.platform != job["need_platform"]:
         raise SystemExit(f"reference came up on {dev.platform!r}")
     key = weights.root_key(seed)
-    glob = jax.jit(lambda key: {k: v.astype(jnp.float32) for k, v in
-                                weights.serving_globals(key, d).items()})(key)
+    glob = jax.jit(lambda key: fam.reference_globals(key, d, "serve"))(key)
 
-    @jax.jit
-    def layer_weights(key, l):
-        return weights.dense_f32(weights.serving_layer(key, l, d), d)
+    @partial(jax.jit, static_argnames="kind")
+    def layer_weights(key, l, kind):
+        return fam.reference_layer(key, l, d, kind, "serve")
 
-    @partial(jax.jit, static_argnames="lower", donate_argnums=0)
-    def run_block(x, w, positions, lower=None):
-        return model.block(x, w, positions, d, lower)
+    @partial(jax.jit, static_argnames=("kind", "lower"), donate_argnums=0)
+    def run_block(x, w, positions, kind, lower=None):
+        return fam.block(x, w, positions, d, lower, kind)
 
     @partial(jax.jit, static_argnames="lower")
     def run_head(x, at, final_norm, lm_head, lower=None):
-        return model.head(x[at], final_norm, lm_head, d, lower)
+        return fam.head(x[at], final_norm, lm_head, d, lower)
 
     embed = jax.jit(lambda table, ids: table[ids])
     n_max = max(len(r["served"]) for r in job["requests"])
@@ -70,10 +73,10 @@ def score(job: dict) -> dict:
         feed[:len(toks) - 1] = toks[:-1]
         xs = {m: embed(glob["embedding"], jnp.asarray(feed)) for m in modes}
         positions = jnp.arange(T)
-        for l in range(d["L"]):
-            w = layer_weights(key, l)
+        for l, kind in enumerate(kinds):
+            w = layer_weights(key, l, kind=kind)
             for m in modes:
-                xs[m] = run_block(xs[m], w, positions, lower=m)
+                xs[m] = run_block(xs[m], w, positions, kind=kind, lower=m)
         at = np.zeros(n_max, np.int32)           # positions that predict
         at[:n_s] = np.arange(n_p - 1, n_p - 1 + n_s)
         at = jnp.asarray(at)

@@ -3,7 +3,8 @@
     python -m benchmark.reference.score_train <job.json>
 
 Follows the program's first steps from the same seed: the weights are
-rebuilt by ``benchmark.weights`` (bf16 values, held in float32), the rows
+rebuilt by the configuration's family (``benchmark/families``: the values
+the program holds, in float32; one compilation a KIND of layer), the rows
 are the steps' own, the loss is the mean cross-entropy over every position,
 the optimizer is AdamW written out below. It reports each step's loss, the
 norm of the first gradient leaf by leaf, and the norm of each leaf's change
@@ -29,11 +30,15 @@ def score(job: dict) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import weights
-    from benchmark.reference import model
+    from benchmark import families, weights
 
     config = json.load(open(job["config_file"]))
-    d = weights.dims(config)
+    fam = families.load(config, "train")
+    d = fam.dims(config)
+    kinds = fam.layer_kinds(d)
+    # layers of one kind, in order: the program stacks them under one name
+    stacks = {k: [i for i, kk in enumerate(kinds) if kk == k]
+              for k in dict.fromkeys(kinds)}
     opt = config["train"]["optimizer"]
     lr, b1, b2, eps, wd = (opt[k] for k in (
         "learning_rate", "b1", "b2", "eps", "weight_decay"))
@@ -42,33 +47,34 @@ def score(job: dict) -> dict:
     dev = jax.devices()[0]
     if job.get("need_platform") and dev.platform != job["need_platform"]:
         raise SystemExit(f"reference came up on {dev.platform!r}")
-    f32 = lambda t: jax.tree.map(lambda x: x.astype(jnp.float32), t)
     positions = jnp.arange(seq)
 
     # One layer at a time, forward and backward: a whole-model gradient at
     # "highest" precision keeps three bf16 pieces of every weight beside
     # the float32 tree (11 GB of temporaries at 4 layers, chip run PR 23).
     key = weights.root_key(seed)
-    make_globals = jax.jit(lambda key: f32(weights.training_globals(key, d)))
+    make_globals = jax.jit(lambda key: fam.reference_globals(key, d, "train"))
     make_layer = jax.jit(
-        lambda key, l: f32(weights.training_layer(key, l, d)))
+        lambda key, l, kind: fam.reference_layer(key, l, d, kind, "train"),
+        static_argnames="kind")
 
     def init():
         return {**make_globals(key),
-                "layers": [make_layer(key, l) for l in range(d["L"])]}
+                "layers": [make_layer(key, l, kind=kind)
+                           for l, kind in enumerate(kinds)]}
 
-    @jax.jit
-    def block(x, w):
-        return model.block(x, w, positions, d, lower)
+    @partial(jax.jit, static_argnames="kind")
+    def block(x, w, kind):
+        return fam.block(x, w, positions, d, lower, kind)
 
-    @jax.jit
-    def block_back(x, w, gy):
-        _, vjp = jax.vjp(lambda x, w: model.block(x, w, positions, d, lower),
-                         x, w)
+    @partial(jax.jit, static_argnames="kind")
+    def block_back(x, w, gy, kind):
+        _, vjp = jax.vjp(
+            lambda x, w: fam.block(x, w, positions, d, lower, kind), x, w)
         return vjp(gy)                                   # (gx, gw)
 
     def head_loss(x, final_norm, lm_head, targets):
-        logits = model.head(x, final_norm, lm_head, d, lower)
+        logits = fam.head(x, final_norm, lm_head, d, lower)
         logz = jax.scipy.special.logsumexp(logits, -1)
         gold = jnp.take_along_axis(logits, targets[:, None], -1)[:, 0]
         return jnp.mean(logz - gold) / rows    # mean over rows and positions
@@ -84,7 +90,7 @@ def score(job: dict) -> dict:
         layer's gradient is folded into the running sum as it is made, so
         no second gradient tree ever exists."""
         loss = 0.0
-        grads = {"layers": [None] * d["L"]}
+        grads = {"layers": [None] * len(kinds)}
 
         def fold(key, g, where=None):
             where = grads if where is None else where
@@ -93,16 +99,17 @@ def score(job: dict) -> dict:
         for toks in batch:
             ids, targets = jnp.asarray(toks[:-1]), jnp.asarray(toks[1:])
             xs = [embed(params["embedding"], ids)]
-            for w in params["layers"]:
-                xs.append(block(xs[-1], w))
+            for w, kind in zip(params["layers"], kinds):
+                xs.append(block(xs[-1], w, kind=kind))
             l, (gx, g_norm, g_head) = head_grad(
                 xs.pop(), params["final_norm"], params["lm_head"], targets)
             grads.setdefault("final_norm", None)
             grads.setdefault("lm_head", None)
             fold("final_norm", g_norm)
             fold("lm_head", g_head)
-            for i in reversed(range(d["L"])):
-                gx, gw = block_back(xs.pop(), params["layers"][i], gx)
+            for i in reversed(range(len(kinds))):
+                gx, gw = block_back(xs.pop(), params["layers"][i], gx,
+                                    kind=kinds[i])
                 fold(i, gw, grads["layers"])
             grads["embedding"] = scatter(
                 grads["embedding"] if "embedding" in grads
@@ -114,16 +121,18 @@ def score(job: dict) -> dict:
     sq_diff = jax.jit(lambda a, b: jnp.sum(jnp.square(a - b)))
 
     def norms(tree, other=None):
-        """Leaf norms under the program's names: the layers' leaves are
-        stacked there, so a leaf's square sums over the layers."""
+        """Leaf norms under the program's names: the layers of a kind are
+        stacked there, so a leaf's square sums over those layers."""
         one = (lambda a, b: float(sq(a))) if other is None else (
             lambda a, b: float(sq_diff(a, b)))
         out = {k: one(tree[k], other and other[k]) ** 0.5
                for k in ("embedding", "final_norm", "lm_head")}
-        for name in tree["layers"][0]:
-            out["layers/" + name] = sum(
-                one(w[name], o and o[name]) for w, o in zip(
-                    tree["layers"], (other or tree)["layers"])) ** 0.5
+        for kind, idx in stacks.items():
+            for name in tree["layers"][idx[0]]:
+                out[fam.program_leaf(kind, name)] = sum(
+                    one(tree["layers"][i][name],
+                        other and other["layers"][i][name])
+                    for i in idx) ** 0.5
         return out
 
     @partial(jax.jit, donate_argnums=0)
@@ -144,20 +153,23 @@ def score(job: dict) -> dict:
     l0, g1 = value_and_grad(params, batch(0))
     losses = [l0]
     def sample(tree):
-        """The gradient at ``weights.sample_positions``; a layers' leaf is
-        stacked [L, ...] on the program's side, so a flat position there is
-        (layer, position inside the layer)."""
+        """The gradient at ``weights.sample_positions``; the leaves of a
+        kind's layers are stacked [n, ...] on the program's side, so a flat
+        position there is (layer of the kind, position inside the layer)."""
         out = {}
         for k in ("embedding", "final_norm", "lm_head"):
             pos = weights.sample_positions(seed, k, tree[k].size)
             out[k] = np.asarray(tree[k].reshape(-1)[pos]).tolist()
-        for name, leaf in tree["layers"][0].items():
-            pos = weights.sample_positions(seed, "layers/" + name,
-                                           leaf.size * d["L"])
-            flat = [np.asarray(w[name].reshape(-1)[pos % leaf.size])
-                    for w in tree["layers"]]
-            out["layers/" + name] = [
-                float(flat[p // leaf.size][i]) for i, p in enumerate(pos)]
+        for kind, idx in stacks.items():
+            for name, leaf in tree["layers"][idx[0]].items():
+                where = fam.program_leaf(kind, name)
+                pos = weights.sample_positions(seed, where,
+                                               leaf.size * len(idx))
+                flat = [np.asarray(
+                    tree["layers"][i][name].reshape(-1)[pos % leaf.size])
+                    for i in idx]
+                out[where] = [float(flat[p // leaf.size][i])
+                              for i, p in enumerate(pos)]
         return out
 
     out = {"platform": dev.platform, "device_kind": dev.device_kind,

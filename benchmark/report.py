@@ -63,20 +63,24 @@ def run_child(module: str, job: dict, rehearsal: bool, timeout: float,
 
 
 class Checks:
-    """The numbers ``correct`` is decided on, each printed beside its limit."""
+    """The numbers ``correct`` is decided on, each printed beside its limit
+    as it is read. ``compared`` keeps every one in the order read (a name
+    may come twice: both stay) for the result line's last key and the run's
+    last lines on standard error (``run.py``)."""
 
     def __init__(self):
-        self.results = []
+        self.compared = []
 
     def limit(self, name, value, bound, ok=None):
         ok = (value <= bound) if ok is None else ok
-        self.results.append(ok)
+        self.compared.append({"name": name, "value": value, "limit": bound,
+                              "ok": bool(ok)})
         print(f"# check {name}: {value} (limit {bound}) "
               f"{'ok' if ok else 'FAILED'}", flush=True)
 
     @property
     def correct(self) -> bool:
-        return all(self.results)
+        return all(c["ok"] for c in self.compared)
 
 
 def reported(bench: dict, cell: dict):

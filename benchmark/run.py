@@ -7,8 +7,10 @@ A new process each time: it loads, warms the cell's own shapes (set-up),
 measures for ``--seconds``, checks what the timed path produced against the
 float32 reference, and prints one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1``
-its per-layer metrics), ``device``, and ``breakdown`` when traced. Everything
-else goes on earlier ``#`` lines. This process never imports JAX: the pod's
+its per-layer metrics), ``device``, ``breakdown`` when traced, and last
+``compared``: every number ``correct`` was decided on beside its limit, which
+are also the run's last lines on standard error. Everything else goes on
+earlier ``#`` lines. This process never imports JAX: the pod's
 worker (serving) or a child (training) holds the chips. No chip, or fewer
 than the cell asks for: exit code 3 and no result line.
 
@@ -99,7 +101,11 @@ def main(argv=None) -> int:
     if args.override or not args.reference:
         line["sweep"] = {"override": args.override,
                          "reference": bool(args.reference)}
+    compared = line["compared"] = line.pop("compared")      # the last key
     print(json.dumps(line), flush=True)
+    for c in compared:
+        print(f"compared {c['name']}: {c['value']} (limit {c['limit']}) "
+              f"{'ok' if c['ok'] else 'FAILED'}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -13,9 +13,9 @@ import time
 
 import numpy as np
 
-from benchmark import loadgen, manifest, report, stats
+from benchmark import families, loadgen, manifest, report, stats
 from benchmark.report import CellFailure, note, run_child
-from benchmark.weights_dims import dims_of
+
 
 def live_positions(records, t0, t1, samples=64):
     """Time-average over [t0, t1] of the positions the active rows hold
@@ -55,7 +55,7 @@ def run(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
         server_cls=None) -> dict:
     traffic, config = cell["traffic_json"], cell["config_json"]
     dep = traffic["deployment"]
-    d = dims_of(config)
+    d = families.load(config, "serve").dims(config)
     names = report.reported(bench, cell)
     from kubetorch_tpu.config import compile_cache_dir
 
@@ -256,4 +256,5 @@ def run(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
     if scored is not None:
         line["reference"] = {k: v for k, v in scored.items()
                              if k != "requests"}
+    line["compared"] = checks.compared
     return line

@@ -5,9 +5,11 @@ as ``chip_smoke.py`` deploys ``examples/llama_serve.py::LlamaServer``, so the
 normal path is what is timed: pod server -> worker (the one process that
 holds the chip) -> ``DecodeEngine(RollingGenerator)``. It differs from
 ``LlamaServer`` in what a benchmark needs: the model comes from a
-configuration FILE (``LlamaConfig(**keys)``, not a preset name), the weights
-from ``benchmark.weights`` (so the float32 reference can rebuild them), there
-is no static ``Generator`` beside the engine, and the worker can trace itself.
+configuration FILE (not a preset name) through the file's family
+(``benchmark/families``: the program's configuration object and the weights
+from the seed, which the float32 reference can rebuild), there is no static
+``Generator`` beside the engine, and the worker can trace itself. The family
+chooses neither this class nor the engine path.
 """
 
 from __future__ import annotations
@@ -22,8 +24,7 @@ class BenchServer:
                  warm=()):
         import jax
 
-        from benchmark import weights
-        from kubetorch_tpu.models import LlamaConfig
+        from benchmark import families
         from kubetorch_tpu.models.rolling import RollingGenerator
         from kubetorch_tpu.observability import devstats
         from kubetorch_tpu.serving.engine import DecodeEngine
@@ -32,12 +33,10 @@ class BenchServer:
         self._compiles = devstats.watch_compiles()
         config = json.load(open(config_file))
         dep = dict(deployment)
-        cfg = LlamaConfig(**weights.llama_config_keys(config),
-                          max_seq_len=dep["max_len"], remat=False,
-                          dtype=config["compute_dtype"],
-                          param_dtype=config["compute_dtype"])
+        family = families.load(config, "serve")
+        cfg = family.program_config(config, "serve", dep)
         params = jax.block_until_ready(
-            weights.serving_tree(seed, weights.dims(config)))
+            family.serving_tree(seed, family.dims(config)))
         self._weights_s = time.perf_counter() - self._t_init
         self.cfg, self.deployment = cfg, dep
         self._generator = RollingGenerator(

@@ -9,9 +9,8 @@ import shutil
 import statistics
 import tempfile
 
-from benchmark import manifest, report
+from benchmark import families, manifest, report
 from benchmark.report import CellFailure, note, run_child
-from benchmark.weights_dims import dims_of
 
 
 def worst_leaf_gap(prog: dict, ref: dict):
@@ -65,7 +64,7 @@ def run(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
         t_start_epoch: float, rehearsal: bool = False, control: bool = False,
         child_module: str = "benchmark.train_child") -> dict:
     traffic, config = cell["traffic_json"], cell["config_json"]
-    d = dims_of(config)
+    d = families.load(config, "train").dims(config)
     names = report.reported(bench, cell)
     from kubetorch_tpu.config import compile_cache_dir
 
@@ -108,8 +107,14 @@ def run(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
         lim = cell["correct"]
         limit("first_loss_gap_relative", gaps["loss0_gap"],
               lim["loss0_gap_limit"])
-        limit("loss_gap_relative_worst_of_three", gaps["loss_gap"],
-              lim["loss_gap_limit"])
+        # the later losses are always printed (``# reference``: ``loss_gap``)
+        # and compared where the cell holds a limit for them: after two
+        # updates a bfloat16 run's third loss lies 6e-5 to 5e-3 from the
+        # float32 reference's by the seed, and an fp8 control no further
+        # (PERF.md section 2), so only a float32 cell states one
+        if "loss_gap_limit" in lim:
+            limit("loss_gap_relative_worst_of_three", gaps["loss_gap"],
+                  lim["loss_gap_limit"])
         limit(f"first_gradient_norm_gap_worst_leaf[{gaps['grad_norm_gap'][1]}]",
               gaps["grad_norm_gap"][0], lim["grad_norm_gap_limit"])
         limit(f"first_gradient_sample_distance_worst_leaf"
@@ -163,4 +168,5 @@ def run(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
                          for k, v in gaps.items()}
     if controls:
         line["controls"] = controls
+    line["compared"] = checks.compared
     return line

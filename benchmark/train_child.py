@@ -3,7 +3,8 @@
     python -m benchmark.train_child <job.json>
 
 Builds ONE ``Trainer`` (the program's, on the program's ``MeshSpec``) with
-the benchmark's weights as its ``init_fn``, drives it from the seed through
+the weights of the configuration's family (``benchmark/families``) as its
+``init_fn``, drives it from the seed through
 its first three steps by the window's own call and feed, reads what the
 reference will be compared with (each step's loss, the first gradient's norm
 leaf by leaf out of the optimizer's first moment, each leaf's change), and
@@ -36,22 +37,17 @@ def build(job, wrap_step=None):
     import jax.numpy as jnp
     import optax
 
-    from benchmark import weights
-    from kubetorch_tpu.models import LlamaConfig
+    from benchmark import families, weights
     from kubetorch_tpu.parallel import MeshSpec, ShardingRules
     from kubetorch_tpu.training import Trainer
     from kubetorch_tpu.training.trainer import param_shardings
 
     config = json.load(open(job["config_file"]))
-    d = weights.dims(config)
+    family = families.load(config, "train")
+    d = family.dims(config)
     tr, opt = config["train"], config["train"]["optimizer"]
     seed = job["seed"]
-    cfg = LlamaConfig(**weights.llama_config_keys(config),
-                      max_seq_len=tr["seq"], remat=True,
-                      remat_policy=tr["remat_policy"],
-                      attn_impl=tr["attn_impl"], xent_chunk=tr["xent_chunk"],
-                      dtype=config["compute_dtype"],
-                      param_dtype=config["weights_dtype"])
+    cfg = family.program_config(config, "train")
     mesh = MeshSpec(**config["mesh"]).build()
     rules = ShardingRules.default()
     shardings = param_shardings(cfg, mesh, rules)
@@ -65,7 +61,7 @@ def build(job, wrap_step=None):
         return jax.tree.map(
             lambda x, s: jax.lax.with_sharding_constraint(
                 x.astype(cfg.storage_dtype), s),
-            weights.training_tree(key, d), shardings)
+            family.training_tree(key, d), shardings)
 
     optimizer = optax.adamw(opt["learning_rate"], b1=opt["b1"], b2=opt["b2"],
                             eps=opt["eps"], weight_decay=opt["weight_decay"])

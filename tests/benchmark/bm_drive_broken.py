@@ -1,6 +1,6 @@
 """Drive one rehearsal run with the timed path broken; print the line.
 
-    python bm_drive_broken.py serve|train
+    python bm_drive_broken.py serve|train [cell]
 """
 
 import json
@@ -15,20 +15,20 @@ sys.path[:0] = [str(HERE.parents[1]), str(HERE)]
 from benchmark import manifest, serve_cell, train_cell  # noqa: E402
 
 
-def main(kind):
+def main(kind, cell=None):
     bench = manifest.benchmark_json()
     if kind == "serve":
         from bm_broken_server import BrokenServer
 
-        line = serve_cell.run(manifest.cell("rehearsal-serve"), bench, 5,
-                              5.0, False, T0, rehearsal=True,
+        line = serve_cell.run(manifest.cell(cell or "rehearsal-serve"),
+                              bench, 5, 5.0, False, T0, rehearsal=True,
                               server_cls=BrokenServer)
     else:
-        line = train_cell.run(manifest.cell("rehearsal-train"), bench, 5,
-                              2.0, False, T0_EPOCH, rehearsal=True,
+        line = train_cell.run(manifest.cell(cell or "rehearsal-train"),
+                              bench, 5, 2.0, False, T0_EPOCH, rehearsal=True,
                               child_module="tests.benchmark.bm_broken_child")
     print(json.dumps(line), flush=True)
 
 
 if __name__ == "__main__":
-    main(sys.argv[1])
+    main(*sys.argv[1:3])
