@@ -8,7 +8,8 @@ model code. Layers are stacked and scanned (``lax.scan``) so compile time is
 O(1) in depth.
 """
 
-from kubetorch_tpu.models.configs import LlamaConfig, MoEConfig, ViTConfig
+from kubetorch_tpu.models.configs import (LatentMoEConfig, LlamaConfig,
+                                          MoEConfig, ViTConfig)
 from kubetorch_tpu.models import llama
 
 
@@ -19,7 +20,7 @@ def __getattr__(name):
     import importlib
 
     if name in ("generate", "quant", "rolling", "speculative", "lora",
-                "embed"):
+                "embed", "decoder", "latent_moe"):
         return importlib.import_module(f"kubetorch_tpu.models.{name}")
     if name == "LoraConfig":
         return importlib.import_module(
@@ -42,7 +43,8 @@ def __getattr__(name):
     raise AttributeError(name)
 
 
-__all__ = ["LlamaConfig", "MoEConfig", "ViTConfig", "llama", "Generator",
+__all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig", "ViTConfig",
+           "decoder", "latent_moe", "llama", "Generator",
            "generate", "quant", "quantize_params", "RollingGenerator",
            "SpeculativeGenerator", "speculative", "lora", "LoraConfig",
            "embed", "Embedder"]

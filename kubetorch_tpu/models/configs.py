@@ -17,8 +17,11 @@ class MoEConfig:
     #   fine for few experts / small models).
     # "capacity": GShard-style fixed-capacity dispatch — each expert
     #   processes at most ceil(tokens * top_k / num_experts) *
-    #   capacity_factor tokens (overflow dropped), cutting expert FLOPs by
-    #   num_experts/top_k at static shapes XLA can tile.
+    #   capacity_factor tokens, cutting expert FLOPs by num_experts/top_k
+    #   at static shapes XLA can tile. It DROPS the overflow: a token past
+    #   an expert's capacity loses that expert's share of its output, so
+    #   this dispatch cannot match a reference's logits. The dropless
+    #   many-expert layer is ``models/latent_moe.py``'s.
     dispatch: str = "dense"
     capacity_factor: float = 1.25
 
@@ -89,6 +92,85 @@ class LlamaConfig:
         base = dict(moe=MoEConfig(num_experts=4, top_k=2, expert_mlp_dim=128))
         base.update(kw)
         return cls.tiny(**base)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    """Decoder with latent (compressed) attention and sigmoid-routed
+    experts beside shared ones (``models/latent_moe.py``; the layer as the
+    DeepSeek-V3 family of public configs writes it).
+
+    Attention, every layer: queries of ``n_heads x (qk_nope_dim +
+    qk_rope_dim)`` straight from the hidden state (no query compression);
+    keys and values through one ``kv_latent_dim`` vector a position plus one
+    ``qk_rope_dim`` rope key shared by all heads: the cache holds those
+    two, nothing a head. The first ``n_dense_layers`` layers have a SwiGLU of
+    ``dense_mlp_dim``; the rest route each token to ``top_k`` of
+    ``n_experts`` SwiGLUs of ``expert_mlp_dim`` (sigmoid scores, a bias that
+    only selects, weights renormalised over the chosen and scaled by
+    ``routed_scale``) and add ``n_shared_experts`` shared ones computed as
+    one SwiGLU of ``n_shared_experts * expert_mlp_dim``."""
+
+    vocab_size: int = 128256
+    embed_dim: int = 2048
+    n_layers: int = 8
+    n_heads: int = 32
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    kv_latent_dim: int = 512
+    dense_mlp_dim: int = 6144
+    n_dense_layers: int = 1
+    n_experts: int = 128
+    top_k: int = 6
+    expert_mlp_dim: int = 768
+    n_shared_experts: int = 2
+    routed_scale: float = 2.448
+    norm_topk: bool = True
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    max_seq_len: int = 8192
+    dtype: str = "bfloat16"        # compute dtype
+    param_dtype: str = "bfloat16"  # storage dtype
+
+    def __post_init__(self):
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError(
+                f"n_dense_layers {self.n_dense_layers} outside "
+                f"0..n_layers {self.n_layers}")
+        if self.top_k > self.n_experts:
+            raise ValueError(f"top_k {self.top_k} > n_experts "
+                             f"{self.n_experts}")
+        if self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim must be even (rope pairs)")
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def storage_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @classmethod
+    def tiny(cls, **kw) -> "LatentMoEConfig":
+        """CI config: 1 dense + 2 expert layers, float32."""
+        base = dict(vocab_size=512, embed_dim=64, n_layers=3, n_heads=4,
+                    qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+                    kv_latent_dim=32, dense_mlp_dim=128, n_dense_layers=1,
+                    n_experts=8, top_k=2, expert_mlp_dim=32,
+                    n_shared_experts=1, max_seq_len=128,
+                    dtype="float32", param_dtype="float32")
+        base.update(kw)
+        return cls(**base)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -213,6 +213,12 @@ def _moe_block_capacity(x, layer, cfg: LlamaConfig, rules: ShardingRules):
     weighted by their renormalized gates. No [tokens, X, C] one-hot is ever
     materialized (GShard's einsum formulation costs O(n·X·C) memory; the
     scatter form is O(n·K + X·C·E)).
+
+    This dispatch DROPS tokens: an assignment past an expert's capacity
+    contributes nothing, so its output cannot match a reference that
+    computes every chosen expert. Kept for the dense decoder's users who
+    train with it; the dropless layer (sorted pairs, grouped products over
+    uneven groups) is ``models/latent_moe.py``'s.
     """
     moe = cfg.moe
     B, S, E = x.shape

@@ -9,11 +9,12 @@ batch blocking until its slowest member finishes (the static
 
 TPU shape discipline + dispatch discipline:
 
-- Everything is static-shaped. The engine owns a ``[L, max_slots, max_len,
-  Hkv, D]`` cache; a *slot* is a batch row. New requests prefill into a
-  free slot (jitted per padded-length bucket), and decode advances **all**
-  active slots — each at its own depth via the per-sequence ``write_at``
-  scatter in ``llama.forward_cached``.
+- Everything is static-shaped. The engine owns a cache of ``[L, max_slots,
+  max_len, ...]`` leaves; a *slot* is a batch row. New requests prefill into
+  a free slot (jitted per padded-length bucket), and decode advances **all**
+  active slots, each at its own depth.
+- The layers and what a cache leaf holds are the decoder's
+  (``models/decoder.py``: ``decoder_for(cfg)``); this module names no leaf.
 - All decode state (cache, pending logits, depths, active mask) lives on
   device between calls; the host holds only bookkeeping. Each
   :meth:`step` is ONE jit call running ``steps_per_call`` tokens through a
@@ -40,10 +41,9 @@ import numpy as np
 from kubetorch_tpu.config import env_float, env_int
 from kubetorch_tpu.lookahead import LookaheadState, spec_stats_dict
 from kubetorch_tpu.observability import devstats
-from kubetorch_tpu.models import llama
-from kubetorch_tpu.models.configs import LlamaConfig
+from kubetorch_tpu.models.decoder import (decoder_for, grid_dims,
+                                          position_bytes)
 from kubetorch_tpu.models.generate import filter_logits
-from kubetorch_tpu.ops import decode_attention
 from kubetorch_tpu.parallel.sharding import ShardingRules
 
 
@@ -114,7 +114,7 @@ class RollingGenerator:
     ...         ...
     """
 
-    def __init__(self, params: Dict[str, Any], cfg: LlamaConfig,
+    def __init__(self, params: Dict[str, Any], cfg,
                  max_slots: int = 8, max_len: Optional[int] = None,
                  mesh=None, rules: Optional[ShardingRules] = None,
                  eos_id: Optional[int] = None, top_k: Optional[int] = None,
@@ -183,6 +183,14 @@ class RollingGenerator:
         pre-computed) keep it regardless."""
         self.params = params
         self.cfg = cfg
+        self.model = decoder_for(cfg)
+        # a decoder that lacks a serving feature says so here, by name
+        self.model.check_serving(
+            cfg, kv_dtype=kv_dtype,
+            spec=(spec_k if spec_k is not None
+                  else env_int("KT_SPEC_K_MAX")) > 1,
+            adapters=adapters is not None,
+            mesh=mesh is not None and mesh.size > 1)
         self.mesh = mesh
         self.rules = rules or ShardingRules.default()
         self.max_slots = max_slots
@@ -247,8 +255,8 @@ class RollingGenerator:
         self.spec_ema_alpha = (spec_ema_alpha if spec_ema_alpha is not None
                                else env_float("KT_SPEC_EMA_ALPHA"))
         self.spec = spec_k > 1
-        self.cache = llama.init_cache(cfg, max_slots, self.max_len,
-                                      quantized=self.kv_quantized)
+        self.cache = self.model.init_cache(cfg, max_slots, self.max_len,
+                                           quantized=self.kv_quantized)
         self._logits = jnp.zeros((max_slots, cfg.vocab_size), jnp.float32)
         self._dpos = jnp.zeros((max_slots,), jnp.int32)
         self._dactive = jnp.zeros((max_slots,), bool)
@@ -315,11 +323,14 @@ class RollingGenerator:
         self._depth = np.zeros(max_slots, np.int64)
         self._kv_positions = {"live": 0, "read": 0, "grid": 0}
         with self._mesh_ctx():
-            self._ragged_block = (
-                decode_attention.block_for(self.max_len)
-                if not self.spec and decode_attention.engages(
-                    1, self.max_len, cfg.n_kv_heads, cfg.head_dim,
-                    self.cache["k"].dtype) else None)
+            self._ragged_block = self.model.ragged_block(
+                cfg, self.max_len, self.cache, self.spec)
+        # the decoder's own per-step counters (``model.counters``): decode
+        # chunks sum them on the device and they ride the tokens' fetch;
+        # what a prefill adds is counted here, on the host
+        self._model_counts = {name: 0 for name in self.model.counters}
+        self._kv_position_bytes = position_bytes(self.model, cfg,
+                                                 self.kv_quantized)
 
         # Device-truth utilization accounting: every jitted dispatch
         # below routes through this accumulator, which captures each
@@ -411,9 +422,19 @@ class RollingGenerator:
         """Host-only counters of this generator (no device read): what
         decode attention read of the KV grid. ``read / grid`` is 1.0 where
         the einsum pair runs and the live share, rounded up to key blocks,
-        where the ragged kernel does."""
-        return {f"decode_kv_positions_{k}": int(v)
-                for k, v in self._kv_positions.items()}
+        where the ragged kernel does. Beside them the decoder's own
+        counters (fetched with the tokens of each decode chunk) and the
+        bytes one position holds over all layers, a gauge."""
+        out = {f"decode_kv_positions_{k}": int(v)
+               for k, v in self._kv_positions.items()}
+        out.update(self._model_counts)
+        out["kv_position_bytes"] = self._kv_position_bytes
+        return out
+
+    def _count_prefill(self, prompt_tokens: int) -> None:
+        for name, n in self.model.prefill_counters(
+                self.cfg, prompt_tokens).items():
+            self._model_counts[name] += n
 
     def _count_kv_read(self) -> None:
         """Account one decode chunk, from the depths it starts at."""
@@ -744,6 +765,7 @@ class RollingGenerator:
         ``submit`` must pass the matching ``adapter_id``. Per-adapter
         prefix caches are just multiple ``register_prefix`` calls."""
         self._check_adapter_id(adapter_id)
+        self.model.check_serving(self.cfg, prefix=True)
         tokens = list(tokens)
         p_pad = _bucket(len(tokens))
         toks = np.zeros((1, p_pad), np.int32)
@@ -963,13 +985,14 @@ class RollingGenerator:
             kk: np.concatenate(
                 [np.asarray(blocks[b]) for b in sorted(blocks)], axis=1)
             for kk, blocks in state["kv"].items()}
-        dend = planes["k"].shape[1]
-        if dend > self.max_len or planes["k"].shape[0] != \
-                self.cache["k"].shape[0] or \
-                planes["k"].shape[2:] != self.cache["k"].shape[3:]:
-            raise ValueError(
-                f"imported KV shape {planes['k'].shape} does not fit "
-                f"grid {self.cache['k'].shape} (max_len {self.max_len})")
+        dend = next(iter(planes.values())).shape[1]
+        for kk, plane in planes.items():
+            grid = self.cache[kk].shape
+            if dend > self.max_len or plane.shape[0] != grid[0] or \
+                    plane.shape[1] != dend or plane.shape[2:] != grid[3:]:
+                raise ValueError(
+                    f"imported KV shape {plane.shape} does not fit "
+                    f"grid {grid} (max_len {self.max_len})")
         margin = self.steps_per_call * (self.spec_k if self.spec else 1)
         if dpos + (max_new - n_emitted) + margin > self.max_len:
             raise ValueError(
@@ -1095,6 +1118,7 @@ class RollingGenerator:
         self._slot_adapter[req.slot] = req.adapter_id
         self._prefilling[req.slot] = req
         self.prefill_tokens += len(req.prompt)
+        self._count_prefill(len(req.prompt))
 
     def _admit_group(self, group: List[Request], p_pad: int,
                      prefix_id: Optional[int] = None):
@@ -1128,6 +1152,7 @@ class RollingGenerator:
                 self._prefixes[prefix_id]["len"] if prefix_id is not None
                 else 0)
             self.prefill_tokens += len(req.prompt)
+            self._count_prefill(len(req.prompt))
         with self._mesh_ctx():
             if prefix_id is None:
                 (self.cache, self._logits, self._dpos,
@@ -1195,6 +1220,11 @@ class RollingGenerator:
         with self.tick_phase("decode_sync"):
             toks = np.asarray(toks)                   # [K, B] — the one sync
         with self.tick_phase("route"):
+            # the decoder's counters came as rows under the tokens
+            for i, name in enumerate(self.model.counters):
+                self._model_counts[name] += int(
+                    toks[self.steps_per_call + i, 0])
+            toks = toks[:self.steps_per_call]
             # roll the host-side penalty windows by this chunk's tokens
             K = toks.shape[0]
             W = self._win.shape[1]
@@ -1357,11 +1387,9 @@ class RollingGenerator:
         m = jnp.arange(p_pad)[None, None, :]
         t = positions[:, :, None]
         mask = (m <= t) & (m < prompt_lens[:, None, None])
-        own = llama.init_cache(cfg, N, p_pad,
-                               dtype=(None if "ks" in cache
-                                      else cache["k"].dtype),
-                               quantized="ks" in cache)
-        out, own = llama.forward_cached(
+        model = decoder_for(cfg)
+        own = model.init_cache_like(cfg, cache, N, p_pad)
+        out, own, _ = model.forward_cached(
             params, tokens, positions, own, 0, mask, cfg, rules,
             unembed_positions=prompt_lens - 1, lora=lora)
         return RollingGenerator._finish_admit(
@@ -1381,8 +1409,8 @@ class RollingGenerator:
         into the own-cache first), so the splice touches only that span.
         ``last``: [N, V] logits at each row's final real token.
         """
-        B = cache["k"].shape[1]
-        M_own = own["k"].shape[2]
+        B = grid_dims(cache)[1]
+        M_own = grid_dims(own)[2]
         onehot = slots[None, :] == jnp.arange(B)[:, None]       # [B, N]
         sel = jnp.argmax(onehot, axis=1)                        # [B]
         any_valid = onehot.any(axis=1)
@@ -1416,8 +1444,9 @@ class RollingGenerator:
         positions = jnp.arange(p_pad)[None, :]
         m = jnp.arange(p_pad)[None, None, :]
         mask = (m <= positions[:, :, None]) & (m < prefix_len)
-        own = llama.init_cache(cfg, 1, p_pad, quantized=quantized)
-        out, own = llama.forward_cached(
+        model = decoder_for(cfg)
+        own = model.init_cache(cfg, 1, p_pad, quantized=quantized)
+        out, own, _ = model.forward_cached(
             params, tokens, positions, own, 0, mask, cfg, rules,
             unembed_positions=(prefix_len - 1)[None], lora=lora)
         return own, out[0, 0]
@@ -1438,19 +1467,17 @@ class RollingGenerator:
         private cache, so the int8 serving grid composes with shared
         prefixes. ``lora``: the suffix forward runs under the prefix's
         owning adapter (submit enforced the match)."""
-        M = cache["k"].shape[2]
+        M = grid_dims(cache)[2]
         N = tokens.shape[0]
-        L, _, Ppad, Hkv, D = planes["k"].shape
+        L, _, Ppad = grid_dims(planes)
+        model = decoder_for(cfg)
         # Rows needed: the prefix block plus the suffix span — suffix rows
         # write at [prefix_len, prefix_len + p_pad) and prefix_len ≤ Ppad.
         # Clamped to the grid's M: a long prefix whose BUCKET plus the
         # suffix bucket overshoots max_len (the real tokens fit — submit()
         # checked) must not build an own-cache wider than the grid it
         # splices into.
-        own = llama.init_cache(cfg, N, min(Ppad + p_pad, M),
-                               dtype=(None if "ks" in cache
-                                      else cache["k"].dtype),
-                               quantized="ks" in cache)
+        own = model.init_cache_like(cfg, cache, N, min(Ppad + p_pad, M))
 
         def bcast(plane_own, plane_px):
             shp = (L, N) + plane_px.shape[2:]
@@ -1461,9 +1488,9 @@ class RollingGenerator:
         own = {kk: bcast(own[kk], planes[kk]) for kk in own}
         positions = prefix_len + jnp.broadcast_to(
             jnp.arange(p_pad)[None, :], (N, p_pad))
-        m = jnp.arange(own["k"].shape[2])[None, None, :]
+        m = jnp.arange(grid_dims(own)[2])[None, None, :]
         mask = m <= positions[:, :, None]
-        out, own = llama.forward_cached(
+        out, own, _ = model.forward_cached(
             params, tokens, positions, own, prefix_len, mask, cfg, rules,
             unembed_positions=prompt_lens - 1, lora=lora)
         return RollingGenerator._finish_admit(
@@ -1491,10 +1518,9 @@ class RollingGenerator:
         this path fills them, which is what lets the serving engine
         interleave prefill chunks between decode chunks without ever
         stalling token emission."""
-        M = cache["k"].shape[2]
+        M = grid_dims(cache)[2]
         B = feed.shape[0]
-        L, _, _, Hkv, D = cache["k"].shape
-        cdt = jnp.bfloat16 if "ks" in cache else cache["k"].dtype
+        model = decoder_for(cfg)
         live = counts > 0
         positions = dpos[:, None] + jnp.arange(C)[None, :]
         gmask = jnp.broadcast_to(
@@ -1508,13 +1534,12 @@ class RollingGenerator:
                   <= jnp.arange(C)[None, :, None])
                  & (jnp.arange(C)[None, None, :]
                     < counts[:, None, None]))
-        chunk = {"k": jnp.zeros((L, B, C, Hkv, D), cdt),
-                 "v": jnp.zeros((L, B, C, Hkv, D), cdt)}
-        out, chunk = llama.forward_cached(
+        chunk = model.init_chunk(cfg, cache, B, C)
+        out, chunk, _ = model.forward_cached(
             params, feed, positions, cache, None, gmask, cfg, rules,
             chunk=chunk, chunk_col=0, chunk_mask=emask,
             unembed_positions=jnp.maximum(counts - 1, 0), lora=lora)
-        cache = llama.merge_chunk_into_grid(cache, chunk, dpos, counts)
+        cache = model.merge_chunk_into_grid(cache, chunk, dpos, counts)
         fin = finals & live
         logits = jnp.where(fin[:, None], out[:, 0], logits)
         return cache, logits, dpos + counts, dactive | fin
@@ -1549,9 +1574,9 @@ class RollingGenerator:
         (positive logits divided, negative multiplied). The window rolls
         inside the scan so a token sampled at step k is already penalized
         at step k+1."""
-        M = cache["k"].shape[2]
+        M = grid_dims(cache)[2]
         B = last_logits.shape[0]
-        L, _, _, Hkv, D = cache["k"].shape
+        model = decoder_for(cfg)
         pos0 = pos
         # Grid contents never change during the chunk: rows < pos0 hold
         # every previous token, the current chunk's rows live in the
@@ -1560,14 +1585,14 @@ class RollingGenerator:
                  & active[:, None, None])
         # the same mask as a length: what the ragged kernel reads to
         depth0 = jnp.where(active, pos0, 0)
-        cdt = (jnp.bfloat16 if "ks" in cache else cache["k"].dtype)
-        chunk0 = {
-            "k": jnp.zeros((L, B, n_steps, Hkv, D), cdt),
-            "v": jnp.zeros((L, B, n_steps, Hkv, D), cdt),
-        }
+        chunk0 = model.init_chunk(cfg, cache, B, n_steps)
+        # the decoder's counters of a step, summed over the chunk ({} for a
+        # decoder that has none: nothing is added to the program)
+        counts0 = {name: jnp.zeros((), jnp.int32)
+                   for name in model.counters}
 
         def one(carry, inp):
-            chunk, logits, pos, win = carry
+            chunk, logits, pos, win, counts = carry
             j, step_key = inp
             pen = penalties[:, None]                       # [B, 1]
             idx = jnp.maximum(win, 0)
@@ -1597,15 +1622,22 @@ class RollingGenerator:
             positions = pos[:, None]
             emask = ((jnp.arange(n_steps)[None, None, :] <= j)
                      & active[:, None, None])
-            out, chunk = llama.forward_cached(
+            out, chunk, step = model.forward_cached(
                 params, tok[:, None], positions, cache, None, gmask, cfg,
                 rules, chunk=chunk, chunk_col=j, chunk_mask=emask,
                 lora=lora, grid_depth=depth0)
-            return (chunk, out[:, 0], pos + 1, win), tok
+            counts = {name: counts[name] + step[name] for name in counts}
+            return (chunk, out[:, 0], pos + 1, win, counts), tok
 
-        (chunk, logits, pos, _), toks = jax.lax.scan(
-            one, (chunk0, last_logits, pos, window),
+        (chunk, logits, pos, _, counts), toks = jax.lax.scan(
+            one, (chunk0, last_logits, pos, window, counts0),
             (jnp.arange(n_steps), jax.random.split(key, n_steps)))
+        if counts:
+            # one row a counter under the tokens, its value in column 0:
+            # fetched by the one read that fetches the tokens
+            toks = jnp.concatenate(
+                [toks, jnp.zeros((len(counts), B), jnp.int32).at[:, 0].set(
+                    jnp.stack([counts[name] for name in model.counters]))])
 
         # Merge the chunk into the grid at each slot's offset — shared
         # one-hot einsum select (llama.merge_chunk_into_grid; see its
@@ -1614,7 +1646,7 @@ class RollingGenerator:
         # advance either: a row mid-CHUNKED-PREFILL (owned but not yet
         # decoding) rides through decode chunks, and a drifting dpos
         # would land its next prefill chunk past the real prompt.
-        new_cache = llama.merge_chunk_into_grid(
+        new_cache = model.merge_chunk_into_grid(
             cache, chunk, pos0, jnp.where(active, n_steps, 0))
         return new_cache, logits, jnp.where(active, pos, pos0), toks
 
@@ -1666,13 +1698,11 @@ class RollingGenerator:
             residual_next,
         )
 
-        M = cache["k"].shape[2]
+        M = grid_dims(cache)[2]
         B = last_logits.shape[0]
-        L = cache["k"].shape[0]
-        Hkv, D = cache["k"].shape[3], cache["k"].shape[4]
+        model = decoder_for(cfg)
         Lctx = ctx.shape[1]
         bidx = jnp.arange(B)[:, None]
-        cdt = jnp.bfloat16 if "ks" in cache else cache["k"].dtype
         # `sampling` is STATIC (the host re-jits once if sampled traffic
         # ever appears): all-greedy dispatches — the established serving
         # path — must not pay the softmax/filter/categorical machinery
@@ -1721,9 +1751,8 @@ class RollingGenerator:
                 jnp.arange(k)[None, None, :]
                 <= jnp.arange(k)[None, :, None], (B, k, k)) \
                 & active[:, None, None]
-            chunk = {"k": jnp.zeros((L, B, k, Hkv, D), cdt),
-                     "v": jnp.zeros((L, B, k, Hkv, D), cdt)}
-            lg, chunk = llama.forward_cached(
+            chunk = model.init_chunk(cfg, cache, B, k)
+            lg, chunk, _ = model.forward_cached(
                 params, feed, positions, cache, None, gmask, cfg, rules,
                 chunk=chunk, chunk_col=0, chunk_mask=emask, lora=lora)
             g = jnp.argmax(lg, axis=-1).astype(jnp.int32)         # [B, k]
@@ -1743,7 +1772,7 @@ class RollingGenerator:
                 acc_s = rejection_accept(probs, feed, k_acc, k=k, kk=kk)
                 acc = jnp.where(sampled, acc_s, acc)
             emit = jnp.where(active, 1 + acc, 0)
-            cache = llama.merge_chunk_into_grid(cache, chunk, pos, emit)
+            cache = model.merge_chunk_into_grid(cache, chunk, pos, emit)
             # context mirrors the grid's accepted prefix
             cpos = pos[:, None] + jnp.arange(k)[None, :]
             cvalid = jnp.arange(k)[None, :] < emit[:, None]

@@ -636,6 +636,13 @@ class DecodeEngine:
                            * kvpool.blocks_for(self._row_cap_tokens, bt))
             budget = 2 * grid_blocks
         self._kv = kvpool.PagedKVPool(budget, bt, prefix_split)
+        # a decoder that does not carry what this engine was configured
+        # for (models/decoder.py: check_serving) says so now, by name
+        self._model = getattr(engine, "model", None)
+        if self._model is not None:
+            self._model.check_serving(
+                engine.cfg, handoff=self._phase != "mixed",
+                prefix=self._kv.split is not None)
         # rid -> {"blocks", "session", "prefix_pid"} — the release-side
         # bookkeeping of the ledger reservations made at submit
         self._rid_meta: Dict[int, Dict[str, Any]] = {}
@@ -759,6 +766,9 @@ class DecodeEngine:
                 "this engine is a prefill-tier pod "
                 "(KT_DISAGG_PHASE=prefill): programs must carry "
                 "handoff= — decode runs on the decode tier")
+        if self._model is not None and (prog.handoff is not None
+                                        or prog.handoff_id is not None):
+            self._model.check_serving(self.engine.cfg, handoff=True)
         sink: "_queue.SimpleQueue" = _queue.SimpleQueue()
         # exemplar context for the TTFT histogram: the submit runs
         # under the call's ambient span; first token lands in the

@@ -202,3 +202,62 @@ def test_kernel_compiles_for_v5e_with_the_planes_as_stored(kv, v5e_chip):
              if plane.search(line.split("=", 1)[-1].split("(", 1)[0])
              and re.search(r"\b(copy|transpose|fusion)\(", line)]
     assert not moved, moved
+
+
+# The latent-attention decoder's kernels at the published widths of the
+# benchmark's second configuration, compiled for the same described chip
+# (this is the one test file whose worker may load the TPU's compiler).
+@pytest.mark.parametrize("kernel", ["latent_decode", "latent_prefill",
+                                    "grouped_decode", "grouped_prefill"])
+def test_latent_and_grouped_kernels_compile_for_v5e(kernel, v5e_chip):
+    """32 heads over one 640-wide latent leaf at 32 slots x 8192 (the stack
+    goes into the custom call as it lies); blocked prefill attention with
+    key width 192 (padded to 256) beside value width 128 at 8192 tokens;
+    the grouped product of 128 experts of 2048 x 1536 over a decode step's
+    192 pairs and a prefill's 49152."""
+    from kubetorch_tpu.ops import grouped_matmul, latent_attention
+
+    bf16 = jnp.bfloat16
+
+    def spec(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    if kernel == "latent_decode":
+        b, m = 32, 8192
+
+        def fn(q, grid, layer, depth):
+            return latent_attention.ragged_decode_attention(
+                q, grid, layer, latent_attention.plan(depth, m), 512,
+                192 ** -0.5)
+        args = (spec((b, 32, 640)), spec((8, b, m, 640)),
+                spec((), jnp.int32), spec((b,), jnp.int32))
+    elif kernel == "latent_prefill":
+        t = 8192
+
+        def fn(qn, qr, kn, kr, v):
+            return latent_attention.prefill_attention(
+                qn, qr, kn, kr, v, 192 ** -0.5, interpret=False)
+        args = (spec((1, t, 32, 128)), spec((1, t, 32, 64)),
+                spec((1, t, 32, 128)), spec((1, t, 64)),
+                spec((1, t, 32, 128)))
+    else:
+        rows = 192 if kernel == "grouped_decode" else 49152
+
+        def fn(lhs, rhs, layer, sizes):
+            return grouped_matmul.grouped_matmul(lhs, rhs, layer, sizes,
+                                                 interpret=False)
+        args = (spec((rows, 2048)), spec((7, 128, 2048, 1536)),
+                spec((), jnp.int32), spec((128,), jnp.int32))
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    assert text.count("tpu_custom_call") == 1
+    # nothing the size of the cache or of a layer's experts is copied
+    big = re.compile(r"bf16\[(8,32,8192,640|128,2048,1536)\]")
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line.split("=", 1)[-1].split("(", 1)[0])
+             and re.search(r"\b(copy|transpose|fusion)\(", line)]
+    assert not moved, moved

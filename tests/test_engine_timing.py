@@ -361,3 +361,40 @@ def test_nested_phase_time_is_exclusive():
     assert 0.01 <= st["tick_evict_s"] < 0.03
     assert st["tick_evict_n"] == st["tick_evict_sync_n"] == 1
     assert timer.open is None
+
+
+# ---------------- (f) the second decoder's counters, held to a hand count
+@pytest.mark.level("minimal")
+def test_expert_counters_equal_a_hand_count():
+    """``moe_*`` ride ``RollingGenerator.stats()`` into
+    ``DecodeEngine.stats()``: pairs computed = tokens x top_k x expert
+    layers (prompt tokens counted at admission, one a live row a decode
+    step, the steps a finished row idles to its chunk's end included);
+    touched <= slots = expert layers x steps x experts; cache positions
+    fetched >= positions live."""
+    from kubetorch_tpu.models import LatentMoEConfig, latent_moe
+
+    cfg = LatentMoEConfig.tiny()
+    params = latent_moe.init(jax.random.key(0), cfg)
+    gen = RollingGenerator(params, cfg, max_slots=2, max_len=96,
+                           steps_per_call=4)
+    eng = DecodeEngine(gen, poll_s=0.002)
+    try:
+        prompts, n_new = [[3, 1, 4, 1, 5, 9, 2], [2, 7, 1, 8]], 8
+        _run(eng, prompts, n_new=n_new)
+        s = eng.stats()
+    finally:
+        eng.close()
+    layers, steps = cfg.n_moe_layers, s["steps"] * 4
+    # every row decodes n_new tokens = 2 chunks of 4: both rows live in
+    # every step they were dispatched in
+    decoded = 2 * n_new
+    assert s["moe_assignments"] == (
+        sum(len(p) for p in prompts) + decoded) * cfg.top_k * layers
+    assert s["moe_expert_slots"] == layers * steps * cfg.n_experts
+    assert 0 < s["moe_experts_touched"] <= s["moe_expert_slots"]
+    # a step's rows choose top_k experts each: at most rows x top_k touched
+    assert s["moe_experts_touched"] <= decoded * cfg.top_k * layers
+    assert s["moe_group_max"] >= layers * steps    # some expert, every step
+    assert s["decode_kv_positions_read"] >= s["decode_kv_positions_live"] > 0
+    assert s["kv_position_bytes"] == cfg.n_layers * 128 * 4
