@@ -23,7 +23,12 @@ object it was built with (``decoder_for(cfg)``):
 - ``forward_cached(...)``: ``models/llama.py::forward_cached``'s contract
   (prefill into a private cache, or chunk mode over the read-only grid) with
   a third result, the step's counters (``{}`` where the decoder has none).
-- ``merge_chunk_into_grid(cache, chunk, start, count)``.
+- ``merge_chunk_into_grid(cache, chunk, start, count)``: chunk columns
+  ``[0, count[b])`` of row ``b`` land at positions ``start[b] + col`` of
+  every layer and leaf, and nothing else of the grid is read or written
+  (``ops/grid_write.py``: a loop over the landing rows of slice updates, not
+  a scatter and not a select over whole planes); a column at or past
+  ``count[b]`` or a position ``>= M`` never lands.
 - ``ragged_block(cfg, max_len, cache, spec)``: the key block of the decode
   attention that reads a row only to its depth, or None where every step
   streams the whole grid: what ``decode_kv_positions_read`` counts.

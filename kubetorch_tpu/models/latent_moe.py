@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from kubetorch_tpu.models.configs import LatentMoEConfig
 from kubetorch_tpu.models.decoder import CacheLeaf
-from kubetorch_tpu.ops import grouped_matmul, latent_attention
+from kubetorch_tpu.ops import grid_write, grouped_matmul, latent_attention
 from kubetorch_tpu.ops.norms import rms_norm
 from kubetorch_tpu.ops.rope import rope_angles
 
@@ -448,33 +448,12 @@ def init_cache(cfg: LatentMoEConfig, batch: int, max_len: int, dtype=None,
 
 def merge_chunk_into_grid(cache, chunk, start, count):
     """Write chunk columns ``[0, count[b])`` into grid positions
-    ``start[b] + col`` of every layer and leaf: the one-hot select of
-    ``llama.merge_chunk_into_grid`` (why never a scatter: its docstring),
-    over leaves of one vector a position."""
-    L, _, M = cache["ckr"].shape[:3]
-    K = chunk["ckr"].shape[2]
-    cdt = cache["ckr"].dtype
-    idx = jnp.arange(M)[None, :] - start[:, None]                  # [B, M]
-    inwin = (idx >= 0) & (idx < count[:, None])
-    onehot = ((jnp.arange(K)[None, None, :] == idx[:, :, None])
-              & inwin[:, :, None]).astype(cdt)                     # [B, M, K]
-    names = tuple(cache)
-
-    def merge_layer(grids, inp):
-        li, cols = inp
-        out = []
-        for grid_all, col in zip(grids, cols):
-            new = jnp.einsum("bmk,bkd->bmd", onehot,
-                             col.astype(cdt)).astype(cdt)
-            old = jax.lax.dynamic_index_in_dim(grid_all, li, 0, False)
-            out.append(jax.lax.dynamic_update_index_in_dim(
-                grid_all, jnp.where(inwin[:, :, None], new, old), li, 0))
-        return tuple(out), None
-
-    grids, _ = jax.lax.scan(
-        merge_layer, tuple(cache[n] for n in names),
-        (jnp.arange(L), tuple(chunk[n] for n in names)))
-    return dict(zip(names, grids))
+    ``start[b] + col`` of every layer and leaf:
+    ``llama.merge_chunk_into_grid``'s row loop of slice updates
+    (``ops/grid_write.py``; why that is not a scatter: its docstring), over
+    leaves of one vector a position. Only the rows' windows are read and
+    written, never a layer's whole ``[B, M]`` plane."""
+    return grid_write.write_columns(cache, chunk, start, count)
 
 
 def forward_cached(params: Params, tokens, positions, cache, write_at, mask,

@@ -31,7 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.ops import apply_rope, dot_product_attention, rms_norm, rope_angles
-from kubetorch_tpu.ops import quant_matmul
+from kubetorch_tpu.ops import grid_write, quant_matmul
 from kubetorch_tpu.parallel.sharding import ShardingRules, shard_constraint
 
 Params = Dict[str, Any]
@@ -646,91 +646,29 @@ def merge_chunk_into_grid(cache: Dict[str, jax.Array],
     ``start[b] + col`` for every layer — the ONLY per-sequence-offset
     cache write in the decode paths, amortized over a whole chunk.
 
-    A one-hot EINSUM select, not take_along_axis/scatter: generic gathers
-    with computed index maps serialize on TPU (measured ~1.8 s/step — 50×
-    the whole decode step — when this was a full-cache take_along_axis;
-    same pathology as generic scatters). The einsum is matmul-shaped, so
-    it runs on the MXU at HBM speed; scanning per layer keeps the temp at
-    one layer's [B, M, Hkv, D]. Shared by rolling decode (uniform count =
-    chunk size for active slots) and speculative verify (count = accepted
-    prefix; rejected drafts never land, so there is no rollback).
+    A loop over the rows of slice updates (``ops/grid_write.py``): each
+    row's ``[L, 1, K]`` window is read, the chunk's columns selected in and
+    the window written back in place, so the bytes moved follow
+    ``rows x K`` and the rest of the grid is never touched. ``B`` scalar
+    offsets make ``B`` contiguous writes, not a scatter: generic gathers and
+    scatters with computed index maps serialize on TPU (measured
+    ~1.8 s/step — 50× the whole decode step — when this was a full-cache
+    take_along_axis), which is why this was a one-hot select over every
+    layer's whole ``[B, M]`` plane until PR 28 (30% of the decode
+    executable at 7B serving scale). An int8 grid quantizes the (tiny)
+    chunk first and lands the int8 values and their f32 scales as they
+    are. Shared by rolling decode (uniform count = chunk size for active
+    slots), chunked prefill (count = the row's tokens of this chunk) and
+    speculative verify (count = accepted prefix; rejected drafts never
+    land, so there is no rollback).
     """
-    gk_all, gv_all = cache["k"], cache["v"]
-    K = chunk["k"].shape[2]
-    M = gk_all.shape[2]
-    L = gk_all.shape[0]
-    quantized = "ks" in cache
-    cdt = jnp.bfloat16 if quantized else gk_all.dtype
-    idx = jnp.arange(M)[None, :] - start[:, None]              # [B, M]
-    inwin = (idx >= 0) & (idx < count[:, None])
-    onehot = (jnp.arange(K)[None, None, :] == idx[:, :, None]
-              ).astype(cdt) * inwin[:, :, None].astype(cdt)    # [B, M, K]
-
-    if quantized:
-        # int8 grid: quantize the chunk rows first, then one-hot-select
-        # the int8 values and their per-vector scales into the grid's
-        # planes. Selection on int8-as-f32 is exact (0/1 weights, values
-        # in [-127, 127]).
-        gks_all, gvs_all = cache["ks"], cache["vs"]
-
-        def merge_layer_q(carry, inp):
-            gk_all, gv_all, gks_all, gvs_all = carry
-            li, ek, ev = inp                   # ek/ev: [B, K, Hkv, D]
-            qk, sk = _kv_quantize(ek)
-            qv, sv = _kv_quantize(ev)
-            ohf = onehot.astype(jnp.float32)
-            mk = jnp.einsum("bmk,bkhd->bmhd", ohf,
-                            qk.astype(jnp.float32))
-            mv = jnp.einsum("bmk,bkhd->bmhd", ohf,
-                            qv.astype(jnp.float32))
-            msk = jnp.einsum("bmk,bkh->bmh", ohf, sk)
-            msv = jnp.einsum("bmk,bkh->bmh", ohf, sv)
-            gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0,
-                                              keepdims=False)
-            gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0,
-                                              keepdims=False)
-            gks = jax.lax.dynamic_index_in_dim(gks_all, li, 0,
-                                               keepdims=False)
-            gvs = jax.lax.dynamic_index_in_dim(gvs_all, li, 0,
-                                               keepdims=False)
-            w4 = inwin[:, :, None, None]
-            w3 = inwin[:, :, None]
-            gk = jnp.where(w4, mk.astype(jnp.int8), gk)
-            gv = jnp.where(w4, mv.astype(jnp.int8), gv)
-            gks = jnp.where(w3, msk, gks)
-            gvs = jnp.where(w3, msv, gvs)
-            gk_all = jax.lax.dynamic_update_index_in_dim(gk_all, gk, li, 0)
-            gv_all = jax.lax.dynamic_update_index_in_dim(gv_all, gv, li, 0)
-            gks_all = jax.lax.dynamic_update_index_in_dim(
-                gks_all, gks, li, 0)
-            gvs_all = jax.lax.dynamic_update_index_in_dim(
-                gvs_all, gvs, li, 0)
-            return (gk_all, gv_all, gks_all, gvs_all), None
-
-        (new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
-            merge_layer_q, (gk_all, gv_all, gks_all, gvs_all),
-            (jnp.arange(L), chunk["k"], chunk["v"]))
-        return {"k": new_k, "v": new_v, "ks": new_ks, "vs": new_vs}
-
-    def merge_layer(carry, inp):
-        gk_all, gv_all = carry
-        li, ek, ev = inp                       # ek/ev: [B, K, Hkv, D]
-        mk = jnp.einsum("bmk,bkhd->bmhd", onehot,
-                        ek.astype(cdt)).astype(cdt)
-        mv = jnp.einsum("bmk,bkhd->bmhd", onehot,
-                        ev.astype(cdt)).astype(cdt)
-        gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
-        gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
-        gk = jnp.where(inwin[:, :, None, None], mk, gk)
-        gv = jnp.where(inwin[:, :, None, None], mv, gv)
-        gk_all = jax.lax.dynamic_update_index_in_dim(gk_all, gk, li, 0)
-        gv_all = jax.lax.dynamic_update_index_in_dim(gv_all, gv, li, 0)
-        return (gk_all, gv_all), None
-
-    (new_k, new_v), _ = jax.lax.scan(
-        merge_layer, (gk_all, gv_all),
-        (jnp.arange(L), chunk["k"], chunk["v"]))
-    return {"k": new_k, "v": new_v}
+    if "ks" in cache:
+        qk, sk = _kv_quantize(chunk["k"])
+        qv, sv = _kv_quantize(chunk["v"])
+        cols = {"k": qk, "v": qv, "ks": sk, "vs": sv}
+    else:
+        cols = {"k": chunk["k"], "v": chunk["v"]}
+    return grid_write.write_columns(cache, cols, start, count)
 
 
 def _cached_attn_merged(q, gk, gv, ek, ev, gmask, emask, cfg: LlamaConfig):

@@ -44,6 +44,7 @@ from kubetorch_tpu.observability import devstats
 from kubetorch_tpu.models.decoder import (decoder_for, grid_dims,
                                           position_bytes)
 from kubetorch_tpu.models.generate import filter_logits
+from kubetorch_tpu.ops import grid_write
 from kubetorch_tpu.parallel.sharding import ShardingRules
 
 
@@ -322,6 +323,9 @@ class RollingGenerator:
         # ``_dpos`` for decoding rows, so none of it waits for the device.
         self._depth = np.zeros(max_slots, np.int64)
         self._kv_positions = {"live": 0, "read": 0, "grid": 0}
+        # What the once-a-chunk merges land and what they rewrite to land
+        # it (``_count_merge``), from the same mirror.
+        self._merge_positions = {"new": 0, "written": 0}
         with self._mesh_ctx():
             self._ragged_block = self.model.ragged_block(
                 cfg, self.max_len, self.cache, self.spec)
@@ -422,11 +426,16 @@ class RollingGenerator:
         """Host-only counters of this generator (no device read): what
         decode attention read of the KV grid. ``read / grid`` is 1.0 where
         the einsum pair runs and the live share, rounded up to key blocks,
-        where the ragged kernel does. Beside them the decoder's own
+        where the ragged kernel does; and what the merges wrote:
+        ``merge_positions_written / _new`` is 1.0 where every landing row
+        lands a whole chunk (plain decode) and the window's share above it
+        where a row lands part of one. Beside them the decoder's own
         counters (fetched with the tokens of each decode chunk) and the
         bytes one position holds over all layers, a gauge."""
         out = {f"decode_kv_positions_{k}": int(v)
                for k, v in self._kv_positions.items()}
+        out.update((f"merge_positions_{k}", int(v))
+                   for k, v in self._merge_positions.items())
         out.update(self._model_counts)
         out["kv_position_bytes"] = self._kv_position_bytes
         return out
@@ -445,6 +454,16 @@ class RollingGenerator:
         self._kv_positions["read"] += (
             grid if block is None else int((-(-live // block) * block).sum()))
         self._kv_positions["grid"] += grid
+
+    def _count_merge(self, counts, cols: int) -> None:
+        """Account merges of ``cols``-column chunks: ``counts`` is what each
+        row lands in each (0 for a row that sits the merge out). ``new`` is
+        the positions that land, ``written`` what ``grid_write`` rewrites
+        for them: a window of ``cols`` for every row that lands anything,
+        and nothing for the others."""
+        self._merge_positions["new"] += int(np.sum(counts))
+        self._merge_positions["written"] += grid_write.positions_written(
+            counts, cols)
 
     def devstats_snapshot(self) -> Dict[str, float]:
         """Cumulative compiler-truth dispatch costs (FLOPs / HBM bytes
@@ -665,6 +684,7 @@ class RollingGenerator:
             if req.consumed >= len(req.prompt):
                 finals[slot] = True
                 done_reqs.append(req)
+        self._count_merge(counts, C)
         with self._mesh_ctx():
             (self.cache, self._logits, self._dpos,
              self._dactive) = self._devstats.call(
@@ -1205,6 +1225,9 @@ class RollingGenerator:
     def _decode_chunk(self) -> List[Tuple[int, List[int], bool]]:
         with self.tick_phase("decode_dispatch"):
             self._count_kv_read()
+            self._count_merge(
+                np.full(len(self._slots), self.steps_per_call),
+                self.steps_per_call)
             self._depth[list(self._slots)] += self.steps_per_call
             self._rng, key = jax.random.split(self._rng)
             with self._mesh_ctx():
@@ -1289,6 +1312,7 @@ class RollingGenerator:
             emits = np.asarray(emits)          # [R, B]
         with self.tick_phase("route"):
             R = toks.shape[0]
+            self._count_merge(emits, kd)       # one merge a round
             new_by_slot: Dict[int, List[int]] = {}
             for slot in self._slots:
                 new: List[int] = []
@@ -1504,10 +1528,11 @@ class RollingGenerator:
         GRID-RESIDENT: the chunk forward runs at full grid width (rows
         with ``counts == 0`` are masked out and merge nothing), attends
         over each row's already-written grid rows plus the causal chunk,
-        and merges the new K/V at each row's depth via the shared
-        one-hot einsum (``llama.merge_chunk_into_grid``) — the exact
-        write path decode chunks use, so ONE compiled executable per
-        ``C`` covers every chunk of every prompt length.
+        and merges the new K/V at each row's depth via the shared row
+        loop of slice updates (``merge_chunk_into_grid``: only the
+        ``C``-column windows of the rows that prefill are written) — the
+        exact write path decode chunks use, so ONE compiled executable
+        per ``C`` covers every chunk of every prompt length.
 
         ``finals`` marks rows whose prompt completes in this chunk:
         their last real token's logits (``unembed_positions`` keeps the
@@ -1554,10 +1579,14 @@ class RollingGenerator:
         Deferred cache merge: inside the scan each step's K/V lands at the
         step-index column of a small [L, B, n_steps] *chunk* cache (a
         uniform-offset write, like the static decoder's), and attention
-        merges the read-only grid with the chunk. The grid is rewritten
-        ONCE after the scan — per-sequence offsets force a full-layer
-        rewrite, and doing that every step measured ~2× the whole step at
-        8B serving scale (38 → ~20 ms/step at B=96).
+        merges the read-only grid with the chunk. The chunk lands in the
+        grid ONCE after the scan, each active row's ``n_steps`` columns at
+        its own depth: ``B`` scalar offsets are ``B`` contiguous slice
+        updates (``ops/grid_write.py``), which move the chunk's bytes and
+        nothing else. (Writing at per-sequence offsets every step, as a
+        one-hot select over the whole layer, measured ~2× the whole step
+        at 8B serving scale, 38 → ~20 ms/step at B=96; the same select
+        once a chunk was still 30% of the decode executable until PR 28.)
 
         Which attention reads the grid: this is the one chunk-mode caller
         with a single query position, and it hands the grid mask down as
@@ -1640,12 +1669,13 @@ class RollingGenerator:
                     jnp.stack([counts[name] for name in model.counters]))])
 
         # Merge the chunk into the grid at each slot's offset — shared
-        # one-hot einsum select (llama.merge_chunk_into_grid; see its
-        # docstring for why never take_along_axis/scatter). Inactive
-        # slots merge nothing: count 0 — and their depth must not
-        # advance either: a row mid-CHUNKED-PREFILL (owned but not yet
-        # decoding) rides through decode chunks, and a drifting dpos
-        # would land its next prefill chunk past the real prompt.
+        # row loop of slice updates (llama.merge_chunk_into_grid; see
+        # its docstring for why never take_along_axis/scatter). Inactive
+        # slots merge nothing (count 0: the loop never visits them) and
+        # their depth must not advance either: a row mid-CHUNKED-PREFILL
+        # (owned but not yet decoding) rides through decode chunks, and a
+        # drifting dpos would land its next prefill chunk past the real
+        # prompt.
         new_cache = model.merge_chunk_into_grid(
             cache, chunk, pos0, jnp.where(active, n_steps, 0))
         return new_cache, logits, jnp.where(active, pos, pos0), toks
@@ -1660,9 +1690,9 @@ class RollingGenerator:
         Per round and slot: the carried next token plus up to ``k − 1``
         prompt-lookup drafts from the slot's device context run through
         ONE chunk-mode forward at the slot's own depth; the accepted
-        prefix merges into the grid with the shared one-hot einsum
-        (per-slot variable count — rejected drafts never land, so there
-        is no rollback).
+        prefix merges into the grid with the shared row loop of slice
+        updates (per-slot variable count inside a ``k``-column window —
+        rejected drafts never land, so there is no rollback).
 
         ``kk`` [B]: per-slot lookahead inside the width-``k`` dispatch
         — draft positions past ``kk − 1`` are forced-rejected (greedy:
@@ -1688,9 +1718,9 @@ class RollingGenerator:
         round merges: round r+1's verify must read round r's accepted
         K/V, and per-slot acceptance lengths break the uniform-column
         chunk layout. One merge per ~tokens_per_pass tokens instead of
-        one per ``steps_per_call`` — priced in; the verify forward
-        replacing several single-token steps is the bigger term in the
-        weight-bound regime this mode targets.
+        one per ``steps_per_call``: cheap since the merge writes only the
+        rows' ``k``-column windows (it rewrote every layer's whole plane
+        a round until PR 28).
         """
         from kubetorch_tpu.models.speculative import (
             _ngram_draft,

@@ -29,9 +29,10 @@ TPU-first mechanics:
   scatters would rewrite whole cache layers per K-token pass and were
   measured to erase the entire speculation win on device;
 - only the ACCEPTED prefix merges into the grid, once per round, with
-  the same one-hot einsum select rolling decode uses (matmul-shaped →
-  MXU at HBM speed); rejected drafts are simply never merged, so there
-  is no rollback;
+  the same row loop of slice updates rolling decode uses
+  (``ops/grid_write.py``: each row's K-column window, nothing else of
+  the grid); rejected drafts are simply never merged, so there is no
+  rollback;
 - the whole generate loop is one jitted ``lax.while_loop`` — draft
   matching, the K-token verify forward, acceptance-prefix math, the
   merge, and the output scatter all run on device with static shapes.
@@ -330,10 +331,12 @@ class SpeculativeGenerator:
             ctx = ctx.at[bidx, jnp.where(cvalid, cpos, L)].set(
                 jnp.where(cvalid, feed, 0), mode="drop")
             # --- merge ONLY the accepted prefix of the chunk into the
-            # grid (shared one-hot einsum select,
-            # llama.merge_chunk_into_grid); rejected drafts never land,
-            # so there is nothing to roll back. ``emit`` is already 0 for
-            # done rows and budget-clamped — it IS the per-row advance.
+            # grid (llama.merge_chunk_into_grid: a row loop of slice
+            # updates over each row's k-column window, the columns at or
+            # past ``emit`` left as they were); rejected drafts never
+            # land, so there is nothing to roll back. ``emit`` is already
+            # 0 for done rows (not visited) and budget-clamped — it IS
+            # the per-row advance.
             cache = llama.merge_chunk_into_grid(cache, chunk, clen, emit)
             clen = clen + emit
             out_len = out_len + emit

@@ -1,0 +1,88 @@
+"""The once-a-chunk cache write of the serving grid: a chunk's columns land
+at each row's own depth, and nothing else of the grid is touched.
+
+A grid leaf is ``[L, B, M, ...]`` (layers, rows, positions), its chunk
+``[L, B, K, ...]``. Row ``b`` takes columns ``[0, count[b])`` at positions
+``start[b] + col``; ``start`` is ``B`` scalars, so this is a loop over the
+rows that have something to land, each a ``dynamic_slice`` of the row's
+``[L, 1, K, ...]`` window, a ``where`` between the chunk's columns and what is
+there, and a ``dynamic_update_slice`` back on the loop-carried (donated) leaf,
+which XLA updates in place. The bytes moved follow ``rows x K``, not
+``B x M``.
+
+Contiguous slice writes at a scalar offset are not the scatter that once
+serialised here (a full-cache ``take_along_axis`` read ~1.8 s a step: computed
+index maps, one element at a time), and not the select over whole planes that
+stood in for it until PR 28 (a one-hot einsum over all ``M`` positions of
+every layer: 4.4 GB read and written a chunk to land ~19 x 8 positions; kept
+as the oracle in ``tests/test_grid_write.py``). Never ``vmap`` of an update or
+``.at[].set`` with computed indices: those lower to that scatter again.
+
+Pure data movement on every backend and mesh (the window is cut along rows
+and positions, which no serving mesh shards): what lands is bit for bit what
+the chunk held.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def write_columns(grid: Dict[str, jax.Array], cols: Dict[str, jax.Array],
+                  start: jax.Array, count: jax.Array
+                  ) -> Dict[str, jax.Array]:
+    """``grid[name][:, b, start[b] + c] = cols[name][:, b, c]`` for
+    ``c < count[b]``, every leaf in one loop over the rows.
+
+    ``start`` (>= 0) and ``count`` are ``[B]`` int32. A column at or past
+    ``count[b]`` never lands, a row with ``count == 0`` is not visited, and
+    a position ``>= M`` is dropped: a window that would pass the end is cut
+    at ``M - K`` and the chunk's columns shifted inside it, so no write moves
+    back onto a neighbour."""
+    names = tuple(grid)
+    _, B, M = grid[names[0]].shape[:3]
+    K = min(cols[names[0]].shape[2], M)
+    # K empty columns in front: one slice then picks the row AND shifts its
+    # columns right by however far the window was pulled back from the end
+    padded = tuple(
+        jnp.pad(cols[n][:, :, :K].astype(grid[n].dtype),
+                ((0, 0), (0, 0), (K, 0)) + ((0, 0),) * (grid[n].ndim - 3))
+        for n in names)
+    # rows with something to land, first; the loop stops after them
+    order = jnp.argsort(count <= 0, stable=True).astype(jnp.int32)
+    zero = jnp.int32(0)
+
+    def row(i, leaves):
+        b = order[i]
+        s, n = start[b], count[b]
+        w = jnp.clip(s, 0, M - K)            # the window's first position
+        back = s - w                         # > 0: pulled back from the end
+        col = jnp.arange(K) - back           # the column a window slot takes
+        lands = (col >= 0) & (col < n)
+        shift = jnp.clip(back, 0, K)
+        out = []
+        for leaf, pad in zip(leaves, padded):
+            tail = (zero,) * (leaf.ndim - 3)
+            size = (leaf.shape[0], 1, K) + leaf.shape[3:]
+            new = jax.lax.dynamic_slice(pad, (zero, b, K - shift) + tail,
+                                        size)
+            old = jax.lax.dynamic_slice(leaf, (zero, b, w) + tail, size)
+            keep = lands.reshape((1, 1, K) + (1,) * (leaf.ndim - 3))
+            out.append(jax.lax.dynamic_update_slice(
+                leaf, jnp.where(keep, new, old), (zero, b, w) + tail))
+        return tuple(out)
+
+    leaves = jax.lax.fori_loop(
+        0, jnp.sum(count > 0, dtype=jnp.int32), row,
+        tuple(grid[n] for n in names))
+    return dict(zip(names, leaves))
+
+
+def positions_written(count, cols: int) -> int:
+    """Positions ``write_columns`` rewrites for these counts, on the host: a
+    window of ``cols`` for every row that lands anything."""
+    return int(np.count_nonzero(np.asarray(count) > 0)) * cols

@@ -261,3 +261,66 @@ def test_latent_and_grouped_kernels_compile_for_v5e(kernel, v5e_chip):
              if big.search(line.split("=", 1)[-1].split("(", 1)[0])
              and re.search(r"\b(copy|transpose|fusion)\(", line)]
     assert not moved, moved
+
+
+# The once-a-chunk merge (ops/grid_write.py) at both serving cells' grids,
+# compiled for the same described chip: PR 28's mechanism as the v5e's
+# compiler sees it.
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("cell", ["chat_int8", "kanana2_latent",
+                                  "docqa_extend_int8"])
+def test_merge_compiles_for_v5e_in_place_with_window_temporaries(
+        cell, v5e_chip):
+    """32 layers x 32 slots x 2048 int8 planes with f32 scales under an
+    8-column chunk; 8 layers x 32 x 8192 x 640 bf16 latents; 6 slots x 8192
+    under a 256-column prefill chunk. The grid is updated in place (all of
+    it aliased to the donated argument), the temporaries are windows and
+    copies of the chunk — where the select this replaced rewrote every
+    layer — and no gather, scatter, select, copy or product makes anything
+    the size of a plane: only the in-place slice updates do."""
+    from kubetorch_tpu.models import latent_moe
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    if cell == "kanana2_latent":
+        layers, b, m, k = 8, 32, 8192, 8
+        merge = latent_moe.merge_chunk_into_grid
+        cache = {"ckr": spec((layers, b, m, 640), jnp.bfloat16)}
+        chunk = {"ckr": spec((layers, b, k, 640), jnp.bfloat16)}
+    else:
+        layers, b, m, k = ((32, 32, 2048, 8) if cell == "chat_int8"
+                           else (32, 6, 8192, 256))
+        merge = llama.merge_chunk_into_grid
+        cache = {"k": spec((layers, b, m, 8, 128), jnp.int8),
+                 "v": spec((layers, b, m, 8, 128), jnp.int8),
+                 "ks": spec((layers, b, m, 8), jnp.float32),
+                 "vs": spec((layers, b, m, 8), jnp.float32)}
+        chunk = {n: spec((layers, b, k, 8, 128), jnp.bfloat16)
+                 for n in ("k", "v")}
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(merge, donate_argnums=(0,)).lower(
+            cache, chunk, spec((b,), jnp.int32),
+            spec((b,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    memory = compiled.memory_analysis()
+    grid_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                     for x in cache.values())
+    chunk_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in chunk.values())
+    assert memory.alias_size_in_bytes >= grid_bytes
+    # under the chunk's own bytes, which is what follows ``rows x K`` (read
+    # here, PR 28: 0.58, 0.16 and 152 MB — the 256-column chunk quantised
+    # and padded — against chunks of 8.4, 2.6 and 201 MB; one layer of the
+    # three grids is 138, 336 and 104 MB)
+    assert memory.temp_size_in_bytes < chunk_bytes
+    text = compiled.as_text()
+    plane = re.compile(rf"\[({layers},)?{b},{m}[,\]]")
+    made = [line.strip()[:160] for line in text.splitlines()
+            if plane.search(line.split("=", 1)[-1].split("(", 1)[0])
+            and re.search(r"\b(copy|transpose|gather|scatter|select|"
+                          r"convolution|dot)\(", line)]
+    assert not made, made

@@ -420,6 +420,41 @@ def test_export_and_import_of_a_row_resume_the_stream(toy):
     assert first + rest == whole
 
 
+@pytest.mark.parametrize("engine", [dict(), dict(prefill_chunk=16)],
+                         ids=["decode", "prefill_extend"])
+def test_engine_stream_and_grid_equal_the_one_hot_selects(toy, engine,
+                                                          monkeypatch):
+    """PR 28: the merge as a row loop of slice updates gives the tokens and
+    the latent grid, bit for bit, that the one-hot select it replaced gave
+    (the select lives on as the oracle of ``tests/test_grid_write.py``), and
+    counts what it wrote."""
+    from test_grid_write import _select_latent
+
+    d, cfg, params = toy
+    prompts = [tokens_of(29, seed=12), tokens_of(7, seed=13)]
+
+    def run():
+        gen = RollingGenerator(params, cfg, max_slots=4, max_len=128,
+                               steps_per_call=4, **engine)
+        rids = [gen.submit(p, max_new_tokens=9) for p in prompts]
+        out = gen.run()
+        return ([out[r] for r in rids],
+                np.asarray(gen.cache["ckr"].astype(jnp.float32)),
+                gen.stats())
+
+    toks, grid, stats = run()
+    monkeypatch.setattr(latent_moe.LatentMoEDecoder, "merge_chunk_into_grid",
+                        staticmethod(_select_latent))
+    want_toks, want_grid, _ = run()
+    assert toks == want_toks
+    np.testing.assert_array_equal(grid, want_grid)
+    assert 0 < stats["merge_positions_new"] <= stats[
+        "merge_positions_written"]
+    if not engine:
+        assert stats["merge_positions_written"] == stats[
+            "merge_positions_new"]
+
+
 def test_engine_stream_is_the_same_through_the_kernels(toy, monkeypatch):
     """The decode path as the chip runs it, in interpret mode: the ragged
     latent kernel joined to the chunk by the log-sum-exp rule, and the
