@@ -28,8 +28,8 @@ def train_vit(model: str = "tiny", batch_per_chip: int = 8,
         MeshSpec, ShardingRules, named_sharding,
     )
 
-    # remat on for the full-size model: measured best on one v5e chip at
-    # batch 64/chip (221 img/s vs 196 at batch 16 without remat)
+    # remat on for the full-size model, so that batch 64 a chip fits (its
+    # rate is not measured on this round's chip)
     cfg = (ViTConfig.vit_l16(remat=True) if model == "l16"
            else ViTConfig.tiny())
     n_dev = len(jax.devices())

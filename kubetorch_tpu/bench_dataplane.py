@@ -1,11 +1,12 @@
-"""Data-plane microbenchmarks: store throughput, delta code-sync,
-broadcast-tree fan-out (VERDICT r1 weak #9 — "data-plane performance is
-asserted, never measured").
+"""Data-plane microbenchmarks on the HOST: store throughput, delta
+code-sync, broadcast-tree fan-out, streamed restore, wire codecs.
 
-Run directly (``python -m kubetorch_tpu.bench_dataplane``) or via the main
-``bench.py`` suite, which merges the numbers into its JSON line. Everything
-here is CPU/localhost — the point is the protocol overheads (delta
-manifests, rolling-join tree, HTTP framing), not the NIC.
+A host measurement and nothing else: everything here is CPU/localhost,
+and what it times is protocol overhead (delta manifests, rolling-join
+tree, HTTP framing, codec passes), not the NIC and never the chip. No
+cell of ``BENCHMARK.json`` covers these paths; a number from here is not
+a device number and is not written under the name of one. Run directly
+(``python -m kubetorch_tpu.bench_dataplane [--dryrun]``).
 
 The reference's comparable pitch is rsync-delta code sync + NCCL/fs
 broadcast (``data_store/rsync_client.py``, ``pod_data_server.py``); it
@@ -39,9 +40,9 @@ REPS = 5
 
 def _spread(samples, key: str, out: Dict[str, float], scale=1.0,
             invert=False):
-    """Record median + [min, max] for a repeated measurement (VERDICT r4
-    weak #4: single-shot numbers make regressions unfalsifiable on a
-    1-CPU host). ``invert``: samples are durations but the reported
+    """Record median + [min, max] for a repeated measurement
+    (single-shot numbers make regressions unfalsifiable on a shared
+    host). ``invert``: samples are durations but the reported
     metric is a rate (min duration → max rate)."""
     xs = sorted(samples)
     med = xs[len(xs) // 2]
@@ -248,7 +249,7 @@ def bench_broadcast(store: "_Store", world: int = 8,
         bcast_egresses.append(store.stats()["bytes_out"] - out0)
     bcast_egress = sorted(bcast_egresses)[len(bcast_egresses) // 2]
 
-    # Relay-tax isolation (VERDICT r3 weak #5): same 2 peers, same bytes —
+    # Relay-tax isolation: same 2 peers, same bytes —
     # once with the adaptive direct policy (world ≤ direct_below → both
     # pull from the store), once with the tree forced (fanout 1: rank 1
     # relays through rank 0). The delta is the pure per-hop relay cost on
@@ -688,35 +689,11 @@ def bench_delta_broadcast(store: "_Store",
     return out
 
 
-def _prior_round_dataplane():
-    """The newest BENCH_r*.json's dataplane block (+ its round number;
-    empty/-1 if none) — the baseline for the >20% regression flags."""
-    import glob
-    import re
-
-    best: Dict[str, float] = {}
-    best_n = -1
-    for path in glob.glob("BENCH_r*.json"):
-        m = re.search(r"BENCH_r(\d+)\.json$", path)
-        if not m:
-            continue
-        try:
-            data = json.load(open(path))
-            block = (data.get("parsed", data).get("extra", {})
-                     .get("dataplane", {}))
-        except Exception:
-            continue
-        if block and int(m.group(1)) > best_n:
-            best_n, best = int(m.group(1)), block
-    return best, best_n
-
-
 def run(dryrun: bool = False) -> Dict[str, float]:
     """Full data-plane bench; ``dryrun=True`` is the CI smoke shape — the
     same code paths (including the streaming pipelined restore) at toy
     sizes and 1 rep, emitting the same metric KEYS so a key that vanishes
-    (a silently-dropped measurement) fails the smoke test, while the toy
-    VALUES are never compared to prior rounds."""
+    (a silently-dropped measurement) fails the smoke test."""
     from kubetorch_tpu.observability import tracing
 
     # RAM-backed when available: measure the data plane, not the VM disk
@@ -754,31 +731,6 @@ def run(dryrun: bool = False) -> Dict[str, float]:
     out["trace_span_count"] = tracing.recorder.seq - trace_seq0
     out["trace_overhead_us_per_span"] = round(
         tracing.measure_overhead_us(), 3)
-    if dryrun:
-        return out
-    # >20% medians-vs-prior-round flags (VERDICT r4 weak #4: r4's −34%
-    # broadcast delta was indistinguishable from noise; with spreads +
-    # explicit flags a real regression now has a name in the output)
-    prior, prior_n = _prior_round_dataplane()
-    flags = {}
-    for key, prev in prior.items():
-        now = out.get(key)
-        if (isinstance(prev, (int, float)) and isinstance(now, (int, float))
-                and prev and not key.endswith("_spread")):
-            delta = (now - prev) / abs(prev)
-            if abs(delta) > 0.20:
-                flags[key] = {"prev": prev, "now": now,
-                              "delta_pct": round(delta * 100, 1)}
-    if flags:
-        out["vs_prior_round_gt20pct"] = flags
-        if prior_n <= 4:
-            # pre-r5 rounds recorded best-of-N / single-shot values;
-            # this round's medians-of-5 are systematically lower, so the
-            # first cross-round comparison flags methodology, not code
-            out["vs_prior_round_note"] = (
-                f"baseline round r{prior_n:02d} used best-of/single-shot "
-                f"methodology; flags vs medians-of-{REPS} may be "
-                f"methodology deltas, not regressions")
     return out
 
 

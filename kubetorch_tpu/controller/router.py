@@ -2,9 +2,10 @@
 
 PR 17 put phase-aware routing inline in the controller's
 ``POST /route/generate`` handler; this module lifts the policy out into
-a pure function so the virtual-time fleet bench (and the tests) can
-route against a rollup dict without an HTTP server in the loop, and so
-the handler's job shrinks to transport + the scale-from-zero park.
+a pure function so the virtual-time fleet simulator
+(``tests/fleet_sim.py``) and the tests can route against a rollup dict
+without an HTTP server in the loop, and so the handler's job shrinks to
+transport + the scale-from-zero park.
 
 Policy (BandPilot-style contention-aware dispatch — route to where the
 program will RUN soonest, not to the emptiest queue):

@@ -767,7 +767,7 @@ class ControllerServer:
           in the store, and a mixed pod can import it.
 
         ISSUE 20 lifts the selection policy into
-        ``controller.router.select_route`` (pure, bench-testable) and
+        ``controller.router.select_route`` (pure, testable alone) and
         adds two fleet behaviors here: per-pod admission sheds become
         router-visible backpressure (a shedding pod is deprioritized
         within its tier), and a routable-pod MISS on an autoscaled

@@ -327,7 +327,9 @@ class _InflateDecoder:
             self._off += n
 
     def finish(self):
-        self.feed(b"")  # flush any buffered tail (no-op for zlib obj)
+        # nothing to flush: both libraries' decompress() hands back all
+        # it has, and a zstandard object refuses any call after its
+        # frame's end
         if self._off != len(self._buf):
             raise ValueError(
                 f"compressed leaf short: {self._off}/{len(self._buf)}")

@@ -62,9 +62,9 @@ def spec_stats_dict(rounds: int, emitted: int, drafted: int,
     """The ``spec_stats`` derivation shared by the real rolling engine
     and the CPU sim — one copy, because the derived ratios feed both
     the shed-check verify pricing and the published ``engine_spec_*``
-    metrics, and the sim is what the bench floors and scheduler tests
-    assert against: a formula fix applied to one engine but not the
-    other would silently split them."""
+    metrics, and the sim is what the scheduler tests assert against:
+    a formula fix applied to one engine but not the other would
+    silently split them."""
     accepted = max(0, emitted - rounds)
     return {"rounds": rounds, "emitted": emitted,
             "tokens_per_pass": emitted / rounds if rounds else 0.0,

@@ -21,7 +21,7 @@ is measured by the engine driver around the same calls.
 
 ``SimRollingEngine`` gets an analytic twin (:class:`AnalyticCosts`)
 with the same snapshot surface so the whole utilization plane runs
-CPU-only in the dryrun bench and CI.
+CPU-only in the tests.
 
 HBM occupancy rides the same module: :func:`hbm_stats` reads
 ``device.memory_stats()`` without ever *initializing* a backend (the
@@ -38,8 +38,8 @@ from typing import Any, Dict, Optional, Tuple
 # ------------------------------------------------------------------
 # Hardware peaks, keyed by substrings of ``device.device_kind``.
 # (peak dense FLOP/s in the serving dtype (bf16), peak HBM bytes/s).
-# Sources: published TPU spec sheets; the v5e bandwidth matches the
-# 819e9 constant the serving bench has always used for its roofline.
+# Sources: published TPU spec sheets (the v5e row equals
+# ``benchmark/peaks.json``, the yardstick the benchmark reads).
 # Unknown kinds (CPU hosts, unrecognized accelerators) map to None — the
 # engine then publishes *no* MFU/MBU gauge rather than a made-up one, the
 # same absent-not-zero semantics as ``kv_blocks_free``.
@@ -224,9 +224,8 @@ class ExecutableCosts:
 
     def per_key_costs(self) -> Dict[Tuple[str, Any], Tuple[float, float]]:
         """The captured (flops, bytes) per-dispatch cost table, keyed by
-        (kind, static key) — lets the bench pull one executable's bytes
-        (e.g. the decode chunk it differenced a wall for) instead of the
-        blended totals."""
+        (kind, static key) — one executable's bytes (e.g. the decode
+        chunk's) instead of the blended totals."""
         with self._lock:
             return dict(self._costs)
 
@@ -274,37 +273,3 @@ def utilization(flops: float, bytes_: float, wall_s: float,
     mbu = min(1.0, max(0.0, bytes_ / (wall_s * peak_bw))) \
         if peak_bw > 0 else 0.0
     return mfu, mbu
-
-
-# ------------------------------------------------------------------
-# Analytic fallbacks shared with the serving bench. These are the
-# formulas the bench used to inline; they live here now so "proxy"
-# numbers and compiler-truth numbers come from one module and the
-# bench labels which one it reports.
-
-def analytic_decode_bytes(params_bytes: float, embedding_bytes: float,
-                          kv_bytes: float, avg_fill: float) -> float:
-    """HBM bytes one decode step streams under the classic roofline
-    model: every non-embedding weight once (the embedding row gather is
-    negligible) plus the live fraction of the KV cache."""
-    return (params_bytes - embedding_bytes) + kv_bytes * avg_fill
-
-
-def mbu_from_bytes(bytes_per_step: float, step_s: float,
-                   peak_bw: float) -> float:
-    """Bandwidth utilization for an analytically-modeled step."""
-    if step_s <= 0 or peak_bw <= 0:
-        return 0.0
-    return bytes_per_step / step_s / peak_bw
-
-
-def decode_mbu_proxy(tokens: float, ticks: float, batch: int,
-                     steps_per_call: int) -> float:
-    """Token-efficiency proxy for decode-tier bandwidth utilization
-    when no device (and therefore no wall/cost truth) exists: emitted
-    tokens over the tick-capacity ceiling, with speculation's 2x verify
-    headroom. Used by the dryrun disagg bench; the hardware bench
-    reports compiler-truth MBU instead."""
-    if ticks <= 0 or batch <= 0 or steps_per_call <= 0:
-        return 0.0
-    return tokens / (ticks * 2 * batch * steps_per_call)

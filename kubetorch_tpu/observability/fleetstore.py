@@ -74,7 +74,7 @@ def build_frame(metrics: Dict[str, Any],
     Delta semantics: with ``last_sent`` (the mutable dict of values the
     pod last shipped) only CHANGED keys are included — unchanged
     counters/gauges cost zero bytes on the heartbeat, which is what
-    keeps the piggyback under the <3 % bench budget on an idle pod.
+    keeps the piggyback small on an idle pod.
     ``last_sent`` is updated in place for the keys shipped; callers
     roll it back (or pass ``full=True`` next frame) when the send
     fails. Histograms ship whenever their ``count`` moved.
@@ -242,7 +242,7 @@ class FleetStore:
     """Per-service, per-pod metric rings + fleet rollups (see module
     docstring). Thread-safe: ingest lands on the controller loop, but
     queries also arrive from executor threads (dashboard gather) and
-    the bench drives it from plain threads."""
+    the tests drive it from plain threads."""
 
     def __init__(self, raw_s: Optional[float] = None,
                  mid_s: Optional[float] = None,

@@ -16,8 +16,9 @@ Crash safety follows the PR 15 discipline: desired counts, cooldown /
 settle deadlines, and manual overrides live in durable controller-DB
 rows, every actuated decision is an append-only ``scale_decisions`` row,
 and a restarted controller resumes mid-cooldown instead of re-deriving a
-fresh opinion and flapping the fleet (the bench asserts zero spurious
-decisions across a seeded mid-ramp controller kill).
+fresh opinion and flapping the fleet (``tests/test_fleet_smoke.py``
+asserts zero spurious decisions across a seeded mid-ramp controller
+kill).
 
 Guard order per service, checked before any actuation:
 
@@ -89,9 +90,9 @@ class FleetScaler:
     controller runs it from the resilience sweep in an executor and
     passes ``actuate_in_thread=True`` so a slow backend (LocalBackend
     waits for pod readiness) never stalls the sweep cadence. The
-    virtual-time fleet bench passes a ``clock`` and a sim backend and
-    keeps actuation inline — every decision is then a pure function of
-    the trace."""
+    virtual-time fleet simulator (``tests/fleet_sim.py``) passes a
+    ``clock`` and a sim backend and keeps actuation inline — every
+    decision is then a pure function of the trace."""
 
     def __init__(self, db, fleet, *, slo=None, restart_policy=None,
                  grace_remaining: Optional[Callable[[], float]] = None,
@@ -443,8 +444,8 @@ class FleetScaler:
                 and now - self._last_decision_ts.get(service, 0.0)
                 < self.cooldown_s):
             # only overrides can reach here (the guard stops auto
-            # decisions); count the flap so the bench's zero-flap floor
-            # is a measurement, not an assumption
+            # decisions); count the flap so the fleet test's zero-flap
+            # floor is a count, not an assumption
             self.flaps_total += 1
         self._desired[service] = target
         self._last_direction[service] = direction

@@ -53,9 +53,10 @@ PARTITION = "partition"
 # bound under a pod that is alive but drowning.
 SLOW_POD = "slow-pod"
 # controller-kill: kill the CONTROL plane mid-flight (ISSUE 15). The
-# data plane must not notice; the harness (bench_resilience's recovery
-# leg, tests/test_controller_crash.py) draws the kill moment from the
-# policy so "when the controller dies" is seeded and reproducible.
+# data plane must not notice; the harness
+# (tests/test_controller_crash.py, tests/fleet_sim.py) draws the kill
+# moment from the policy so "when the controller dies" is seeded and
+# reproducible.
 CONTROLLER_KILL = "controller-kill"
 # ws-flap: sever the pod↔controller WebSocket (the liveness/telemetry
 # channel, NOT the data-plane call channel) — drives the reconnect

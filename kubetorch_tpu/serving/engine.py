@@ -85,7 +85,7 @@ out-of-band, no worker hop).
 This module must stay importable without jax: the real engine
 (:class:`~kubetorch_tpu.models.rolling.RollingGenerator`) is
 constructed by user code and passed in; :class:`SimRollingEngine` is
-the host-only twin the CPU bench/tests drive the scheduler with.
+the host-only twin the tests drive the scheduler with.
 """
 
 from __future__ import annotations
@@ -1948,8 +1948,8 @@ class DecodeEngine:
         """One flight record for the tick that just ran: stamps, the
         host/device decomposition, per-tick scheduler deltas, load, the
         devstats window's MFU/MBU, and the live programs' trace ids —
-        the join key against PR-4 spans. One ring-slot tuple write;
-        asserted <1% of a driver tick by the dryrun bench."""
+        the join key against PR-4 spans. One ring-slot tuple write; its
+        share of a tick is ``tick_publish_ms`` (PERF.md section 5)."""
         fl = self._flight
         if fl is None:
             return
@@ -2118,8 +2118,7 @@ class DecodeEngine:
         binding: slice its state off the device, evict the row, and
         publish in the BACKGROUND (one short-lived thread per export —
         the driver tick must not block on wire time, and the next
-        program's prefill runs while the publish is in flight: that
-        overlap is the pipelining the bench asserts). The stream's
+        program's prefill runs while the publish is in flight). The stream's
         handoff sentinel is delivered only after the publish lands —
         the same durable-then-sentinel discipline as park()."""
         if not hasattr(self.engine, "export_row"):
@@ -2291,9 +2290,9 @@ class SimRollingEngine:
     :meth:`expected_tokens` — so byte-identity across PR-8 replay is
     assertable from the client side without a model; ``step_s`` models
     the per-decode-chunk device time (one sleep per chunk regardless of
-    occupancy, like a real batched step). Used by the CPU ``--dryrun``
-    bench and the engine e2e tests; the scheduler above cannot tell it
-    from the real thing.
+    occupancy, like a real batched step). Used by the scheduler's and
+    the engine's tests; the scheduler above cannot tell it from the
+    real thing. It gives counts and identities, never a rate.
     """
 
     kv_quantized = False
@@ -2335,8 +2334,8 @@ class SimRollingEngine:
         # step becomes steps_per_call verify ROUNDS; per-row lookahead
         # adapts through the shared LookaheadState machine against a
         # SCRIPTED accept rate (`spec_accept`: float, or
-        # callable(prompt) -> rate — deterministic, so the scheduler /
-        # adaptation / bench logic all run CPU-only). Emission stays
+        # callable(prompt) -> rate — deterministic, so the scheduler and
+        # the adaptation logic run CPU-only). Emission stays
         # the same pure function of (prompt, index): speculation
         # changes how many tokens land per chunk, never which — the
         # spec-on ≡ spec-off byte-identity the greedy engine pins.
@@ -2349,7 +2348,7 @@ class SimRollingEngine:
         self._spec_rounds = 0
         self._spec_emitted = 0
         self._spec_drafted = 0
-        # rid -> lookahead at completion (bench convergence probe;
+        # rid -> lookahead at completion (the tests' convergence probe;
         # bounded — oldest entries drop)
         self.spec_k_done: Dict[int, int] = {}
         # device-truth twin (observability/devstats.py): nominal
@@ -2382,9 +2381,8 @@ class SimRollingEngine:
         """Host twin of ``RollingGenerator.load_adapter_slot``: record
         the write (``adapter`` is whatever the pool's loader produced —
         the sim never reads it) and charge the simulated device-write
-        time. The CPU bench's cold-load-hidden probe needs the write to
-        cost wall time while decode keeps stepping — the real engine's
-        shape exactly."""
+        time: the write costs wall time while decode keeps stepping —
+        the real engine's shape exactly."""
         if not self.adapter_slots:
             raise ValueError("sim engine has no adapter slots "
                              "(construct with adapter_slots=)")

@@ -33,7 +33,7 @@ One session owns:
   :class:`~kubetorch_tpu.exceptions.ServerOverloaded` carrying a
   computed ``retry_after`` — a fast retryable rejection instead of a
   timeout that wasted a queue slot. The estimate is
-  :func:`retry_after_estimate`, shared with the bench;
+  :func:`retry_after_estimate`;
 - **deadline enforcement at the queue head**: a call whose propagated
   ``deadline`` passed while it waited is rejected with
   :class:`~kubetorch_tpu.exceptions.DeadlineExceeded` without
@@ -94,8 +94,8 @@ def retry_after_estimate(queue_depth: int, max_depth: int,
     roughly when a slot will actually be free — floored at 50 ms (a
     zero tells the client to hammer) and capped at
     ``KT_MAX_QUEUE_DELAY_S`` (a server asking for minutes is not load
-    shedding, it is down). Shared by the pod server and
-    ``bench_resilience`` so the bench models the real arithmetic."""
+    shedding, it is down). The pod server and the engine's KV-block
+    admission both price a shed with it."""
     if cap_s is None:
         cap_s = env_float("KT_MAX_QUEUE_DELAY_S")
     excess = max(1, queue_depth - max_depth + 1)

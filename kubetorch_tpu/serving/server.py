@@ -365,9 +365,8 @@ class PodServer:
                 corrupt = chaos_mod.maybe(chaos_mod.CORRUPT_HEARTBEAT, pod)
                 # fleet telemetry piggyback: a compact delta frame of
                 # the pod's changed counters/gauges + histogram buckets
-                # rides every KT_TELEMETRY_EVERY-th beat. Frame build
-                # is bench-bounded (<3% of a heartbeat tick,
-                # telemetry_ingest_overhead_pct in bench_serving).
+                # rides every KT_TELEMETRY_EVERY-th beat. What a frame
+                # costs a beat is not measured.
                 telemetry = None
                 if tele_every and beats % tele_every == 0:
                     try:

@@ -429,7 +429,7 @@ def test_scale_churn_survives_controller_kill(tmp_path, monkeypatch):
                           "min_scale": 0, "max_scale": 6,
                           "metric": "concurrency"}})
     # the seeded scale-storm chaos kind drives the ramp: a hit triples
-    # the offered queue depth exactly as in bench_fleet's trace
+    # the offered queue depth exactly as in tests/fleet_sim.py's trace
     storm = ChaosPolicy(seed=5, scale_storm=1.0, pod_lag=1.0)
     queue = 4 * (3 if storm.decide(SCALE_STORM, "block-0") else 1)
     feed(s1, ["p0"], active=4, free=4, queue=queue)

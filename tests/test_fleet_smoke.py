@@ -1,71 +1,49 @@
-"""Tier-1-safe fleet autoscaling smoke: ``bench_fleet.run(dryrun=True)``
-drives the REAL FleetScaler + select_route over SimRollingEngine pods in
-pure virtual time (seconds of wall clock for 10 simulated minutes), and
-this test fails if any ``fleet_*`` metric KEY disappears or an ISSUE-20
-acceptance floor regresses."""
+"""The autoscaling loop closed over the REAL ``FleetScaler`` and
+``select_route`` in virtual time (``fleet_sim.py``: seconds of wall
+clock for ten simulated minutes). Every assertion is a count or an
+identity; the simulator gives no rate."""
 
 import pytest
 
-# The bench's stable contract: keys are the interface, values are
-# environment-independent here (virtual time) but still asserted only as
-# floors. Losing a key fails here first, not in a bench-round diff.
-EXPECTED_KEYS = {
-    # tracking phase: seeded diurnal ramp + mid-plateau controller kill
-    "fleet_programs",
-    "fleet_scale_decisions",
-    "fleet_scale_ups",
-    "fleet_scale_downs",
-    "fleet_parked_programs",
-    "fleet_tracking_error",
-    "fleet_peak_replicas",
-    "fleet_cold_starts",
-    "fleet_lagged_pods",
-    "fleet_cold_start_worst_s",
-    "fleet_cold_start_budget_s",
-    "fleet_cold_starts_within_budget",
-    "fleet_flap_count",
-    "fleet_spurious_scale_events",
-    "fleet_decisions_at_kill",
-    "fleet_scaled_to_zero",
-    # routing phase: earliest-ETA fleet routing vs blind round-robin
-    "fleet_routed_goodput_tok_s",
-    "fleet_rr_goodput_tok_s",
-    "fleet_routed_goodput_ratio",
-}
+import fleet_sim
 
 
 @pytest.mark.level("minimal")
-def test_fleet_dryrun_metric_keys_and_floors():
-    from kubetorch_tpu import bench_fleet
+def test_scaler_tracks_ramp_across_controller_kill(tmp_path):
+    """A seeded diurnal ramp from zero replicas and back, with a
+    controller kill on the plateau: the scaler follows the load up and
+    down, every cold start lands inside the budget, nothing flaps, and
+    the kill leaves no trace in the durable decision log."""
+    arrivals = fleet_sim.diurnal_arrivals()
+    control = fleet_sim.run_tracking(arrivals, str(tmp_path), kill=False)
+    killed = fleet_sim.run_tracking(arrivals, str(tmp_path), kill=True)
 
-    out = bench_fleet.run(dryrun=True)
-    missing = EXPECTED_KEYS - set(out)
-    assert not missing, (
-        f"fleet bench dropped metric keys: {sorted(missing)} — a "
-        f"measurement went silent; restore it (or update EXPECTED_KEYS "
-        f"if the rename is deliberate)")
-    # ISSUE 20 acceptance floors, re-asserted here so CI owns them:
-    # replicas track the offered-load ramp...
-    assert out["fleet_tracking_error"] < 0.6
-    assert out["fleet_scale_ups"] >= 2 and out["fleet_scale_downs"] >= 1
-    assert out["fleet_peak_replicas"] >= 4
-    # ...every cold start (pod-lag chaos included) lands inside the
-    # budget...
-    assert out["fleet_cold_starts"] >= 3
-    assert out["fleet_cold_starts_within_budget"] == 1
-    assert out["fleet_cold_start_worst_s"] <= out["fleet_cold_start_budget_s"]
-    # ...the loop neither flaps nor re-decides across the seeded
-    # controller kill (the bench compares the killed run's durable
-    # decision log against a no-kill control run — any divergence is a
-    # spurious event)...
-    assert out["fleet_flap_count"] == 0
-    assert out["fleet_spurious_scale_events"] == 0
-    assert out["fleet_decisions_at_kill"] > 0  # the kill hit mid-trace
-    # ...scale-from-zero parks programs instead of erroring, and the
+    rows = killed["decisions"]
+    ups = sum(1 for _, frm, to, _kind in rows if to > frm)
+    downs = sum(1 for _, frm, to, _kind in rows if to < frm)
+    assert ups >= 2 and downs >= 1, rows
+    assert killed["peak_replicas"] >= 4
+    # pod-lag chaos included, every pod is ready inside the budget
+    assert len(killed["cold_starts"]) >= 3
+    assert killed["lagged_pods"] >= 1
+    assert max(killed["cold_starts"]) <= fleet_sim.COLD_START_BUDGET_S
+    assert killed["flaps"] == 0 and control["flaps"] == 0
+    # the kill hit mid-trace, and a faithful resume re-decides nothing:
+    # the killed run's log equals the control's, row for row
+    assert killed["decisions_at_kill"] > 0
+    assert rows == control["decisions"]
+    # scale-from-zero parks programs instead of failing them, and the
     # idle tail crosses the scale-to-zero grace back to zero replicas
-    assert out["fleet_parked_programs"] > 0
-    assert out["fleet_scaled_to_zero"] == 1
-    # routing: ETA routing must beat blind round-robin on the
-    # heterogeneous fleet (goodput = TTFT-SLO-attainment tokens/s)
-    assert out["fleet_routed_goodput_ratio"] > 1.0
-    assert out["fleet_routed_goodput_tok_s"] > 0
+    assert killed["parked"] > 0
+    assert killed["scaled_to_zero"] and control["scaled_to_zero"]
+
+
+@pytest.mark.level("minimal")
+def test_eta_routing_beats_round_robin_on_first_token_count():
+    """On a fleet of two fast pods and two at half speed, under the
+    same seeded arrivals, more programs get their first token inside
+    the limit when ``select_route`` places them than when they are
+    dealt round-robin."""
+    routed = fleet_sim.run_routing(routed=True)
+    dealt = fleet_sim.run_routing(routed=False)
+    assert dealt < routed <= fleet_sim.ROUTED_PROGRAMS, (routed, dealt)

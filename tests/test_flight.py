@@ -7,6 +7,7 @@ re-weighted by each tick's differenced device time; and the sim engine
 exposes the same devstats surface as the real one."""
 
 import json
+import os
 import threading
 import time
 
@@ -165,6 +166,36 @@ class TestEngineFlight:
         assert st["devstats_dispatches"] == snap["dispatches_total"]
         flight.reset()
 
+    def test_preemption_dump_holds_the_ticks_just_run(self, tmp_path,
+                                                      monkeypatch):
+        """What a terminating pod leaves behind: ``maybe_dump`` writes
+        this process's ring into ``KT_FLIGHT_DIR`` as
+        ``flight-<pid>.json``, and the file parses back to the driver
+        ticks the engine ran; without the directory it writes nothing."""
+        from kubetorch_tpu.serving.engine import (
+            DecodeEngine,
+            SimRollingEngine,
+        )
+
+        flight.reset()
+        eng = DecodeEngine(
+            SimRollingEngine(max_slots=2, steps_per_call=8, step_s=0.0),
+            poll_s=0.001)
+        try:
+            assert len(_drain(eng, [1, 2, 3], 32)) == 32
+        finally:
+            eng.close()
+        monkeypatch.delenv("KT_FLIGHT_DIR", raising=False)
+        assert flight.maybe_dump() is None
+        monkeypatch.setenv("KT_FLIGHT_DIR", str(tmp_path))
+        path = flight.maybe_dump()
+        assert path is not None
+        assert path.name == f"flight-{os.getpid()}.json"
+        report = json.loads(path.read_text())
+        assert report["pid"] == os.getpid()
+        assert sum(r["decode_tokens"] for r in report["records"]) == 32
+        flight.reset()
+
 
 # --------------------------------------------------------- perfetto
 @pytest.mark.level("unit")
@@ -261,10 +292,6 @@ class TestDevstats:
         if devstats.device_peaks() is None:
             assert snap["captured_executables"] == 0.0
             assert snap["flops_total"] == 0.0
-
-    def test_decode_mbu_proxy_guards_zero(self):
-        assert devstats.decode_mbu_proxy(10, 0, 4, 8) == 0.0
-        assert devstats.decode_mbu_proxy(64, 2, 2, 8) == 1.0
 
 
 @pytest.mark.level("minimal")

@@ -332,6 +332,33 @@ def test_hit_prefix_never_evicted_to_admit_its_own_row():
 
 
 # ---------------------------------------- session park / restore (sim)
+def _park_mid_stream(eng, prompt, n, session):
+    """Stream ``prompt`` under ``session`` until tokens have arrived,
+    park it, and return what was emitted before the parked frame."""
+    first_half: list = []
+    parked = threading.Event()
+
+    def run_first():
+        for f in eng.generate({"prompt": prompt, "max_new_tokens": n,
+                               "session_id": session}):
+            if f.get("parked"):
+                parked.set()
+                return
+            first_half.extend(f["tokens"])
+
+    th = threading.Thread(target=run_first)
+    th.start()
+    deadline = time.time() + 10
+    while not first_half and time.time() < deadline:
+        time.sleep(0.002)
+    assert first_half, "no tokens before park"
+    assert eng.park(session) == 1
+    th.join(10)
+    assert parked.is_set(), "stream never saw the parked frame"
+    assert 0 < len(first_half) < n
+    return first_half
+
+
 @pytest.mark.level("unit")
 def test_park_resume_round_trip_token_identical(local_store):
     """Acceptance: park mid-generation, resume by session_id — the
@@ -343,29 +370,8 @@ def test_park_resume_round_trip_token_identical(local_store):
     sim = SimRollingEngine(max_slots=2, steps_per_call=4, step_s=0.01)
     eng = DecodeEngine(sim, poll_s=0.002)
     try:
-        first_half: list = []
-        parked = threading.Event()
-
-        def run_first():
-            for f in eng.generate({"prompt": prompt, "max_new_tokens": n,
-                                   "session_id": "sess-rt"}):
-                if f.get("parked"):
-                    parked.set()
-                    return
-                first_half.extend(f["tokens"])
-
-        th = threading.Thread(target=run_first)
-        th.start()
-        deadline = time.time() + 10
-        while not first_half and time.time() < deadline:
-            time.sleep(0.002)
-        assert first_half, "no tokens before park"
-        assert eng.park("sess-rt") == 1
-        th.join(10)
-        assert parked.is_set(), "stream never saw the parked frame"
+        first_half = _park_mid_stream(eng, prompt, n, "sess-rt")
         assert eng.stats()["free_rows"] == 2
-        pre = len(first_half)
-        assert 0 < pre < n
 
         # prefill accounting before/after: the resume must not prefill
         prefill_before = sim.prefill_tokens
@@ -381,6 +387,50 @@ def test_park_resume_round_trip_token_identical(local_store):
 
         # the restore rode the PR-1 streaming path
         assert prom.restore_metrics()["restore_last_streaming"] == 1.0
+    finally:
+        eng.close()
+
+
+@pytest.mark.level("unit")
+def test_resumed_session_first_token_within_four_ticks(local_store):
+    """A resume costs decode chunks, not the prompt's prefill: cold, a
+    64-token prompt at chunk 8 runs 8 prefill ticks before its first
+    token; parked and resumed, the first token comes within 4 driver
+    ticks of the submit and no tick prefills. Counted in the flight
+    ring's per-tick records, so no clock is read."""
+    from kubetorch_tpu.observability import flight
+
+    rec = flight.get_recorder()
+    assert rec is not None, "flight ring disabled in the test environment"
+    prompt = list(range(7, 71))
+    n = 4096
+    sim = SimRollingEngine(max_slots=2, steps_per_call=8, prefill_chunk=8,
+                           step_s=0.002)
+    eng = DecodeEngine(sim, poll_s=0.002)
+    try:
+        seq0 = rec.seq
+        first_half = _park_mid_stream(eng, prompt, n, "sess-ticks")
+        cold = rec.snapshot(since_seq=seq0 - 1)
+        assert sum(r["prefill_chunks"] for r in cold) == 8
+
+        # the engine is idle now, so it ticks only for the resume
+        seq1 = rec.seq
+        second_half: list = []
+        stream = eng.generate({"prompt": prompt, "max_new_tokens": n,
+                               "session_id": "sess-ticks"})
+        for f in stream:
+            second_half.extend(f["tokens"])
+            if second_half:
+                break
+        stream.close()
+        got = first_half + second_half
+        assert got == SimRollingEngine.expected_tokens(prompt, len(got))
+        eng.stats()                       # waits out the tick in flight
+        ticks = rec.snapshot(since_seq=seq1 - 1)
+        to_first = next(i for i, r in enumerate(ticks, 1)
+                        if r["decode_tokens"])
+        assert to_first <= 4, [r["decode_tokens"] for r in ticks]
+        assert not any(r["prefill_chunks"] for r in ticks)
     finally:
         eng.close()
 

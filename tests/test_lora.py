@@ -433,3 +433,32 @@ def test_pad_adapter_slots_fixed_axis(cfg, params):
     assert out[0] != base[0]          # the loaded slot still steers
     with pytest.raises(ValueError, match="raise KT_LORA_SLOTS"):
         pad_adapter_slots(padded, 2)
+
+
+def test_lora_select_compiled_cost_flat_in_adapter_axis():
+    """The per-row select is a gather: each row reads its own rank-r
+    factors, so the compiled FLOPs of ``_lora_apply`` do not grow as the
+    adapter axis widens 1 -> 8 (a one-hot select would stream every
+    resident adapter through the matmul). A compiler count, not a time."""
+    B, K, r, N = 8, 64, 8, 64
+
+    def flops(n_slots):
+        def select(h, a, b, slots):
+            return llama._lora_apply(h, ({"wq": {"a": a, "b": b}}, slots, 2.0),
+                                     "wq")
+
+        compiled = jax.jit(select).lower(
+            jax.ShapeDtypeStruct((B, 1, K), jnp.float32),
+            jax.ShapeDtypeStruct((n_slots, K, r), jnp.float32),
+            jax.ShapeDtypeStruct((n_slots, r, N), jnp.float32),
+            jax.ShapeDtypeStruct((B,), jnp.int32)).compile()
+        cost = compiled.cost_analysis()
+        if isinstance(cost, (list, tuple)):
+            cost = cost[0]
+        return cost["flops"]
+
+    one, eight = flops(1), flops(8)
+    assert one >= 2 * B * r * (K + N)      # both products are counted
+    # the wider axis adds the gather's index clamp (a few integer ops a
+    # row); a one-hot select would cost eight times the products
+    assert one <= eight < 1.01 * one, (one, eight)
