@@ -23,6 +23,14 @@ object it was built with (``decoder_for(cfg)``):
 - ``forward_cached(...)``: ``models/llama.py::forward_cached``'s contract
   (prefill into a private cache, or chunk mode over the read-only grid) with
   a third result, the step's counters (``{}`` where the decoder has none).
+  A caller states what its mask is where that lets a decoder choose an
+  implementation: ``grid_depth`` (a plain prefix mask, as a length) and
+  ``causal_lens`` (causal from position 0 under a length: a prompt's own
+  prefill); a decoder may ignore either.
+- ``prefill_flash_engages(cfg, p_pad)``: whether a bucketed prefill of
+  ``p_pad`` positions that passes ``causal_lens`` attends through the
+  blocked flash kernel instead of the einsum pair over its private cache:
+  what ``prefill_flash_positions`` counts.
 - ``merge_chunk_into_grid(cache, chunk, start, count)``: chunk columns
   ``[0, count[b])`` of row ``b`` land at positions ``start[b] + col`` of
   every layer and leaf, and nothing else of the grid is read or written
@@ -142,6 +150,13 @@ class LlamaDecoder:
                 1, max_len, cfg.n_kv_heads, cfg.head_dim, cache["k"].dtype):
             return None
         return decode_attention.block_for(max_len)
+
+    @staticmethod
+    def prefill_flash_engages(cfg, p_pad: int) -> bool:
+        from kubetorch_tpu.ops import flash_attention
+
+        return flash_attention.prefill_engages(
+            p_pad, p_pad, 0, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
 
     @staticmethod
     def prefill_counters(cfg, prompt_tokens: int) -> Dict[str, int]:

@@ -459,7 +459,7 @@ def merge_chunk_into_grid(cache, chunk, start, count):
 def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
                    cfg: LatentMoEConfig, rules=None, unembed_positions=None,
                    chunk=None, chunk_col=None, chunk_mask=None, lora=None,
-                   grid_depth=None):
+                   grid_depth=None, causal_lens=None):
     """``llama.forward_cached``'s contract over the latent cache ->
     (logits [B,T,V] float32, new cache or chunk, counters).
 
@@ -467,9 +467,12 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     attend to themselves through the EXPAND path and their ``(c, k_r)``
     are written at positions ``[0, T)``; ``write_at`` must be the literal 0
     and the cache as long as the call (prefix reuse, which would write
-    behind a spliced prefix, is not carried). With ``chunk`` (decode steps,
-    prefill chunks): the grid is read-only, this call's ``(c, k_r)`` land
-    at column ``chunk_col`` of the chunk, and attention is the ABSORBED
+    behind a spliced prefix, is not carried); ``causal_lens`` (the caller's
+    statement that ``mask`` is causal from 0 under a length) is accepted and
+    changes nothing: this prefill is that by construction. With ``chunk``
+    (decode steps, prefill chunks): the grid is read-only, this call's
+    ``(c, k_r)`` land at column ``chunk_col`` of the chunk, and attention is
+    the ABSORBED
     path over grid and chunk; ``grid_depth`` [B] (the grid mask as a
     length) lets one query position a row take the ragged kernel.
 
@@ -570,6 +573,13 @@ class LatentMoEDecoder:
         if not latent_attention.decode_engages(1, max_len):
             return None
         return latent_attention.block_for(max_len)
+
+    @staticmethod
+    def prefill_flash_engages(cfg, p_pad: int) -> bool:
+        """Nothing here is chosen by the caller's ``causal_lens``: the
+        expand path takes its own kernel by its own rule
+        (``latent_attention.prefill_engages``)."""
+        return False
 
     @staticmethod
     def prefill_counters(cfg: LatentMoEConfig, prompt_tokens: int):

@@ -32,6 +32,8 @@ from jax.ad_checkpoint import checkpoint_name
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.ops import apply_rope, dot_product_attention, rms_norm, rope_angles
 from kubetorch_tpu.ops import grid_write, quant_matmul
+from kubetorch_tpu.ops.flash_attention import (
+    prefill_attention, prefill_engages)
 from kubetorch_tpu.parallel.sharding import ShardingRules, shard_constraint
 
 Params = Dict[str, Any]
@@ -920,13 +922,16 @@ def _qkv_proj(x, layer, sin, cos, cfg: LlamaConfig, lctx=None):
 
 def _block_cached_q(x, layer, li, sin, cos, ck_all, cv_all, ks_all, vs_all,
                     write_at, mask, cfg: LlamaConfig, rules: ShardingRules,
-                    lctx=None):
+                    lctx=None, own_causal: bool = False):
     """Decoder block over a QUANTIZED cache (int8 K/V + per-vector
     scales). Scalar ``write_at`` only — used by the static Generator's
     uniform slots AND by rolling admission prefills over a private
     quantized own-cache (``RollingGenerator(kv_dtype="int8")``, which
     splices the rows into the int8 grid): this step's K/V quantize on
-    write, attention dequants via scale folding."""
+    write, attention dequants via scale folding. ``own_causal``: the call
+    is a prompt's own causal self-attention from position 0
+    (``forward_cached`` decides), so the cache is written as always and
+    attention runs in the flash kernel on the K/V just projected."""
     dt = cfg.compute_dtype
     B, T, _ = x.shape
     H, D = cfg.n_heads, cfg.head_dim
@@ -942,12 +947,15 @@ def _block_cached_q(x, layer, li, sin, cos, ck_all, cv_all, ks_all, vs_all,
         ks_all, kscale[None], (li, 0, write_at, 0))
     vs_all = jax.lax.dynamic_update_slice(
         vs_all, vscale[None], (li, 0, write_at, 0))
-    ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-    cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-    ks = jax.lax.dynamic_index_in_dim(ks_all, li, 0, keepdims=False)
-    vs = jax.lax.dynamic_index_in_dim(vs_all, li, 0, keepdims=False)
-
-    attn = _cached_attn_q(q, ck, cv, ks, vs, mask, cfg).reshape(B, T, H * D)
+    if own_causal:
+        attn = prefill_attention(q, k, v)
+    else:
+        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
+        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
+        ks = jax.lax.dynamic_index_in_dim(ks_all, li, 0, keepdims=False)
+        vs = jax.lax.dynamic_index_in_dim(vs_all, li, 0, keepdims=False)
+        attn = _cached_attn_q(q, ck, cv, ks, vs, mask, cfg)
+    attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
     x = x + _mlp(x, layer, cfg, rules, lctx)
@@ -955,7 +963,8 @@ def _block_cached_q(x, layer, li, sin, cos, ck_all, cv_all, ks_all, vs_all,
 
 
 def _block_cached(x, layer, li, sin, cos, ck_all, cv_all, write_at, mask,
-                  cfg: LlamaConfig, rules: ShardingRules, lctx=None):
+                  cfg: LlamaConfig, rules: ShardingRules, lctx=None,
+                  own_causal: bool = False):
     """One decoder block in cache mode, updating the stacked ``[L, ...]``
     cache in place at layer ``li``.
 
@@ -968,7 +977,8 @@ def _block_cached(x, layer, li, sin, cos, ck_all, cv_all, write_at, mask,
     output would allocate (and fill) a fresh stacked cache buffer every
     forward — +2 × cache bytes of pure HBM traffic per decode step, ~7 ms
     of the 8B B=64 step — while dynamic-update-slice on a carry aliases in
-    place under the compiled while loop.
+    place under the compiled while loop. ``own_causal`` as in
+    ``_block_cached_q``.
     """
     dt = cfg.compute_dtype
     B, T, _ = x.shape
@@ -1009,7 +1019,11 @@ def _block_cached(x, layer, li, sin, cos, ck_all, cv_all, write_at, mask,
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
 
-    attn = _cached_attn(q, ck, cv, mask, cfg).reshape(B, T, H * D)
+    if own_causal:
+        attn = prefill_attention(q, k, v)
+    else:
+        attn = _cached_attn(q, ck, cv, mask, cfg)
+    attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
     x = x + _mlp(x, layer, cfg, rules, lctx)
@@ -1032,6 +1046,7 @@ def forward_cached(
     chunk_mask: Optional[jax.Array] = None,         # [B, T, K] bool
     lora: Optional[Dict[str, Any]] = None,          # multi-adapter serving
     grid_depth: Optional[jax.Array] = None,         # [B]: mask as a length
+    causal_lens: Optional[jax.Array] = None,        # [B]: mask as causal
 ):
     """Forward with KV cache → (logits [B, T, V] float32, new cache).
 
@@ -1061,6 +1076,26 @@ def forward_cached(
     over all ``max_len`` positions (``_cached_attn_merged_q`` /
     ``_cached_attn_merged``), which is also the kernel's oracle. The shape
     decides; there is no switch.
+
+    The third case is a prompt's own prefill. A caller whose ``mask`` is
+    causal from position 0 under a length says so by passing the lengths
+    too: ``causal_lens`` [B] with ``mask[b, t, m] == (m <= t) & (m <
+    causal_lens[b])`` (``RollingGenerator._prefill_impl``). With it, no
+    ``chunk``, a static ``write_at == 0``, ``T`` equal to the private
+    cache's length (every key there is one this call projects), a bucket of
+    at least ``flash_attention._PREFILL_MIN`` positions that the kernel
+    tiles, and the TPU backend on one device
+    (``ops.flash_attention.prefill_engages``), attention runs in the
+    blocked flash kernel on the K and V the projection just made, which
+    skips the blocks above the diagonal and never writes the ``[heads, T,
+    T]`` scores; the cache is written (quantised on write for int8) as
+    always. Under a causal mask the length is redundant for every real
+    query, so the kernel takes none; the rows of padding compute finite
+    values nobody reads, as they do under the einsum. Everything else (a
+    prefix-extended admission, whose queries also see a prefix; the static
+    ``Generator``, whose cache is longer than ``T``; short buckets; CPU; a
+    mesh) runs the einsum pair over the cache (``_cached_attn_q`` /
+    ``_cached_attn``), the kernel's oracle (tests/test_prefill_flash.py).
     """
     rules = rules or ShardingRules.default()
     dt = cfg.compute_dtype
@@ -1083,6 +1118,11 @@ def forward_cached(
                 tokens.shape[1], cache["k"].shape[2], cfg.n_kv_heads,
                 cfg.head_dim, cache["k"].dtype):
         items = decode_attention.plan(grid_depth, cache["k"].shape[2])
+
+    own_causal = (
+        chunk is None and causal_lens is not None
+        and prefill_engages(tokens.shape[1], cache["k"].shape[2], write_at,
+                            cfg.n_heads, cfg.n_kv_heads, cfg.head_dim))
 
     def lctx_of(lslice):
         if lora is None:
@@ -1123,7 +1163,7 @@ def forward_cached(
             layer, li, lslice = inp
             x, ck_all, cv_all, ks_all, vs_all = _block_cached_q(
                 x, layer, li, sin, cos, ck_all, cv_all, ks_all, vs_all,
-                write_at, mask, cfg, rules, lctx_of(lslice))
+                write_at, mask, cfg, rules, lctx_of(lslice), own_causal)
             return (x, ck_all, cv_all, ks_all, vs_all), None
 
         (x, new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
@@ -1159,7 +1199,7 @@ def forward_cached(
             x, ck_all, cv_all = _block_cached(x, layer, li, sin, cos,
                                               ck_all, cv_all,
                                               write_at, mask, cfg, rules,
-                                              lctx_of(lslice))
+                                              lctx_of(lslice), own_causal)
             return (x, ck_all, cv_all), None
 
         (x, new_k, new_v), _ = jax.lax.scan(

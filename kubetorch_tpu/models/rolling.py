@@ -326,6 +326,10 @@ class RollingGenerator:
         # What the once-a-chunk merges land and what they rewrite to land
         # it (``_count_merge``), from the same mirror.
         self._merge_positions = {"new": 0, "written": 0}
+        # Padded positions of every bucketed admission, and of those whose
+        # attention took the flash kernel (``_count_admission``).
+        self._prefill_positions = {"prefill_positions": 0,
+                                   "prefill_flash_positions": 0}
         with self._mesh_ctx():
             self._ragged_block = self.model.ragged_block(
                 cfg, self.max_len, self.cache, self.spec)
@@ -429,13 +433,17 @@ class RollingGenerator:
         where the ragged kernel does; and what the merges wrote:
         ``merge_positions_written / _new`` is 1.0 where every landing row
         lands a whole chunk (plain decode) and the window's share above it
-        where a row lands part of one. Beside them the decoder's own
+        where a row lands part of one; and what the bucketed admissions
+        ran: ``prefill_positions`` (rows x the bucket's padded length) and
+        the part of them whose attention took the flash kernel,
+        ``prefill_flash_positions``. Beside them the decoder's own
         counters (fetched with the tokens of each decode chunk) and the
         bytes one position holds over all layers, a gauge."""
         out = {f"decode_kv_positions_{k}": int(v)
                for k, v in self._kv_positions.items()}
         out.update((f"merge_positions_{k}", int(v))
                    for k, v in self._merge_positions.items())
+        out.update(self._prefill_positions)
         out.update(self._model_counts)
         out["kv_position_bytes"] = self._kv_position_bytes
         return out
@@ -464,6 +472,17 @@ class RollingGenerator:
         self._merge_positions["new"] += int(np.sum(counts))
         self._merge_positions["written"] += grid_write.positions_written(
             counts, cols)
+
+    def _count_admission(self, rows: int, p_pad: int, own: bool) -> None:
+        """Account one bucketed admission of ``rows`` (padded) rows at
+        ``p_pad`` positions each; ``own``: the prompt's own prefill from
+        position 0 (``_prefill_impl``, which states its mask as causal), the
+        one the flash kernel can take, not a prefix-extended one. Called
+        under the generator's mesh, which the kernel's rule asks for."""
+        n = rows * p_pad
+        self._prefill_positions["prefill_positions"] += n
+        if own and self.model.prefill_flash_engages(self.cfg, p_pad):
+            self._prefill_positions["prefill_flash_positions"] += n
 
     def devstats_snapshot(self) -> Dict[str, float]:
         """Cumulative compiler-truth dispatch costs (FLOPs / HBM bytes
@@ -1174,6 +1193,7 @@ class RollingGenerator:
             self.prefill_tokens += len(req.prompt)
             self._count_prefill(len(req.prompt))
         with self._mesh_ctx():
+            self._count_admission(n_pad, p_pad, own=prefix_id is None)
             if prefix_id is None:
                 (self.cache, self._logits, self._dpos,
                  self._dactive) = self._devstats.call(
@@ -1415,7 +1435,8 @@ class RollingGenerator:
         own = model.init_cache_like(cfg, cache, N, p_pad)
         out, own, _ = model.forward_cached(
             params, tokens, positions, own, 0, mask, cfg, rules,
-            unembed_positions=prompt_lens - 1, lora=lora)
+            unembed_positions=prompt_lens - 1, lora=lora,
+            causal_lens=prompt_lens)
         return RollingGenerator._finish_admit(
             cache, own, out[:, 0], logits, dpos, dactive, slots,
             prompt_lens)

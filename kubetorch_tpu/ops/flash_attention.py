@@ -110,10 +110,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale: float, causal: bool, block_q: int, block_k: int,
-    interpret: bool, with_lse: bool = True,
+    interpret: bool, with_lse: bool = True, name: Optional[str] = None,
 ):
     """[B,H,S,D] layout. Returns (out, lse[B,H,S,_STATS] f32) — lse is None
-    when ``with_lse=False`` (forward-only: skips the residual writes)."""
+    when ``with_lse=False`` (forward-only: skips the residual writes).
+    ``name`` names the custom call in a device trace (training's stays
+    unnamed, so its executables are the ones they were)."""
     B, Hq, S, D = q.shape
     _, Hkv, T, _ = k.shape
     group = Hq // Hkv
@@ -156,6 +158,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, D), jnp.float32),        # output accumulator
         ],
         interpret=interpret,
+        name=name,
     )(q, k, v)
     return (res[0], res[1]) if with_lse else (res[0], None)
 
@@ -469,3 +472,61 @@ def flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), scale, causal, block_q, block_k, interpret)
     return out.transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Serving: a prompt's own causal self-attention at admission
+# ---------------------------------------------------------------------------
+
+# Test hook, as ``decode_attention._FORCE_INTERPRET``: take the kernel (in
+# interpret mode) wherever ``prefill_engages`` is asked, whatever the backend.
+_FORCE_INTERPRET = False
+
+# Shortest prompt bucket that takes the kernel, from the v5e (32 heads over
+# 8 KV heads x 128, ms a layer, einsum pair over an int8 private cache
+# against this kernel; PERF.md, PR 30): 0.084 / 0.104 at 256 and 0.121 /
+# 0.150 at 512, where the pair's scores are a few MB and it is ahead;
+# 0.705 / 0.232 at 1024; 2.45 / 0.61 at 2048.
+_PREFILL_MIN = 1024
+
+
+def _one_tpu_device() -> bool:
+    mesh = jax.sharding.get_abstract_mesh()
+    return jax.default_backend() == "tpu" and (mesh.empty or mesh.size == 1)
+
+
+def prefill_engages(t: int, cache_len: int, write_at, n_heads: int,
+                    n_kv_heads: int, head_dim: int) -> bool:
+    """Whether a cached forward whose caller has stated its mask as causal
+    from position 0 (``forward_cached``'s ``causal_lens``) attends through
+    ``prefill_attention``, from what the code can see: the call fills a
+    private cache of its own length from a static position 0 (so the K and V
+    it just projected are all there is to attend to), the bucket is long
+    enough to gain, the kernel tiles the shape, and the TPU backend holds the
+    call on one device. Everything else runs the einsum pair over the
+    cache, which is also the oracle."""
+    if not (isinstance(write_at, int) and write_at == 0 and t == cache_len
+            and t >= _PREFILL_MIN):
+        return False
+    if not flash_tileable((1, t, n_heads, head_dim),
+                          (1, t, n_kv_heads, head_dim)):
+        return False
+    return _FORCE_INTERPRET or _one_tpu_device()
+
+
+def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal self-attention of ``[B, T, H, D]`` queries over the
+    ``[B, T, Hkv, D]`` keys and values of the same positions: the training
+    kernel's forward as it is (no residual statistics, no gradient), under a
+    name a device trace can show. Padding past a prompt's end needs no
+    length mask: a real query sees only keys at or before itself, and what
+    the padded queries compute is read by nobody."""
+    T, D = q.shape[1], q.shape[3]
+    with jax.named_scope("admit_flash_attention"):
+        out, _ = _flash_forward(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), scale=D ** -0.5, causal=True,
+            block_q=auto_block_q(T), block_k=auto_block_k(T),
+            interpret=not _one_tpu_device(), with_lse=False,
+            name="admit_flash_attention")
+        return out.transpose(0, 2, 1, 3)

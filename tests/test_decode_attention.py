@@ -324,3 +324,74 @@ def test_merge_compiles_for_v5e_in_place_with_window_temporaries(
             and re.search(r"\b(copy|transpose|gather|scatter|select|"
                           r"convolution|dot)\(", line)]
     assert not made, made
+
+
+# The chat cell's admission executable (``RollingGenerator._prefill_impl``,
+# Mistral-7B int8, 32 slots x 2048) at its two largest buckets, compiled for
+# the same described chip with its attention as the einsum pair over the
+# private cache and as the flash kernel (PR 30). ``prefill_engages`` asks the
+# backend, which is the CPU here, so the test answers for it.
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("p_pad", [1024, 2048])
+def test_admission_compiles_for_v5e_without_the_scores(p_pad, v5e_chip,
+                                                       monkeypatch):
+    """Mosaic takes the kernel at the cell's widths inside the whole
+    executable (one custom call in the layer scan), the grid stays aliased in
+    place, and the temporaries fall by most of the float32 scores
+    ``[32 heads, p_pad, p_pad]`` (537 MB at 2048; read here, PR 30: 0.831 ->
+    0.376 GB at 2048 and 0.205 -> 0.071 GB at 1024; what is left is the
+    private cache, the splice and the MLP's activations)."""
+    from kubetorch_tpu.models import quant
+    from kubetorch_tpu.models.configs import LlamaConfig
+    from kubetorch_tpu.models.rolling import RollingGenerator
+    from kubetorch_tpu.ops import flash_attention
+    from kubetorch_tpu.parallel.sharding import ShardingRules
+
+    layers, b, m, vocab = 32, 32, 2048, 32768
+    cfg = LlamaConfig(vocab_size=vocab, embed_dim=4096, n_layers=layers,
+                      n_heads=32, n_kv_heads=8, head_dim=128, mlp_dim=14336,
+                      rope_theta=1e6, rms_eps=1e-5, tie_embeddings=False,
+                      max_seq_len=m, remat=False, dtype="bfloat16",
+                      param_dtype="bfloat16")
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = jax.tree.map(
+        lambda x: spec(x.shape, x.dtype),
+        jax.eval_shape(lambda k: quant.init_quantized(k, cfg, fuse=True),
+                       jax.random.key(0)))
+    cache = {"k": spec((layers, b, m, 8, 128), jnp.int8),
+             "v": spec((layers, b, m, 8, 128), jnp.int8),
+             "ks": spec((layers, b, m, 8), jnp.float32),
+             "vs": spec((layers, b, m, 8), jnp.float32)}
+    args = (params, cache, spec((b, vocab), jnp.float32),
+            spec((b,), jnp.int32), spec((b,), jnp.bool_),
+            spec((1, p_pad), jnp.int32), spec((1,), jnp.int32),
+            spec((1,), jnp.int32))
+    rules = ShardingRules.default()
+
+    def compiled(on_tpu: bool):
+        monkeypatch.setattr(flash_attention, "_one_tpu_device",
+                            lambda: on_tpu)
+        return jax.jit(
+            lambda *a: RollingGenerator._prefill_impl(
+                *a, None, p_pad=p_pad, cfg=cfg, rules=rules),
+            donate_argnums=(1, 2, 3, 4)).lower(*args).compile()
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        einsum, flash = compiled(False), compiled(True)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    assert einsum.as_text().count("tpu_custom_call") == 0
+    assert flash.as_text().count("tpu_custom_call") == 1
+    grid_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                     for x in cache.values())
+    scores = 32 * p_pad * p_pad * 4
+    for exe in (einsum, flash):
+        assert exe.memory_analysis().alias_size_in_bytes >= grid_bytes
+    saved = (einsum.memory_analysis().temp_size_in_bytes
+             - flash.memory_analysis().temp_size_in_bytes)
+    assert saved >= 0.8 * scores, (saved, scores)
