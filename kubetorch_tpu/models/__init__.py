@@ -8,7 +8,8 @@ model code. Layers are stacked and scanned (``lax.scan``) so compile time is
 O(1) in depth.
 """
 
-from kubetorch_tpu.models.configs import (LatentMoEConfig, LlamaConfig,
+from kubetorch_tpu.models.configs import (HybridLinearConfig,
+                                          LatentMoEConfig, LlamaConfig,
                                           MoEConfig, ViTConfig)
 from kubetorch_tpu.models import llama
 
@@ -20,7 +21,7 @@ def __getattr__(name):
     import importlib
 
     if name in ("generate", "quant", "rolling", "speculative", "lora",
-                "embed", "decoder", "latent_moe"):
+                "embed", "decoder", "latent_moe", "hybrid_linear"):
         return importlib.import_module(f"kubetorch_tpu.models.{name}")
     if name == "LoraConfig":
         return importlib.import_module(
@@ -43,8 +44,9 @@ def __getattr__(name):
     raise AttributeError(name)
 
 
-__all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig", "ViTConfig",
-           "decoder", "latent_moe", "llama", "Generator",
+__all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig",
+           "HybridLinearConfig", "ViTConfig",
+           "decoder", "latent_moe", "hybrid_linear", "llama", "Generator",
            "generate", "quant", "quantize_params", "RollingGenerator",
            "SpeculativeGenerator", "speculative", "lora", "LoraConfig",
            "embed", "Embedder"]

@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import ClassVar, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -169,6 +169,90 @@ class LatentMoEConfig:
                     n_experts=8, top_k=2, expert_mlp_dim=32,
                     n_shared_experts=1, max_seq_len=128,
                     dtype="float32", param_dtype="float32")
+        base.update(kw)
+        return cls(**base)
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridLinearConfig:
+    """Decoder whose layers are of two kinds (``models/hybrid_linear.py``;
+    the layer as the ``olmo_hybrid`` public config writes it):
+    ``linear_attention`` layers keep a recurrent state of ``linear_key_dim x
+    linear_value_dim`` a head a ROW (gated delta rule behind a short causal
+    convolution) and no position, ``full_attention`` layers keep keys and
+    values of ``n_kv_heads x head_dim`` a position (causal softmax, no
+    rotary embedding). ``layer_types`` names each layer's kind. Both kinds
+    end in a SwiGLU of ``mlp_dim``; every block's output is normed before
+    the residual add (``x + norm(f(x))``)."""
+
+    vocab_size: int = 100352
+    embed_dim: int = 3840
+    layer_types: Tuple[str, ...] = (("linear_attention",) * 3
+                                    + ("full_attention",)) * 4
+    n_heads: int = 30
+    n_kv_heads: int = 30
+    head_dim: int = 128
+    linear_heads: int = 30          # key heads = value heads
+    linear_key_dim: int = 96
+    linear_value_dim: int = 192
+    conv_width: int = 4
+    neg_eigval: bool = True         # beta in (0, 2): eigenvalues in (-1, 1)
+    mlp_dim: int = 11008
+    rms_eps: float = 1e-6
+    max_seq_len: int = 4096
+    dtype: str = "bfloat16"        # compute dtype
+    param_dtype: str = "bfloat16"  # storage dtype
+
+    KINDS: ClassVar[Tuple[str, ...]] = ("linear_attention",
+                                        "full_attention")
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        bad = sorted(set(self.layer_types) - set(self.KINDS))
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types must name {self.KINDS}, "
+                             f"got {bad or 'no layer'}")
+        if self.n_heads != self.n_kv_heads:
+            raise ValueError("the full-attention layers are multi-head: "
+                             "n_kv_heads must equal n_heads")
+        if self.conv_width < 2:
+            raise ValueError("conv_width must be >= 2")
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def storage_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_linear_layers(self) -> int:
+        return self.layer_types.count("linear_attention")
+
+    @property
+    def n_full_layers(self) -> int:
+        return self.layer_types.count("full_attention")
+
+    @property
+    def conv_channels(self) -> int:
+        """Channels the short convolution runs over: ``[q | k | v]``."""
+        return self.linear_heads * (2 * self.linear_key_dim
+                                    + self.linear_value_dim)
+
+    @classmethod
+    def tiny(cls, **kw) -> "HybridLinearConfig":
+        """CI config: one period and a half (L L L F L L), float32."""
+        base = dict(vocab_size=512, embed_dim=64,
+                    layer_types=("linear_attention",) * 3
+                    + ("full_attention",) + ("linear_attention",) * 2,
+                    n_heads=4, n_kv_heads=4, head_dim=16, linear_heads=4,
+                    linear_key_dim=8, linear_value_dim=16, mlp_dim=128,
+                    max_seq_len=128, dtype="float32", param_dtype="float32")
         base.update(kw)
         return cls(**base)
 

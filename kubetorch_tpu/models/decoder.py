@@ -8,18 +8,34 @@ decoder only through the functions below, found from the configuration
 object it was built with (``decoder_for(cfg)``):
 
 - ``layer_kinds(cfg)``: one label a layer (``("dense",) * L``; a leading
-  dense layer before expert layers is ``("dense", "moe", ...)``). Layers of
-  one kind have one shape.
+  dense layer before expert layers is ``("dense", "moe", ...)``; three
+  linear-attention layers to one full-attention layer is ``("linear_attention",
+  "linear_attention", "linear_attention", "full_attention", ...)``). Layers
+  of one kind have one shape and keep the same leaves.
 - ``cache_leaves(cfg, quantized)``: kind -> the leaves a layer of that kind
-  keeps a position (``CacheLeaf``: name, per-position shape, dtype). A leaf
-  is one array ``[L, B, M, *shape]`` stacked over the layers (every layer of
-  both decoders here keeps the same leaves), so the generator addresses a
-  row as ``leaf[:, slot, :depth]`` whatever the leaf holds.
+  keeps (``CacheLeaf``: name, shape, dtype, positional). Leaves are of two
+  sorts. A **positional** leaf holds ``shape`` a POSITION: one array ``[L,
+  B, M, *shape]`` that the generator addresses as ``leaf[:, slot, :depth]``
+  whatever it holds (keys and values, a latent). A **row-state** leaf
+  (``positional=False``) holds ``shape`` a ROW whatever the row's depth:
+  one array ``[L, B, *shape]``, addressed as ``leaf[:, slot]`` (a recurrent
+  state, a convolution's tail); it is what a row IS after its last real
+  token, so it is spliced whole at admission, carried through a decode
+  chunk (the grid is read-only there, the state is not), held for a row
+  that is not active, exported and imported whole, and zeroed when a row is
+  freed. A leaf is stacked over the layers of the kinds that list it, in
+  layer order, so ``L`` is a leaf's own: all layers where every kind lists
+  the leaf (both decoders before the third), the full-attention layers for
+  the hybrid's ``k`` and ``v``, the linear ones for its ``state``. The
+  cache is one flat dict of both sorts; ``row_leaves(model, cfg)`` names the
+  second.
 - ``init_cache(cfg, batch, max_len, dtype=None, quantized=False)``,
   ``init_cache_like(cfg, cache, batch, max_len)`` (a private cache of the
   grid's own leaves and dtypes, for a bucketed prefill) and
   ``init_chunk(cfg, cache, batch, cols)`` (the few columns a decode chunk or
-  a prefill chunk writes before the merge).
+  a prefill chunk writes before the merge; a decoder with row-state leaves
+  puts the grid's own there too: the chunk is what a chunk-mode forward may
+  write, and ``merge_chunk_into_grid`` takes them back whole).
 - ``forward_cached(...)``: ``models/llama.py::forward_cached``'s contract
   (prefill into a private cache, or chunk mode over the read-only grid) with
   a third result, the step's counters (``{}`` where the decoder has none).
@@ -44,39 +60,74 @@ object it was built with (``decoder_for(cfg)``):
   in chunk mode, summed over a decode chunk on the device and fetched with
   the chunk's tokens; ``prefill_counters(cfg, prompt_tokens)`` what a
   prefill of that many prompt tokens adds to them, counted on the host.
+- ``state_rows_touched(cfg, rows, live)``: rows whose row-state leaves one
+  decode step over a grid of ``rows`` rows, ``live`` of them decoding, reads
+  and writes (0 for a decoder that keeps none): what
+  ``decode_state_rows_touched`` counts. ``scan_positions(cfg, rows,
+  length)``: positions a recurrent layer's scan walks for a prefill of
+  ``rows`` rows padded to ``length`` (0 where no layer scans): what
+  ``linear_scan_positions`` counts.
 - ``check_serving(cfg, **features)``: raises for a serving feature the
   decoder does not carry, naming the feature.
 
 ``models/llama.py`` is the first instance (``LlamaDecoder`` only names its
 functions; its executables are the ones they were), ``models/latent_moe.py``
-the second.
+the second, ``models/hybrid_linear.py`` the third and the first with
+row-state leaves.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, FrozenSet, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
 
 class CacheLeaf(NamedTuple):
     name: str
-    shape: Tuple[int, ...]      # of one position of one layer
+    shape: Tuple[int, ...]      # of one position (or one row) of one layer
     dtype: Any
+    positional: bool = True     # False: a row-state leaf, no position axis
 
 
-def grid_dims(cache: Dict[str, Any]) -> Tuple[int, int, int]:
-    """``(layers, rows, positions)`` of a cache: every leaf is
-    ``[L, B, M, ...]``."""
-    return next(iter(cache.values())).shape[:3]
+def row_leaves(model, cfg) -> FrozenSet[str]:
+    """Names of the cache's row-state leaves (``[L, B, *shape]``); every
+    other leaf is positional (``[L, B, M, *shape]``)."""
+    return frozenset(leaf.name
+                     for leaves in model.cache_leaves(cfg).values()
+                     for leaf in leaves if not leaf.positional)
+
+
+def grid_dims(cache: Dict[str, Any], rows=()) -> Tuple[int, int]:
+    """``(rows, positions)`` of a cache's grid: ``B`` and ``M`` of its
+    positional leaves (``[L, B, M, ...]``, each with its own ``L``), which
+    agree; ``rows`` names the row-state leaves, which have no ``M``."""
+    dims = {leaf.shape[1:3] for name, leaf in cache.items()
+            if name not in rows}
+    if len(dims) != 1:
+        raise ValueError(f"positional leaves disagree on (rows, positions): "
+                         f"{sorted(dims)}")
+    return dims.pop()
+
+
+def _leaf_bytes(model, cfg, quantized: bool, positional: bool) -> int:
+    leaves = model.cache_leaves(cfg, quantized)
+    return sum(math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
+               for kind in model.layer_kinds(cfg) for leaf in leaves[kind]
+               if leaf.positional == positional)
 
 
 def position_bytes(model, cfg, quantized: bool = False) -> int:
-    """Bytes one position holds over all layers."""
-    leaves = model.cache_leaves(cfg, quantized)
-    return sum(math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
-               for kind in model.layer_kinds(cfg) for leaf in leaves[kind])
+    """Bytes one position holds over the layers that keep positions: the
+    positional leaves of each layer's kind."""
+    return _leaf_bytes(model, cfg, quantized, True)
+
+
+def row_bytes(model, cfg, quantized: bool = False) -> int:
+    """Bytes a row holds whatever its depth: the row-state leaves of each
+    layer's kind, over the layers (0 for a decoder that keeps none)."""
+    return _leaf_bytes(model, cfg, quantized, False)
 
 
 class LlamaDecoder:
@@ -163,13 +214,22 @@ class LlamaDecoder:
         return {}
 
     @staticmethod
+    def state_rows_touched(cfg, rows: int, live: int) -> int:
+        return 0
+
+    @staticmethod
+    def scan_positions(cfg, rows: int, length: int) -> int:
+        return 0
+
+    @staticmethod
     def check_serving(cfg, **features) -> None:
         """Carries every serving feature the generator has."""
 
 
 def decoder_for(cfg):
     """The decoder of a configuration object, by its type."""
-    from kubetorch_tpu.models.configs import LatentMoEConfig, LlamaConfig
+    from kubetorch_tpu.models.configs import (HybridLinearConfig,
+                                              LatentMoEConfig, LlamaConfig)
 
     if isinstance(cfg, LlamaConfig):
         return LlamaDecoder
@@ -177,5 +237,9 @@ def decoder_for(cfg):
         from kubetorch_tpu.models.latent_moe import LatentMoEDecoder
 
         return LatentMoEDecoder
+    if isinstance(cfg, HybridLinearConfig):
+        from kubetorch_tpu.models.hybrid_linear import HybridLinearDecoder
+
+        return HybridLinearDecoder
     raise TypeError(f"no decoder for a configuration of type "
                     f"{type(cfg).__name__}")

@@ -589,8 +589,17 @@ class LatentMoEDecoder:
                 prompt_tokens * cfg.top_k * cfg.n_moe_layers}
 
     @staticmethod
+    def state_rows_touched(cfg, rows: int, live: int) -> int:
+        return 0
+
+    @staticmethod
+    def scan_positions(cfg, rows: int, length: int) -> int:
+        return 0
+
+    @staticmethod
     def check_serving(cfg, kv_dtype: str = "bf16", **features) -> None:
-        asked = [name for name, on in features.items() if on]
+        asked = [name for name, on in features.items()
+                 if on and name in _REFUSED]
         if kv_dtype != "bf16":
             asked.insert(0, "kv_dtype")
         if asked:

@@ -9,12 +9,17 @@ batch blocking until its slowest member finishes (the static
 
 TPU shape discipline + dispatch discipline:
 
-- Everything is static-shaped. The engine owns a cache of ``[L, max_slots,
-  max_len, ...]`` leaves; a *slot* is a batch row. New requests prefill into
-  a free slot (jitted per padded-length bucket), and decode advances **all**
-  active slots, each at its own depth.
+- Everything is static-shaped. The engine owns a cache whose leaves are of
+  two sorts (``models/decoder.py``): positional ones, ``[L, max_slots,
+  max_len, ...]`` (keys and values, a latent: read to a row's depth), and
+  row-state ones, ``[L, max_slots, ...]`` with no position axis (a
+  recurrent state: what a row is after its last token, whatever its depth).
+  A *slot* is a batch row. New requests prefill into a free slot (jitted per
+  padded-length bucket), and decode advances **all** active slots, each at
+  its own depth.
 - The layers and what a cache leaf holds are the decoder's
-  (``models/decoder.py``: ``decoder_for(cfg)``); this module names no leaf.
+  (``models/decoder.py``: ``decoder_for(cfg)``); this module names no leaf
+  and asks only which leaves are row state (``row_leaves``).
 - All decode state (cache, pending logits, depths, active mask) lives on
   device between calls; the host holds only bookkeeping. Each
   :meth:`step` is ONE jit call running ``steps_per_call`` tokens through a
@@ -42,7 +47,8 @@ from kubetorch_tpu.config import env_float, env_int
 from kubetorch_tpu.lookahead import LookaheadState, spec_stats_dict
 from kubetorch_tpu.observability import devstats
 from kubetorch_tpu.models.decoder import (decoder_for, grid_dims,
-                                          position_bytes)
+                                          position_bytes, row_bytes,
+                                          row_leaves)
 from kubetorch_tpu.models.generate import filter_logits
 from kubetorch_tpu.ops import grid_write
 from kubetorch_tpu.parallel.sharding import ShardingRules
@@ -339,6 +345,18 @@ class RollingGenerator:
         self._model_counts = {name: 0 for name in self.model.counters}
         self._kv_position_bytes = position_bytes(self.model, cfg,
                                                  self.kv_quantized)
+        # Row-state leaves (no position axis): what a row holds whatever its
+        # depth. Their names, their bytes a row (a gauge), and what decode
+        # and admission do with them: rows decoding against rows whose state
+        # a step reads and writes, summed over decode steps, and positions
+        # the admissions' recurrent scans walk (rounded by chunk and bucket)
+        # beside the prompt tokens those admissions held.
+        self._row_leaves = row_leaves(self.model, cfg)
+        self._state_row_bytes = row_bytes(self.model, cfg,
+                                          self.kv_quantized)
+        self._state_rows = {"live": 0, "touched": 0}
+        self._scan_positions = {"linear_scan_positions": 0,
+                                "linear_scan_prompt_tokens": 0}
 
         # Device-truth utilization accounting: every jitted dispatch
         # below routes through this accumulator, which captures each
@@ -438,7 +456,14 @@ class RollingGenerator:
         the part of them whose attention took the flash kernel,
         ``prefill_flash_positions``. Beside them the decoder's own
         counters (fetched with the tokens of each decode chunk) and the
-        bytes one position holds over all layers, a gauge."""
+        bytes one position holds over the layers that keep positions, a
+        gauge. For a decoder with row-state leaves (0 for the others):
+        ``decode_state_rows_live`` / ``_touched`` (rows decoding, and rows
+        whose state a decode step reads and writes, summed over steps),
+        ``linear_scan_positions`` / ``_prompt_tokens`` (positions the
+        admissions' recurrent scans walk, a layer, and the prompt tokens
+        they held) and ``state_row_bytes``, the bytes a row holds whatever
+        its depth, a gauge."""
         out = {f"decode_kv_positions_{k}": int(v)
                for k, v in self._kv_positions.items()}
         out.update((f"merge_positions_{k}", int(v))
@@ -446,6 +471,10 @@ class RollingGenerator:
         out.update(self._prefill_positions)
         out.update(self._model_counts)
         out["kv_position_bytes"] = self._kv_position_bytes
+        out.update((f"decode_state_rows_{k}", int(v))
+                   for k, v in self._state_rows.items())
+        out.update(self._scan_positions)
+        out["state_row_bytes"] = self._state_row_bytes
         return out
 
     def _count_prefill(self, prompt_tokens: int) -> None:
@@ -463,6 +492,27 @@ class RollingGenerator:
             grid if block is None else int((-(-live // block) * block).sum()))
         self._kv_positions["grid"] += grid
 
+    def _count_state_rows(self, steps: int) -> None:
+        """Account one decode chunk of ``steps`` steps: the rows that
+        decode, and the rows whose row-state leaves each step reads and
+        writes (the decoder says: all of the grid's where an idle row is
+        held and not skipped)."""
+        if not self._row_leaves:
+            return
+        live = len(self._slots)
+        self._state_rows["live"] += steps * live
+        self._state_rows["touched"] += steps * self.model.state_rows_touched(
+            self.cfg, self.max_slots, live)
+
+    def _count_scan(self, rows: int, length: int, prompt_tokens: int) -> None:
+        """Account a prefill of ``rows`` (padded) rows of ``length``
+        (padded) positions that held ``prompt_tokens`` real ones: what the
+        decoder's recurrent scans walk for it, a layer."""
+        walked = self.model.scan_positions(self.cfg, rows, length)
+        if walked:
+            self._scan_positions["linear_scan_positions"] += walked
+            self._scan_positions["linear_scan_prompt_tokens"] += (
+                prompt_tokens)
     def _count_merge(self, counts, cols: int) -> None:
         """Account merges of ``cols``-column chunks: ``counts`` is what each
         row lands in each (0 for a row that sits the merge out). ``new`` is
@@ -704,6 +754,7 @@ class RollingGenerator:
                 finals[slot] = True
                 done_reqs.append(req)
         self._count_merge(counts, C)
+        self._count_scan(B, C, int(counts.sum()))
         with self._mesh_ctx():
             (self.cache, self._logits, self._dpos,
              self._dactive) = self._devstats.call(
@@ -749,10 +800,11 @@ class RollingGenerator:
     def evict(self, rid: int) -> bool:
         """Row-granular eviction: cancel a queued, mid-prefill, or
         decoding request and free its row immediately. The freed row's
-        cache plane is reusable as-is — attention is masked to rows
+        positional planes are reusable as-is — attention is masked to rows
         below each slot's depth (and a fresh admission rewrites from
-        row 0), so stale K/V is never read. Returns whether the rid was
-        found."""
+        row 0), so stale K/V is never read; its row-state leaves, which
+        have no depth to mask by, are zeroed (``_free_rows``). Returns
+        whether the rid was found."""
         for i, req in enumerate(self._queue):
             if req.rid == rid:
                 self._queue.pop(i)
@@ -837,7 +889,10 @@ class RollingGenerator:
     def export_row(self, rid: int, block_tokens: int = 16
                    ) -> Dict[str, Any]:
         """Export a decode-active row as a host pytree — its grid KV up
-        to the row's depth plus everything needed to resume the request
+        to the row's depth, its row-state leaves whole (``row_state``:
+        what a decoder keeps a row whatever its depth, a recurrent state;
+        absent for a decoder that keeps none), plus everything needed to
+        resume the request
         elsewhere/later (sampler params, penalty window, emitted tokens,
         stop sequences). The serving engine's session-park path publishes
         this tree through the store codec (``serving/kvpool.py``).
@@ -889,6 +944,8 @@ class RollingGenerator:
                     f"KT_KV_BLOCK_TOKENS that divides max_len")
         kv: Dict[str, Dict[str, np.ndarray]] = {}
         for kk in self.cache:
+            if kk in self._row_leaves:
+                continue
             plane = np.array(self.cache[kk][:, slot, :dend])
             # ZERO the block-padded tail beyond the row's depth: freed
             # rows never clear their cache planes (attention masks them
@@ -925,6 +982,13 @@ class RollingGenerator:
             "geom": np.asarray([bt, self.max_len, self.n_adapters],
                                np.int64),
         }
+        if self._row_leaves:
+            # what the row holds whatever its depth (a recurrent state, a
+            # convolution's tail), each leaf whole: ``[L, *shape]``. No
+            # block structure and no stale tail: it is all the row's own.
+            state["row_state"] = {
+                kk: np.array(self.cache[kk][:, slot])
+                for kk in sorted(self._row_leaves)}
         if self.spec:
             # round-carried speculation state. The draft haystack ships
             # explicitly (a prefixed row's prefix tokens live only on
@@ -952,11 +1016,25 @@ class RollingGenerator:
         slot-axis width); importing into an engine that differs on ANY
         axis raises :class:`KVGeometryMismatch` naming both geometries
         instead of splicing corrupt state. States without the leaf
-        (pre-geometry exports) keep the legacy shape-fit checks only."""
+        (pre-geometry exports) keep the legacy shape-fit checks only.
+        Row-state leaves are held to the same guard: the state's
+        ``row_state`` must hold exactly this grid's, each of this grid's
+        ``[L, *shape]``."""
+        from kubetorch_tpu.exceptions import KVGeometryMismatch
+
+        exported = {kk: tuple(np.shape(v))
+                    for kk, v in (state.get("row_state") or {}).items()}
+        importer = {kk: self.cache[kk].shape[:1] + self.cache[kk].shape[2:]
+                    for kk in self._row_leaves}
+        if exported != importer:
+            raise KVGeometryMismatch(
+                f"cannot import row: exported row-state leaves {exported} "
+                f"do not match the importing engine's {importer} (a row's "
+                f"recurrent state is whole or it is nothing)",
+                axis="row_state", exported=exported, importer=importer)
         geom = state.get("geom")
         if geom is None:
             return
-        from kubetorch_tpu.exceptions import KVGeometryMismatch
 
         g = [int(x) for x in np.asarray(geom).reshape(-1)]
         exported = {"block_tokens": g[0], "max_len": g[1],
@@ -987,9 +1065,10 @@ class RollingGenerator:
         must match, depth must fit ``max_len``).
 
         The splice writes the row's KV at positions ``[0, depth)`` with
-        one ``.at[].set`` per cache plane — a fresh compile per distinct
-        block-rounded depth, which the block rounding keeps to a handful
-        of shapes. Returns the NEW rid (rids are engine-local). Sampler
+        one ``.at[].set`` per positional plane — a fresh compile per
+        distinct block-rounded depth, which the block rounding keeps to a
+        handful of shapes — and each row-state leaf whole at the row.
+        Returns the NEW rid (rids are engine-local). Sampler
         RNG is engine-global and not part of the export: greedy resumes
         are token-identical to an uninterrupted run; sampled resumes are
         distribution-correct but draw a fresh key sequence.
@@ -1011,10 +1090,11 @@ class RollingGenerator:
         self._check_geometry(state, block_tokens)
         if not self._free:
             raise RuntimeError("no free row to import into")
-        if set(state["kv"]) != set(self.cache):
+        positional = set(self.cache) - self._row_leaves
+        if set(state["kv"]) != positional:
             raise ValueError(
                 f"KV planes {sorted(state['kv'])} do not match this "
-                f"grid's {sorted(self.cache)} — kv_dtype mismatch "
+                f"grid's {sorted(positional)} — kv_dtype mismatch "
                 f"between export and import engines")
         scalars = [int(x) for x in np.asarray(state["scalars"])]
         dpos, n_emitted, max_new = scalars[0], scalars[1], scalars[2]
@@ -1040,9 +1120,13 @@ class RollingGenerator:
                 f"max_len {self.max_len}")
         slot = self._free.pop(0)
         with self._mesh_ctx():
-            for kk in self.cache:
+            for kk in planes:
                 self.cache[kk] = self.cache[kk].at[:, slot, :dend].set(
                     jnp.asarray(planes[kk]).astype(self.cache[kk].dtype))
+            for kk in self._row_leaves:
+                self.cache[kk] = self.cache[kk].at[:, slot].set(
+                    jnp.asarray(state["row_state"][kk]).astype(
+                        self.cache[kk].dtype))
             self._logits = self._logits.at[slot].set(
                 jnp.asarray(np.asarray(state["logits"], np.float32)))
             self._dpos = self._dpos.at[slot].set(dpos)
@@ -1149,8 +1233,9 @@ class RollingGenerator:
     # ----------------------------------------------------------- interns
     def _start_chunked(self, req: Request) -> None:
         """Claim the row for a chunked prefill. No dispatch here: the
-        row's ``dpos`` is already 0 (rows reset on free/evict) and its
-        grid rows are rewritten from position 0 by the chunk forwards.
+        row's ``dpos`` is already 0 and its row-state leaves zero (rows
+        reset on free/evict) and its grid rows are rewritten from position
+        0 by the chunk forwards.
         Only the slot's adapter index must be live during prefill — the
         chunk forwards run under it."""
         req.consumed = 0
@@ -1192,6 +1277,7 @@ class RollingGenerator:
                 else 0)
             self.prefill_tokens += len(req.prompt)
             self._count_prefill(len(req.prompt))
+        self._count_scan(n_pad, p_pad, sum(len(r.prompt) for r in group))
         with self._mesh_ctx():
             self._count_admission(n_pad, p_pad, own=prefix_id is None)
             if prefix_id is None:
@@ -1245,6 +1331,7 @@ class RollingGenerator:
     def _decode_chunk(self) -> List[Tuple[int, List[int], bool]]:
         with self.tick_phase("decode_dispatch"):
             self._count_kv_read()
+            self._count_state_rows(self.steps_per_call)
             self._count_merge(
                 np.full(len(self._slots), self.steps_per_call),
                 self.steps_per_call)
@@ -1404,6 +1491,14 @@ class RollingGenerator:
         mask = jnp.asarray(mask)
         self._dactive = jnp.where(mask, False, self._dactive)
         self._dpos = jnp.where(mask, 0, self._dpos)
+        # a row-state leaf has no depth to mask a stale row by: a freed row
+        # goes back to a sequence's start, which is what a chunked prefill
+        # begins from (a bucketed admission splices the whole row anyway)
+        for kk in self._row_leaves:
+            leaf = self.cache[kk]
+            self.cache[kk] = jnp.where(
+                mask.reshape((1, -1) + (1,) * (leaf.ndim - 2)),
+                jnp.zeros((), leaf.dtype), leaf)
         self._slot_adapter[freed] = -1
         self._depth[freed] = 0
         for slot in freed:
@@ -1439,11 +1534,11 @@ class RollingGenerator:
             causal_lens=prompt_lens)
         return RollingGenerator._finish_admit(
             cache, own, out[:, 0], logits, dpos, dactive, slots,
-            prompt_lens)
+            prompt_lens, row_leaves(model, cfg))
 
     @staticmethod
     def _finish_admit(cache, own, last, logits, dpos, dactive, slots,
-                      new_pos):
+                      new_pos, rows=frozenset()):
         """Splice own-cache rows into the grid and update per-slot state.
 
         Gather + masked select, NOT a scatter: batched-axis scatters on the
@@ -1452,23 +1547,29 @@ class RollingGenerator:
         ``own`` spans rows [0, M_own) of the grid's M axis — prefill always
         writes from position 0 (prefixed admission broadcasts the prefix
         into the own-cache first), so the splice touches only that span.
-        ``last``: [N, V] logits at each row's final real token.
+        ``last``: [N, V] logits at each row's final real token. ``rows``
+        names the row-state leaves (``[L, B, *shape]``, no position axis):
+        an admitted row takes the own-cache's whole, which the prefill left
+        at the row's last real token.
         """
-        B = grid_dims(cache)[1]
-        M_own = grid_dims(own)[2]
+        B = grid_dims(cache, rows)[0]
+        M_own = grid_dims(own, rows)[1]
         onehot = slots[None, :] == jnp.arange(B)[:, None]       # [B, N]
         sel = jnp.argmax(onehot, axis=1)                        # [B]
         any_valid = onehot.any(axis=1)
 
-        def splice(plane_c, plane_o):
+        def splice(kk):
+            plane_c, plane_o = cache[kk], own[kk]
             # plane-generic (int8 grids add 4-D ks/vs scale planes)
             v = any_valid.reshape((1, B) + (1,) * (plane_c.ndim - 2))
+            if kk in rows:
+                return jnp.where(v, plane_o[:, sel], plane_c)
             return jax.lax.dynamic_update_slice_in_dim(
                 plane_c,
                 jnp.where(v, plane_o[:, sel], plane_c[:, :, :M_own]),
                 0, axis=2)
 
-        cache = {kk: splice(cache[kk], own[kk]) for kk in cache}
+        cache = {kk: splice(kk) for kk in cache}
         logits = logits.at[slots].set(last, mode="drop")
         dpos = dpos.at[slots].set(new_pos, mode="drop")
         dactive = dactive.at[slots].set(True, mode="drop")
@@ -1512,10 +1613,11 @@ class RollingGenerator:
         private cache, so the int8 serving grid composes with shared
         prefixes. ``lora``: the suffix forward runs under the prefix's
         owning adapter (submit enforced the match)."""
-        M = grid_dims(cache)[2]
-        N = tokens.shape[0]
-        L, _, Ppad = grid_dims(planes)
         model = decoder_for(cfg)
+        rows = row_leaves(model, cfg)
+        M = grid_dims(cache, rows)[1]
+        N = tokens.shape[0]
+        Ppad = grid_dims(planes, rows)[1]
         # Rows needed: the prefix block plus the suffix span — suffix rows
         # write at [prefix_len, prefix_len + p_pad) and prefix_len ≤ Ppad.
         # Clamped to the grid's M: a long prefix whose BUCKET plus the
@@ -1525,7 +1627,7 @@ class RollingGenerator:
         own = model.init_cache_like(cfg, cache, N, min(Ppad + p_pad, M))
 
         def bcast(plane_own, plane_px):
-            shp = (L, N) + plane_px.shape[2:]
+            shp = (plane_px.shape[0], N) + plane_px.shape[2:]
             return jax.lax.dynamic_update_slice(
                 plane_own, jnp.broadcast_to(plane_px, shp)
                 .astype(plane_own.dtype), (0,) * plane_own.ndim)
@@ -1533,14 +1635,14 @@ class RollingGenerator:
         own = {kk: bcast(own[kk], planes[kk]) for kk in own}
         positions = prefix_len + jnp.broadcast_to(
             jnp.arange(p_pad)[None, :], (N, p_pad))
-        m = jnp.arange(grid_dims(own)[2])[None, None, :]
+        m = jnp.arange(grid_dims(own, rows)[1])[None, None, :]
         mask = m <= positions[:, :, None]
         out, own, _ = model.forward_cached(
             params, tokens, positions, own, prefix_len, mask, cfg, rules,
             unembed_positions=prompt_lens - 1, lora=lora)
         return RollingGenerator._finish_admit(
             cache, own, out[:, 0], logits, dpos, dactive, slots,
-            prefix_len + prompt_lens)
+            prefix_len + prompt_lens, rows)
 
     @staticmethod
     def _prefill_extend_impl(params, cache, logits, dpos, dactive, feed,
@@ -1564,9 +1666,9 @@ class RollingGenerator:
         this path fills them, which is what lets the serving engine
         interleave prefill chunks between decode chunks without ever
         stalling token emission."""
-        M = grid_dims(cache)[2]
         B = feed.shape[0]
         model = decoder_for(cfg)
+        M = grid_dims(cache, row_leaves(model, cfg))[1]
         live = counts > 0
         positions = dpos[:, None] + jnp.arange(C)[None, :]
         gmask = jnp.broadcast_to(
@@ -1624,9 +1726,9 @@ class RollingGenerator:
         (positive logits divided, negative multiplied). The window rolls
         inside the scan so a token sampled at step k is already penalized
         at step k+1."""
-        M = grid_dims(cache)[2]
         B = last_logits.shape[0]
         model = decoder_for(cfg)
+        M = grid_dims(cache, row_leaves(model, cfg))[1]
         pos0 = pos
         # Grid contents never change during the chunk: rows < pos0 hold
         # every previous token, the current chunk's rows live in the
@@ -1749,9 +1851,9 @@ class RollingGenerator:
             residual_next,
         )
 
-        M = grid_dims(cache)[2]
         B = last_logits.shape[0]
         model = decoder_for(cfg)
+        M = grid_dims(cache, row_leaves(model, cfg))[1]
         Lctx = ctx.shape[1]
         bidx = jnp.arange(B)[:, None]
         # `sampling` is STATIC (the host re-jits once if sampled traffic

@@ -631,11 +631,20 @@ class DecodeEngine:
         # admission at min(context + budget, max_len) blocks, exactly
         # what the row can occupy
         self._row_cap_tokens = int(getattr(engine, "max_len", 2048))
+        # bytes a row holds whatever its depth (a decoder's row-state
+        # leaves: a recurrent state), priced as the positions they would
+        # be; the generator's two gauges say both (a sim engine has none)
+        gauges = engine.stats() if hasattr(engine, "stats") else {}
+        pos_bytes = int(gauges.get("kv_position_bytes", 0))
+        row_bytes = int(gauges.get("state_row_bytes", 0))
+        row_state_tokens = -(-row_bytes // pos_bytes) if pos_bytes else 0
         if not budget:
             grid_blocks = (int(getattr(engine, "max_slots", 8))
-                           * kvpool.blocks_for(self._row_cap_tokens, bt))
+                           * kvpool.blocks_for(
+                               self._row_cap_tokens + row_state_tokens, bt))
             budget = 2 * grid_blocks
-        self._kv = kvpool.PagedKVPool(budget, bt, prefix_split)
+        self._kv = kvpool.PagedKVPool(budget, bt, prefix_split,
+                                      row_state_tokens=row_state_tokens)
         # a decoder that does not carry what this engine was configured
         # for (models/decoder.py: check_serving) says so now, by name
         self._model = getattr(engine, "model", None)

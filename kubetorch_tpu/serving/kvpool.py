@@ -642,10 +642,15 @@ class PagedKVPool:
     ``row_cost(ctx_tokens)`` is the admission currency: the scheduler
     asks "how many blocks would this program pin" and compares against
     :attr:`free_blocks` — a prefix-hit program's ``ctx_tokens`` is only
-    its suffix + budget, which is the whole point."""
+    its suffix + budget, which is the whole point. ``row_state_tokens``:
+    what a row pins whatever its depth (a decoder's row-state leaves: a
+    recurrent state), in the currency of positions; every row's cost and
+    reservation carries it (0 for a decoder that keeps none)."""
 
     def __init__(self, budget_blocks: int, block_tokens: int,
-                 split_rule: Optional[str] = None):
+                 split_rule: Optional[str] = None,
+                 row_state_tokens: int = 0):
+        self.row_state_tokens = max(0, int(row_state_tokens))
         self.ledger = KVBlockLedger(budget_blocks, block_tokens)
         self.prefixes = PrefixCache(self.ledger)
         self.split = parse_split_rule(split_rule)
@@ -665,11 +670,13 @@ class PagedKVPool:
         return self.ledger.used
 
     def row_cost(self, ctx_tokens: int) -> int:
-        return blocks_for(ctx_tokens, self.ledger.block_tokens)
+        return blocks_for(ctx_tokens + self.row_state_tokens,
+                          self.ledger.block_tokens)
 
     def reserve_row(self, rid: int, ctx_tokens: int,
                     prefix_pid: Optional[int] = None) -> int:
-        blocks = self.ledger.reserve_row(rid, ctx_tokens)
+        blocks = self.ledger.reserve_row(
+            rid, ctx_tokens + self.row_state_tokens)
         if prefix_pid is not None:
             entry = self.prefixes._by_pid.get(prefix_pid)
             if entry is not None:

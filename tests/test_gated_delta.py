@@ -1,0 +1,113 @@
+"""The gated delta rule (``ops/gated_delta.py``): its chunked form, as the
+``lax.scan`` over chunks and as the Pallas kernel in interpret mode, against
+the rule itself one token at a time.
+
+Tolerance. All three are float32 sums of the same products in different
+orders; the chunked form also inverts a unit triangular matrix a chunk
+(forward substitution, exact in exact arithmetic). Outputs are O(1); they
+agree to ~2e-6 on these seeds, and 2e-5 is held. What the chunking could
+get wrong (a decay applied on the wrong side of a token, the diagonal left
+out of the intra-chunk product, a chunk boundary's state) moves outputs by
+1e-2 or more.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kubetorch_tpu.ops import gated_delta as gd
+
+TOL = 2e-5
+B, H, DK, DV = 2, 4, 8, 16
+
+
+def draw(T, seed=0, b=B):
+    """Inputs as the layer makes them: unit keys with a common positive
+    part (SiLU's), queries of norm dk^-1/2, decays from A ~ U(0, 16), beta
+    in (0, 2)."""
+    ks = jax.random.split(jax.random.key(seed), 7)
+    q = jax.random.normal(ks[0], (b, T, H, DK))
+    k = jax.nn.silu(jax.random.normal(ks[1], (b, T, H, DK)))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, T, H, DV))
+    log_alpha = -16 * jax.random.uniform(ks[3], (b, T, H)) * jax.nn.softplus(
+        jax.random.normal(ks[4], (b, T, H)) - 4)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, T, H)))
+    state = jax.random.normal(ks[6], (b, H, DK, DV))
+    return q, k, v, log_alpha, beta, state
+
+
+def close(a, b):
+    return float(jnp.abs(a - b).max()) < TOL
+
+
+# T: under one solve block, under one chunk, one chunk, not a multiple of
+# the chunk, several chunks
+@pytest.mark.parametrize("T", [5, 37, 128, 300, 384])
+@pytest.mark.parametrize("kernel", [False, True])
+def test_chunked_scan_equals_the_recurrence(T, kernel):
+    args = draw(T, seed=T)
+    want_o, want_s = gd.recurrence(*args)
+    got_o, got_s = gd.prefill_scan(*args, kernel=kernel)
+    assert got_o.shape == want_o.shape == (B, T, H, DV)
+    assert float(jnp.abs(want_o).max()) > 0.5
+    assert close(got_o, want_o) and close(got_s, want_s)
+
+
+def test_step_is_the_rule_as_written():
+    *token, S = draw(1, seed=3)
+    q, k, v, la, beta = (x[:, 0] for x in token)
+    o, new = gd.step(q, k, v, la, beta, S)
+    a, b_ = jnp.exp(la)[..., None, None], beta[..., None, None]
+    kk = k[..., :, None] * k[..., None, :]                       # k k^T
+    want = a * (S - b_ * jnp.einsum("bhij,bhjv->bhiv", kk, S)) \
+        + b_ * k[..., :, None] * v[..., None, :]
+    assert close(new, want)
+    assert close(o, jnp.einsum("bhkv,bhk->bhv", want, q))
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_padding_past_a_rows_end_leaves_its_state_untouched(kernel):
+    """Rows of different lengths in one padded call: ``log_alpha = 0, beta
+    = 0`` past a row's end. Each row's final state is the state at ITS last
+    real token, and its real outputs are those of a call of its own."""
+    T, lens = 256, (256, 131)
+    q, k, v, la, beta, S = draw(T, seed=9)
+    live = jnp.arange(T)[None, :, None] < jnp.asarray(lens)[:, None, None]
+    la, beta = jnp.where(live, la, 0.0), jnp.where(live, beta, 0.0)
+    got_o, got_s = gd.prefill_scan(q, k, v, la, beta, S, kernel=kernel)
+    for row, n in enumerate(lens):
+        one = tuple(x[row:row + 1, :n] for x in (q, k, v, la, beta))
+        want_o, want_s = gd.recurrence(*one, S[row:row + 1])
+        assert close(got_o[row:row + 1, :n], want_o), row
+        assert close(got_s[row:row + 1], want_s), row
+
+
+def test_a_held_token_is_bit_for_bit_no_token():
+    *token, S = draw(1, seed=4)
+    q, k, v, la, beta = (x[:, 0] for x in token)
+    _, new = gd.step(q, k, v, jnp.zeros_like(la), jnp.zeros_like(beta), S)
+    assert np.array_equal(np.asarray(new), np.asarray(S))
+
+
+def test_triangular_inverse_where_a_series_would_lose_its_digits():
+    """Keys that nearly coincide and ``beta`` near 2: ``I + A`` has entries
+    near 2 all below the diagonal, its inverse stays O(1), and the powers
+    of ``A`` a Neumann series would sum reach 1e30."""
+    n = 128
+    a = jnp.tril(jnp.full((n, n), 1.9, jnp.float32), -1)
+    inv = gd._inv_unit_lower(a[None])[0]
+    err = jnp.abs(inv @ (jnp.eye(n) + a) - jnp.eye(n)).max()
+    assert float(err) < 1e-3 and float(jnp.abs(inv).max()) < 4.0
+
+
+def test_kernel_engages_only_where_its_chunk_divides_the_scan(monkeypatch):
+    assert not gd.prefill_engages(256)               # the CPU
+    monkeypatch.setattr(gd, "_FORCE_INTERPRET", True)
+    assert gd.prefill_engages(256) and gd.prefill_engages(4096)
+    assert not gd.prefill_engages(64) and not gd.prefill_engages(200)
+    # what ``linear_scan_positions`` counts: the chunk's rounding
+    assert [gd.scan_positions(t) for t in (16, 128, 200, 4096)] == [
+        16, 128, 256, 4096]
