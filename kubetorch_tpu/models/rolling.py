@@ -332,6 +332,10 @@ class RollingGenerator:
         # What the once-a-chunk merges land and what they rewrite to land
         # it (``_count_merge``), from the same mirror.
         self._merge_positions = {"new": 0, "written": 0}
+        # What the bucketed admissions land (each admitted row's own-cache
+        # span) and what they rewrite of the grid to land it
+        # (``_count_admit``).
+        self._admit_positions = {"new": 0, "written": 0}
         # Padded positions of every bucketed admission, and of those whose
         # attention took the flash kernel (``_count_admission``).
         self._prefill_positions = {"prefill_positions": 0,
@@ -451,7 +455,10 @@ class RollingGenerator:
         where the ragged kernel does; and what the merges wrote:
         ``merge_positions_written / _new`` is 1.0 where every landing row
         lands a whole chunk (plain decode) and the window's share above it
-        where a row lands part of one; and what the bucketed admissions
+        where a row lands part of one; what the bucketed admissions landed
+        in the grid and rewrote of it to do so: ``admit_positions_written
+        / _new`` is 1.0 while only the admitted rows' spans are written
+        (a dummy row of a padded width counts in neither); and what they
         ran: ``prefill_positions`` (rows x the bucket's padded length) and
         the part of them whose attention took the flash kernel,
         ``prefill_flash_positions``. Beside them the decoder's own
@@ -468,6 +475,8 @@ class RollingGenerator:
                for k, v in self._kv_positions.items()}
         out.update((f"merge_positions_{k}", int(v))
                    for k, v in self._merge_positions.items())
+        out.update((f"admit_positions_{k}", int(v))
+                   for k, v in self._admit_positions.items())
         out.update(self._prefill_positions)
         out.update(self._model_counts)
         out["kv_position_bytes"] = self._kv_position_bytes
@@ -522,6 +531,16 @@ class RollingGenerator:
         self._merge_positions["new"] += int(np.sum(counts))
         self._merge_positions["written"] += grid_write.positions_written(
             counts, cols)
+
+    def _count_admit(self, rows: int, slots, span: int) -> None:
+        """Account one bucketed admission's landing: ``rows`` requests go to
+        ``slots`` (of the padded width: ``max_slots`` for a dummy row), each
+        with the ``span`` positions of the own cache. ``new`` is the
+        admitted rows' spans, ``written`` what ``grid_write`` rewrites of
+        the grid for them."""
+        self._admit_positions["new"] += rows * span
+        self._admit_positions["written"] += grid_write.row_positions_written(
+            slots, self.max_slots, span)
 
     def _count_admission(self, rows: int, p_pad: int, own: bool) -> None:
         """Account one bucketed admission of ``rows`` (padded) rows at
@@ -1247,8 +1266,8 @@ class RollingGenerator:
     def _admit_group(self, group: List[Request], p_pad: int,
                      prefix_id: Optional[int] = None):
         """Prefill N same-(bucket, prefix) requests in one call. N pads
-        to one of two widths (dummy rows target slot ``max_slots`` and
-        drop in the splice) so compile count stays O(buckets)."""
+        to one of two widths (dummy rows target slot ``max_slots``, which
+        lands nothing) so compile count stays O(buckets)."""
         n = len(group)
         # two admission shapes only (single vs full-width) — prefill FLOPs
         # on dummy rows are cheap; compiles are not
@@ -1281,6 +1300,7 @@ class RollingGenerator:
         with self._mesh_ctx():
             self._count_admission(n_pad, p_pad, own=prefix_id is None)
             if prefix_id is None:
+                self._count_admit(n, slots, p_pad)
                 (self.cache, self._logits, self._dpos,
                  self._dactive) = self._devstats.call(
                     "prefill", (n_pad, p_pad), self._prefill,
@@ -1290,6 +1310,11 @@ class RollingGenerator:
                     p_pad=p_pad)
             else:
                 pfx = self._prefixes[prefix_id]
+                # the own cache there: the prefix's bucket and the
+                # suffix's, cut at the grid's end (``_prefill_px_impl``)
+                self._count_admit(n, slots, min(
+                    grid_dims(pfx["planes"], self._row_leaves)[1] + p_pad,
+                    self.max_len))
                 (self.cache, self._logits, self._dpos,
                  self._dactive) = self._devstats.call(
                     "prefill_px", (n_pad, p_pad), self._prefill_px,
@@ -1513,8 +1538,8 @@ class RollingGenerator:
     def _prefill_impl(params, cache, logits, dpos, dactive, tokens,
                       prompt_lens, slots, lora, *, p_pad, cfg, rules):
         """Prefill N slots at once: one forward over a private N-row
-        cache, then scatter the rows into the shared grid at ``slots``
-        (out-of-range dummy rows drop).
+        cache, then land the rows in the shared grid at ``slots``
+        (out-of-range dummy rows land nothing).
 
         The private cache covers only the ``p_pad`` rows prefill writes —
         full-``M`` would be a second multi-GB grid live beside the real
@@ -1534,42 +1559,26 @@ class RollingGenerator:
             causal_lens=prompt_lens)
         return RollingGenerator._finish_admit(
             cache, own, out[:, 0], logits, dpos, dactive, slots,
-            prompt_lens, row_leaves(model, cfg))
+            prompt_lens)
 
     @staticmethod
     def _finish_admit(cache, own, last, logits, dpos, dactive, slots,
-                      new_pos, rows=frozenset()):
-        """Splice own-cache rows into the grid and update per-slot state.
+                      new_pos):
+        """Land own-cache rows in the grid and update per-slot state.
 
-        Gather + masked select, NOT a scatter: batched-axis scatters on the
-        [L,B,M,Hkv,D] grid lower to a serialized generic scatter on TPU
-        (measured ~7 s per admission on the 0.8B bench vs ~60 ms this way).
-        ``own`` spans rows [0, M_own) of the grid's M axis — prefill always
-        writes from position 0 (prefixed admission broadcasts the prefix
-        into the own-cache first), so the splice touches only that span.
-        ``last``: [N, V] logits at each row's final real token. ``rows``
-        names the row-state leaves (``[L, B, *shape]``, no position axis):
-        an admitted row takes the own-cache's whole, which the prefill left
-        at the row's last real token.
+        Row ``n`` of ``own`` goes to grid row ``slots[n]`` by slice update
+        (``grid_write.write_rows``: why that is neither a scatter nor a
+        select over the grid, its module's docstring); a dummy row's slot
+        is out of range and nothing of it lands. ``own`` spans positions
+        [0, M_own) of the grid's M axis — prefill always writes from
+        position 0 (prefixed admission broadcasts the prefix into the
+        own-cache first), so the row takes that span whole, pad positions
+        past its depth included, and the rest of the grid row stays. A
+        row-state leaf (``[L, B, *shape]``, no position axis) takes the
+        own-cache's whole, which the prefill left at the row's last real
+        token. ``last``: [N, V] logits at each row's final real token.
         """
-        B = grid_dims(cache, rows)[0]
-        M_own = grid_dims(own, rows)[1]
-        onehot = slots[None, :] == jnp.arange(B)[:, None]       # [B, N]
-        sel = jnp.argmax(onehot, axis=1)                        # [B]
-        any_valid = onehot.any(axis=1)
-
-        def splice(kk):
-            plane_c, plane_o = cache[kk], own[kk]
-            # plane-generic (int8 grids add 4-D ks/vs scale planes)
-            v = any_valid.reshape((1, B) + (1,) * (plane_c.ndim - 2))
-            if kk in rows:
-                return jnp.where(v, plane_o[:, sel], plane_c)
-            return jax.lax.dynamic_update_slice_in_dim(
-                plane_c,
-                jnp.where(v, plane_o[:, sel], plane_c[:, :, :M_own]),
-                0, axis=2)
-
-        cache = {kk: splice(kk) for kk in cache}
+        cache = grid_write.write_rows(cache, own, slots)
         logits = logits.at[slots].set(last, mode="drop")
         dpos = dpos.at[slots].set(new_pos, mode="drop")
         dactive = dactive.at[slots].set(True, mode="drop")
@@ -1642,7 +1651,7 @@ class RollingGenerator:
             unembed_positions=prompt_lens - 1, lora=lora)
         return RollingGenerator._finish_admit(
             cache, own, out[:, 0], logits, dpos, dactive, slots,
-            prefix_len + prompt_lens, rows)
+            prefix_len + prompt_lens)
 
     @staticmethod
     def _prefill_extend_impl(params, cache, logits, dpos, dactive, feed,

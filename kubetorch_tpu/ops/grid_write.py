@@ -1,5 +1,7 @@
-"""The once-a-chunk cache write of the serving grid: a chunk's columns land
-at each row's own depth, and nothing else of the grid is touched.
+"""The two cache writes of the serving grid: a chunk's columns land at each
+row's own depth (``write_columns``, once a decode or prefill chunk), an
+admission's rows land whole at their slots (``write_rows``), and nothing else
+of the grid is touched.
 
 A grid leaf is ``[L, B, M, ...]`` (layers, rows, positions), its chunk
 ``[L, B, K, ...]``. Row ``b`` takes columns ``[0, count[b])`` at positions
@@ -8,19 +10,26 @@ rows that have something to land, each a ``dynamic_slice`` of the row's
 ``[L, 1, K, ...]`` window, a ``where`` between the chunk's columns and what is
 there, and a ``dynamic_update_slice`` back on the loop-carried (donated) leaf,
 which XLA updates in place. The bytes moved follow ``rows x K``, not
-``B x M``.
+``B x M``. An admission's private cache is ``[L, N, M_own, ...]``: row ``n``
+takes the span ``[0, M_own)`` of grid row ``slots[n]`` whole, so the same loop
+needs no read of the grid and no ``where``, one ``dynamic_update_slice`` a
+leaf; a row-state leaf (``[L, B, *shape]``, no position axis) lands the same
+way.
 
 Contiguous slice writes at a scalar offset are not the scatter that once
-serialised here (a full-cache ``take_along_axis`` read ~1.8 s a step: computed
-index maps, one element at a time), and not the select over whole planes that
-stood in for it until PR 28 (a one-hot einsum over all ``M`` positions of
-every layer: 4.4 GB read and written a chunk to land ~19 x 8 positions; kept
-as the oracle in ``tests/test_grid_write.py``). Never ``vmap`` of an update or
-``.at[].set`` with computed indices: those lower to that scatter again.
+serialised here (a full-cache ``take_along_axis`` read ~1.8 s a step, a
+batched-axis scatter ~7 s an admission: computed index maps, one element at a
+time), and not the selects over whole planes that stood in for it: until
+PR 28 a one-hot einsum over all ``M`` positions of every layer (4.4 GB read
+and written a chunk to land ~19 x 8 positions), until PR 32 a gather + masked
+select over every row's ``[0, M_own)`` (the whole grid at the largest bucket
+to land one row); both are kept as the oracles in
+``tests/test_grid_write.py``. Never ``vmap`` of an update or ``.at[].set``
+with computed indices: those lower to that scatter again.
 
 Pure data movement on every backend and mesh (the window is cut along rows
 and positions, which no serving mesh shards): what lands is bit for bit what
-the chunk held.
+the chunk, or the private cache, held.
 """
 
 from __future__ import annotations
@@ -86,3 +95,39 @@ def positions_written(count, cols: int) -> int:
     """Positions ``write_columns`` rewrites for these counts, on the host: a
     window of ``cols`` for every row that lands anything."""
     return int(np.count_nonzero(np.asarray(count) > 0)) * cols
+
+
+def write_rows(grid: Dict[str, jax.Array], own: Dict[str, jax.Array],
+               slots: jax.Array) -> Dict[str, jax.Array]:
+    """``grid[name][:, slots[n]]`` takes ``own[name][:, n]`` from the front
+    (positions ``[0, M_own)`` of a positional leaf, the whole of a row-state
+    leaf), every leaf in one loop over the rows whose slot is in range.
+
+    ``slots`` is ``[N]`` int32, distinct where in range. A dummy row
+    (``slots[n] == B``, how an admission pads its width) is not visited, so
+    nothing is clamped onto the last row."""
+    names = tuple(grid)
+    B = grid[names[0]].shape[1]
+    valid = (slots >= 0) & (slots < B)
+    # rows with a slot, first; the loop stops after them
+    order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+
+    def row(i, leaves):
+        n = order[i]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                leaf, jax.lax.dynamic_slice_in_dim(own[name], n, 1, axis=1),
+                slots[n], axis=1)
+            for leaf, name in zip(leaves, names))
+
+    leaves = jax.lax.fori_loop(
+        0, jnp.sum(valid, dtype=jnp.int32), row,
+        tuple(grid[n] for n in names))
+    return dict(zip(names, leaves))
+
+
+def row_positions_written(slots, rows: int, span: int) -> int:
+    """Positions ``write_rows`` rewrites for these slots on a grid of
+    ``rows`` rows, on the host: a span for every slot in range."""
+    slots = np.asarray(slots)
+    return int(np.count_nonzero((slots >= 0) & (slots < rows))) * span

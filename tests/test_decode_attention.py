@@ -331,20 +331,13 @@ def test_merge_compiles_for_v5e_in_place_with_window_temporaries(
 # the same described chip with its attention as the einsum pair over the
 # private cache and as the flash kernel (PR 30). ``prefill_engages`` asks the
 # backend, which is the CPU here, so the test answers for it.
-@pytest.mark.level("unit")
-@pytest.mark.parametrize("p_pad", [1024, 2048])
-def test_admission_compiles_for_v5e_without_the_scores(p_pad, v5e_chip,
-                                                       monkeypatch):
-    """Mosaic takes the kernel at the cell's widths inside the whole
-    executable (one custom call in the layer scan), the grid stays aliased in
-    place, and the temporaries fall by most of the float32 scores
-    ``[32 heads, p_pad, p_pad]`` (537 MB at 2048; read here, PR 30: 0.831 ->
-    0.376 GB at 2048 and 0.205 -> 0.071 GB at 1024; what is left is the
-    private cache, the splice and the MLP's activations)."""
+def _chat_admission(v5e_chip, p_pad, width=1):
+    """(``compile()`` of the chat cell's ``_prefill_impl`` for ``width``
+    rows of ``p_pad`` on the described chip, traced anew at every call; the
+    grid's leaves)."""
     from kubetorch_tpu.models import quant
     from kubetorch_tpu.models.configs import LlamaConfig
     from kubetorch_tpu.models.rolling import RollingGenerator
-    from kubetorch_tpu.ops import flash_attention
     from kubetorch_tpu.parallel.sharding import ShardingRules
 
     layers, b, m, vocab = 32, 32, 2048, 32768
@@ -367,24 +360,44 @@ def test_admission_compiles_for_v5e_without_the_scores(p_pad, v5e_chip,
              "vs": spec((layers, b, m, 8), jnp.float32)}
     args = (params, cache, spec((b, vocab), jnp.float32),
             spec((b,), jnp.int32), spec((b,), jnp.bool_),
-            spec((1, p_pad), jnp.int32), spec((1,), jnp.int32),
-            spec((1,), jnp.int32))
+            spec((width, p_pad), jnp.int32), spec((width,), jnp.int32),
+            spec((width,), jnp.int32))
     rules = ShardingRules.default()
+
+    def compile():
+        cache_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            return jax.jit(
+                lambda *a: RollingGenerator._prefill_impl(
+                    *a, None, p_pad=p_pad, cfg=cfg, rules=rules),
+                donate_argnums=(1, 2, 3, 4)).lower(*args).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+
+    return compile, cache
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("p_pad", [1024, 2048])
+def test_admission_compiles_for_v5e_without_the_scores(p_pad, v5e_chip,
+                                                       monkeypatch):
+    """Mosaic takes the kernel at the cell's widths inside the whole
+    executable (one custom call in the layer scan), the grid stays aliased in
+    place, and the temporaries fall by most of the float32 scores
+    ``[32 heads, p_pad, p_pad]`` (537 MB at 2048; read here, PR 30: 0.831 ->
+    0.376 GB at 2048 and 0.205 -> 0.071 GB at 1024; what is left is the
+    private cache and the MLP's activations)."""
+    from kubetorch_tpu.ops import flash_attention
+
+    compile, cache = _chat_admission(v5e_chip, p_pad)
 
     def compiled(on_tpu: bool):
         monkeypatch.setattr(flash_attention, "_one_tpu_device",
                             lambda: on_tpu)
-        return jax.jit(
-            lambda *a: RollingGenerator._prefill_impl(
-                *a, None, p_pad=p_pad, cfg=cfg, rules=rules),
-            donate_argnums=(1, 2, 3, 4)).lower(*args).compile()
+        return compile()
 
-    cache_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        einsum, flash = compiled(False), compiled(True)
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_on)
+    einsum, flash = compiled(False), compiled(True)
     assert einsum.as_text().count("tpu_custom_call") == 0
     assert flash.as_text().count("tpu_custom_call") == 1
     grid_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
@@ -395,6 +408,35 @@ def test_admission_compiles_for_v5e_without_the_scores(p_pad, v5e_chip,
     saved = (einsum.memory_analysis().temp_size_in_bytes
              - flash.memory_analysis().temp_size_in_bytes)
     assert saved >= 0.8 * scores, (saved, scores)
+
+
+@pytest.mark.level("unit")
+def test_admission_of_two_rows_compiles_for_v5e_landing_in_place(
+        v5e_chip, monkeypatch):
+    """A width-2 admission at the chat cell's largest bucket (PR 32): the
+    rows land by slice update on the aliased grid, so the executable holds
+    no select, gather, scatter or copy the size of a grid leaf, and its
+    temporaries are two rows' private caches and activations (read here,
+    PR 32: 0.749 GB, twice the 0.379 of width 1). Under the gather + select
+    it replaced the chip's compiler refused this executable outright
+    (``RESOURCE_EXHAUSTED``: 17.14 GB of 15.75; on the chip, PR 23, the
+    splice wanted 6 GB), which is what held ``admit_rows`` at 1."""
+    from kubetorch_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_one_tpu_device", lambda: True)
+    compile, cache = _chat_admission(v5e_chip, 2048, width=2)
+    exe = compile()
+    memory = exe.memory_analysis()
+    grid_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                     for x in cache.values())
+    assert memory.alias_size_in_bytes >= grid_bytes
+    assert memory.temp_size_in_bytes < 1.0e9, memory.temp_size_in_bytes
+    plane = re.compile(r"\[(32,)?32,2048[,\]]")
+    made = [line.strip()[:160] for line in exe.as_text().splitlines()
+            if plane.search(line.split("=", 1)[-1].split("(", 1)[0])
+            and re.search(r"\b(copy|transpose|gather|scatter|select|"
+                          r"convolution|dot)\(", line)]
+    assert not made, made
 
 
 # The hybrid linear-attention decoder (models/hybrid_linear.py) at the

@@ -1,15 +1,20 @@
-"""The once-a-chunk cache write (``ops/grid_write.py``, through both
-decoders' ``merge_chunk_into_grid``) against the one-hot select it replaced
-in PR 28, which lives on here as the oracle: bit-equal on every leaf, and
-built from slice updates only — nothing the size of a layer's ``[B, M]``
-plane is computed."""
+"""The two cache writes of ``ops/grid_write.py`` against the selects over
+whole planes they replaced, which live on here as the oracles: the
+once-a-chunk merge (through the decoders' ``merge_chunk_into_grid``) against
+PR 28's one-hot select, an admission's landing (through
+``RollingGenerator._finish_admit``) against PR 32's gather + masked select.
+Bit-equal on every leaf, and built from slice updates only — nothing the
+size of a layer's ``[B, M]`` plane is computed."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubetorch_tpu.models import LlamaConfig, latent_moe, llama
+from kubetorch_tpu.models import (HybridLinearConfig, LatentMoEConfig,
+                                  LlamaConfig, hybrid_linear, latent_moe,
+                                  llama)
+from kubetorch_tpu.models.decoder import decoder_for, grid_dims, row_leaves
 from kubetorch_tpu.models.rolling import RollingGenerator
 from kubetorch_tpu.ops import grid_write
 
@@ -281,3 +286,234 @@ def test_counters_count_nothing_for_rows_that_land_nothing(model):
     s = chunked.stats()
     assert s["merge_positions_new"] >= 40
     assert s["merge_positions_written"] >= 3 * 16
+
+
+# =========================================================================
+# An admission's landing: ``write_rows`` against the gather + masked select
+# ``_finish_admit`` held until PR 32.
+def _select_rows(cache, own, slots, rows=frozenset()):
+    """The splice as it stood until PR 32: every grid row gathers the
+    own-cache row that names it (``plane_o[:, sel]``, ``[L, B, M_own, ...]``)
+    and a ``where`` over all ``B`` rows keeps the others; ``rows`` names the
+    row-state leaves."""
+    B = grid_dims(cache, rows)[0]
+    M_own = grid_dims(own, rows)[1]
+    onehot = slots[None, :] == jnp.arange(B)[:, None]       # [B, N]
+    sel = jnp.argmax(onehot, axis=1)                        # [B]
+    any_valid = onehot.any(axis=1)
+
+    def splice(kk):
+        plane_c, plane_o = cache[kk], own[kk]
+        v = any_valid.reshape((1, B) + (1,) * (plane_c.ndim - 2))
+        if kk in rows:
+            return jnp.where(v, plane_o[:, sel], plane_c)
+        return jax.lax.dynamic_update_slice_in_dim(
+            plane_c, jnp.where(v, plane_o[:, sel], plane_c[:, :, :M_own]),
+            0, axis=2)
+
+    return {kk: splice(kk) for kk in cache}
+
+
+ROW_KINDS = ("bf16", "int8", "latent", "hybrid")
+
+
+def _fill(tree, seed):
+    """The same leaves, every element drawn (int8 over its whole range)."""
+    rng = np.random.default_rng(seed)
+    return {n: jnp.asarray(
+        rng.integers(-127, 128, x.shape) if x.dtype == jnp.int8
+        else rng.standard_normal(x.shape), x.dtype) for n, x in tree.items()}
+
+
+def _admission(kind, n, span, seed=0):
+    """(grid, own cache of ``n`` rows x ``span`` positions, row-state leaf
+    names) of one decoder's leaf kinds at toy widths."""
+    if kind == "hybrid":        # K/V of 1 layer beside row state of 5
+        cfg = HybridLinearConfig.tiny()
+        rows = row_leaves(decoder_for(cfg), cfg)
+        return (_fill(hybrid_linear.init_cache(cfg, B, M), seed),
+                _fill(hybrid_linear.init_cache(cfg, n, span), seed + 1), rows)
+    if kind == "latent":
+        cfg = LatentMoEConfig.tiny()
+        grid, own = (latent_moe.init_cache(cfg, b, m, dtype=jnp.bfloat16)
+                     for b, m in ((B, M), (n, span)))
+    else:
+        cfg = _cfg()
+        grid, own = (llama.init_cache(
+            cfg, b, m, dtype=jnp.bfloat16, quantized=kind == "int8")
+            for b, m in ((B, M), (n, span)))
+    return _fill(grid, seed), _fill(own, seed + 1), frozenset()
+
+
+# name -> the slots of an admission's (padded) width; ``B`` is a dummy row
+SLOTS = {
+    "one_first": [0], "one_last": [B - 1], "one_dummy": [B],
+    "three": [B - 1, 0, 3], "three_one_dummy": [2, B, B - 1],
+    "three_dummy_first": [B, 5, B], "three_all_dummy": [B, B, B],
+}
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("span", [64, M], ids=["part", "whole"])
+@pytest.mark.parametrize("case", list(SLOTS))
+@pytest.mark.parametrize("kind", ROW_KINDS)
+def test_row_write_is_the_select_bit_for_bit(kind, case, span):
+    slots = jnp.asarray(SLOTS[case], jnp.int32)
+    grid, own, rows = _admission(kind, len(SLOTS[case]), span)
+    want = jax.jit(lambda *a: _select_rows(*a, rows))(grid, own, slots)
+    got = jax.jit(grid_write.write_rows)(grid, own, slots)
+    assert set(got) == set(grid)
+    for name in grid:
+        assert got[name].dtype == grid[name].dtype
+        np.testing.assert_array_equal(
+            np.asarray(got[name].astype(jnp.float32)),
+            np.asarray(want[name].astype(jnp.float32)), err_msg=name)
+    # and, without the oracle: an admitted row holds its own-cache row from
+    # the front, a dummy lands nowhere, everything else is what it was
+    for name in grid:
+        old = np.array(grid[name].astype(jnp.float32))
+        new = np.asarray(got[name].astype(jnp.float32))
+        src = np.asarray(own[name].astype(jnp.float32))
+        for n, b in enumerate(SLOTS[case]):
+            if b == B:
+                continue
+            if name in rows:
+                old[:, b] = src[:, n]
+            else:
+                old[:, b, :span] = src[:, n]
+        np.testing.assert_array_equal(new, old, err_msg=name)
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("n", [1, 3])
+@pytest.mark.parametrize("kind", ROW_KINDS)
+def test_an_admission_computes_nothing_the_size_of_a_plane(kind, n):
+    """The traced ``_finish_admit`` holds no operation whose result is a
+    whole grid leaf (or a layer of one) except the in-place slice updates
+    and the loop that carries the leaves, and no gather, scatter or select
+    that reads or makes a leaf of the grid or of the own cache: the scatters
+    left are the ``[B, V]`` and ``[B]`` per-slot sets."""
+    grid, own, _ = _admission(kind, n, 64)
+    vocab = 32
+    jaxpr = jax.make_jaxpr(RollingGenerator._finish_admit)(
+        grid, own, jnp.zeros((n, vocab)), jnp.zeros((B, vocab)),
+        jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
+        jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32))
+    planes = {v.shape for v in grid.values()} | {
+        v.shape[1:] for v in grid.values()}
+    eqns = list(_eqns(jaxpr.jaxpr))
+    grid_sized = [e.primitive.name for e in eqns
+                  if any(getattr(v.aval, "shape", None) in planes
+                         for v in e.outvars)]
+    assert set(grid_sized) == {"dynamic_update_slice", "while"}, grid_sized
+    assert grid_sized.count("dynamic_update_slice") == len(grid)
+    leaves = planes | {v.shape for v in own.values()}
+    over_a_leaf = [e.primitive.name for e in eqns
+                   if e.primitive.name.split("-")[0] in (
+                       "gather", "scatter", "select_n", "dot_general")
+                   and any(getattr(v.aval, "shape", None) in leaves
+                           for v in (*e.invars, *e.outvars))]
+    assert not over_a_leaf, over_a_leaf
+    # (what the v5e's compiler makes of it inside the cells' whole admission
+    # executables is held by tests/test_decode_attention.py)
+
+
+def _toy(decoder):
+    """(params, cfg, row-state leaf names) of a decoder at toy widths."""
+    if decoder == "dense":
+        cfg = _cfg()
+        return llama.init(jax.random.key(0), cfg), cfg, frozenset()
+    if decoder == "latent":
+        cfg = LatentMoEConfig.tiny()
+        return latent_moe.init(jax.random.key(1), cfg), cfg, frozenset()
+    cfg = HybridLinearConfig.tiny()
+    return (hybrid_linear.init(jax.random.key(2), cfg), cfg,
+            row_leaves(decoder_for(cfg), cfg))
+
+
+def _admit_run(params, cfg, prefix=0, **engine):
+    """Tokens by request, the final grid and the counters of one toy run
+    whose admissions are bucketed: two prompts of one bucket go in one call
+    of the full width (two dummy rows beside them), the third alone; with
+    ``prefix``, behind a shared prefix of that many tokens."""
+    eng = RollingGenerator(params, cfg, max_slots=4, max_len=128,
+                           steps_per_call=4, **engine)
+    pid = (eng.register_prefix([(7 * i) % 190 + 2 for i in range(prefix)])
+           if prefix else None)
+    prompts = [[1, 2, 3, 4, 5], [(5 * i) % 200 + 3 for i in range(40)],
+               [9, 8, 7]]
+    rids = [eng.submit(p, max_new_tokens=n, prefix_id=pid)
+            for p, n in zip(prompts, (12, 9, 5))]
+    out = eng.run()
+    grid = {n: np.asarray(v.astype(jnp.float32))
+            for n, v in eng.cache.items()}
+    return [out[r] for r in rids], grid, eng.stats()
+
+
+# (decoder, shared prefix's tokens, engine): a prefix of 20 makes an own
+# cache of 32 + the bucket, one of 70 fills its 128 bucket, so the own cache
+# is cut at the grid's end (M_own == M)
+ADMISSIONS = {
+    "bucketed": ("dense", 0, {}),
+    "bucketed_int8": ("dense", 0, dict(kv_dtype="int8")),
+    "prefixed": ("dense", 20, {}),
+    "prefixed_int8": ("dense", 20, dict(kv_dtype="int8")),
+    "prefixed_to_the_end": ("dense", 70, {}),
+    "bucketed_latent": ("latent", 0, {}),
+    "bucketed_hybrid": ("hybrid", 0, {}),
+}
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("path", list(ADMISSIONS))
+def test_engine_tokens_and_grid_equal_the_row_selects(path, monkeypatch):
+    """One toy engine run through ``_prefill_impl`` or ``_prefill_px_impl``:
+    the same tokens and the same grid, bit for bit, as with the parent's
+    gather + select in the row writer's place; and the counters say that
+    only the admitted rows' spans were written."""
+    decoder, prefix, engine = ADMISSIONS[path]
+    params, cfg, rows = _toy(decoder)
+    toks, grid, stats = _admit_run(params, cfg, prefix, **engine)
+    monkeypatch.setattr(
+        grid_write, "write_rows",
+        lambda cache, own, slots: _select_rows(cache, own, slots, rows))
+    want_toks, want_grid, _ = _admit_run(params, cfg, prefix, **engine)
+    assert toks == want_toks
+    for name in grid:
+        np.testing.assert_array_equal(grid[name], want_grid[name],
+                                      err_msg=name)
+    new, written = (stats["admit_positions_new"],
+                    stats["admit_positions_written"])
+    # three rows: two of the 16 bucket together, one of the 64 bucket, each
+    # behind the prefix's bucket and cut at the grid's 128
+    behind = 0 if not prefix else 32 if prefix <= 32 else 128
+    assert new == written == 2 * min(behind + 16, 128) + min(behind + 64, 128)
+
+
+@pytest.mark.level("minimal")
+def test_admit_counters_count_nothing_for_a_dummy_row():
+    """``admit_positions_*`` from the host: an admission of one counts its
+    bucket once, an admission of two padded to the full width counts two
+    (the dummy rows land nothing and count in neither)."""
+    assert grid_write.row_positions_written([3], 4, 64) == 64
+    assert grid_write.row_positions_written([1, 4, 0, 4], 4, 16) == 32
+    assert grid_write.row_positions_written([4, 4], 4, 16) == 0
+    params, cfg, _ = _toy("dense")
+    eng = RollingGenerator(params, cfg, max_slots=4, max_len=128,
+                           steps_per_call=4)
+    assert eng.stats()["admit_positions_new"] == 0
+    eng.submit(list(range(1, 41)), max_new_tokens=4)
+    eng.step()
+    s = eng.stats()
+    assert s["admit_positions_new"] == s["admit_positions_written"] == 64
+    eng.submit([1, 2, 3], max_new_tokens=4)
+    eng.submit([4, 5, 6, 7], max_new_tokens=4)
+    eng.step()                  # one call of width 4: two rows, two dummies
+    s = eng.stats()
+    assert s["admit_positions_new"] == s["admit_positions_written"] == 96
+    # a chunked prefill lands through the merge, not through an admission
+    chunked = RollingGenerator(params, cfg, max_slots=4, max_len=128,
+                               steps_per_call=4, prefill_chunk=16)
+    chunked.submit(list(range(1, 41)), max_new_tokens=1)
+    chunked.run()
+    assert chunked.stats()["admit_positions_new"] == 0
