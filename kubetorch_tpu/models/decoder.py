@@ -61,12 +61,12 @@ object it was built with (``decoder_for(cfg)``):
   the chunk's tokens; ``prefill_counters(cfg, prompt_tokens)`` what a
   prefill of that many prompt tokens adds to them, counted on the host.
 - ``state_rows_touched(cfg, rows, live)``: rows whose row-state leaves one
-  decode step over a grid of ``rows`` rows, ``live`` of them decoding, reads
-  and writes (0 for a decoder that keeps none): what
-  ``decode_state_rows_touched`` counts. ``scan_positions(cfg, rows,
-  length)``: positions a recurrent layer's scan walks for a prefill of
-  ``rows`` rows padded to ``length`` (0 where no layer scans): what
-  ``linear_scan_positions`` counts.
+  decode step over ``rows`` rows, ``live`` decoding, reads and writes (0: no
+  such leaf; ``live`` where the step skips an idle row, ``rows`` where it
+  holds it in place): what ``decode_state_rows_touched`` counts.
+  ``scan_positions(cfg, rows, length)``: positions a recurrent layer's scan
+  walks for a prefill of ``rows`` rows padded to ``length`` (0 where no
+  layer scans): what ``linear_scan_positions`` counts.
 - ``check_serving(cfg, **features)``: raises for a serving feature the
   decoder does not carry, naming the feature.
 

@@ -32,8 +32,10 @@ with each row's count of REAL tokens: a bucketed prefill (state and tail
 zero, counts the prompt lengths: padding up to the bucket leaves the state
 as the last real token left it), a decode step (``T = 1``, count 1 for a
 row that decodes and 0 for one that does not: its state is held) and a
-prefill chunk are that one function. ``T = 1`` takes ``gated_delta.step``,
-anything longer the chunked scan.
+prefill chunk are that one function. ``T = 1`` takes ``gated_delta.step``
+(on one TPU device its kernel, ``gated_delta.step_rows``, which works on the
+stacked state leaf in place and fetches only the rows that decode), anything
+longer the chunked scan.
 
 Layers of one kind are stacked (``params["linear"]``, ``params["full"]``)
 and scanned by index; cache leaves are stacked over the layers of the kind
@@ -170,10 +172,27 @@ def _unit(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _linear_mixer(x, layer, state, tail, counts, cfg: HybridLinearConfig):
-    """The gated-delta mixer over ``T`` tokens. ``x`` [B,T,E] in the compute
-    dtype, ``state`` [B,H,dk,dv] float32, ``tail`` [B,K-1,C], ``counts``
-    [B] -> (out [B,T,E], new state, new tail)."""
+def _step_kernel_engages(cfg: HybridLinearConfig) -> bool:
+    return gated_delta.step_engages(
+        cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim)
+
+
+def _step_plan(T: int, counts, cfg: HybridLinearConfig):
+    """The decoding rows of a one-token call as the step kernel's work list
+    (``gated_delta.step_plan``), or None where the XLA step or the scan
+    runs: more than one token, the CPU, a mesh."""
+    if T == 1 and _step_kernel_engages(cfg):
+        return gated_delta.step_plan(counts > 0)
+    return None
+
+
+def _linear_mixer(x, layer, states, i, tail, counts, plan,
+                  cfg: HybridLinearConfig):
+    """The gated-delta mixer over ``T`` tokens on layer ``i`` of the stacked
+    state leaf. ``x`` [B,T,E] in the compute dtype, ``states``
+    [L,B,H,dk,dv] float32, ``tail`` [B,K-1,C], ``counts`` [B], ``plan``
+    from ``_step_plan`` -> (out [B,T,E], the leaf with layer ``i``
+    advanced, new tail)."""
     B, T, _ = x.shape
     H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
     dt = cfg.compute_dtype
@@ -193,22 +212,32 @@ def _linear_mixer(x, layer, state, tail, counts, cfg: HybridLinearConfig):
     log_alpha = jnp.where(valid[..., None], log_alpha, 0.0)
     beta = jnp.where(valid[..., None], beta, 0.0)
     q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
-    if T == 1:
-        with jax.named_scope("linear_attention_decode"):
-            o, state = gated_delta.step(q[:, 0], k[:, 0], v[:, 0],
-                                        log_alpha[:, 0], beta[:, 0], state)
+    with jax.named_scope("linear_attention_decode" if T == 1
+                         else "linear_attention_prefill"):
+        if plan is not None:
+            # the kernel works on the leaf in place: no slice of it is made
+            o, states = gated_delta.step_rows(
+                q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0],
+                states, i, plan)
             o = o[:, None]
-    else:
-        with jax.named_scope("linear_attention_prefill"):
-            o, state = gated_delta.prefill_scan(q, k, v, log_alpha, beta,
-                                                state)
+        else:
+            state = _at(states, i)
+            if T == 1:
+                o, state = gated_delta.step(q[:, 0], k[:, 0], v[:, 0],
+                                            log_alpha[:, 0], beta[:, 0],
+                                            state)
+                o = o[:, None]
+            else:
+                o, state = gated_delta.prefill_scan(q, k, v, log_alpha,
+                                                    beta, state)
+            states = _put(states, state, i)
     # gate and output stay flat ``[.., H * dv]``: a head axis of 192 beside
     # the weights would have XLA re-lay the weight stacks out, a copy a call
     gate = jnp.einsum("bte,en->btn", x, layer["wg"].astype(dt))
     o = rms_norm(o, layer["o_norm"], cfg.rms_eps).reshape(B, T, H * dv)
     o = (o * jax.nn.silu(gate.astype(f32))).astype(dt)
     out = jnp.einsum("btn,ne->bte", o, layer["wo"].astype(dt))
-    return out, state, tail
+    return out, states, tail
 
 
 # ------------------------------------------------------------ full layer
@@ -312,13 +341,13 @@ def _put(stack, row, i):
         stack, row.astype(stack.dtype), i, 0)
 
 
-def _linear_block(x, rows, layer, i, counts, cfg: HybridLinearConfig):
+def _linear_block(x, rows, layer, i, counts, plan, cfg: HybridLinearConfig):
     """A linear layer on the stream; ``rows`` = (state, conv) stacks."""
     state, conv = rows
-    out, s, t = _linear_mixer(x.astype(cfg.compute_dtype), layer,
-                              _at(state, i), _at(conv, i), counts, cfg)
+    out, state, t = _linear_mixer(x.astype(cfg.compute_dtype), layer, state,
+                                  i, _at(conv, i), counts, plan, cfg)
     x = _residual(x, out, layer["attn_norm"], cfg)
-    return x, (_put(state, s, i), _put(conv, t, i))
+    return x, (state, _put(conv, t, i))
 
 
 def _mlp_block(x, layer, cfg: HybridLinearConfig):
@@ -444,13 +473,14 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
             raise _refuse("prefix")
         real = jnp.diagonal(mask, axis1=1, axis2=2)                 # [B,T]
         counts = jnp.sum(real, axis=1, dtype=jnp.int32)
+        plan = _step_plan(T, counts, cfg)
         flash = causal_lens is not None and flash_attention.prefill_engages(
             T, M, write_at, H, cfg.n_kv_heads, D)
 
         def body(carry, layer, i, kind):
             x, kv, rows = carry
             if kind == LINEAR:
-                x, rows = _linear_block(x, rows, layer, i, counts, cfg)
+                x, rows = _linear_block(x, rows, layer, i, counts, plan, cfg)
             else:
                 q, k, v = _full_qkv(x.astype(dt), layer, cfg)
                 kv = tuple(jax.lax.dynamic_update_slice(
@@ -480,11 +510,12 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     own = jax.lax.dynamic_slice_in_dim(chunk_mask, chunk_col, T, axis=2)
     counts = jnp.sum(jnp.diagonal(own, axis1=1, axis2=2), axis=1,
                      dtype=jnp.int32)
+    plan = _step_plan(T, counts, cfg)
 
     def body(carry, layer, i, kind):
         x, cols, rows = carry
         if kind == LINEAR:
-            x, rows = _linear_block(x, rows, layer, i, counts, cfg)
+            x, rows = _linear_block(x, rows, layer, i, counts, plan, cfg)
         else:
             q, k, v = _full_qkv(x.astype(dt), layer, cfg)
             cols = tuple(jax.lax.dynamic_update_slice(
@@ -574,10 +605,12 @@ class HybridLinearDecoder:
 
     @staticmethod
     def state_rows_touched(cfg, rows: int, live: int) -> int:
-        """A decode step reads and writes the state of every row of the
-        grid: a row that does not decode is held by ``alpha = 1, beta =
-        0``, not skipped."""
-        return rows
+        """Where the step kernel engages (``gated_delta.step_engages``:
+        one TPU device) a decode step reads and writes the state of the
+        rows that decode, once; where the XLA step runs, of every row of
+        the grid: a row that does not decode is held by ``alpha = 1, beta
+        = 0``, not skipped."""
+        return live if _step_kernel_engages(cfg) else rows
 
     @staticmethod
     def scan_positions(cfg, rows: int, length: int) -> int:

@@ -504,8 +504,8 @@ class RollingGenerator:
     def _count_state_rows(self, steps: int) -> None:
         """Account one decode chunk of ``steps`` steps: the rows that
         decode, and the rows whose row-state leaves each step reads and
-        writes (the decoder says: all of the grid's where an idle row is
-        held and not skipped)."""
+        writes (the decoder says: the decoding rows where its step skips
+        an idle row, all of the grid's where it holds one in place)."""
         if not self._row_leaves:
             return
         live = len(self._slots)

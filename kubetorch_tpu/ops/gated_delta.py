@@ -10,10 +10,20 @@ in [0, 2):
 ``alpha = 1, beta = 0`` leaves ``S`` as it was, bit for bit: that is how a
 padded position of a bucket and a row that is not decoding pass through.
 
-Three forms of the one rule:
+Four forms of the one rule:
 
-- ``step``: one token a row (a decode step). Two passes over the state:
-  ``S^T [k, q]`` read together, then the update.
+- ``step``: one token a row (a decode step), in XLA: two passes over the
+  state of EVERY row (``S^T [k, q]`` read together, then the update), a row
+  that does not decode held by ``alpha = 1, beta = 0``. The oracle of
+  ``step_rows``, and the decode step wherever that does not engage.
+- ``step_rows``: the same step as the Pallas kernel ``gated_delta_step`` on
+  one TPU device, over the stacked state leaf ``[L, B, H, dk, dv]`` in place
+  (``input_output_aliases``: no slice of the leaf is made). Its grid is the
+  rows that decode (``step_plan``, scalar-prefetched beside the layer
+  index): a row's ``H x [dk, dv]`` block is fetched once, ``S^T k``, ``S^T
+  q``, the update and the output are made from the copy in VMEM in float32
+  on the vector unit, and the block is written back once. A row that does
+  not decode is neither fetched nor written.
 - ``recurrence``: ``step`` over the tokens of a sequence, in order: the
   oracle of the tests.
 - ``prefill_scan``: the chunked form of an admission. Inside a chunk of ``C``
@@ -47,8 +57,10 @@ _SOLVE_BLOCK = 16    # rows solved by substitution before blocks are merged
 _HEAD_BLOCK = 6      # most heads a grid step of the kernel takes
 _HIGHEST = jax.lax.Precision.HIGHEST
 
-# Test hook, as ``decode_attention._FORCE_INTERPRET``: take the kernel (in
-# interpret mode) wherever ``prefill_engages`` is asked.
+_STEP_VMEM = 100 << 20   # most a decode step's kernel may ask of VMEM
+
+# Test hook, as ``decode_attention._FORCE_INTERPRET``: take the kernels (in
+# interpret mode) wherever ``prefill_engages`` / ``step_engages`` are asked.
 _FORCE_INTERPRET = False
 
 
@@ -61,6 +73,21 @@ def prefill_engages(t: int) -> bool:
     """The kernel takes a scan whose length its chunk divides, on one TPU
     device; the ``lax.scan`` over chunks takes the rest."""
     return t % CHUNK == 0 and (_FORCE_INTERPRET or _one_tpu_device())
+
+
+def _step_vmem_bytes(heads: int, dk: int, dv: int) -> int:
+    """What ``gated_delta_step`` holds in VMEM: a row's state block as the
+    chip tiles it (8 x 128 float32), in and out, each double-buffered, and
+    room for the small operands and the compiler's own temporaries."""
+    block = heads * -(-dk // 8) * 8 * -(-dv // _LANES) * _LANES * 4
+    return 4 * block + (8 << 20)
+
+
+def step_engages(heads: int, dk: int, dv: int) -> bool:
+    """The kernel takes a decode step on one TPU device where a row's state
+    block fits its VMEM; the XLA ``step`` takes the rest."""
+    return (_step_vmem_bytes(heads, dk, dv) <= _STEP_VMEM
+            and (_FORCE_INTERPRET or _one_tpu_device()))
 
 
 def scan_positions(t: int) -> int:
@@ -85,6 +112,96 @@ def step(q, k, v, log_alpha, beta, state):
     new = alpha[..., None] * state + k[..., :, None] * u[..., None, :]
     o = alpha * r[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
     return o, new
+
+
+def step_plan(live):
+    """The kernel's work list from the rows that decode (``live`` [B] bool),
+    made once a decode step (it is the same for every layer): ``(rows [B],
+    n [1])`` int32, the decoding rows first, in row order, and their number.
+    An entry at or past ``n`` repeats the last decoding row (row 0 where
+    none decodes), so the block index of a grid step with no work does not
+    move and nothing is fetched or written for it."""
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    n = jnp.sum(live, dtype=jnp.int32)
+    at = jnp.minimum(jnp.arange(live.shape[0]), jnp.maximum(n - 1, 0))
+    return order[at], n[None]
+
+
+def _step_kernel(li_ref, rows_ref, n_ref, alpha_ref, beta_ref, kq_ref,
+                 kqt_ref, v_ref, s0_ref, o0_ref, o_ref, s_ref):
+    """Grid step ``j``: the ``j``-th decoding row's state block of layer
+    ``li``, every head of it. ``alpha_ref``, ``beta_ref``, ``kq_ref``
+    (SMEM, [B * H]) hold the decay, the step and ``k.q`` of every (row,
+    head); ``kqt_ref`` [dk, 2H] the row's keys and queries as columns;
+    ``v_ref`` [H, dv]. ``o0_ref`` is the zeros the output aliases (never
+    read: a row that does not decode keeps them)."""
+    del li_ref, o0_ref
+    heads = v_ref.shape[0]
+    j = pl.program_id(0)
+    n = n_ref[0]
+
+    @pl.when(j < n)
+    def _decodes():
+        base = rows_ref[j] * heads
+        for h in range(heads):
+            alpha, beta = alpha_ref[base + h], beta_ref[base + h]
+            s = s0_ref[h]                                       # [dk, dv]
+            k = kqt_ref[:, h:h + 1]                             # [dk, 1]
+            q = kqt_ref[:, heads + h:heads + h + 1]
+            r_k = jnp.sum(s * k, axis=0, keepdims=True)         # [1, dv]
+            r_q = jnp.sum(s * q, axis=0, keepdims=True)
+            u = beta * (v_ref[h:h + 1, :] - alpha * r_k)
+            s_ref[h] = alpha * s + k * u
+            o_ref[h:h + 1, :] = alpha * r_q + kq_ref[base + h] * u
+
+    # no row decodes: the one block the grid holds goes back as it came
+    @pl.when(jnp.logical_and(n == 0, j == 0))
+    def _held():
+        s_ref[...] = s0_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def step_rows(q, k, v, log_alpha, beta, states, layer, plan):
+    """``step`` for the rows of ``plan`` (``step_plan``) on layer ``layer``
+    of the stacked leaf ``states`` [L,B,H,dk,dv] float32, in place. ``q``,
+    ``k`` [B,H,dk], ``v`` [B,H,dv], ``log_alpha``, ``beta`` [B,H] -> (o
+    [B,H,dv] float32, the leaf). A row outside the plan keeps its state bit
+    for bit, in every layer, and its ``o`` is zeros. Off the TPU the kernel
+    is interpreted."""
+    _, B, H, dk, dv = states.shape
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    rows, n = plan
+    kqt = jnp.concatenate([k, q], axis=1).swapaxes(1, 2)        # [B,dk,2H]
+
+    def row(*tail):                 # a [B, *tail] operand, a row a step
+        return pl.BlockSpec(
+            (None,) + tail,
+            lambda j, li, rows, *_: (rows[j],) + (0,) * len(tail))
+
+    block = pl.BlockSpec((None, None, H, dk, dv),
+                         lambda j, li, rows, *_: (li[0], rows[j], 0, 0, 0))
+    o, states = pl.pallas_call(
+        _step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(B,),
+            in_specs=[row(dk, 2 * H), row(H, dv), block,
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[row(H, dv), block]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv), f32),
+                   jax.ShapeDtypeStruct(states.shape, f32)],
+        # operands count from the scalar-prefetched ones
+        input_output_aliases={8: 1, 9: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_step_vmem_bytes(H, dk, dv)),
+        name="gated_delta_step",
+        interpret=jax.default_backend() != "tpu",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows, n,
+      jnp.exp(log_alpha.astype(f32)).ravel(), beta.astype(f32).ravel(),
+      jnp.sum(k * q, axis=-1).ravel(), kqt, v, states,
+      jnp.zeros((B, H, dv), f32))
+    return o, states
 
 
 def recurrence(q, k, v, log_alpha, beta, state):

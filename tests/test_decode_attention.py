@@ -472,6 +472,43 @@ def test_gated_delta_kernel_compiles_for_v5e_at_the_published_widths(
 
 
 @pytest.mark.level("unit")
+def test_gated_delta_step_kernel_compiles_for_v5e_on_the_leaf_in_place(
+        v5e_chip, monkeypatch):
+    """Mosaic takes a row's ``(30, 96, 192)`` float32 block of the stacked
+    state leaf as it is (a lane dim of one and a half tiles: the leaf is
+    stored at 256 lanes, 566 MB for the 425 it holds), the leaf is the
+    call's input and output in place, and nothing the size of the leaf or
+    of a layer of it is made beside it (PR 36)."""
+    from kubetorch_tpu.ops import gated_delta
+
+    layers, b, h, dk, dv = 12, 16, 30, 96, 192
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def step(q, k, v, log_alpha, beta, states, layer, live):
+        return gated_delta.step_rows(q, k, v, log_alpha, beta, states, layer,
+                                     gated_delta.step_plan(live))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        exe = jax.jit(step, donate_argnums=(5,)).lower(
+            spec((b, h, dk), jnp.bfloat16), spec((b, h, dk), jnp.bfloat16),
+            spec((b, h, dv), jnp.bfloat16), spec((b, h)), spec((b, h)),
+            spec((layers, b, h, dk, dv)), spec((), jnp.int32),
+            spec((b,), jnp.bool_)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert text.count("tpu_custom_call") == 1 and "gated_delta_step" in text
+    stored = layers * b * h * dk * 256 * 4
+    assert mem.alias_size_in_bytes >= stored
+    assert mem.temp_size_in_bytes < 1 << 20, mem.temp_size_in_bytes
+
+
+@pytest.mark.level("unit")
 @pytest.mark.parametrize("which", ["decode", "admit_1024"])
 def test_hybrid_executables_compile_for_v5e_with_state_beside_kv(
         which, v5e_chip, monkeypatch):
@@ -481,7 +518,10 @@ def test_hybrid_executables_compile_for_v5e_with_state_beside_kv(
     the row-state leaves stay aliased in place; the decode chunk's
     temporaries hold no copy of a weight stack or of the state (read here,
     PR 31: 0.012 GB; 0.57 GB while the output gate kept a head axis of
-    192 beside its weights)."""
+    192 beside its weights); since PR 36 the linear layers' step is the
+    kernel ``gated_delta_step`` on the state leaf in place, the leaf riding
+    the layer loops as a carry: no operation but the kernel makes an array
+    of the leaf's shape, and the temporaries are as they were."""
     from kubetorch_tpu.models import HybridLinearConfig, hybrid_linear
     from kubetorch_tpu.models.rolling import RollingGenerator
     from kubetorch_tpu.parallel.sharding import ShardingRules
@@ -532,7 +572,13 @@ def test_hybrid_executables_compile_for_v5e_with_state_beside_kv(
     if which == "decode":
         assert "ragged_decode_attention" in text
         assert "gated_delta_prefill" not in text
-        assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+        assert "gated_delta_step" in text
+        made = [line for line in text.splitlines()
+                if " = f32[12,16,30,96,192]" in line and not any(
+                    op in line for op in ("parameter(", "get-tuple-element(",
+                                          "bitcast("))]
+        assert made == [], made
+        assert mem.temp_size_in_bytes < 0.05e9, mem.temp_size_in_bytes
     else:
         assert "gated_delta_prefill" in text
         assert "admit_flash_attention" in text

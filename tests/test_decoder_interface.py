@@ -53,6 +53,20 @@ def test_old_decoders_declare_what_they_declared(name):
     assert model.scan_positions(cfg, 1, 4096) == 0
 
 
+@pytest.mark.parametrize("kernel", [False, True])
+def test_the_hybrid_counts_the_state_rows_its_step_touches(kernel,
+                                                           monkeypatch):
+    """Every row of the grid where the XLA step holds an idle row by
+    ``alpha = 1, beta = 0`` (the CPU); the rows that decode where the step
+    kernel engages (one TPU device; forced here)."""
+    from kubetorch_tpu.ops import gated_delta
+
+    monkeypatch.setattr(gated_delta, "_FORCE_INTERPRET", kernel)
+    cfg = HybridLinearConfig.tiny()
+    assert decoder_for(cfg).state_rows_touched(cfg, 8, 3) == (
+        3 if kernel else 8)
+
+
 def test_the_int8_grid_keeps_its_four_positional_leaves():
     cfg = LlamaConfig.tiny()
     leaves = LlamaDecoder.cache_leaves(cfg, quantized=True)["dense"]

@@ -1,6 +1,7 @@
 """The gated delta rule (``ops/gated_delta.py``): its chunked form, as the
 ``lax.scan`` over chunks and as the Pallas kernel in interpret mode, against
-the rule itself one token at a time.
+the rule itself one token at a time; and the decode step's kernel over the
+stacked state leaf (``step_rows``, interpret mode) against the XLA ``step``.
 
 Tolerance. All three are float32 sums of the same products in different
 orders; the chunked form also inverts a unit triangular matrix a chunk
@@ -22,20 +23,20 @@ TOL = 2e-5
 B, H, DK, DV = 2, 4, 8, 16
 
 
-def draw(T, seed=0, b=B):
+def draw(T, seed=0, b=B, h=H, dk=DK, dv=DV):
     """Inputs as the layer makes them: unit keys with a common positive
     part (SiLU's), queries of norm dk^-1/2, decays from A ~ U(0, 16), beta
     in (0, 2)."""
     ks = jax.random.split(jax.random.key(seed), 7)
-    q = jax.random.normal(ks[0], (b, T, H, DK))
-    k = jax.nn.silu(jax.random.normal(ks[1], (b, T, H, DK)))
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
+    q = jax.random.normal(ks[0], (b, T, h, dk))
+    k = jax.nn.silu(jax.random.normal(ks[1], (b, T, h, dk)))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (b, T, H, DV))
-    log_alpha = -16 * jax.random.uniform(ks[3], (b, T, H)) * jax.nn.softplus(
-        jax.random.normal(ks[4], (b, T, H)) - 4)
-    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, T, H)))
-    state = jax.random.normal(ks[6], (b, H, DK, DV))
+    v = jax.random.normal(ks[2], (b, T, h, dv))
+    log_alpha = -16 * jax.random.uniform(ks[3], (b, T, h)) * jax.nn.softplus(
+        jax.random.normal(ks[4], (b, T, h)) - 4)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, T, h)))
+    state = jax.random.normal(ks[6], (b, h, dk, dv))
     return q, k, v, log_alpha, beta, state
 
 
@@ -111,3 +112,70 @@ def test_kernel_engages_only_where_its_chunk_divides_the_scan(monkeypatch):
     # what ``linear_scan_positions`` counts: the chunk's rounding
     assert [gd.scan_positions(t) for t in (16, 128, 200, 4096)] == [
         16, 128, 256, 4096]
+
+
+# ---- the decode step's kernel over the stacked leaf (PR 36)
+
+LIVE = {"every": lambda b: np.ones(b, bool),
+        "some": lambda b: np.arange(b) % 3 != 1,
+        "none": lambda b: np.zeros(b, bool)}
+
+
+# the published head shape (96 keys x 192 values: a lane dim of one and a
+# half tiles) and a lane-aligned one
+@pytest.mark.parametrize("dk,dv", [(96, 192), (128, 128)])
+@pytest.mark.parametrize("h", [5, 30])
+@pytest.mark.parametrize("b", [1, 16])
+@pytest.mark.parametrize("live", sorted(LIVE))
+def test_step_kernel_equals_the_step_on_the_rows_that_decode(dk, dv, h, b,
+                                                             live):
+    """Layer by layer of a stacked leaf, the layer index traced: outputs
+    and new state of a decoding row within float32 rounding of ``step``;
+    the state of a row that does not decode bit-identical to its input, in
+    every layer; its output zeros."""
+    layers, seed = 3, dk + h + b
+    q, k, v, log_alpha, beta = (x[:, 0] for x in draw(
+        1, seed, b, h, dk, dv)[:5])
+    states = jax.random.normal(jax.random.key(seed + 1),
+                               (layers, b, h, dk, dv))
+    on = LIVE[live](b)
+    # what the mixer hands over for a row that does not decode
+    log_alpha = jnp.where(on[:, None], log_alpha, 0.0)
+    beta = jnp.where(on[:, None], beta, 0.0)
+    kernel = jax.jit(lambda s, i: gd.step_rows(
+        q, k, v, log_alpha, beta, s, i, gd.step_plan(jnp.asarray(on))))
+    got = states
+    for layer in (2, 0):
+        before = got
+        o, got = kernel(before, jnp.int32(layer))
+        want_o, want_s = gd.step(q, k, v, log_alpha, beta, before[layer])
+        assert o.shape == (b, h, dv) and got.shape == states.shape
+        if on.any():
+            assert close(o[on], want_o[on])
+            assert close(got[layer][on], want_s[on])
+        assert not np.asarray(o)[~on].any()
+        assert np.array_equal(got[layer][~on], before[layer][~on])
+        others = [i for i in range(layers) if i != layer]
+        assert np.array_equal(got[jnp.asarray(others)],
+                              before[jnp.asarray(others)])
+    assert np.array_equal(got[1], states[1])                # never asked
+    if on.any():
+        assert float(jnp.abs(got[0][on] - states[0][on]).max()) > 1e-2
+
+
+def test_step_plan_lists_the_decoding_rows_first_and_then_stands_still():
+    rows, n = gd.step_plan(jnp.asarray([False, True, True, False, True]))
+    assert rows.dtype == n.dtype == jnp.int32
+    assert rows.tolist() == [1, 2, 4, 4, 4] and n.tolist() == [3]
+    rows, n = gd.step_plan(jnp.zeros(4, bool))
+    assert rows.tolist() == [0, 0, 0, 0] and n.tolist() == [0]
+    rows, n = gd.step_plan(jnp.ones(3, bool))
+    assert rows.tolist() == [0, 1, 2] and n.tolist() == [3]
+
+
+def test_step_kernel_engages_by_platform_and_block_size(monkeypatch):
+    assert not gd.step_engages(30, 96, 192)                     # the CPU
+    monkeypatch.setattr(gd, "_FORCE_INTERPRET", True)
+    assert gd.step_engages(30, 96, 192) and gd.step_engages(2, 8, 16)
+    # a row's block, in and out and double-buffered, has to fit VMEM
+    assert not gd.step_engages(64, 256, 512)

@@ -83,6 +83,16 @@ def generator(toy, **kw):
     return RollingGenerator(params, cfg, **kw)
 
 
+@pytest.fixture(params=[False, True], ids=["xla_step", "step_kernel"])
+def step_kernel(request, monkeypatch):
+    """The linear layers' decode step as the CPU takes it (``gated_delta.
+    step`` over every row) and as one TPU device does (the kernel over the
+    stacked leaf, ``gated_delta.step_rows``, interpreted here)."""
+    if request.param:
+        monkeypatch.setattr(gated_delta, "_FORCE_INTERPRET", True)
+    return request.param
+
+
 def slot_of(gen, rid):
     return next(s for s, r in gen._slots.items() if r.rid == rid)
 
@@ -143,7 +153,7 @@ def test_bfloat16_program_stays_near_the_float32_reference(toy):
 
 # ------------------------------------------------------------ (ii)
 def test_prefill_then_decode_through_cache_and_state_equals_the_reference(
-        toy):
+        toy, step_kernel):
     """Through ``RollingGenerator``: a bucketed prefill (the chunked scan
     into a private state, K/V into a private cache, both spliced into the
     grid), then one decode step a call (the one-token rule over the state
@@ -166,7 +176,7 @@ def test_prefill_then_decode_through_cache_and_state_equals_the_reference(
 
 
 def test_ragged_prompts_in_one_bucket_an_idle_row_and_a_mid_chunk_finish(
-        toy):
+        toy, step_kernel):
     """Three prompts of 17, 25 and 31 tokens admitted in ONE padded call
     (bucket 32: the scan must stop each row's state at its own last real
     token), a fourth row never used (held through every chunk), and output
@@ -202,8 +212,14 @@ def test_ragged_prompts_in_one_bucket_an_idle_row_and_a_mid_chunk_finish(
     # the rows that finished were zeroed when they were freed
     assert not np.asarray(gen.cache["state"]).any()
     assert not np.asarray(gen.cache["conv"]).any()
+    # the XLA step carries every row of the grid, the kernel the live ones
     s = gen.stats()
-    assert s["decode_state_rows_touched"] > s["decode_state_rows_live"] > 0
+    if step_kernel:
+        assert (s["decode_state_rows_touched"]
+                == s["decode_state_rows_live"] > 0)
+    else:
+        assert (s["decode_state_rows_touched"]
+                > s["decode_state_rows_live"] > 0)
     assert s["linear_scan_positions"] == 4 * 32
     assert s["linear_scan_prompt_tokens"] == 17 + 25 + 31
 
@@ -224,7 +240,7 @@ def test_chunked_prefill_carries_the_state_from_chunk_to_chunk(toy):
     assert out[0] == [int(t) for t in want[36:45].argmax(-1)]
 
 
-def test_a_freed_row_starts_the_next_sequence_from_zero(toy):
+def test_a_freed_row_starts_the_next_sequence_from_zero(toy, step_kernel):
     """Evict mid-generation, then reuse the row through the CHUNKED path,
     which starts from whatever the row holds."""
     d, _, _ = toy
@@ -243,7 +259,8 @@ def test_a_freed_row_starts_the_next_sequence_from_zero(toy):
 
 
 # ----------------------------------------------------------- (iii)
-def test_export_then_import_then_continue_equals_uninterrupted(toy):
+def test_export_then_import_then_continue_equals_uninterrupted(
+        toy, step_kernel):
     d, _, _ = toy
     prompt = tokens_of(19, seed=9)
     gen = generator(toy, max_slots=2)
