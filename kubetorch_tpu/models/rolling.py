@@ -363,13 +363,18 @@ class RollingGenerator:
                                 "linear_scan_prompt_tokens": 0}
 
         # Device-truth utilization accounting: every jitted dispatch
-        # below routes through this accumulator, which captures each
-        # executable's cost_analysis() once per (kind, static-shape
-        # key) — mixed spec-k widths attribute to the right executable
-        # — and counts per-dispatch FLOPs/HBM bytes for the engine's
-        # MFU/MBU gauges.
+        # below routes through this accumulator (``_dispatch``), which
+        # captures each executable's cost_analysis() once per (kind,
+        # static-shape key) — mixed spec-k widths attribute to the right
+        # executable — and counts per-dispatch FLOPs/HBM bytes for the
+        # engine's MFU/MBU gauges.
         self._devstats = devstats.ExecutableCosts()
         self._devstats_peaks: Any = "unset"
+        # ``dispatched(kind, key)``: called wherever an executable has
+        # been queued. The serving engine installs its tick timer's here
+        # beside ``tick_phase``: the tick is filed by what it held, and
+        # the stretch in which the device had nothing to do ends.
+        self.dispatched = devstats.no_dispatch
         # ``tick_phase(name)`` -> context manager. The serving engine
         # installs its phase timer here so that a decode chunk reports
         # its own halves (``decode_dispatch`` up to the return of the
@@ -422,6 +427,21 @@ class RollingGenerator:
                 donate_argnums=(1, 3, 5, 6, 7))
             self._ctx_admit = jax.jit(_ctx_admit_impl,
                                       donate_argnums=(0, 1))
+
+    def _dispatch(self, kind: str, key: Any, fn, *args, **kwargs):
+        """Run an executable: the ONE place the generator does. Kinds:
+        ``prefill`` / ``prefill_px`` keyed ``(n_pad, p_pad)`` (a bucketed
+        admission, own or prefix-extended), ``prefill_ext`` keyed by the
+        chunk, ``prefix_fill`` by its bucket, ``decode`` by the steps,
+        ``decode_spec`` by ``(width, sampling)``, ``ctx_admit`` by its
+        padded rows, ``adapter_write``. In a profiler trace the call lies
+        in a ``kt.dispatch`` host event with ``kind`` and ``key``, on the
+        clock of the module event it queues; once ``fn`` has returned the
+        engine's hook hears of it."""
+        with jax.profiler.TraceAnnotation("kt.dispatch", kind=kind, key=key):
+            out = self._devstats.call(kind, key, fn, *args, **kwargs)
+        self.dispatched(kind, key)
+        return out
 
     def _check_adapter_id(self, adapter_id: int) -> None:
         if adapter_id >= 0 and self.adapters is None:
@@ -634,7 +654,8 @@ class RollingGenerator:
                 f"engine tree's {sorted(self.adapters)} — stack with "
                 f"the same layer_names")
         with self._mesh_ctx():
-            self.adapters = self._adapter_write(
+            self.adapters = self._dispatch(
+                "adapter_write", None, self._adapter_write,
                 self.adapters, adapter, jnp.int32(slot))
 
     def submit(self, prompt, max_new_tokens: int = 128,
@@ -776,7 +797,7 @@ class RollingGenerator:
         self._count_scan(B, C, int(counts.sum()))
         with self._mesh_ctx():
             (self.cache, self._logits, self._dpos,
-             self._dactive) = self._devstats.call(
+             self._dactive) = self._dispatch(
                 "prefill_ext", C, self._prefill_ext,
                 self.params, self.cache, self._logits, self._dpos,
                 self._dactive, jnp.asarray(feed), jnp.asarray(counts),
@@ -811,7 +832,8 @@ class RollingGenerator:
                 self._spec_state[req.slot] = LookaheadState(
                     self.spec_k, self.spec_cap)
             with self._mesh_ctx():
-                self._ctx, self._dnt_valid = self._ctx_admit(
+                self._ctx, self._dnt_valid = self._dispatch(
+                    "ctx_admit", n_pad, self._ctx_admit,
                     self._ctx, self._dnt_valid, jnp.asarray(rows),
                     jnp.asarray(slots))
         return activated
@@ -882,7 +904,7 @@ class RollingGenerator:
         toks[0, :len(tokens)] = tokens
         idx = np.full(1, adapter_id, np.int32)
         with self._mesh_ctx():
-            planes, logits = self._devstats.call(
+            planes, logits = self._dispatch(
                 "prefix_fill", p_pad, self._prefix_fill,
                 self.params, jnp.asarray(toks),
                 jnp.int32(len(tokens)), self._lora(idx), p_pad=p_pad)
@@ -1302,7 +1324,7 @@ class RollingGenerator:
             if prefix_id is None:
                 self._count_admit(n, slots, p_pad)
                 (self.cache, self._logits, self._dpos,
-                 self._dactive) = self._devstats.call(
+                 self._dactive) = self._dispatch(
                     "prefill", (n_pad, p_pad), self._prefill,
                     self.params, self.cache, self._logits, self._dpos,
                     self._dactive, jnp.asarray(toks), jnp.asarray(lens),
@@ -1316,7 +1338,7 @@ class RollingGenerator:
                     grid_dims(pfx["planes"], self._row_leaves)[1] + p_pad,
                     self.max_len))
                 (self.cache, self._logits, self._dpos,
-                 self._dactive) = self._devstats.call(
+                 self._dactive) = self._dispatch(
                     "prefill_px", (n_pad, p_pad), self._prefill_px,
                     self.params, self.cache, self._logits, self._dpos,
                     self._dactive, pfx["planes"],
@@ -1336,7 +1358,8 @@ class RollingGenerator:
                     rows[i, :len(seq)] = seq
                     self._spec_state[req.slot] = LookaheadState(
                         self.spec_k, self.spec_cap)
-                self._ctx, self._dnt_valid = self._ctx_admit(
+                self._ctx, self._dnt_valid = self._dispatch(
+                    "ctx_admit", n_pad, self._ctx_admit,
                     self._ctx, self._dnt_valid, jnp.asarray(rows),
                     jnp.asarray(slots))
 
@@ -1364,7 +1387,7 @@ class RollingGenerator:
             self._rng, key = jax.random.split(self._rng)
             with self._mesh_ctx():
                 (self.cache, self._logits, self._dpos,
-                 toks) = self._devstats.call(
+                 toks) = self._dispatch(
                     "decode", self.steps_per_call, self._decode,
                     self.params, self.cache, self._logits, self._dpos,
                     self._dactive, jnp.asarray(self._temps),
@@ -1429,7 +1452,7 @@ class RollingGenerator:
             self._rng, key = jax.random.split(self._rng)
             with self._mesh_ctx():
                 (self.cache, self._dpos, self._ctx, self._dnt,
-                 self._dnt_valid, toks, emits) = self._devstats.call(
+                 self._dnt_valid, toks, emits) = self._dispatch(
                     "decode_spec", (kd, self._spec_sampling), self._decode_sp,
                     self.params, self.cache, self._logits, self._dpos,
                     self._dactive, self._ctx, self._dnt, self._dnt_valid,

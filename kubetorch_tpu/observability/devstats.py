@@ -153,6 +153,12 @@ def cost_from_analysis(analysis: Any) -> Tuple[float, float]:
     return flops, bytes_
 
 
+def no_dispatch(kind: str, key: Any) -> None:
+    """A generator's ``dispatched`` hook while no serving engine drives
+    it (warm-up, hand-driven use): the engine installs its tick timer's
+    there, and hears of every executable the generator queues."""
+
+
 class ExecutableCosts:
     """Per-(kind, key) compiled-cost table + dispatch accumulator.
 

@@ -77,7 +77,11 @@ worker's trace ring. The driver times its own ticks and requests
 lifecycle stamps from one set of ``perf_counter`` stamps, read as flat
 counters in ``stats()``, as those spans, and as ``kt.tick.<phase>``
 host events in any ``jax.profiler`` trace of the process, on the device
-events' clock. Clients poll the snapshot without touching the
+events' clock. The generator tells the timer what it dispatched, where it
+dispatches, so every tick is also filed under one class by what it held
+(``tick_class_<c>_*``) and the host seconds in which the device had
+nothing to do are summed by phase (``tick_starved_<phase>_s``): counters
+only. Clients poll the snapshot without touching the
 device via a channel **control frame**
 (``CallChannel.control("stats")`` — answered by the pod server
 out-of-band, no worker hop).
@@ -160,6 +164,23 @@ _TICK_PHASES = ("evict", "evict_sync", "admit", "prefill", "handoff",
                 "handoff_sync", "decode_dispatch", "decode_sync", "route",
                 "publish", "idle", "handover")
 _IDLE = _TICK_PHASES.index("idle")
+# a blocking read of a device value: when one returns the device has worked
+# off everything that was queued, and until the generator's next dispatch it
+# has nothing to do. Seconds of these (the wait itself) and of ``idle`` (no
+# work to give it) never count as the device starving.
+_SYNC = tuple(i for i, name in enumerate(_TICK_PHASES)
+              if name.endswith("_sync"))
+_NOT_STARVED = frozenset(_SYNC + (_IDLE,))
+# A tick is filed under what it held, by the executables the generator says
+# it dispatched (``_TickTimer.dispatched``): the largest bucket of a
+# bucketed admission (``b<p_pad>``), else ``admit`` (an admission of a
+# generator that names no bucket: the sim), else ``chunk`` (a chunked
+# prefill dispatch), else ``plain`` (a decode chunk and nothing else), else
+# ``empty``. A class's rank is its place in that order; a bucket's is its
+# ``p_pad`` (16 and up).
+_EMPTY, _PLAIN, _CHUNK, _ADMIT = range(4)
+_CLASS_RANK = {"prefill_ext": _CHUNK, "admit": _ADMIT}
+_CLASS_FIELDS = ("n", "wall_s", "sync_s", "starved_s", "tokens")
 # a tick is slow when its wall (with the handover before it) passes this
 # many running medians; the median is over the last _WALL_RING ticks and
 # nothing is judged before _WALL_MIN of them
@@ -192,24 +213,42 @@ class _Phase:
 
     def __enter__(self) -> "_Phase":
         timer = self.timer
-        self._parent = timer.open
+        parent = self._parent = timer.open
         timer.open = self
         self._child_s = 0.0
         self._ann = timer.annotate(self.label)
         self._ann.__enter__()
-        self.t0 = time.perf_counter()
+        now = self.t0 = time.perf_counter()
+        if timer.dry_t is not None:
+            timer.starve(now, parent)
         return self
 
     def __exit__(self, *exc) -> None:
-        self.last_s = dt = time.perf_counter() - self.t0
+        now = time.perf_counter()
+        self.last_s = dt = now - self.t0
         self._ann.__exit__(*exc)
         timer = self.timer
+        if self.index in _SYNC:
+            timer.dry_t = now
+        elif timer.dry_t is not None:
+            timer.starve(now, self)
         # exclusive time: what a nested phase took is the nested one's
         timer.seconds[self.index] += dt - self._child_s
         timer.calls[self.index] += 1
         parent = timer.open = self._parent
         if parent is not None:
             parent._child_s += dt
+
+
+class _TickClass:
+    """What the ticks of one class add up to."""
+
+    __slots__ = ("name",) + _CLASS_FIELDS
+
+    def __init__(self, name: str):
+        self.name = name
+        self.n = self.tokens = 0
+        self.wall_s = self.sync_s = self.starved_s = 0.0
 
 
 class _TickTimer:
@@ -223,9 +262,20 @@ class _TickTimer:
     tick's phase split), and as ``kt.tick`` / ``kt.tick.<phase>`` host
     events inside any ``jax.profiler`` trace of the process, on the
     clock the device events carry. Always on; with no jax in the
-    process the annotations are no-ops."""
+    process the annotations are no-ops.
 
-    def __init__(self):
+    The tick also accounts for itself. The generator calls
+    :meth:`dispatched` wherever it has queued an executable, so every
+    tick is filed under ONE class by what it held (ticks, wall, wait
+    for the device, starved seconds, tokens routed; flat
+    ``tick_class_<c>_<field>`` in ``stats()``, every key there from the
+    start), and the host seconds in which the device had nothing to do
+    (from the return of a blocking read to the next dispatch) are summed
+    by the phase they lay in (``starved``; ``tick_starved_<phase>_s``,
+    their sum ``tick_starved_s``). ``max_len`` bounds the admission
+    buckets a generator can name (``rolling._bucket``'s powers of two)."""
+
+    def __init__(self, max_len: int = 0):
         profiler = getattr(sys.modules.get("jax"), "profiler", None)
         self.annotate = (profiler.TraceAnnotation if profiler is not None
                          else _no_annotation)
@@ -245,14 +295,55 @@ class _TickTimer:
         self._base = list(self.seconds)
         self._walls: List[float] = []
         self._step = _NO_ANNOTATION
+        # when the last blocking read returned with nothing dispatched
+        # since (the device's queue is dry), moved on as the stretch is
+        # booked phase by phase; None while the device has work
+        self.dry_t: Optional[float] = self._t_end
+        self.starved = [0.0] * len(_TICK_PHASES)
+        self.starved_s = 0.0
+        self._base_starved = 0.0
+        # the current tick: the highest rank dispatched, tokens routed
+        self._held = _EMPTY
+        self.tokens = 0
+        names = {_EMPTY: "empty", _PLAIN: "plain", _CHUNK: "chunk",
+                 _ADMIT: "admit"}
+        bucket = 16
+        while bucket < 2 * max_len:
+            names[bucket] = f"b{bucket}"
+            bucket *= 2
+        self._by_rank = {rank: _TickClass(name)
+                         for rank, name in names.items()}
 
     def __call__(self, name: str) -> _Phase:
         return self._phases[name]
+
+    def starve(self, now: float, phase: Optional[_Phase]) -> None:
+        """The dry stretch up to ``now`` lay in ``phase`` (the innermost
+        one open, None between phases)."""
+        if phase is not None and phase.index not in _NOT_STARVED:
+            dt = now - self.dry_t
+            self.starved[phase.index] += dt
+            self.starved_s += dt
+        self.dry_t = now
+
+    def dispatched(self, kind: str, key: Any) -> None:
+        """The generator has queued an executable (its ``fn`` returned):
+        the device has work again, and the tick holds what ``kind`` and
+        ``key`` say (``RollingGenerator._dispatch`` has the kinds)."""
+        if self.dry_t is not None:
+            self.starve(time.perf_counter(), self.open)
+            self.dry_t = None
+        rank = (key[1] if kind in ("prefill", "prefill_px")
+                else _CLASS_RANK.get(kind, _PLAIN))
+        if rank > self._held:
+            self._held = rank
 
     def __enter__(self) -> "_TickTimer":
         self._step = self._annotate_step("kt.tick", step_num=self.started)
         self._step.__enter__()
         self.started += 1
+        self._held = _EMPTY
+        self.tokens = 0
         self.t0 = time.perf_counter()
         return self
 
@@ -262,6 +353,15 @@ class _TickTimer:
         seconds, base = self.seconds, self._base
         wall = now - self._t_end - (seconds[_IDLE] - base[_IDLE])
         self._t_end = now
+        held = self._by_rank.get(self._held)
+        if held is None:        # a bucket past max_len's: filed, late
+            held = self._by_rank[self._held] = _TickClass(f"b{self._held}")
+        held.n += 1
+        held.wall_s += wall
+        held.sync_s += sum(seconds[i] - base[i] for i in _SYNC)
+        held.starved_s += self.starved_s - self._base_starved
+        held.tokens += self.tokens
+        self._base_starved = self.starved_s
         walls = self._walls
         if (len(walls) >= _WALL_MIN
                 and wall > _SLOW_FACTOR * sorted(walls)[len(walls) // 2]):
@@ -271,6 +371,7 @@ class _TickTimer:
                          for i, name in enumerate(_TICK_PHASES)
                          if seconds[i] > base[i]}
                 attrs["tick"] = self.started - 1
+                attrs["class"] = held.name
                 tracing.record_span("engine.slow_tick", wall, attrs=attrs)
         if len(walls) < _WALL_RING:
             walls.append(wall)
@@ -295,7 +396,13 @@ class _TickTimer:
         for i, name in enumerate(_TICK_PHASES):
             out[f"tick_{name}_s"] = self.seconds[i]
             out[f"tick_{name}_n"] = self.calls[i]
+            if i not in _NOT_STARVED:
+                out[f"tick_starved_{name}_s"] = self.starved[i]
         out["slow_ticks"] = self.slow_ticks
+        out["tick_starved_s"] = self.starved_s
+        for held in list(self._by_rank.values()):   # read lock-free
+            for field in _CLASS_FIELDS:
+                out[f"tick_class_{held.name}_{field}"] = getattr(held, field)
         return out
 
 
@@ -550,7 +657,9 @@ class DecodeEngine:
     ``admit`` and ``prefill_step`` return the rids they admitted /
     activated, and ``decode_step`` enters the ``tick_phase`` the engine
     installs on it around its dispatch, its blocking read and its
-    bookkeeping (``decode_dispatch`` / ``decode_sync`` / ``route``).
+    bookkeeping (``decode_dispatch`` / ``decode_sync`` / ``route``); it
+    calls the ``dispatched(kind, key)`` hook installed beside it wherever
+    it has queued an executable.
     Prefix sharing additionally uses ``register_prefix/drop_prefix`` and
     the ``prefill_tokens`` counter; session park/restore uses
     ``export_row/import_row``; speculative engines (``engine.spec``)
@@ -704,9 +813,10 @@ class DecodeEngine:
         # histogram name -> [sum, count] of what this engine observed
         self._life = {name: [0.0, 0] for name in _LIFE_HISTS}
         # the driver's phase timer; the generator reports the halves of
-        # its decode chunk through it
-        self._timer = _TickTimer()
+        # its decode chunk through it, and each executable it dispatches
+        self._timer = _TickTimer(self._row_cap_tokens)
         engine.tick_phase = self._timer
+        engine.dispatched = self._timer.dispatched
         self._exec_counts: Dict[str, int] = {}
         # seconds-per-row-freed EMA — the admission estimate's clock
         # (same role the session's ema_exec_s plays for call shedding)
@@ -1778,14 +1888,11 @@ class DecodeEngine:
                    getattr(eng, "_spec_rounds", 0),
                    getattr(eng, "_spec_emitted", 0))
         with self._timer as timer:
-            with timer("evict") as phase:
+            with timer("evict"):
                 self._evict_expired_locked()
                 # cold-adapter installs (finished background fetches)
-                installed = (self._adapter_pool.admit_ready()
-                             if self._adapter_pool is not None else None)
-            if installed:
-                timer.span("engine.adapter_admit", phase.last_s,
-                           ("adapters",), len(installed))
+                if self._adapter_pool is not None:
+                    self._adapter_pool.admit_ready()
             # ---- per-row admission into the live batch ---------------
             if eng.queued and eng.free_rows:
                 with timer("admit") as phase:
@@ -1825,7 +1932,8 @@ class DecodeEngine:
             events = eng.decode_step() if self._phase != "prefill" else []
             with timer("route"):
                 device_dt = 0.0
-                decode_tokens = sum(len(t) for _, t, _ in events)
+                decode_tokens = timer.tokens = sum(
+                    len(t) for _, t, _ in events)
                 if events:
                     # the host's wall of dispatch + sync, not device
                     # time: the device also works off what admission
@@ -2370,9 +2478,10 @@ class SimRollingEngine:
         self.peak_flops = 100e12
         self.peak_bw = 1.0e12
         self._devstats = devstats.AnalyticCosts()
-        # the serving engine installs its phase timer here, as on
-        # RollingGenerator; hand-driven, every phase is a no-op
+        # the serving engine installs its phase timer and its dispatch
+        # hook here, as on RollingGenerator; hand-driven, both are no-ops
         self.tick_phase = contextlib.nullcontext
+        self.dispatched = devstats.no_dispatch
 
     # -------------------------------------------------------- interface
     @staticmethod
@@ -2442,6 +2551,7 @@ class SimRollingEngine:
 
     def admit(self, max_rows: Optional[int] = None) -> List[int]:
         admitted: List[int] = []
+        rows_before = len(self._rows)
         while self._free and self._queue and (
                 max_rows is None or len(admitted) < max_rows):
             req = self._queue.pop(0)
@@ -2458,11 +2568,15 @@ class SimRollingEngine:
             else:
                 req["consumed"] = len(req["prompt"])
                 self._rows[req["rid"]] = req
+        if len(self._rows) > rows_before:
+            # the one-shot admissions' prefill, which names no bucket
+            self.dispatched("admit", None)
         return admitted
 
     def prefill_step(self) -> List[int]:
         if not self._prefilling:
             return []
+        self.dispatched("prefill_ext", self.prefill_chunk)
         if self.prefill_s:
             time.sleep(self.prefill_s)
         activated = []
@@ -2484,10 +2598,10 @@ class SimRollingEngine:
         if not self._rows:
             return []
         # the real generator's three stretches of a chunk: the dispatch
-        # (nothing to do here), the blocking read (the modelled device
+        # (only said here), the blocking read (the modelled device
         # time), and trimming the chunk into events
         with self.tick_phase("decode_dispatch"):
-            pass
+            self.dispatched("decode", self.steps_per_call)
         with self.tick_phase("decode_sync"):
             if self.step_s:
                 time.sleep(self.step_s)

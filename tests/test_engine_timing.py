@@ -224,7 +224,10 @@ def test_slow_tick_counted_and_named():
                if s["attrs"].get("decode_sync", 0.0) >= 0.39]
     assert len(stalled) == 1
     attrs = stalled[0]["attrs"]
-    split = {k: v for k, v in attrs.items() if k != "tick"}
+    # its number, and its class: the stall is in the program's first chunk,
+    # in the tick that admitted it (the sim names no bucket)
+    assert attrs["class"] == "admit"
+    split = {k: v for k, v in attrs.items() if k not in ("tick", "class")}
     assert max(split, key=split.get) == "decode_sync"
     assert set(split) <= set(_TICK_PHASES)
     assert stalled[0]["dur"] >= 0.39
