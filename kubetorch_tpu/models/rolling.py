@@ -65,9 +65,52 @@ def _named(impl, **fixed):
     return fn
 
 
-def _ctx_admit_impl(ctx, valid, rows, slots):
-    return (ctx.at[slots].set(rows, mode="drop"),
-            valid.at[slots].set(False, mode="drop"))
+def _ctx_admit_impl(ctx, rows, slots):
+    return ctx.at[slots].set(rows, mode="drop")
+
+
+def _fold(key):
+    """Raw key data as ``RollingGenerator._draw_key`` hands it out -> the
+    dispatch's own key."""
+    return jax.random.wrap_key_data(key)
+
+
+# jitted in its own right: every executable that ends in it (one a prefill
+# bucket and width, the decode chunk's step) then traces it once a shape,
+# not once an executable, and the compiler inlines it all the same
+@partial(jax.jit, static_argnames=("top_k", "top_p"))
+def draw_tokens(logits, temps, penalties, window, key, top_k, top_p):
+    """Logits ``[B, V]`` to a token a row: the one sampler of the rolling
+    engine, called by a decode step on the carried logits and by an
+    admission on the prefill's.
+
+    ``window`` [B, W] holds each row's recent token ids (-1 = empty);
+    ``penalties`` [B] apply HF-style repetition penalty to those ids
+    (positive logits divided, negative multiplied). Rows with ``temps > 0``
+    draw from the tempered, filtered distribution under ``key``; the others
+    take the argmax of the penalised logits."""
+    B = logits.shape[0]
+    pen = penalties[:, None]                               # [B, 1]
+    idx = jnp.maximum(window, 0)
+    gathered = jnp.take_along_axis(logits, idx, axis=1)    # [B, W]
+    adjusted = jnp.where(gathered > 0, gathered / pen, gathered * pen)
+    # Empty window slots (-1) scatter out of range and drop: a
+    # duplicate-index .set is nondeterministic, so routing them to
+    # index 0 could silently erase token 0's penalty.
+    sidx = jnp.where(window >= 0, window, logits.shape[-1])
+    logits = logits.at[jnp.arange(B)[:, None], sidx].set(
+        adjusted, mode="drop")
+    # temper BEFORE filtering (generate.sample_tokens order), so the top-p
+    # nucleus is computed on the tempered distribution (filter-then-temper
+    # picked a different support whenever top_p was set and
+    # temperature != 1)
+    logits_f = filter_logits(
+        logits / jnp.maximum(temps, 1e-6)[:, None],
+        top_k=top_k, top_p=top_p)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled = jax.random.categorical(
+        key, logits_f, axis=-1).astype(jnp.int32)
+    return jnp.where(temps > 0, sampled, greedy)
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -213,7 +256,11 @@ class RollingGenerator:
         self.top_k = top_k
         self.top_p = top_p
         self.steps_per_call = max(1, steps_per_call)
-        self._rng = jax.random.key(seed)
+        # every executable that samples takes its key as raw data made on
+        # the host (``_draw_key``): a key of its own for every dispatch
+        # with no split to dispatch first and nothing to trace for it
+        self._key_data = np.array(jax.random.key_data(jax.random.key(seed)))
+        self._draws = 0
         # multi-adapter serving (models/lora.py stack_adapters): a
         # per-slot adapter INDEX rides every prefill/decode call
         # (−1 = base model); llama._lora_apply gathers each row's own
@@ -267,20 +314,35 @@ class RollingGenerator:
         self._logits = jnp.zeros((max_slots, cfg.vocab_size), jnp.float32)
         self._dpos = jnp.zeros((max_slots,), jnp.int32)
         self._dactive = jnp.zeros((max_slots,), bool)
+        # Carried next token a row, with a validity flag. An admission
+        # draws the row's first token from its prefill's logits and leaves
+        # it here (``_finish_admit``): the driver reads it while the decode
+        # chunk behind the admission runs, and step 0 of that chunk takes
+        # it as given. Speculative rounds carry their next token here all
+        # the time: exact speculative SAMPLING draws the post-rejection
+        # token from the RESIDUAL distribution inside the verify round, a
+        # distribution that cannot be reconstructed later from logits. A
+        # row whose flag is False (one that came by ``import_row`` without
+        # a carried token) draws its next token from ``_logits``.
+        self._dnt = jnp.zeros((max_slots,), jnp.int32)
+        self._dnt_valid = jnp.zeros((max_slots,), bool)
+        # slot -> (the admission's token array, the row's index in it):
+        # first tokens drawn and not yet read. Read, routed and emptied by
+        # the next decode chunk, behind its dispatch (``_first_events``).
+        self._first_pending: Dict[int, Tuple[Any, int]] = {}
+        # rows admitted (each one's prefill complete), and those of them
+        # whose first token left at the admission, ahead of the chunk
+        self._admissions = {"admitted": 0, "first_tokens_at_admit": 0}
+        # ``first_frames(events)``: the serving engine installs its router
+        # here, and a fresh row's first token goes out as a frame of its
+        # own while the device works off the decode chunk. Hand-driven
+        # (None) the token heads the row's list of that chunk, as ever.
+        self.first_frames = None
         if self.spec:
             # device-resident token context per slot (prompt + accepted
             # tokens) — the n-gram draft matcher's haystack. Width
             # max_len + 1 so the carried token can sit at slot pos.
             self._ctx = jnp.zeros((max_slots, self.max_len + 1), jnp.int32)
-            # Carried next-token state. Exact speculative SAMPLING must
-            # draw the post-rejection token from the RESIDUAL
-            # distribution inside the verify round — a distribution that
-            # cannot be reconstructed later from logits — so rounds
-            # carry the drawn TOKEN (`_dnt`); `_dnt_valid` is False for
-            # freshly admitted slots, whose first token comes from the
-            # prefill logits instead.
-            self._dnt = jnp.zeros((max_slots,), jnp.int32)
-            self._dnt_valid = jnp.zeros((max_slots,), bool)
             # acceptance accounting for the serving bench / stats API
             self._spec_rounds = 0
             self._spec_emitted = 0
@@ -386,23 +448,29 @@ class RollingGenerator:
         # Donation matters here: the cache grid is the largest buffer in
         # the server and every call rewrites it — aliasing in/out keeps
         # updates in place.
+        # the sampler's filter is the generator's own and never changes:
+        # the admission executables hold it fixed, so a bucket's prefill
+        # stays ONE program (keyed by ``p_pad`` and the padded width)
         self._prefill = jax.jit(
-            _named(self._prefill_impl, cfg=cfg, rules=self.rules),
-            static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4))
+            _named(self._prefill_impl, cfg=cfg, rules=self.rules,
+                   top_k=top_k, top_p=top_p),
+            static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4, 5, 6))
         self._decode = jax.jit(
             _named(self._decode_impl, cfg=cfg, rules=self.rules),
             static_argnames=("top_k", "top_p", "n_steps"),
-            donate_argnums=(1, 2, 3))
+            donate_argnums=(1, 2, 3, 6))
         self._prefix_fill = jax.jit(
             _named(self._prefix_fill_impl, cfg=cfg, rules=self.rules,
                    quantized=self.kv_quantized),
             static_argnames=("p_pad",))
         self._prefill_px = jax.jit(
-            _named(self._prefill_px_impl, cfg=cfg, rules=self.rules),
-            static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4))
+            _named(self._prefill_px_impl, cfg=cfg, rules=self.rules,
+                   top_k=top_k, top_p=top_p),
+            static_argnames=("p_pad",), donate_argnums=(1, 2, 3, 4, 5, 6))
         self._prefill_ext = jax.jit(
-            _named(self._prefill_extend_impl, cfg=cfg, rules=self.rules),
-            static_argnames=("C",), donate_argnums=(1, 2, 3, 4))
+            _named(self._prefill_extend_impl, cfg=cfg, rules=self.rules,
+                   top_k=top_k, top_p=top_p),
+            static_argnames=("C",), donate_argnums=(1, 2, 3, 4, 5, 6))
         if self.adapters is not None:
             # hot-load: write ONE adapter's factors into a slot of the
             # stacked tree. The slot index is a traced scalar and the
@@ -426,7 +494,7 @@ class RollingGenerator:
                                  "top_p", "sampling"),
                 donate_argnums=(1, 3, 5, 6, 7))
             self._ctx_admit = jax.jit(_ctx_admit_impl,
-                                      donate_argnums=(0, 1))
+                                      donate_argnums=(0,))
 
     def _dispatch(self, kind: str, key: Any, fn, *args, **kwargs):
         """Run an executable: the ONE place the generator does. Kinds:
@@ -490,9 +558,15 @@ class RollingGenerator:
         ``linear_scan_positions`` / ``_prompt_tokens`` (positions the
         admissions' recurrent scans walk, a layer, and the prompt tokens
         they held) and ``state_row_bytes``, the bytes a row holds whatever
-        its depth, a gauge."""
+        its depth, a gauge. And ``admitted`` (rows whose prefill is
+        complete) beside ``first_tokens_at_admit`` (those of them whose
+        first token was read and routed behind the dispatch of the decode
+        chunk that follows the admission, not at that chunk's end): equal
+        wherever the admission draws the token, which is everywhere but a
+        row that leaves before it decodes (a handoff, an eviction)."""
         out = {f"decode_kv_positions_{k}": int(v)
                for k, v in self._kv_positions.items()}
+        out.update(self._admissions)
         out.update((f"merge_positions_{k}", int(v))
                    for k, v in self._merge_positions.items())
         out.update((f"admit_positions_{k}", int(v))
@@ -542,6 +616,7 @@ class RollingGenerator:
             self._scan_positions["linear_scan_positions"] += walked
             self._scan_positions["linear_scan_prompt_tokens"] += (
                 prompt_tokens)
+
     def _count_merge(self, counts, cols: int) -> None:
         """Account merges of ``cols``-column chunks: ``counts`` is what each
         row lands in each (0 for a row that sits the merge out). ``new`` is
@@ -793,28 +868,23 @@ class RollingGenerator:
             if req.consumed >= len(req.prompt):
                 finals[slot] = True
                 done_reqs.append(req)
+                # the chunk that completes the prompt draws its first token
+                self._seat(req, len(req.prompt))
         self._count_merge(counts, C)
         self._count_scan(B, C, int(counts.sum()))
+        key = self._draw_key()
         with self._mesh_ctx():
-            (self.cache, self._logits, self._dpos,
-             self._dactive) = self._dispatch(
+            (self.cache, self._logits, self._dpos, self._dactive,
+             self._dnt, self._dnt_valid, first) = self._dispatch(
                 "prefill_ext", C, self._prefill_ext,
                 self.params, self.cache, self._logits, self._dpos,
-                self._dactive, jnp.asarray(feed), jnp.asarray(counts),
-                jnp.asarray(finals), self._lora(self._slot_adapter), C=C)
+                self._dactive, self._dnt, self._dnt_valid, feed, counts,
+                finals, self._temps, self._penalties, self._win, key,
+                self._lora(self._slot_adapter), C=C)
         activated: List[int] = []
         for req in done_reqs:
             del self._prefilling[req.slot]
-            # the host half _admit_group does for one-shot admissions
-            self._temps[req.slot] = req.temperature
-            self._penalties[req.slot] = req.repetition_penalty
-            W = self._win.shape[1]
-            tail = req.prompt[-W:]
-            self._win[req.slot] = -1
-            if req.repetition_penalty != 1.0 and tail:
-                self._win[req.slot, -len(tail):] = tail
-            self._slots[req.slot] = req
-            self._depth[req.slot] = len(req.prompt)
+            self._first_pending[req.slot] = (first, req.slot)
             activated.append(req.rid)
         if self.spec and done_reqs:
             # the chunked-prefill × speculation composition: the draft
@@ -829,13 +899,7 @@ class RollingGenerator:
             for i, req in enumerate(done_reqs):
                 rows[i, :len(req.prompt)] = req.prompt
                 slots[i] = req.slot
-                self._spec_state[req.slot] = LookaheadState(
-                    self.spec_k, self.spec_cap)
-            with self._mesh_ctx():
-                self._ctx, self._dnt_valid = self._dispatch(
-                    "ctx_admit", n_pad, self._ctx_admit,
-                    self._ctx, self._dnt_valid, jnp.asarray(rows),
-                    jnp.asarray(slots))
+            self._seed_drafts(done_reqs, rows, slots)
         return activated
 
     def evict(self, rid: int) -> bool:
@@ -1298,6 +1362,13 @@ class RollingGenerator:
         lens = np.ones(n_pad, np.int32)
         slots = np.full(n_pad, self.max_slots, np.int32)  # OOB → dropped
         idx = np.full(n_pad, -1, np.int32)
+        # the admitted rows' own sampler inputs: the admission draws each
+        # row's first token (a dummy row: greedy, no penalty, empty window)
+        temps = np.zeros(n_pad, np.float32)
+        penalties = np.ones(n_pad, np.float32)
+        win = np.full((n_pad, self._win.shape[1]), -1, np.int32)
+        head = (self._prefixes[prefix_id]["len"] if prefix_id is not None
+                else 0)
         for i, req in enumerate(group):
             toks[i, :len(req.prompt)] = req.prompt
             lens[i] = len(req.prompt)
@@ -1305,31 +1376,27 @@ class RollingGenerator:
             aid = getattr(req, "adapter_id", -1)
             idx[i] = aid
             self._slot_adapter[req.slot] = aid
-            self._temps[req.slot] = req.temperature
-            self._penalties[req.slot] = req.repetition_penalty
-            W = self._win.shape[1]
-            tail = req.prompt[-W:]
-            self._win[req.slot] = -1
-            if req.repetition_penalty != 1.0 and tail:
-                self._win[req.slot, -len(tail):] = tail
-            self._slots[req.slot] = req
-            self._depth[req.slot] = len(req.prompt) + (
-                self._prefixes[prefix_id]["len"] if prefix_id is not None
-                else 0)
+            self._seat(req, head + len(req.prompt))
+            temps[i] = req.temperature
+            penalties[i] = req.repetition_penalty
+            win[i] = self._win[req.slot]
             self.prefill_tokens += len(req.prompt)
             self._count_prefill(len(req.prompt))
         self._count_scan(n_pad, p_pad, sum(len(r.prompt) for r in group))
+        key = self._draw_key()
         with self._mesh_ctx():
             self._count_admission(n_pad, p_pad, own=prefix_id is None)
+            state = (self.cache, self._logits, self._dpos, self._dactive,
+                     self._dnt, self._dnt_valid)
+            # host arrays go in as they are: the call uploads them itself,
+            # which costs a fraction of an upload each made beforehand
+            rows_in = (toks, lens, slots, temps, penalties, win, key,
+                       self._lora(idx))
             if prefix_id is None:
                 self._count_admit(n, slots, p_pad)
-                (self.cache, self._logits, self._dpos,
-                 self._dactive) = self._dispatch(
+                out = self._dispatch(
                     "prefill", (n_pad, p_pad), self._prefill,
-                    self.params, self.cache, self._logits, self._dpos,
-                    self._dactive, jnp.asarray(toks), jnp.asarray(lens),
-                    jnp.asarray(slots), self._lora(idx),
-                    p_pad=p_pad)
+                    self.params, *state, *rows_in, p_pad=p_pad)
             else:
                 pfx = self._prefixes[prefix_id]
                 # the own cache there: the prefix's bucket and the
@@ -1337,31 +1404,64 @@ class RollingGenerator:
                 self._count_admit(n, slots, min(
                     grid_dims(pfx["planes"], self._row_leaves)[1] + p_pad,
                     self.max_len))
-                (self.cache, self._logits, self._dpos,
-                 self._dactive) = self._dispatch(
+                out = self._dispatch(
                     "prefill_px", (n_pad, p_pad), self._prefill_px,
-                    self.params, self.cache, self._logits, self._dpos,
-                    self._dactive, pfx["planes"],
-                    jnp.int32(pfx["len"]), jnp.asarray(toks),
-                    jnp.asarray(lens), jnp.asarray(slots),
-                    self._lora(idx), p_pad=p_pad)
-            if self.spec:
-                # seed the draft haystack: the full token context (shared
-                # prefix + prompt) per admitted slot. One extra tiny
-                # dispatch per admission wave — the hot path (the decode
-                # chunk) stays one dispatch.
-                rows = np.zeros((n_pad, self._ctx.shape[1]), np.int32)
-                head = (self._prefixes[prefix_id]["tokens"]
-                        if prefix_id is not None else [])
-                for i, req in enumerate(group):
-                    seq = head + req.prompt
-                    rows[i, :len(seq)] = seq
-                    self._spec_state[req.slot] = LookaheadState(
-                        self.spec_k, self.spec_cap)
-                self._ctx, self._dnt_valid = self._dispatch(
-                    "ctx_admit", n_pad, self._ctx_admit,
-                    self._ctx, self._dnt_valid, jnp.asarray(rows),
-                    jnp.asarray(slots))
+                    self.params, *state, pfx["planes"],
+                    jnp.int32(pfx["len"]), *rows_in, p_pad=p_pad)
+            (self.cache, self._logits, self._dpos, self._dactive,
+             self._dnt, self._dnt_valid, first) = out
+        for i, req in enumerate(group):
+            self._first_pending[req.slot] = (first, i)
+        if self.spec:
+            # seed the draft haystack: the full token context (shared
+            # prefix + prompt) per admitted slot. One extra tiny
+            # dispatch per admission wave — the hot path (the decode
+            # chunk) stays one dispatch.
+            rows = np.zeros((n_pad, self._ctx.shape[1]), np.int32)
+            head_toks = (self._prefixes[prefix_id]["tokens"]
+                         if prefix_id is not None else [])
+            for i, req in enumerate(group):
+                seq = head_toks + req.prompt
+                rows[i, :len(seq)] = seq
+            self._seed_drafts(group, rows, slots)
+
+    def _seat(self, req: Request, depth: int) -> None:
+        """The host half of an admission, before its dispatch: the row's
+        sampler inputs and penalty window (the prompt's tail), its place
+        among the decoding rows and its depth's mirror."""
+        slot = req.slot
+        self._temps[slot] = req.temperature
+        self._penalties[slot] = req.repetition_penalty
+        tail = req.prompt[-self._win.shape[1]:]
+        self._win[slot] = -1
+        if req.repetition_penalty != 1.0 and tail:
+            self._win[slot, -len(tail):] = tail
+        self._slots[slot] = req
+        self._depth[slot] = depth
+        self._admissions["admitted"] += 1
+
+    def _seed_drafts(self, reqs: List[Request], rows, slots) -> None:
+        """Speculation's share of an admission: each row's token context
+        into the draft matcher's haystack (one ``ctx_admit`` dispatch a
+        wave) and a fresh lookahead state."""
+        for req in reqs:
+            self._spec_state[req.slot] = LookaheadState(
+                self.spec_k, self.spec_cap)
+        with self._mesh_ctx():
+            self._ctx = self._dispatch(
+                "ctx_admit", len(slots), self._ctx_admit,
+                self._ctx, rows, slots)
+
+    def _draw_key(self):
+        """Key data for the next sampling dispatch (``_fold`` makes the key
+        of it on the device): the seed's own key with the dispatch's number
+        in its first word, which a seed of 32 bits leaves 0. Distinct
+        dispatches hold distinct keys; no key is ever split off another,
+        which would take a dispatch of its own."""
+        self._draws += 1
+        data = self._key_data.copy()
+        data[0] = self._draws & 0xFFFFFFFF
+        return data
 
     def _lora(self, slots_np):
         """None when no adapters — the hot path must not pay a
@@ -1376,6 +1476,60 @@ class RollingGenerator:
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
 
+    def _first_events(self):
+        """Read the first tokens that admissions have drawn since the last
+        decode chunk and send each on as a one-token frame. Called BEHIND
+        the dispatch of the chunk that follows the admissions: the read
+        returns when the last prefill ends, with the chunk queued behind
+        it, so the device never waits for this host round trip. Trimming
+        (budget, eos, stop) is any frame's: a request of one token
+        finishes here and frees its row.
+
+        Returns what ``_chunk_events`` needs to end the chunk in flight,
+        None where no admission preceded it: the slots whose step-0 token
+        has now gone out and, driven by hand (no ``first_frames``), the
+        events made here with the rows' order, so that the token can head
+        its row's list of the chunk as it always did."""
+        pending, self._first_pending = self._first_pending, {}
+        # a row that left between its admission and this chunk (an export,
+        # an eviction) was dropped from ``pending`` when its row was freed
+        if not pending:
+            return None
+        with self.tick_phase("first_sync"):
+            new = {slot: [int(np.asarray(first)[i])]
+                   for slot, (first, i) in pending.items()}
+        with self.tick_phase("route"):
+            order = {req.rid: n for n, req in enumerate(self._slots.values())}
+            self._admissions["first_tokens_at_admit"] += len(new)
+            events = self._finish_events(new)
+            if self.first_frames is not None:
+                self.first_frames(events)
+                events = []
+        return list(new), events, order
+
+    def _chunk_events(self, new_by_slot: Dict[int, List[int]], first):
+        """The events of a chunk whose tokens are ``new_by_slot``, after
+        ``first`` (``_first_events``): a row whose first token has gone out
+        drops it from the chunk's (step 0 took it as given)."""
+        if first is None:
+            return self._finish_events(new_by_slot)
+        sent, early, order = first
+        for slot in sent:
+            if slot in new_by_slot:            # not finished on that token
+                del new_by_slot[slot][:1]
+        events = self._finish_events(new_by_slot)
+        if not early:
+            return events
+        rest = {rid: (toks, done) for rid, toks, done in events}
+        out = []
+        for rid, toks, done in early:
+            if not done:
+                more, done = rest.pop(rid)
+                toks = toks + more
+            out.append((rid, toks, done))
+        out.extend((rid,) + rest[rid] for rid in rest)
+        return sorted(out, key=lambda ev: order[ev[0]])
+
     def _decode_chunk(self) -> List[Tuple[int, List[int], bool]]:
         with self.tick_phase("decode_dispatch"):
             self._count_kv_read()
@@ -1384,17 +1538,18 @@ class RollingGenerator:
                 np.full(len(self._slots), self.steps_per_call),
                 self.steps_per_call)
             self._depth[list(self._slots)] += self.steps_per_call
-            self._rng, key = jax.random.split(self._rng)
+            key = self._draw_key()
             with self._mesh_ctx():
-                (self.cache, self._logits, self._dpos,
+                (self.cache, self._logits, self._dpos, self._dnt_valid,
                  toks) = self._dispatch(
                     "decode", self.steps_per_call, self._decode,
                     self.params, self.cache, self._logits, self._dpos,
-                    self._dactive, jnp.asarray(self._temps),
-                    jnp.asarray(self._penalties), jnp.asarray(self._win),
+                    self._dactive, self._dnt, self._dnt_valid,
+                    self._temps, self._penalties, self._win,
                     key, self._lora(self._slot_adapter),
                     top_k=self.top_k, top_p=self.top_p,
                     n_steps=self.steps_per_call)
+        first = self._first_events()
         with self.tick_phase("decode_sync"):
             toks = np.asarray(toks)                   # [K, B] — the one sync
         with self.tick_phase("route"):
@@ -1411,9 +1566,9 @@ class RollingGenerator:
             else:
                 self._win[:, :-K] = self._win[:, K:]
                 self._win[:, -K:] = toks.T
-            return self._finish_events(
+            return self._chunk_events(
                 {slot: [int(t) for t in toks[:, slot]]
-                 for slot in self._slots})
+                 for slot in self._slots}, first)
 
     def _decode_spec_chunk(self) -> List[Tuple[int, List[int], bool]]:
         """One dispatch = ``steps_per_call`` verify rounds; each round
@@ -1449,19 +1604,20 @@ class RollingGenerator:
                 kd *= 2
             kd = max(1, min(kd, self.spec_k))
             self._count_kv_read()
-            self._rng, key = jax.random.split(self._rng)
+            key = self._draw_key()
             with self._mesh_ctx():
                 (self.cache, self._dpos, self._ctx, self._dnt,
                  self._dnt_valid, toks, emits) = self._dispatch(
                     "decode_spec", (kd, self._spec_sampling), self._decode_sp,
                     self.params, self.cache, self._logits, self._dpos,
                     self._dactive, self._ctx, self._dnt, self._dnt_valid,
-                    jnp.asarray(self._temps), jnp.asarray(kk), key,
+                    self._temps, kk, key,
                     self._lora(self._slot_adapter),
                     k=kd, ngram=self.spec_ngram,
                     n_rounds=self.steps_per_call,
                     top_k=self.top_k, top_p=self.top_p,
                     sampling=self._spec_sampling)
+        first = self._first_events()
         with self.tick_phase("decode_sync"):
             toks = np.asarray(toks)            # [R, B, kd] — the one sync
             emits = np.asarray(emits)          # [R, B]
@@ -1488,18 +1644,17 @@ class RollingGenerator:
                     st.observe(int(emits[r, slot]), k_used,
                                alpha=self.spec_ema_alpha)
                 st.adapt(self.spec_k, self.spec_cap)
-            return self._finish_events(new_by_slot)
+            return self._chunk_events(new_by_slot, first)
 
     def _finish_events(self, new_by_slot: Dict[int, List[int]]
                        ) -> List[Tuple[int, List[int], bool]]:
-        """Trim each slot's freshly decoded tokens to its budget / eos /
-        stop sequences, emit (rid, tokens, done) events, and free
-        finished slots at the chunk boundary."""
+        """Trim each given slot's freshly decoded tokens to its budget /
+        eos / stop sequences, emit (rid, tokens, done) events, and free
+        the slots that finished."""
         events: List[Tuple[int, List[int], bool]] = []
         freed: List[int] = []
-        for slot in list(self._slots):
+        for slot, new in new_by_slot.items():
             req = self._slots[slot]
-            new = new_by_slot[slot]
             # trim to budget; cut at eos
             room = req.max_new_tokens - len(req.tokens)
             new = new[:room]
@@ -1539,6 +1694,9 @@ class RollingGenerator:
         mask = jnp.asarray(mask)
         self._dactive = jnp.where(mask, False, self._dactive)
         self._dpos = jnp.where(mask, 0, self._dpos)
+        # a token the row's admission drew and no chunk took (the row left
+        # before it decoded) must not be the next occupant's
+        self._dnt_valid = jnp.where(mask, False, self._dnt_valid)
         # a row-state leaf has no depth to mask a stale row by: a freed row
         # goes back to a sequence's start, which is what a chunked prefill
         # begins from (a bucketed admission splices the whole row anyway)
@@ -1552,17 +1710,21 @@ class RollingGenerator:
         for slot in freed:
             self._win[slot] = -1
             self._penalties[slot] = 1.0
+            self._first_pending.pop(slot, None)
             if self.spec:
                 self._spec_state.pop(slot, None)
         self._free.extend(freed)
 
     # ------------------------------------------------------------- jitted
     @staticmethod
-    def _prefill_impl(params, cache, logits, dpos, dactive, tokens,
-                      prompt_lens, slots, lora, *, p_pad, cfg, rules):
+    def _prefill_impl(params, cache, logits, dpos, dactive, dnt, dnt_valid,
+                      tokens, prompt_lens, slots, temps, penalties, window,
+                      key, lora, *, p_pad, top_k, top_p, cfg, rules):
         """Prefill N slots at once: one forward over a private N-row
         cache, then land the rows in the shared grid at ``slots``
-        (out-of-range dummy rows land nothing).
+        (out-of-range dummy rows land nothing) and draw each row's first
+        token (``_finish_admit``; ``temps`` / ``penalties`` / ``window``
+        are the N rows' own sampler inputs, ``key`` a ``_draw_key()``).
 
         The private cache covers only the ``p_pad`` rows prefill writes —
         full-``M`` would be a second multi-GB grid live beside the real
@@ -1581,12 +1743,14 @@ class RollingGenerator:
             unembed_positions=prompt_lens - 1, lora=lora,
             causal_lens=prompt_lens)
         return RollingGenerator._finish_admit(
-            cache, own, out[:, 0], logits, dpos, dactive, slots,
-            prompt_lens)
+            cache, own, out[:, 0], logits, dpos, dactive, dnt, dnt_valid,
+            slots, prompt_lens,
+            draw_tokens(out[:, 0], temps, penalties, window, _fold(key),
+                        top_k, top_p))
 
     @staticmethod
-    def _finish_admit(cache, own, last, logits, dpos, dactive, slots,
-                      new_pos):
+    def _finish_admit(cache, own, last, logits, dpos, dactive, dnt,
+                      dnt_valid, slots, new_pos, first):
         """Land own-cache rows in the grid and update per-slot state.
 
         Row ``n`` of ``own`` goes to grid row ``slots[n]`` by slice update
@@ -1599,13 +1763,20 @@ class RollingGenerator:
         past its depth included, and the rest of the grid row stays. A
         row-state leaf (``[L, B, *shape]``, no position axis) takes the
         own-cache's whole, which the prefill left at the row's last real
-        token. ``last``: [N, V] logits at each row's final real token.
+        token. ``last``: [N, V] logits at each row's final real token;
+        ``first``: [N] the token drawn from them, each row's first. It
+        becomes the row's carried token (step 0 of the row's first decode
+        chunk takes it as given) and is returned as the last output, a
+        small array of its own: the host reads it when this executable
+        ends, whatever is queued behind it.
         """
         cache = grid_write.write_rows(cache, own, slots)
         logits = logits.at[slots].set(last, mode="drop")
         dpos = dpos.at[slots].set(new_pos, mode="drop")
         dactive = dactive.at[slots].set(True, mode="drop")
-        return cache, logits, dpos, dactive
+        dnt = dnt.at[slots].set(first, mode="drop")
+        dnt_valid = dnt_valid.at[slots].set(True, mode="drop")
+        return cache, logits, dpos, dactive, dnt, dnt_valid, first
 
     @staticmethod
     def _prefix_fill_impl(params, tokens, prefix_len, lora, *, p_pad, cfg,
@@ -1630,9 +1801,10 @@ class RollingGenerator:
         return own, out[0, 0]
 
     @staticmethod
-    def _prefill_px_impl(params, cache, logits, dpos, dactive, planes,
-                         prefix_len, tokens, prompt_lens, slots, lora, *,
-                         p_pad, cfg, rules):
+    def _prefill_px_impl(params, cache, logits, dpos, dactive, dnt,
+                         dnt_valid, planes, prefix_len, tokens, prompt_lens,
+                         slots, temps, penalties, window, key, lora, *,
+                         p_pad, top_k, top_p, cfg, rules):
         """Prefill N suffixes on top of a shared, already-computed prefix:
         the prefix KV block is broadcast into each slot's rows [0, Ppad)
         and only the suffix runs through the model (vLLM prefix caching at
@@ -1673,12 +1845,16 @@ class RollingGenerator:
             params, tokens, positions, own, prefix_len, mask, cfg, rules,
             unembed_positions=prompt_lens - 1, lora=lora)
         return RollingGenerator._finish_admit(
-            cache, own, out[:, 0], logits, dpos, dactive, slots,
-            prefix_len + prompt_lens)
+            cache, own, out[:, 0], logits, dpos, dactive, dnt, dnt_valid,
+            slots, prefix_len + prompt_lens,
+            draw_tokens(out[:, 0], temps, penalties, window, _fold(key),
+                        top_k, top_p))
 
     @staticmethod
-    def _prefill_extend_impl(params, cache, logits, dpos, dactive, feed,
-                             counts, finals, lora, *, C, cfg, rules):
+    def _prefill_extend_impl(params, cache, logits, dpos, dactive, dnt,
+                             dnt_valid, feed, counts, finals, temps,
+                             penalties, window, key, lora, *, C, top_k,
+                             top_p, cfg, rules):
         """Advance N in-progress chunked prefills by ≤ ``C`` tokens each,
         GRID-RESIDENT: the chunk forward runs at full grid width (rows
         with ``counts == 0`` are masked out and merge nothing), attends
@@ -1692,7 +1868,10 @@ class RollingGenerator:
         ``finals`` marks rows whose prompt completes in this chunk:
         their last real token's logits (``unembed_positions`` keeps the
         unembed at one position per row — [B, C, V] float32 would be
-        multi-GB at serving scale) seed the decode loop and the row
+        multi-GB at serving scale) seed the decode loop, the row's first
+        token is drawn from them as a bucketed admission draws it (the
+        row's carried token, and the last output's entry at the row: the
+        sampler inputs are the grid's, ``[B]``) and the row
         activates. Rows mid-prompt keep ``dactive`` False — decode
         chunks skip them (zero merge count, no depth advance) while
         this path fills them, which is what lets the serving engine
@@ -1722,11 +1901,14 @@ class RollingGenerator:
         cache = model.merge_chunk_into_grid(cache, chunk, dpos, counts)
         fin = finals & live
         logits = jnp.where(fin[:, None], out[:, 0], logits)
-        return cache, logits, dpos + counts, dactive | fin
+        first = draw_tokens(out[:, 0], temps, penalties, window,
+                            _fold(key), top_k, top_p)
+        return (cache, logits, dpos + counts, dactive | fin,
+                jnp.where(fin, first, dnt), dnt_valid | fin, first)
 
     @staticmethod
-    def _decode_impl(params, cache, last_logits, pos, active, temps,
-                     penalties, window, key, lora, *,
+    def _decode_impl(params, cache, last_logits, pos, active, dnt,
+                     dnt_valid, temps, penalties, window, key, lora, *,
                      top_k, top_p, n_steps, cfg, rules):
         """``n_steps`` tokens for every slot, each at its own depth, in one
         ``lax.scan`` — one dispatch, one emitted [K, B] block.
@@ -1753,11 +1935,13 @@ class RollingGenerator:
         (``llama._cached_attn_merged_q`` / ``_cached_attn_merged``), the
         kernel's oracle. ``stats()`` counts what was read either way.
 
-        ``window`` [B, W] holds each slot's recent token ids (−1 = empty);
-        ``penalties`` [B] apply HF-style repetition penalty to those ids
-        (positive logits divided, negative multiplied). The window rolls
-        inside the scan so a token sampled at step k is already penalized
-        at step k+1."""
+        Each step draws with the engine's one sampler (``draw_tokens``:
+        ``window`` [B, W] the slots' recent token ids, ``penalties`` [B]).
+        The window rolls inside the scan so a token sampled at step k is
+        already penalized at step k+1. A row whose admission drew its
+        first token (``dnt`` where ``dnt_valid``) takes that token at step
+        0 in place of a draw of its own, and the flag is spent: the
+        returned ``dnt_valid`` is False for every row that decoded."""
         B = last_logits.shape[0]
         model = decoder_for(cfg)
         M = grid_dims(cache, row_leaves(model, cfg))[1]
@@ -1778,29 +1962,11 @@ class RollingGenerator:
         def one(carry, inp):
             chunk, logits, pos, win, counts = carry
             j, step_key = inp
-            pen = penalties[:, None]                       # [B, 1]
-            idx = jnp.maximum(win, 0)
-            gathered = jnp.take_along_axis(logits, idx, axis=1)  # [B, W]
-            adjusted = jnp.where(gathered > 0, gathered / pen,
-                                 gathered * pen)
-            # Empty window slots (−1) scatter out of range and drop: a
-            # duplicate-index .set is nondeterministic, so routing them to
-            # index 0 could silently erase token 0's penalty.
-            sidx = jnp.where(win >= 0, win, logits.shape[-1])
-            logits = logits.at[jnp.arange(B)[:, None], sidx].set(
-                adjusted, mode="drop")
-
-            # temper BEFORE filtering — generate.sample_tokens order, so
-            # the top-p nucleus is computed on the tempered distribution
-            # (filter-then-temper picked a different support whenever
-            # top_p was set and temperature != 1)
-            logits_f = filter_logits(
-                logits / jnp.maximum(temps, 1e-6)[:, None],
-                top_k=top_k, top_p=top_p)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            sampled = jax.random.categorical(
-                step_key, logits_f, axis=-1).astype(jnp.int32)
-            tok = jnp.where(temps > 0, sampled, greedy)
+            tok = draw_tokens(logits, temps, penalties, win, step_key,
+                              top_k, top_p)
+            # step 0 of a row the admission drew for takes that token (it
+            # has gone out already; a sampled row must not draw again)
+            tok = jnp.where(dnt_valid & (j == 0), dnt, tok)
             win = jnp.concatenate([win[:, 1:], tok[:, None]], axis=1)
 
             positions = pos[:, None]
@@ -1815,7 +1981,7 @@ class RollingGenerator:
 
         (chunk, logits, pos, _, counts), toks = jax.lax.scan(
             one, (chunk0, last_logits, pos, window, counts0),
-            (jnp.arange(n_steps), jax.random.split(key, n_steps)))
+            (jnp.arange(n_steps), jax.random.split(_fold(key), n_steps)))
         if counts:
             # one row a counter under the tokens, its value in column 0:
             # fetched by the one read that fetches the tokens
@@ -1833,7 +1999,8 @@ class RollingGenerator:
         # prompt.
         new_cache = model.merge_chunk_into_grid(
             cache, chunk, pos0, jnp.where(active, n_steps, 0))
-        return new_cache, logits, jnp.where(active, pos, pos0), toks
+        return (new_cache, logits, jnp.where(active, pos, pos0),
+                dnt_valid & ~active, toks)
 
     @staticmethod
     def _decode_spec_impl(params, cache, last_logits, pos, active, ctx,
@@ -1866,8 +2033,10 @@ class RollingGenerator:
         on rejection the next token draws from the residual (``d``'s
         mass removed, renormalized). The residual draw cannot be
         reconstructed outside the round, so rounds carry the drawn
-        TOKEN (``dnt``); ``dnt_valid=False`` rows (fresh admissions)
-        take their first token from the prefill logits instead.
+        TOKEN (``dnt``). A fresh admission's is the token its prefill
+        drew (``_finish_admit``); only a ``dnt_valid=False`` row (one
+        imported from a plain engine's export) takes its first token
+        from its carried logits instead.
 
         Unlike the plain chunk (grid merged once per dispatch), each
         round merges: round r+1's verify must read round r's accepted
@@ -1904,9 +2073,10 @@ class RollingGenerator:
                  ).reshape(-1, shp[-1]), top_k, top_p)
             return jax.nn.softmax(flat, axis=-1).reshape(shp)
 
-        # fresh rows' first token comes from the (loop-invariant) prefill
-        # logits — computed ONCE, not per round
-        key, k_fresh = jax.random.split(key)
+        # a row with no carried token (imported from a plain export) takes
+        # its first from its (loop-invariant) logits: computed ONCE, not
+        # per round
+        key, k_fresh = jax.random.split(_fold(key))
         nt0 = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         if sampling:
             nt0 = jnp.where(

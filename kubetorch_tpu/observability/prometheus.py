@@ -619,6 +619,7 @@ _ENGINE: Dict[str, float] = {
     "engine_steps_total": 0.0,
     "engine_tokens_total": 0.0,
     "engine_admitted_rows_total": 0.0,
+    "engine_first_tokens_at_admit_total": 0.0,
     "engine_prefill_chunks_total": 0.0,
     "engine_evictions_total": 0.0,
     "engine_sheds_total": 0.0,
@@ -674,6 +675,7 @@ _ENGINE_EVENTS = {
     "step": "engine_steps_total",
     "tokens": "engine_tokens_total",
     "admit": "engine_admitted_rows_total",
+    "first_token_at_admit": "engine_first_tokens_at_admit_total",
     "prefill_chunk": "engine_prefill_chunks_total",
     "evict": "engine_evictions_total",
     "shed": "engine_sheds_total",

@@ -164,6 +164,11 @@ _m("engine_tokens_total", "counter",
 _m("engine_admitted_rows_total", "counter",
    "Rows admitted into the live batch (per-row, never batch swaps).",
    "engine")
+_m("engine_first_tokens_at_admit_total", "counter",
+   "Rows whose first token left as a frame of its own when their "
+   "admission ended, behind the dispatch of the decode chunk that "
+   "follows it (over engine_admitted_rows_total: 1 where every "
+   "admission draws its row's token).", "engine")
 _m("engine_prefill_chunks_total", "counter",
    "Chunked-prefill dispatches interleaved between decode chunks.",
    "engine")
@@ -176,9 +181,10 @@ _m("engine_tick_errors_total", "counter",
    "Engine-loop ticks that raised (streams failed typed, loop "
    "survived).", "engine")
 _m("engine_device_seconds_total", "counter",
-   "Host wall of the decode chunks' dispatch + blocking read (tick "
-   "phases decode_dispatch + decode_sync), summed; NOT device time: "
-   "the device also works off what admission queued.", "engine")
+   "Host wall from a decode chunk's dispatch to the end of its "
+   "blocking read (tick phases decode_dispatch .. decode_sync, the "
+   "first tokens' read and routing between them), summed; NOT device "
+   "time: the device also works off what admission queued.", "engine")
 _m("engine_queue_depth", "gauge",
    "Programs queued ahead of admission.", "engine")
 _m("engine_active_rows", "gauge", "Rows decoding.", "engine")
@@ -197,8 +203,10 @@ _m("engine_queue_wait_seconds", "histogram",
    "Queued in the generator until the tick that admits the row "
    "starts its admission (0 for restored / imported rows).", "engine")
 _m("engine_admit_to_first_seconds", "histogram",
-   "Start of the row's admission to its first frame: the prefill, "
-   "the decode chunk after it, and routing.", "engine")
+   "Start of the row's admission to its first frame: the prefill, the "
+   "read of the token it drew (behind the next decode chunk's "
+   "dispatch) and routing; with the whole decode chunk too for a row "
+   "that carries no such token (a restored or imported one).", "engine")
 _m("kv_blocks_used", "gauge",
    "KV blocks held by row reservations + cached prefixes.", "engine")
 _m("kv_blocks_free", "gauge",

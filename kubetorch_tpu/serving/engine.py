@@ -14,7 +14,10 @@ it server-side:
   process that owns the TPU) runs rolling-engine steps back-to-back,
   device-resident, and routes each chunk's tokens into the program's
   stream as a frame — the per-chunk client round trip disappears from
-  the steady state entirely;
+  the steady state entirely. A prompt's FIRST frame holds its first
+  token alone: the admission draws it, and the driver routes it when the
+  prefill has ended, while the device works off the decode chunk behind
+  it (``_first_frames_locked``);
 - frames ride the PR-2 channel with per-frame ``seq``s recorded in the
   PR-8 result-retention ring, so replay/deadline semantics apply **per
   generation**: a mid-stream partition resumes the token stream
@@ -160,17 +163,24 @@ _PHASE_CODE = {"prefill": 0, "decode": 1, "mixed": 2}
 # ``handover`` the rest of the time between two ticks (lock release, the
 # yield, taking the lock and the GIL back). A ``*_sync`` phase is a wait
 # for a device value: host work and waiting never share a counter.
+# ``first_sync`` is the read of the first tokens that the tick's admissions
+# drew, behind the decode chunk's dispatch; the ``route`` right after it
+# sends them on as frames of their own while the chunk runs.
 _TICK_PHASES = ("evict", "evict_sync", "admit", "prefill", "handoff",
-                "handoff_sync", "decode_dispatch", "decode_sync", "route",
-                "publish", "idle", "handover")
+                "handoff_sync", "decode_dispatch", "first_sync",
+                "decode_sync", "route", "publish", "idle", "handover")
 _IDLE = _TICK_PHASES.index("idle")
-# a blocking read of a device value: when one returns the device has worked
-# off everything that was queued, and until the generator's next dispatch it
-# has nothing to do. Seconds of these (the wait itself) and of ``idle`` (no
-# work to give it) never count as the device starving.
+# a blocking read of a device value. Seconds of these (the wait itself) and
+# of ``idle`` (no work to give it) never count as the device starving.
 _SYNC = tuple(i for i, name in enumerate(_TICK_PHASES)
               if name.endswith("_sync"))
 _NOT_STARVED = frozenset(_SYNC + (_IDLE,))
+# when one of THESE returns the device has worked off everything that was
+# queued, and until the generator's next dispatch it has nothing to do.
+# ``first_sync`` is the one read that returns with work still queued (the
+# decode chunk behind the admission it reads): the device stays busy
+# through it and through the routing of the first frames after it.
+_DRAINS = frozenset(i for i in _SYNC if _TICK_PHASES[i] != "first_sync")
 # A tick is filed under what it held, by the executables the generator says
 # it dispatched (``_TickTimer.dispatched``): the largest bucket of a
 # bucketed admission (``b<p_pad>``), else ``admit`` (an admission of a
@@ -228,7 +238,7 @@ class _Phase:
         self.last_s = dt = now - self.t0
         self._ann.__exit__(*exc)
         timer = self.timer
-        if self.index in _SYNC:
+        if self.index in _DRAINS:
             timer.dry_t = now
         elif timer.dry_t is not None:
             timer.starve(now, self)
@@ -659,7 +669,12 @@ class DecodeEngine:
     installs on it around its dispatch, its blocking read and its
     bookkeeping (``decode_dispatch`` / ``decode_sync`` / ``route``); it
     calls the ``dispatched(kind, key)`` hook installed beside it wherever
-    it has queued an executable.
+    it has queued an executable. A generator whose admissions draw their
+    rows' first tokens (:class:`RollingGenerator`) hands them to the
+    ``first_frames(events)`` hook installed beside those, behind the
+    chunk's dispatch and ahead of its read (``first_sync``, then a
+    ``route`` of its own): a request's first frame holds its first token
+    alone and leaves while the device works off the chunk.
     Prefix sharing additionally uses ``register_prefix/drop_prefix`` and
     the ``prefill_tokens`` counter; session park/restore uses
     ``export_row/import_row``; speculative engines (``engine.spec``)
@@ -817,6 +832,9 @@ class DecodeEngine:
         self._timer = _TickTimer(self._row_cap_tokens)
         engine.tick_phase = self._timer
         engine.dispatched = self._timer.dispatched
+        # a fresh row's first token, read behind the decode chunk's
+        # dispatch, is routed while the chunk runs (a sim draws none)
+        engine.first_frames = self._first_frames_locked
         self._exec_counts: Dict[str, int] = {}
         # seconds-per-row-freed EMA — the admission estimate's clock
         # (same role the session's ema_exec_s plays for call shedding)
@@ -1203,8 +1221,9 @@ class DecodeEngine:
             "pending": int(eng.pending),
             "steps": self._steps,
             "tokens": self._tokens,
-            # the HOST's wall of decode dispatch + blocking read (tick
-            # phases decode_dispatch + decode_sync), not device time
+            # the HOST's wall from a decode chunk's dispatch to the end of
+            # its blocking read (tick phases decode_dispatch .. decode_sync),
+            # not device time
             "device_s": round(self._device_s, 6),
             "prefill_chunks": self._prefill_chunks,
             "admitted_rows": self._admitted,
@@ -1929,17 +1948,24 @@ class DecodeEngine:
             # the generator times its own halves through ``tick_phase``:
             # the dispatch, the one blocking read, and its share of
             # ``route`` (trimming the chunk into events, freeing rows)
+            # (and, between the two, the read and routing of the first
+            # tokens this tick's admissions drew: ``_first_frames_locked``)
             events = eng.decode_step() if self._phase != "prefill" else []
             with timer("route"):
                 device_dt = 0.0
-                decode_tokens = timer.tokens = sum(
-                    len(t) for _, t, _ in events)
-                if events:
-                    # the host's wall of dispatch + sync, not device
-                    # time: the device also works off what admission
-                    # left in its queue, and the host's own dispatch
-                    device_dt = (timer("decode_dispatch").last_s
-                                 + timer("decode_sync").last_s)
+                timer.tokens += sum(len(t) for _, t, _ in events)
+                decode_tokens = timer.tokens
+                # a chunk ran in this tick (its rows may all have finished
+                # on their first frame, ahead of its end: no event is left)
+                chunk = timer("decode_dispatch")
+                if chunk.t0 >= timer.t0:
+                    # the host's wall from the chunk's dispatch to the end
+                    # of its read (the first tokens' read and routing lie
+                    # between the two), not device time: the device also
+                    # works off what admission left in its queue, and
+                    # the host's own dispatch
+                    sync = timer("decode_sync")
+                    device_dt = sync.t0 + sync.last_s - chunk.t0
                     self._steps += 1
                     self._device_s += device_dt
                     _record_engine("step")
@@ -2000,6 +2026,17 @@ class DecodeEngine:
                         + (f" (session {session} parking in background)"
                            if state is not None else ""),
                         deadline=dl)))
+
+    def _first_frames_locked(self, events) -> None:
+        """The generator's ``first_frames`` hook: ``events`` hold the one
+        token each that this tick's admissions drew, read when the last
+        prefill ended. Called inside the generator's ``route`` phase with
+        the decode chunk queued behind the admissions, so the frames leave
+        (and a request of one token finishes) while the device works, and
+        the driver only then blocks on the chunk."""
+        self._timer.tokens += sum(len(t) for _, t, _ in events)
+        _record_engine("first_token_at_admit", len(events))
+        self._route_locked(events)
 
     def _route_locked(self, events) -> None:
         """Frames to their sinks, and the row-free accounting."""

@@ -360,8 +360,13 @@ def _chat_admission(v5e_chip, p_pad, width=1):
              "vs": spec((layers, b, m, 8), jnp.float32)}
     args = (params, cache, spec((b, vocab), jnp.float32),
             spec((b,), jnp.int32), spec((b,), jnp.bool_),
+            spec((b,), jnp.int32), spec((b,), jnp.bool_),  # carried token
             spec((width, p_pad), jnp.int32), spec((width,), jnp.int32),
-            spec((width,), jnp.int32))
+            spec((width,), jnp.int32),
+            # the admitted rows' sampler inputs: the admission draws
+            spec((width,), jnp.float32), spec((width,), jnp.float32),
+            spec((width, 64), jnp.int32),
+            spec((2,), jnp.uint32))
     rules = ShardingRules.default()
 
     def compile():
@@ -370,8 +375,9 @@ def _chat_admission(v5e_chip, p_pad, width=1):
         try:
             return jax.jit(
                 lambda *a: RollingGenerator._prefill_impl(
-                    *a, None, p_pad=p_pad, cfg=cfg, rules=rules),
-                donate_argnums=(1, 2, 3, 4)).lower(*args).compile()
+                    *a, None, p_pad=p_pad, top_k=None, top_p=None, cfg=cfg,
+                    rules=rules),
+                donate_argnums=(1, 2, 3, 4, 5, 6)).lower(*args).compile()
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_on)
 
@@ -541,28 +547,35 @@ def test_hybrid_executables_compile_for_v5e_with_state_beside_kv(
         lambda: hybrid_linear.init_cache(cfg, b, m)))
     assert cache["k"].shape == (4, b, m, 32, 128)
     state = (spec((b, vocab), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_), spec((b,), jnp.int32),
              spec((b,), jnp.bool_))
+
+    def draw(n):
+        """A sampler's inputs for ``n`` rows."""
+        return (spec((n,), jnp.float32), spec((n,), jnp.float32),
+                spec((n, 64), jnp.int32),
+                spec((2,), jnp.uint32))
+
     rules = ShardingRules.default()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         if which == "decode":
-            key = jax.eval_shape(lambda: jax.random.key(0))
             exe = jax.jit(
                 lambda *a: RollingGenerator._decode_impl(
                     *a, None, top_k=None, top_p=None, n_steps=8, cfg=cfg,
-                    rules=rules), donate_argnums=(1, 2, 3)).lower(
-                params, cache, *state, spec((b,), jnp.float32),
-                spec((b,), jnp.float32), spec((b, 64), jnp.int32),
-                spec(key.shape, key.dtype)).compile()
+                    rules=rules), donate_argnums=(1, 2, 3, 6)).lower(
+                params, cache, *state, *draw(b)).compile()
         else:
             exe = jax.jit(
                 lambda *a: RollingGenerator._prefill_impl(
-                    *a, None, p_pad=1024, cfg=cfg, rules=rules),
-                donate_argnums=(1, 2, 3, 4)).lower(
+                    *a, None, p_pad=1024, top_k=None, top_p=None, cfg=cfg,
+                    rules=rules),
+                donate_argnums=(1, 2, 3, 4, 5, 6)).lower(
                 params, cache, *state, spec((1, 1024), jnp.int32),
-                spec((1,), jnp.int32), spec((1,), jnp.int32)).compile()
+                spec((1,), jnp.int32), spec((1,), jnp.int32),
+                *draw(1)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_on)
     text, mem = exe.as_text(), exe.memory_analysis()

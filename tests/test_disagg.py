@@ -571,6 +571,48 @@ def test_real_cross_pod_handoff_token_identical(model, local_store,
 
 
 @pytest.mark.level("minimal")
+def test_prefill_tier_ships_its_row_with_no_first_frame(model, local_store,
+                                                        monkeypatch):
+    """ISSUE 38: a prefill-tier pod decodes nothing, so the token its
+    admission drew is never read there: the handoff row ships with zero
+    tokens emitted locally and ``first_tokens_at_admit`` stays 0 beside
+    ``admitted``; the decode tier, which imports the row, sends the first
+    frame of its first chunk whole (the row carries logits, no drawn
+    token) and the stream is the monolithic one's, whose first frame holds
+    the first token alone."""
+    monkeypatch.setenv("KT_HANDOFF_CODEC", "raw")
+    prompt, n, hid = [5, 9, 13, 2], 10, "h-first-frame"
+    mono = DecodeEngine(_rolling(model), poll_s=0.002)
+    try:
+        whole = list(mono.generate({"prompt": prompt, "max_new_tokens": n}))
+    finally:
+        mono.close()
+    assert [len(f["tokens"]) for f in whole] == [1, 3, 4, 2]
+    assert mono.stats()["first_tokens_at_admit"] == 1
+    pf = DecodeEngine(_rolling(model), poll_s=0.002, phase="prefill")
+    try:
+        frames = list(pf.generate({"prompt": prompt, "max_new_tokens": n,
+                                   "handoff": {"id": hid}}))
+    finally:
+        pf.close()
+    assert [f["tokens"] for f in frames] == [[]] and frames[0]["handoff"]
+    stats = pf.stats()
+    assert stats["admitted"] == 1 and stats["first_tokens_at_admit"] == 0
+    assert stats["tick_first_sync_n"] == 0 and stats["free_rows"] == 2
+    assert not pf.engine._first_pending
+    dc = DecodeEngine(_rolling(model), poll_s=0.002, phase="decode")
+    try:
+        moved = list(dc.generate({"prompt": prompt, "max_new_tokens": n,
+                                  "handoff_id": hid}))
+    finally:
+        dc.close()
+    assert [len(f["tokens"]) for f in moved] == [4, 4, 2]
+    assert [t for f in moved for t in f["tokens"]] == [
+        t for f in whole for t in f["tokens"]]
+    assert dc.stats()["first_tokens_at_admit"] == 0 == dc.stats()["admitted"]
+
+
+@pytest.mark.level("minimal")
 def test_real_bf16_handoff_int8_wire_codec(model, local_store,
                                            monkeypatch):
     """The bf16 grid's DEFAULT handoff codec is the int8 wire codec:

@@ -25,7 +25,7 @@ from kubetorch_tpu.serving.engine import (
     SimRollingEngine,
     _TickTimer,
 )
-from test_engine_timing import _build, _run, _toy_generator
+from test_engine_timing import _build, _run, _toy_generator, program
 
 STARVED = [name for i, name in enumerate(_TICK_PHASES)
            if i not in _NOT_STARVED]
@@ -67,7 +67,8 @@ def test_every_class_and_starved_key_is_born_at_zero(kind, buckets):
     for phase in STARVED:
         assert stats[f"tick_starved_{phase}_s"] == 0.0
     assert stats["tick_starved_s"] == 0.0
-    for phase in ("evict_sync", "handoff_sync", "decode_sync", "idle"):
+    for phase in ("evict_sync", "handoff_sync", "first_sync", "decode_sync",
+                  "idle"):
         assert f"tick_starved_{phase}_s" not in stats
 
 
@@ -91,9 +92,16 @@ def test_classes_partition_the_ticks_and_their_wall(kind):
     total = timer._t_end - t_built - timer._base[_IDLE]
     assert abs(sum(c["wall_s"] for c in classes.values()) - total) < 1e-6
     assert sum(c["tokens"] for c in classes.values()) == stats["tokens"] == 40
+    # every wait for the device, the read of the admissions' first tokens
+    # (behind the chunk's dispatch) among them
     sync = sum(stats[f"tick_{p}_s"] for p in (
-        "decode_sync", "evict_sync", "handoff_sync"))
+        "decode_sync", "first_sync", "evict_sync", "handoff_sync"))
     assert abs(sum(c["sync_s"] for c in classes.values()) - sync) < 1e-6
+    if kind == "rolling":
+        assert stats["tick_first_sync_n"] >= 2       # ticks that admitted
+        assert stats["first_tokens_at_admit"] == stats["admitted"] == 5
+    else:
+        assert stats["tick_first_sync_n"] == 0       # a sim draws none
     # starved seconds: by phase and by class, one sum (the stretch after
     # the last tick's blocking read is in no tick yet)
     by_phase = sum(stats[f"tick_starved_{p}_s"] for p in STARVED)
@@ -251,6 +259,127 @@ def test_route_after_the_read_is_starved_and_dispatch_after_admit_is_not():
                                       + after["tick_handoff_s"]
                                       + after["tick_decode_dispatch_s"]
                                       + 1e-6)
+
+
+# ------------------- the read that returns with the chunk still queued
+@pytest.mark.level("unit")
+def test_first_read_is_a_wait_that_leaves_the_device_busy():
+    """ISSUE 38: ``first_sync`` returns when the admission ends, with the
+    decode chunk queued behind it. Its seconds are a wait for the device
+    (the class's ``sync_s``), it does not start a dry stretch, and the
+    routing of the first frames after it is host time under a busy device:
+    no starved second comes from either, however long the chunk runs."""
+    timer = _TickTimer(64)
+    _tick(timer)                                       # ends with a dry queue
+    before = timer.stats()
+    with timer:
+        with timer("admit"):
+            timer.dispatched("prefill", (1, 64))
+        with timer("decode_dispatch"):
+            timer.dispatched("decode", 4)
+        with timer("first_sync"):
+            time.sleep(0.01)                           # the prefill's wall
+        assert timer.dry_t is None
+        with timer("route"):
+            time.sleep(0.02)                           # the first frames
+        assert timer.dry_t is None
+        with timer("decode_sync"):
+            time.sleep(0.03)                           # the chunk's wall
+        assert timer.dry_t is not None
+        with timer("route"):
+            time.sleep(0.005)                          # the chunk's frames
+    after = timer.stats()
+    grew = {p: after[f"tick_starved_{p}_s"] - before[f"tick_starved_{p}_s"]
+            for p in STARVED}
+    assert after["tick_route_s"] - before["tick_route_s"] >= 0.025
+    assert 0.005 <= grew["route"] < 0.015              # the late route only
+    assert grew["decode_dispatch"] == 0.0
+    # the dry stretch from the last tick's read to the prefill's dispatch
+    # is all that is left: well under the chunk's wall
+    assert sum(grew.values()) - grew["route"] < 0.02
+    assert (after["tick_class_b64_sync_s"]
+            - before["tick_class_b64_sync_s"]) >= 0.04
+    assert after["tick_first_sync_n"] - before["tick_first_sync_n"] == 1
+
+
+class _FirstFrames(SimRollingEngine):
+    """A sim whose admissions draw a first token as the generator's do: read
+    behind the chunk's dispatch (``first_s``: what is left of the prefill),
+    routed through the engine's hook (``route_s`` of bookkeeping first),
+    and only then the wait for the chunk (``step_s``). (Its chunk still adds
+    ``steps_per_call`` tokens to a fresh row, where the generator's adds
+    one fewer: the accounting under test does not care.)"""
+
+    first_s = 0.01
+    route_s = 0.02
+    first_frames = None
+
+    def admit(self, max_rows=None):
+        rids = super().admit(max_rows)
+        self._fresh = [self._rows[rid] for rid in rids]
+        return rids
+
+    def decode_step(self):
+        if not self._rows:
+            return []
+        with self.tick_phase("decode_dispatch"):
+            self.dispatched("decode", self.steps_per_call)
+        fresh, self._fresh = getattr(self, "_fresh", []), []
+        if fresh:
+            with self.tick_phase("first_sync"):
+                time.sleep(self.first_s)
+            with self.tick_phase("route"):
+                time.sleep(self.route_s)
+                events = []
+                for req in fresh:
+                    req["emitted"] = 1
+                    events.append((req["rid"], self.expected_tokens(
+                        req["prompt"], 1), req["n"] == 1))
+                    if req["n"] == 1:
+                        self._free.append(req["slot"])
+                        del self._rows[req["rid"]]
+                self.first_frames(events)
+        with self.tick_phase("decode_sync"):
+            time.sleep(self.step_s)
+        with self.tick_phase("route"):
+            return self._emit_events()
+
+
+@pytest.mark.level("minimal")
+def test_first_frames_leave_mid_tick_and_the_tick_stays_whole():
+    """Through the engine: the first frame arrives a chunk's wall before the
+    second, the starved seconds do not grow by the chunk's wall or by the
+    routing under the busy device, and the classes still hold every tick,
+    every token and every wait of the window."""
+    sim = _FirstFrames(max_slots=2, steps_per_call=4, step_s=0.05)
+    engine = DecodeEngine(sim)
+    stamps = []
+    try:
+        for frame in engine.generate(program([1, 2, 3], max_new_tokens=8)):
+            stamps.append((time.perf_counter(), frame["tokens"]))
+        one = _run(engine, [[4, 5]], n_new=1)[0]
+    finally:
+        engine.close()
+    stats = engine.stats()
+    assert [len(toks) for _, toks in stamps] == [1, 4, 3]
+    assert [t for _, toks in stamps for t in toks] == (
+        SimRollingEngine.expected_tokens([1, 2, 3], 8))
+    assert stamps[1][0] - stamps[0][0] >= sim.step_s * 0.9
+    assert [(f["tokens"], f["done"]) for f in one] == [
+        (SimRollingEngine.expected_tokens([4, 5], 1), True)]
+    classes = _classes(stats)
+    assert stats["tick_class_admit_n"] == 2 and stats["ticks"] == 3
+    assert sum(c["tokens"] for c in classes.values()) == stats["tokens"] == 9
+    sync = sum(stats[f"tick_{p}_s"] for p in ("decode_sync", "first_sync"))
+    assert abs(sum(c["sync_s"] for c in classes.values()) - sync) < 1e-6
+    assert stats["tick_first_sync_s"] >= 2 * sim.first_s
+    assert stats["tick_route_s"] >= 2 * sim.route_s
+    # three chunks' walls and two early routings were waited and worked
+    # through: none of it is the device starving
+    assert stats["tick_starved_route_s"] < 2 * sim.route_s
+    assert stats["tick_starved_s"] < sim.step_s
+    assert stats["engine_admit_to_first_seconds_count"] == 2
+    assert stats["engine_admit_to_first_seconds_sum"] < 2 * sim.step_s
 
 
 # --------------------------------------- the dispatch in the profiler's trace

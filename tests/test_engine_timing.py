@@ -291,6 +291,97 @@ def test_phases_are_host_events_of_the_profilers_trace(tmp_path):
         marks["kt.req.first_frame"])
 
 
+# ------------- (c') the first frame leaves before the chunk's read ends
+def _first_frame_engine(decoder):
+    from test_rolling import _toy_engine
+
+    return DecodeEngine(_toy_engine(decoder, max_slots=2))
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("decoder", ["dense", "latent", "hybrid"])
+def test_first_frame_is_marked_before_the_chunks_read_ends(decoder,
+                                                           tmp_path):
+    """ISSUE 38: in the tick that admits a row, the read of the token its
+    admission drew (``kt.tick.first_sync``) follows the decode chunk's
+    dispatch and precedes the chunk's own read, and the row's
+    ``kt.req.first_frame`` mark lies between the two reads: the frame has
+    left when the driver starts to wait for the chunk."""
+    from jax.profiler import ProfileData
+
+    engine = _first_frame_engine(decoder)
+    try:
+        _run(engine, [[1, 2, 3]], n_new=4)           # compile outside
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _run(engine, [[1, 2, 3, 4], [9, 8]], n_new=8)
+        finally:
+            jax.profiler.stop_trace()
+        stats = engine.stats()
+    finally:
+        engine.close()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    planes = {p.name: p for p in ProfileData.from_file(path).planes}
+    (events,) = [ev for ev in (
+        [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events]
+        for line in planes["/host:CPU"].lines)
+        if any(name == "kt.tick" for name, *_ in ev)]
+    ticks = [(s, e) for name, s, e in events if name == "kt.tick"]
+    marks = [s for name, s, _ in events if name == "kt.req.first_frame"]
+    assert len(marks) == 2
+
+    def inside(tick, name):
+        return [(s, e) for n, s, e in events
+                if n == name and tick[0] <= s and e <= tick[1]]
+
+    for mark in marks:
+        (tick,) = [t for t in ticks if t[0] <= mark <= t[1]]
+        (dispatch,) = inside(tick, "kt.tick.decode_dispatch")
+        (first,) = inside(tick, "kt.tick.first_sync")
+        (sync,) = inside(tick, "kt.tick.decode_sync")
+        assert inside(tick, "kt.tick.admit")
+        # never between the two dispatches, always ahead of the chunk's read
+        assert dispatch[1] <= first[0] and first[1] <= mark <= sync[0]
+    assert stats["first_tokens_at_admit"] == stats["admitted"] == 3
+    assert stats["admitted_rows"] == 3
+    assert stats["tick_first_sync_n"] >= 2
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("decoder", ["dense", "latent", "hybrid"])
+def test_first_frame_holds_the_first_token_alone(decoder):
+    """The stream of a program: one token, then what is left of the first
+    chunk, then whole chunks; a request of one token finishes on its first
+    frame and its row is free when the stream ends; and the tokens are those
+    of the generator driven by hand."""
+    from test_rolling import _toy_engine
+
+    by_hand = _toy_engine(decoder, max_slots=2)
+    rids = [by_hand.submit(p, max_new_tokens=n)
+            for p, n in (([1, 2, 3, 4], 10), ([9, 8], 1))]
+    want = by_hand.run()
+    engine = _first_frame_engine(decoder)
+    try:
+        long, short = _run(engine, [[1, 2, 3, 4]], n_new=10)[0], _run(
+            engine, [[9, 8]], n_new=1)[0]
+    finally:
+        engine.close()
+    # read once the driver is gone: the short stream ended on its first
+    # frame, in the middle of the tick that admitted it
+    stats = engine.stats()
+    assert [len(f["tokens"]) for f in long] == [1, 3, 4, 2]
+    assert [f["done"] for f in long] == [False, False, False, True]
+    assert [t for f in long for t in f["tokens"]] == want[rids[0]]
+    assert [(f["tokens"], f["done"]) for f in short] == [
+        (want[rids[1]], True)]
+    assert stats["free_rows"] == 2 and stats["pending"] == 0
+    assert stats["tokens"] == 11
+    assert stats["first_tokens_at_admit"] == stats["admitted"] == 2
+    # both ticks that admitted dispatched a chunk, and count as steps
+    assert stats["ticks"] == stats["tick_decode_dispatch_n"] == 4
+
+
 # ------------------------------------------ (d) executables carry names
 @pytest.mark.level("minimal")
 def test_jitted_attributes_are_named_for_their_implementations():
@@ -307,8 +398,7 @@ def test_jitted_attributes_are_named_for_their_implementations():
     import jax.numpy as jnp
 
     ctx = jnp.zeros((2, 8), jnp.int32)
-    valid = jnp.zeros((2,), bool)
-    text = gen._ctx_admit.lower(ctx, valid, ctx[:1], jnp.zeros(
+    text = gen._ctx_admit.lower(ctx, ctx[:1], jnp.zeros(
         (1,), jnp.int32)).as_text()
     assert "module @jit__ctx_admit_impl" in text
     plain = _toy_generator()
@@ -316,9 +406,10 @@ def test_jitted_attributes_are_named_for_their_implementations():
     plain.admit()
     lowered = plain._decode.lower(
         plain.params, plain.cache, plain._logits, plain._dpos,
-        plain._dactive, jnp.asarray(plain._temps),
+        plain._dactive, plain._dnt, plain._dnt_valid,
+        jnp.asarray(plain._temps),
         jnp.asarray(plain._penalties), jnp.asarray(plain._win),
-        jax.random.key(0), None, top_k=plain.top_k, top_p=plain.top_p,
+        plain._draw_key(), None, top_k=plain.top_k, top_p=plain.top_p,
         n_steps=plain.steps_per_call).as_text()
     assert "module @jit__decode_impl" in lowered
     assert "unknown" not in lowered.splitlines()[0]

@@ -392,13 +392,16 @@ def test_an_admission_computes_nothing_the_size_of_a_plane(kind, n):
     whole grid leaf (or a layer of one) except the in-place slice updates
     and the loop that carries the leaves, and no gather, scatter or select
     that reads or makes a leaf of the grid or of the own cache: the scatters
-    left are the ``[B, V]`` and ``[B]`` per-slot sets."""
+    left are the ``[B, V]`` and ``[B]`` per-slot sets (the rows' first
+    tokens, drawn by the admission, among them)."""
     grid, own, _ = _admission(kind, n, 64)
     vocab = 32
     jaxpr = jax.make_jaxpr(RollingGenerator._finish_admit)(
         grid, own, jnp.zeros((n, vocab)), jnp.zeros((B, vocab)),
         jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
-        jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32))
+        jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
+        jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32),
+        jnp.zeros((n,), jnp.int32))
     planes = {v.shape for v in grid.values()} | {
         v.shape[1:] for v in grid.values()}
     eqns = list(_eqns(jaxpr.jaxpr))

@@ -1017,3 +1017,191 @@ def test_ragged_decode_kernel_matches_einsum_engine(kvd, monkeypatch):
     assert s_eng["decode_kv_positions_read"] < \
         s_eng["decode_kv_positions_grid"]
     assert s_eng["decode_kv_positions_read"] % 128 == 0
+
+
+# ---------------------------------------------------------------------
+# ISSUE 38: the admission draws the row's first token, the decode chunk
+# behind it takes it as given, and the host reads it behind that chunk's
+# dispatch.
+DECODERS = ("dense", "latent", "hybrid")
+
+
+def _toy_engine(decoder, **kw):
+    from test_grid_write import _toy
+
+    params, cfg, _ = _toy(decoder)
+    kw.setdefault("max_slots", 3)
+    kw.setdefault("max_len", 96)
+    kw.setdefault("steps_per_call", 4)
+    return RollingGenerator(params, cfg, **kw)
+
+
+def _as_the_parent(eng):
+    """Drive ``step()`` as it ran before the admission drew anything: the
+    fresh rows' carried tokens are dropped after every admission, so step 0
+    of the next chunk draws each one's first token from its prefill's
+    logits."""
+    import jax.numpy as jnp
+
+    eng.admit()
+    eng.prefill_step()
+    fresh = np.zeros(eng.max_slots, bool)
+    fresh[list(eng._first_pending)] = True
+    eng._first_pending.clear()
+    eng._dnt_valid = eng._dnt_valid & ~jnp.asarray(fresh)
+    return eng.decode_step()
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("decoder", DECODERS)
+def test_first_token_at_admission_keeps_the_greedy_lists(decoder):
+    """``step()`` by hand returns the token lists it always did, chunk by
+    chunk and in the rows' order: the token the admission drew heads the
+    row's list of its first chunk. A request of one token and one cut by a
+    stop sequence on its first token finish there. And every admitted row's
+    first token was read at its admission."""
+    prompts = [([1, 2, 3, 4, 5], 9, None), ([9, 8, 7], 1, None),
+               ([11, 22, 33, 44, 55, 66, 7], 6, None), ([5, 4], 3, None)]
+    want_eng = _toy_engine(decoder)
+    for p, n, _ in prompts:
+        want_eng.submit(p, max_new_tokens=n)
+    want = []
+    while want_eng.pending:
+        want.append(_as_the_parent(want_eng))
+    assert want_eng.stats()["first_tokens_at_admit"] == 0
+    # a stop sequence that is the first token the third prompt emits
+    first = next(toks[0] for chunk in want for rid, toks, _ in chunk
+                 if rid == 2)
+    for stops in (None, [[first]]):
+        eng = _toy_engine(decoder)
+        for p, n, _ in prompts:
+            eng.submit(p, max_new_tokens=n, stop=stops)
+        got = []
+        while eng.pending:
+            got.append(eng.step())
+        if stops is None:
+            assert got == want
+        else:
+            third = [(toks, done) for chunk in got for rid, toks, done
+                     in chunk if rid == 2]
+            assert third == [([first], True)]
+        stats = eng.stats()
+        assert stats["admitted"] == len(prompts)
+        assert stats["first_tokens_at_admit"] == stats["admitted"]
+        assert eng.free_rows == 3 and not eng._first_pending
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("decoder", DECODERS)
+def test_a_sampled_rows_first_token_is_the_one_its_chunk_forwards(decoder):
+    """A sampled row must not draw twice: the token that went out at the
+    admission is the one step 0 of the decode chunk feeds the model. The
+    served sequence is re-scored by a plain forward (a second engine's
+    prefill over prompt + served tokens): its logits after the chunk's last
+    token are the generator's carried logits only if the cache holds the
+    served first token, and not if it holds another."""
+    eng = _toy_engine(decoder, seed=7)
+    prompts = [[1, 2, 3, 4, 5], [9, 8, 7], [3, 1, 4, 1, 5, 9, 2, 6]]
+    sent = []
+    eng.first_frames = sent.extend          # as the serving engine hears it
+    rids = [eng.submit(p, max_new_tokens=20, temperature=3.0)
+            for p in prompts]
+    eng.admit()
+    drawn = {slot: int(np.asarray(first)[i])
+             for slot, (first, i) in eng._first_pending.items()}
+    assert np.asarray(eng._dnt_valid).all()
+    served = {rid: toks for rid, toks, _ in eng.decode_step()}
+    assert not np.asarray(eng._dnt_valid).any()         # spent
+    # the first frame held the drawn token alone, the chunk's the other 3
+    assert sorted(sent) == sorted(
+        (req.rid, [drawn[slot]], False) for slot, req in eng._slots.items())
+    assert all(len(toks) == 3 for toks in served.values())
+    scorer = _toy_engine(decoder)
+
+    def rescored(seq):
+        scorer.submit(seq, max_new_tokens=1)
+        scorer.admit()
+        (slot,) = scorer._slots
+        out = np.asarray(scorer._logits[slot])
+        scorer.run()
+        return out
+
+    for slot, req in eng._slots.items():
+        seq = prompts[rids.index(req.rid)] + [drawn[slot]] + served[req.rid]
+        assert req.tokens == seq[len(req.prompt):]
+        carried = np.asarray(eng._logits[slot])
+        np.testing.assert_allclose(rescored(seq), carried, rtol=2e-3,
+                                   atol=2e-3)
+        other = list(seq)
+        other[len(req.prompt)] = (drawn[slot] + 1) % eng.cfg.vocab_size
+        assert np.abs(rescored(other) - carried).max() > 0.02
+    # and the admission did sample: greedy rows would have taken others
+    want = _toy_engine(decoder)
+    for p in prompts:
+        want.submit(p, max_new_tokens=20)
+    want.admit()
+    assert drawn != {slot: int(np.asarray(first)[i]) for slot, (first, i)
+                     in want._first_pending.items()}
+
+
+@pytest.mark.level("minimal")
+@pytest.mark.parametrize("path", ["prefix", "chunked", "spec", "penalty"])
+def test_every_admission_path_draws_its_first_token(model, path):
+    """Prefix admissions, a chunked prefill's last chunk, a speculating
+    generator and a row under a repetition penalty: the lists of ``step()``
+    are the parent's, and every row's first token was read at its
+    admission."""
+    params, cfg = model
+    kw = {"chunked": dict(prefill_chunk=8), "spec": dict(spec_k=4)}.get(
+        path, {})
+    submit = {"penalty": dict(repetition_penalty=1.7)}.get(path, {})
+    prompts = [list(range(1, 20)), [9, 8, 7], [5, 5, 5, 5, 6, 5, 5]]
+
+    def build():
+        eng = RollingGenerator(params, cfg, max_slots=2, max_len=96,
+                               steps_per_call=4, **kw)
+        if path == "prefix":
+            submit["prefix_id"] = eng.register_prefix([7, 7, 3, 1])
+        for p in prompts:
+            eng.submit(p, max_new_tokens=7, **submit)
+        return eng
+
+    want_eng, eng = build(), build()
+    want, got = [], []
+    while want_eng.pending:
+        want.append(_as_the_parent(want_eng))
+    while eng.pending:
+        got.append(eng.step())
+    assert got == want
+    stats = eng.stats()
+    assert stats["first_tokens_at_admit"] == stats["admitted"] == 3
+
+
+@pytest.mark.level("minimal")
+def test_a_row_that_leaves_before_it_decodes_takes_its_token_along(model):
+    """An admitted row exported or evicted before any chunk: its drawn token
+    is not read (the counter says so), is not the slot's next occupant's,
+    and the exported row, imported elsewhere, draws from its logits."""
+    params, cfg = model
+    gen = Generator(params, cfg)
+    iso = gen.generate([[9, 8, 7]], max_new_tokens=6, temperature=0.0)[0]
+    eng = RollingGenerator(params, cfg, max_slots=1, max_len=96,
+                           steps_per_call=4)
+    rid = eng.submit([1, 2, 3, 4], max_new_tokens=6)
+    eng.admit()
+    state = eng.export_row(rid)
+    assert len(np.asarray(state["tokens"])) == 0     # a handoff's zero tokens
+    eng.evict(rid)
+    assert not eng._first_pending
+    assert not np.asarray(eng._dnt_valid).any()
+    # the next occupant arrives by import: no carried token, its own logits
+    other = RollingGenerator(params, cfg, max_slots=1, max_len=96,
+                             steps_per_call=4)
+    r2 = other.submit([9, 8, 7], max_new_tokens=6)
+    other.admit()
+    eng.import_row(other.export_row(r2))
+    out = [t for chunk in iter(eng.step, []) for _, toks, _ in chunk
+           for t in toks]
+    assert out == iso
+    stats = eng.stats()
+    assert stats["admitted"] == 1 and stats["first_tokens_at_admit"] == 0
