@@ -10,7 +10,8 @@ O(1) in depth.
 
 from kubetorch_tpu.models.configs import (HybridLinearConfig,
                                           LatentMoEConfig, LlamaConfig,
-                                          MoEConfig, ViTConfig)
+                                          MoEConfig, ViTConfig,
+                                          WindowMoEConfig)
 from kubetorch_tpu.models import llama
 
 
@@ -21,7 +22,8 @@ def __getattr__(name):
     import importlib
 
     if name in ("generate", "quant", "rolling", "speculative", "lora",
-                "embed", "decoder", "latent_moe", "hybrid_linear"):
+                "embed", "decoder", "latent_moe", "hybrid_linear",
+                "window_moe"):
         return importlib.import_module(f"kubetorch_tpu.models.{name}")
     if name == "LoraConfig":
         return importlib.import_module(
@@ -45,8 +47,9 @@ def __getattr__(name):
 
 
 __all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig",
-           "HybridLinearConfig", "ViTConfig",
-           "decoder", "latent_moe", "hybrid_linear", "llama", "Generator",
+           "HybridLinearConfig", "WindowMoEConfig", "ViTConfig",
+           "decoder", "latent_moe", "hybrid_linear", "window_moe", "llama",
+           "Generator",
            "generate", "quant", "quantize_params", "RollingGenerator",
            "SpeculativeGenerator", "speculative", "lora", "LoraConfig",
            "embed", "Embedder"]

@@ -258,6 +258,90 @@ class HybridLinearConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class WindowMoEConfig:
+    """Decoder whose attention layers are of two kinds
+    (``models/window_moe.py``; the layer as the ``smallthinker`` public
+    config writes it): ``full_attention`` layers see every earlier position
+    and carry NO position encoding, ``window_attention`` layers rotate
+    queries and keys (``rope_theta``, over the whole head, halves layout)
+    and see the last ``window`` positions only (key ``j`` by query ``i`` iff
+    ``i - window < j <= i``), so what a row keeps of such a layer is a ring
+    of ``window`` positions. Both are grouped-query attention (``n_heads``
+    over ``n_kv_heads`` of ``head_dim``). Every layer routes each token to
+    ``top_k`` of ``n_experts`` ReGLU experts of ``expert_mlp_dim`` (``down
+    (relu(gate x) * up x)``) by a float32 router that reads the layer's
+    INPUT, ahead of the input norm and of attention; the weights are the
+    softmax over the chosen scores. No shared expert, no bias."""
+
+    vocab_size: int = 151936
+    embed_dim: int = 2560
+    layer_types: Tuple[str, ...] = (("full_attention",)
+                                    + ("window_attention",) * 3) * 2
+    n_heads: int = 28
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    window: int = 4096
+    rope_theta: float = 1.5e6
+    n_experts: int = 64
+    top_k: int = 6
+    expert_mlp_dim: int = 768
+    rms_eps: float = 1e-6
+    max_seq_len: int = 16384
+    dtype: str = "bfloat16"        # compute dtype
+    param_dtype: str = "bfloat16"  # storage dtype
+
+    KINDS: ClassVar[Tuple[str, ...]] = ("full_attention",
+                                        "window_attention")
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        bad = sorted(set(self.layer_types) - set(self.KINDS))
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types must name {self.KINDS}, "
+                             f"got {bad or 'no layer'}")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("n_heads must be a multiple of n_kv_heads")
+        if self.top_k > self.n_experts:
+            raise ValueError(f"top_k {self.top_k} > n_experts "
+                             f"{self.n_experts}")
+        if self.window < 1 or self.head_dim % 2:
+            raise ValueError("window must be >= 1 and head_dim even")
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def storage_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_full_layers(self) -> int:
+        return self.layer_types.count("full_attention")
+
+    @property
+    def n_window_layers(self) -> int:
+        return self.layer_types.count("window_attention")
+
+    @classmethod
+    def tiny(cls, **kw) -> "WindowMoEConfig":
+        """CI config: one period and one layer (F W W W F), float32."""
+        base = dict(vocab_size=512, embed_dim=64,
+                    layer_types=("full_attention",)
+                    + ("window_attention",) * 3 + ("full_attention",),
+                    n_heads=8, n_kv_heads=4, head_dim=16, window=16,
+                    rope_theta=10000.0, n_experts=8, top_k=2,
+                    expert_mlp_dim=32, max_seq_len=64,
+                    dtype="float32", param_dtype="float32")
+        base.update(kw)
+        return cls(**base)
+
+
+@dataclasses.dataclass(frozen=True)
 class ViTConfig:
     """ViT-L/16-style image classifier (BASELINE config #4)."""
 

@@ -28,7 +28,16 @@ object it was built with (``decoder_for(cfg)``):
   the leaf (both decoders before the third), the full-attention layers for
   the hybrid's ``k`` and ``v``, the linear ones for its ``state``. The
   cache is one flat dict of both sorts; ``row_leaves(model, cfg)`` names the
-  second.
+  second. A positional leaf has a **span** of its own (``CacheLeaf.span``):
+  None is the grid's ``max_len`` (position ``p`` at ``leaf[:, slot, p]``); a
+  number is a RING of that many positions (``[L, B, span, *shape]``,
+  position ``p`` at ``leaf[:, slot, p % span]``: a row at depth ``d`` holds
+  its last ``min(d, span)`` positions there, what a window layer can still
+  see). A ring is what the row holds of those layers, so it is exported and
+  imported whole beside the depth it belongs to; a freed row's ring needs
+  no clearing (a ring is read to ``min(depth, span)`` as a plane is read
+  to its depth). ``ring_leaves(model, cfg)`` names the rings with their
+  spans, ``off_grid_leaves`` every leaf that does not lie along ``max_len``.
 - ``init_cache(cfg, batch, max_len, dtype=None, quantized=False)``,
   ``init_cache_like(cfg, cache, batch, max_len)`` (a private cache of the
   grid's own leaves and dtypes, for a bucketed prefill) and
@@ -70,10 +79,16 @@ object it was built with (``decoder_for(cfg)``):
 - ``check_serving(cfg, **features)``: raises for a serving feature the
   decoder does not carry, naming the feature.
 
+- a decoder with rings also gives ``window_key_blocks(cfg, p_pad)``: the
+  key blocks its window layers' admission attention visits for one row of
+  a ``p_pad`` bucket, and those the band touches, a layer (what
+  ``prefill_window_key_blocks`` / ``_band`` count).
+
 ``models/llama.py`` is the first instance (``LlamaDecoder`` only names its
 functions; its executables are the ones they were), ``models/latent_moe.py``
 the second, ``models/hybrid_linear.py`` the third and the first with
-row-state leaves.
+row-state leaves, ``models/window_moe.py`` the fourth and the first with
+rings.
 """
 
 from __future__ import annotations
@@ -89,6 +104,8 @@ class CacheLeaf(NamedTuple):
     shape: Tuple[int, ...]      # of one position (or one row) of one layer
     dtype: Any
     positional: bool = True     # False: a row-state leaf, no position axis
+    span: Optional[int] = None  # positional: None = the grid's max_len; n =
+    #                             a ring of n positions, p held at p % n
 
 
 def row_leaves(model, cfg) -> FrozenSet[str]:
@@ -99,10 +116,26 @@ def row_leaves(model, cfg) -> FrozenSet[str]:
                      for leaf in leaves if not leaf.positional)
 
 
+def ring_leaves(model, cfg) -> Dict[str, int]:
+    """name -> span of the positional leaves held as a ring (``[L, B, span,
+    ...]``, position ``p`` at ``p % span``); {} for a decoder whose every
+    positional leaf lies along the grid's ``max_len``."""
+    return {leaf.name: leaf.span
+            for leaves in model.cache_leaves(cfg).values()
+            for leaf in leaves if leaf.positional and leaf.span is not None}
+
+
+def off_grid_leaves(model, cfg) -> FrozenSet[str]:
+    """Names of the leaves that do not lie along the grid's ``max_len``:
+    the row-state leaves and the rings (what ``grid_dims`` leaves out)."""
+    return row_leaves(model, cfg) | frozenset(ring_leaves(model, cfg))
+
+
 def grid_dims(cache: Dict[str, Any], rows=()) -> Tuple[int, int]:
     """``(rows, positions)`` of a cache's grid: ``B`` and ``M`` of its
     positional leaves (``[L, B, M, ...]``, each with its own ``L``), which
-    agree; ``rows`` names the row-state leaves, which have no ``M``."""
+    agree; ``rows`` names the leaves with no ``M`` or one of their own (the
+    row-state leaves and the rings: ``off_grid_leaves``)."""
     dims = {leaf.shape[1:3] for name, leaf in cache.items()
             if name not in rows}
     if len(dims) != 1:
@@ -111,17 +144,25 @@ def grid_dims(cache: Dict[str, Any], rows=()) -> Tuple[int, int]:
     return dims.pop()
 
 
-def _leaf_bytes(model, cfg, quantized: bool, positional: bool) -> int:
+def _leaf_bytes(model, cfg, quantized: bool, positional: bool,
+                ring: bool = False) -> int:
     leaves = model.cache_leaves(cfg, quantized)
     return sum(math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
                for kind in model.layer_kinds(cfg) for leaf in leaves[kind]
-               if leaf.positional == positional)
+               if leaf.positional == positional
+               and (leaf.span is not None) == ring)
 
 
 def position_bytes(model, cfg, quantized: bool = False) -> int:
-    """Bytes one position holds over the layers that keep positions: the
-    positional leaves of each layer's kind."""
+    """Bytes one position holds over the layers that keep every position:
+    the positional leaves along ``max_len`` of each layer's kind."""
     return _leaf_bytes(model, cfg, quantized, True)
+
+
+def ring_position_bytes(model, cfg, quantized: bool = False) -> int:
+    """Bytes one position holds over the layers that keep a ring: a row
+    holds ``min(depth, span)`` of them (0 for a decoder with no ring)."""
+    return _leaf_bytes(model, cfg, quantized, True, ring=True)
 
 
 def row_bytes(model, cfg, quantized: bool = False) -> int:
@@ -229,7 +270,8 @@ class LlamaDecoder:
 def decoder_for(cfg):
     """The decoder of a configuration object, by its type."""
     from kubetorch_tpu.models.configs import (HybridLinearConfig,
-                                              LatentMoEConfig, LlamaConfig)
+                                              LatentMoEConfig, LlamaConfig,
+                                              WindowMoEConfig)
 
     if isinstance(cfg, LlamaConfig):
         return LlamaDecoder
@@ -241,5 +283,9 @@ def decoder_for(cfg):
         from kubetorch_tpu.models.hybrid_linear import HybridLinearDecoder
 
         return HybridLinearDecoder
+    if isinstance(cfg, WindowMoEConfig):
+        from kubetorch_tpu.models.window_moe import WindowMoEDecoder
+
+        return WindowMoEDecoder
     raise TypeError(f"no decoder for a configuration of type "
                     f"{type(cfg).__name__}")

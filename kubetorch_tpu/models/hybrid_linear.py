@@ -280,29 +280,27 @@ def _runs(kinds: Tuple[str, ...]) -> List[Tuple[str, int]]:
     return runs
 
 
-def _scan_layers(params, cfg: HybridLinearConfig, carry, body):
-    """Run ``body(carry, layer, i, kind) -> carry`` over the layers in
-    order, ``layer`` the kind's leaves at index ``i`` of its stack. The
-    layer pattern is cut into runs of one kind, the shortest repeating unit
-    of runs is the body of one ``lax.scan`` over its repeats and a run of
-    several layers is a ``lax.scan`` inside it, so each kind's layer is
-    compiled once a place in the unit, not once a layer."""
-    runs = _runs(cfg.layer_types)
+def scan_runs(layer_types: Tuple[str, ...], carry, step):
+    """Run ``step(carry, kind, first, j) -> carry`` over the layers in
+    order, ``first + j`` the layer's index among the layers of its kind (the
+    run's first and the place in the run, handed in apart: the sum is the
+    caller's to form where it reads it). The layer pattern is
+    cut into runs of one kind, the shortest repeating unit of runs is the
+    body of one ``lax.scan`` over its repeats and a run of several layers is
+    a ``lax.scan`` inside it, so each kind's layer is compiled once a place
+    in the unit, not once a layer."""
+    runs = _runs(layer_types)
     unit = next(n for n in range(1, len(runs) + 1)
                 if len(runs) % n == 0
                 and runs == runs[:n] * (len(runs) // n))
     per_unit = {kind: sum(c for k, c in runs[:unit] if k == kind)
-                for kind in (LINEAR, FULL)}
-
-    def layer_at(kind, i):
-        return {name: jax.lax.dynamic_index_in_dim(leaf, i, 0, False)
-                for name, leaf in params[STACK[kind]].items()}
+                for kind in dict.fromkeys(layer_types)}
 
     def one_unit(carry, r):
         first = {kind: r * per_unit[kind] for kind in per_unit}
         for kind, count in runs[:unit]:
             def one(carry, j, kind=kind, at=first[kind]):
-                return body(carry, layer_at(kind, at + j), at + j, kind), None
+                return step(carry, kind, at, j), None
 
             if count == 1:
                 carry, _ = one(carry, jnp.int32(0))
@@ -317,6 +315,19 @@ def _scan_layers(params, cfg: HybridLinearConfig, carry, body):
         return one_unit(carry, jnp.int32(0))[0]
     return jax.lax.scan(one_unit, carry,
                         jnp.arange(repeats, dtype=jnp.int32))[0]
+
+
+def _scan_layers(params, cfg: HybridLinearConfig, carry, body):
+    """Run ``body(carry, layer, i, kind) -> carry`` over the layers in
+    order (``scan_runs``), ``layer`` the kind's leaves at index ``i`` of its
+    stack."""
+    def layer_at(kind, i):
+        return {name: jax.lax.dynamic_index_in_dim(leaf, i, 0, False)
+                for name, leaf in params[STACK[kind]].items()}
+
+    return scan_runs(
+        cfg.layer_types, carry, lambda carry, kind, at, j: body(
+            carry, layer_at(kind, at + j), at + j, kind))
 
 
 def _embed(params, tokens):

@@ -289,11 +289,14 @@ def route(m, router, bias, cfg: LatentMoEConfig):
 
 
 def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
-                   cfg: LatentMoEConfig):
+                   cfg, act=jax.nn.silu):
     """Sum over each token's chosen experts, dropless. m [n,E]; ``valid``
     [n] bool (a padded or inactive position is given to no expert);
     ``we_*_all`` the STACKED expert weights [Lm,X,..] and ``li`` this
-    layer's index in them. Returns (y [n,E], counters)."""
+    layer's index in them. ``cfg`` gives ``top_k`` and ``n_experts``;
+    ``act`` is the gate's activation (SiLU here; ``models/window_moe.py``
+    hands in ReLU and its own configuration). Returns (y [n,E],
+    counters)."""
     n, E = m.shape
     K, X = cfg.top_k, cfg.n_experts
     with jax.named_scope("moe_experts"):
@@ -305,7 +308,7 @@ def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
         xs = m[order // K]                                       # [n*K, E]
         h = grouped_matmul.grouped_matmul(xs, we_gu_all, li, sizes)
         half = h.shape[-1] // 2
-        a = (jax.nn.silu(h[:, :half]) * h[:, half:]).astype(m.dtype)
+        a = (act(h[:, :half]) * h[:, half:]).astype(m.dtype)
         y = grouped_matmul.grouped_matmul(a, we_down_all, li, sizes)
         # back to token order, weighted: a gather by the inverse permutation
         inv = jnp.argsort(order)

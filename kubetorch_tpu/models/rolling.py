@@ -14,12 +14,16 @@ TPU shape discipline + dispatch discipline:
   max_len, ...]`` (keys and values, a latent: read to a row's depth), and
   row-state ones, ``[L, max_slots, ...]`` with no position axis (a
   recurrent state: what a row is after its last token, whatever its depth).
+  A positional leaf may be a RING of its own span, ``[L, max_slots, span,
+  ...]`` with position ``p`` at ``p % span`` (a window layer's keys and
+  values: read to ``min(depth, span)``).
   A *slot* is a batch row. New requests prefill into a free slot (jitted per
   padded-length bucket), and decode advances **all** active slots, each at
   its own depth.
 - The layers and what a cache leaf holds are the decoder's
   (``models/decoder.py``: ``decoder_for(cfg)``); this module names no leaf
-  and asks only which leaves are row state (``row_leaves``).
+  and asks only which leaves are row state (``row_leaves``) and which are
+  rings (``ring_leaves``).
 - All decode state (cache, pending logits, depths, active mask) lives on
   device between calls; the host holds only bookkeeping. Each
   :meth:`step` is ONE jit call running ``steps_per_call`` tokens through a
@@ -47,8 +51,9 @@ from kubetorch_tpu.config import env_float, env_int
 from kubetorch_tpu.lookahead import LookaheadState, spec_stats_dict
 from kubetorch_tpu.observability import devstats
 from kubetorch_tpu.models.decoder import (decoder_for, grid_dims,
-                                          position_bytes, row_bytes,
-                                          row_leaves)
+                                          off_grid_leaves, position_bytes,
+                                          ring_leaves, ring_position_bytes,
+                                          row_bytes, row_leaves)
 from kubetorch_tpu.models.generate import filter_logits
 from kubetorch_tpu.ops import grid_write
 from kubetorch_tpu.parallel.sharding import ShardingRules
@@ -423,6 +428,25 @@ class RollingGenerator:
         self._state_rows = {"live": 0, "touched": 0}
         self._scan_positions = {"linear_scan_positions": 0,
                                 "linear_scan_prompt_tokens": 0}
+        # Rings (positional leaves of their own span: a window layer's K
+        # and V): name -> span; the leaves that do not lie along max_len;
+        # the bytes a position holds over the ring layers (a gauge beside
+        # the span: a row holds min(depth, span) of them); what decode
+        # reads of them against what the decoding rows hold there, and the
+        # key blocks the window layers' admission attention visits against
+        # those the band touches (``_count_kv_read``, ``_count_admission``).
+        self._ring_leaves = ring_leaves(self.model, cfg)
+        self._off_grid = off_grid_leaves(self.model, cfg)
+        self._ring_position_bytes = ring_position_bytes(self.model, cfg,
+                                                        self.kv_quantized)
+        self._ring_span = max(self._ring_leaves.values(), default=0)
+        self._window_positions = {"live": 0, "read": 0}
+        self._window_blocks = {"prefill_window_key_blocks": 0,
+                               "prefill_window_key_blocks_band": 0}
+        with self._mesh_ctx():
+            self._ring_block = (self.model.ragged_block(
+                cfg, self._ring_span, self.cache, self.spec)
+                if self._ring_leaves else None)
 
         # Device-truth utilization accounting: every jitted dispatch
         # below routes through this accumulator (``_dispatch``), which
@@ -563,7 +587,16 @@ class RollingGenerator:
         first token was read and routed behind the dispatch of the decode
         chunk that follows the admission, not at that chunk's end): equal
         wherever the admission draws the token, which is everywhere but a
-        row that leaves before it decodes (a handoff, an eviction)."""
+        row that leaves before it decodes (a handoff, an eviction). For a
+        decoder with rings (0 for the others): ``decode_kv_positions_*``
+        count the layers that keep every position, and beside them
+        ``decode_window_positions_live`` / ``_read`` (ring positions the
+        decoding rows hold, ``min(depth, span)``, and those decode attention
+        fetched, by chunk as the other pair), ``prefill_window_key_blocks``
+        / ``_band`` (key blocks the window layers' admission attention
+        computed, a layer, and those the band touches) and the gauges
+        ``window_position_bytes`` (bytes a position holds over the ring
+        layers) and ``window_positions`` (the span)."""
         out = {f"decode_kv_positions_{k}": int(v)
                for k, v in self._kv_positions.items()}
         out.update(self._admissions)
@@ -578,6 +611,11 @@ class RollingGenerator:
                    for k, v in self._state_rows.items())
         out.update(self._scan_positions)
         out["state_row_bytes"] = self._state_row_bytes
+        out.update((f"decode_window_positions_{k}", int(v))
+                   for k, v in self._window_positions.items())
+        out.update(self._window_blocks)
+        out["window_position_bytes"] = self._ring_position_bytes
+        out["window_positions"] = self._ring_span
         return out
 
     def _count_prefill(self, prompt_tokens: int) -> None:
@@ -594,6 +632,13 @@ class RollingGenerator:
         self._kv_positions["read"] += (
             grid if block is None else int((-(-live // block) * block).sum()))
         self._kv_positions["grid"] += grid
+        if self._ring_leaves:
+            span, block = self._ring_span, self._ring_block
+            held = np.minimum(live, span)
+            self._window_positions["live"] += int(held.sum())
+            self._window_positions["read"] += (
+                self.max_slots * span if block is None
+                else int((-(-held // block) * block).sum()))
 
     def _count_state_rows(self, steps: int) -> None:
         """Account one decode chunk of ``steps`` steps: the rows that
@@ -647,6 +692,11 @@ class RollingGenerator:
         self._prefill_positions["prefill_positions"] += n
         if own and self.model.prefill_flash_engages(self.cfg, p_pad):
             self._prefill_positions["prefill_flash_positions"] += n
+        if self._ring_leaves:
+            visited, band = self.model.window_key_blocks(self.cfg, p_pad)
+            self._window_blocks["prefill_window_key_blocks"] += rows * visited
+            self._window_blocks["prefill_window_key_blocks_band"] += (
+                rows * band)
 
     def devstats_snapshot(self) -> Dict[str, float]:
         """Cumulative compiler-truth dispatch costs (FLOPs / HBM bytes
@@ -1049,7 +1099,7 @@ class RollingGenerator:
                     f"KT_KV_BLOCK_TOKENS that divides max_len")
         kv: Dict[str, Dict[str, np.ndarray]] = {}
         for kk in self.cache:
-            if kk in self._row_leaves:
+            if kk in self._off_grid:
                 continue
             plane = np.array(self.cache[kk][:, slot, :dend])
             # ZERO the block-padded tail beyond the row's depth: freed
@@ -1094,6 +1144,17 @@ class RollingGenerator:
             state["row_state"] = {
                 kk: np.array(self.cache[kk][:, slot])
                 for kk in sorted(self._row_leaves)}
+        if self._ring_leaves:
+            # a ring whole, ``[L, span, *shape]``, as it lies: it means
+            # what it means beside the depth it was written to (``scalars``
+            # carries it: position p sits at p % span). Slots the row has
+            # not reached still hold the slot's previous occupant: zeroed,
+            # as the planes' tails are.
+            state["rings"] = {}
+            for kk, span in sorted(self._ring_leaves.items()):
+                ring = np.array(self.cache[kk][:, slot])
+                ring[:, min(dpos, span):] = 0
+                state["rings"][kk] = ring
         if self.spec:
             # round-carried speculation state. The draft haystack ships
             # explicitly (a prefixed row's prefix tokens live only on
@@ -1124,19 +1185,26 @@ class RollingGenerator:
         (pre-geometry exports) keep the legacy shape-fit checks only.
         Row-state leaves are held to the same guard: the state's
         ``row_state`` must hold exactly this grid's, each of this grid's
-        ``[L, *shape]``."""
+        ``[L, *shape]``; and so are rings (``rings``: each of this grid's
+        ``[L, span, *shape]``: a ring of another span holds other
+        positions in other slots)."""
         from kubetorch_tpu.exceptions import KVGeometryMismatch
 
-        exported = {kk: tuple(np.shape(v))
-                    for kk, v in (state.get("row_state") or {}).items()}
-        importer = {kk: self.cache[kk].shape[:1] + self.cache[kk].shape[2:]
-                    for kk in self._row_leaves}
-        if exported != importer:
-            raise KVGeometryMismatch(
-                f"cannot import row: exported row-state leaves {exported} "
-                f"do not match the importing engine's {importer} (a row's "
-                f"recurrent state is whole or it is nothing)",
-                axis="row_state", exported=exported, importer=importer)
+        for axis, key, sort, names, what in (
+                ("row_state", "row_state", "row-state", self._row_leaves,
+                 "a row's recurrent state is whole or it is nothing"),
+                ("ring", "rings", "ring", self._ring_leaves,
+                 "a ring is read modulo its own span")):
+            exported = {kk: tuple(np.shape(v))
+                        for kk, v in (state.get(key) or {}).items()}
+            importer = {kk: (self.cache[kk].shape[:1]
+                             + self.cache[kk].shape[2:]) for kk in names}
+            if exported != importer:
+                raise KVGeometryMismatch(
+                    f"cannot import row: exported {sort} leaves {exported} "
+                    f"do not match the importing engine's {importer} "
+                    f"({what})",
+                    axis=axis, exported=exported, importer=importer)
         geom = state.get("geom")
         if geom is None:
             return
@@ -1195,7 +1263,7 @@ class RollingGenerator:
         self._check_geometry(state, block_tokens)
         if not self._free:
             raise RuntimeError("no free row to import into")
-        positional = set(self.cache) - self._row_leaves
+        positional = set(self.cache) - self._off_grid
         if set(state["kv"]) != positional:
             raise ValueError(
                 f"KV planes {sorted(state['kv'])} do not match this "
@@ -1228,10 +1296,12 @@ class RollingGenerator:
             for kk in planes:
                 self.cache[kk] = self.cache[kk].at[:, slot, :dend].set(
                     jnp.asarray(planes[kk]).astype(self.cache[kk].dtype))
-            for kk in self._row_leaves:
-                self.cache[kk] = self.cache[kk].at[:, slot].set(
-                    jnp.asarray(state["row_state"][kk]).astype(
-                        self.cache[kk].dtype))
+            for key, names in (("row_state", self._row_leaves),
+                               ("rings", self._ring_leaves)):
+                for kk in names:
+                    self.cache[kk] = self.cache[kk].at[:, slot].set(
+                        jnp.asarray(state[key][kk]).astype(
+                            self.cache[kk].dtype))
             self._logits = self._logits.at[slot].set(
                 jnp.asarray(np.asarray(state["logits"], np.float32)))
             self._dpos = self._dpos.at[slot].set(dpos)
@@ -1402,7 +1472,7 @@ class RollingGenerator:
                 # the own cache there: the prefix's bucket and the
                 # suffix's, cut at the grid's end (``_prefill_px_impl``)
                 self._count_admit(n, slots, min(
-                    grid_dims(pfx["planes"], self._row_leaves)[1] + p_pad,
+                    grid_dims(pfx["planes"], self._off_grid)[1] + p_pad,
                     self.max_len))
                 out = self._dispatch(
                     "prefill_px", (n_pad, p_pad), self._prefill_px,
@@ -1699,7 +1769,9 @@ class RollingGenerator:
         self._dnt_valid = jnp.where(mask, False, self._dnt_valid)
         # a row-state leaf has no depth to mask a stale row by: a freed row
         # goes back to a sequence's start, which is what a chunked prefill
-        # begins from (a bucketed admission splices the whole row anyway)
+        # begins from (a bucketed admission splices the whole row anyway).
+        # A ring needs none of this: it is read to min(depth, span) as a
+        # plane is read to its depth, and the depth is 0 from here on
         for kk in self._row_leaves:
             leaf = self.cache[kk]
             self.cache[kk] = jnp.where(
@@ -1818,7 +1890,7 @@ class RollingGenerator:
         prefixes. ``lora``: the suffix forward runs under the prefix's
         owning adapter (submit enforced the match)."""
         model = decoder_for(cfg)
-        rows = row_leaves(model, cfg)
+        rows = off_grid_leaves(model, cfg)
         M = grid_dims(cache, rows)[1]
         N = tokens.shape[0]
         Ppad = grid_dims(planes, rows)[1]
@@ -1879,7 +1951,7 @@ class RollingGenerator:
         stalling token emission."""
         B = feed.shape[0]
         model = decoder_for(cfg)
-        M = grid_dims(cache, row_leaves(model, cfg))[1]
+        M = grid_dims(cache, off_grid_leaves(model, cfg))[1]
         live = counts > 0
         positions = dpos[:, None] + jnp.arange(C)[None, :]
         gmask = jnp.broadcast_to(
@@ -1944,7 +2016,7 @@ class RollingGenerator:
         returned ``dnt_valid`` is False for every row that decoded."""
         B = last_logits.shape[0]
         model = decoder_for(cfg)
-        M = grid_dims(cache, row_leaves(model, cfg))[1]
+        M = grid_dims(cache, off_grid_leaves(model, cfg))[1]
         pos0 = pos
         # Grid contents never change during the chunk: rows < pos0 hold
         # every previous token, the current chunk's rows live in the
@@ -2054,7 +2126,7 @@ class RollingGenerator:
 
         B = last_logits.shape[0]
         model = decoder_for(cfg)
-        M = grid_dims(cache, row_leaves(model, cfg))[1]
+        M = grid_dims(cache, off_grid_leaves(model, cfg))[1]
         Lctx = ctx.shape[1]
         bidx = jnp.arange(B)[:, None]
         # `sampling` is STATIC (the host re-jits once if sampled traffic

@@ -31,6 +31,16 @@ infers for the 5-D operand, so the planes go in with no copy. A head's
 plus a shift (``_head_planes``). The scales are ``{2,3,1,0:T(8,128)}``:
 position-minor, so the caller's ``[L, B, Hkv, M]`` transpose is a bitcast.
 
+A RING (``ring_plan``): a window layer keeps a row's last ``span``
+positions, position ``p`` at slot ``p % span`` of a ``[L, B, span, Hkv, D]``
+leaf. Keys carry their own rotation and a softmax does not care in which
+order it meets them, so the ring is read as it lies: slots ``[0, min(depth,
+span))`` as a plane is read to its depth, less the few oldest entries that
+have left the window of a query ``step`` positions past the depth (the decode
+chunk holds the positions since, beside the ring): a run of slots from
+``lo``, modulo the span. The work list carries that run as two more scalars a
+row, and the kernel masks it; the plane-reading call is the one it was.
+
 Use ``interpret=True`` for tests on CPU.
 """
 
@@ -70,7 +80,17 @@ def engages(t: int, max_len: int, n_kv_heads: int, head_dim: int,
     """Whether the decode forward takes the kernel, from what the code can
     see: one query position, the TPU backend, the whole grid on one device,
     and shapes the kernel's loads cover. Everything else runs the einsum
-    pair, which is also the oracle."""
+    pair, which is also the oracle.
+
+    What the kernel asks of its CALLER beyond this (read off the TPU's
+    compiler, ``tests/test_decode_attention.py``): the query block is ``[G,
+    D]`` a kv head, ``G`` the query heads a kv head, so where ``G`` rows of
+    the queries' dtype are not whole sublane tiles (one query head a kv
+    head; 7 of them) the queries go in as float32, whose rows the compiler
+    slices singly (the kernel rounds them to its operand dtype itself); and
+    kv heads that do not fill a packed word are stored rounded up by the
+    decoder (``hybrid_linear.kv_heads_stored``), never here. 4 bfloat16 kv
+    heads are two whole words a position and go in as they lie."""
     pack = 4 // jnp.dtype(dtype).itemsize
     if (t != 1 or block_for(max_len) is None or head_dim % 128
             or pack < 1 or n_kv_heads % pack):
@@ -110,25 +130,33 @@ def _head_planes(ref, operand_dtype):
 
 
 def _attend_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref, m_ref, l_ref,
-                  start, depth, *, sm_scale: float):
+                  start, depth, *, sm_scale: float, dead=None):
     """Fold one key block of one row into its running (acc, m, l).
     ``q_ref`` [Hkv, G, D]; ``k_ref`` / ``v_ref`` [..., block, Hkv, D];
     ``ks_ref`` / ``vs_ref`` [Hkv, block] or None; ``acc_ref`` [Hkv, G, D],
     ``m_ref`` / ``l_ref`` [Hkv, G, 128] (replicated over the lane tile: a
     [G, 1] plane cannot be sliced by row); the block holds positions
-    ``start ..`` of which those below ``depth`` are live (at least one)."""
+    ``start ..`` of which those below ``depth`` are live (at least one).
+    ``dead`` = (lo, count, span) of a ring: the ``count`` slots from ``lo``,
+    modulo ``span``, hold positions the query's window has left."""
     block = k_ref.shape[-3]
     scaled = ks_ref is not None
     operand = jnp.float32 if k_ref.dtype == jnp.float32 else jnp.bfloat16
     k_heads = _head_planes(k_ref, operand)
     v_heads = _head_planes(v_ref, operand)
-    live = (start + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block), 1)) < depth                   # [1, block]
+    def alive(shape, axis):
+        at = start + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        if dead is None:
+            return at < depth
+        lo, count, span = dead
+        past = jnp.where(at >= lo, at - lo, at - lo + span)
+        return (at < depth) & (past >= count)
+
+    live = alive((1, block), 1)                              # [1, block]
     if not scaled:
         # a float grid can hold anything past a row's depth; 0 x NaN
         # would reach the output through PV
-        live_rows = (start + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0)) < depth
+        live_rows = alive((block, 1), 0)
     # Stage by stage across the heads, not head by head: eight independent
     # QK matmuls, then eight softmax updates, then eight PV matmuls. The
     # same operations in head order ran 1.5-1.7x slower on the v5e (each
@@ -166,10 +194,14 @@ def _attend_block(q_ref, k_ref, v_ref, ks_ref, vs_ref, acc_ref, m_ref, l_ref,
             preferred_element_type=jnp.float32)
 
 
-def _kernel(li_ref, depth_ref, row_ref, blk_ref, n_ref, q_ref, k_hbm, v_hbm,
-            *rest, block: int, sm_scale: float, scaled: bool):
+def _kernel(li_ref, depth_ref, row_ref, blk_ref, n_ref, *rest, block: int,
+            sm_scale: float, scaled: bool, ring: Optional[int] = None):
     """One call a layer: walk the live (row, block) items, double-buffering
-    each item's planes from HBM by hand."""
+    each item's planes from HBM by hand. ``ring``: the span of a ring leaf,
+    whose work list carries two more scalars a row (``ring_plan``)."""
+    if ring is not None:
+        lo_ref, gone_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, *rest = rest
     if scaled:
         (ks_hbm, vs_hbm, acc_ref, m_ref, l_ref, kbuf, vbuf, ksbuf, vsbuf,
          sem) = rest
@@ -217,7 +249,9 @@ def _kernel(li_ref, depth_ref, row_ref, blk_ref, n_ref, q_ref, k_hbm, v_hbm,
                       ksbuf.at[slot] if scaled else None,
                       vsbuf.at[slot] if scaled else None,
                       acc_ref.at[row], m_ref.at[row], l_ref.at[row],
-                      blk_ref[i] * block, depth_ref[row], sm_scale=sm_scale)
+                      blk_ref[i] * block, depth_ref[row], sm_scale=sm_scale,
+                      dead=(None if ring is None
+                            else (lo_ref[row], gone_ref[row], ring)))
         return carry
 
     jax.lax.fori_loop(0, n, body, 0)
@@ -241,6 +275,23 @@ def plan(depth, max_len: int, block: Optional[int] = None):
     return depth, row, blk, ends[-1:].astype(jnp.int32)
 
 
+def ring_plan(depth, step, span: int, block: Optional[int] = None):
+    """The work list over a RING of ``span`` positions (position ``p`` at
+    slot ``p % span``): row ``b`` has written positions ``[0, depth[b])``, so
+    its ring holds the last ``min(depth, span)`` of them in slots ``[0,
+    min(depth, span))``, and its query sits ``step`` (a scalar or ``[B]``)
+    positions past its depth and sees position ``p`` iff ``p > depth + step
+    - span``. ``plan``'s four over the held slots, then ``(lo, gone)``: the
+    ``gone[b]`` oldest entries, in slots ``lo[b] ..`` modulo the span, have
+    left that window and are masked (at depth 0: no item at all)."""
+    depth = depth.astype(jnp.int32)
+    held = jnp.minimum(depth, span)
+    lo = jnp.maximum(depth - span, 0) % span
+    gone = jnp.clip(held + step - span + 1, 0, span)
+    return plan(held, span, block) + (lo.astype(jnp.int32),
+                                      gone.astype(jnp.int32))
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ragged_decode_attention(q, k_all, v_all, ks_all, vs_all, layer, items,
                             *, interpret: bool = False):
@@ -259,7 +310,7 @@ def ragged_decode_attention(q, k_all, v_all, ks_all, vs_all, layer, items,
     B, H, D = q.shape
     _, _, M, Hkv, _ = k_all.shape
     G = H // Hkv
-    depth, row, blk, n = items
+    depth, row, blk, n, *ring = items
     block = (B * M) // row.shape[0]
     scaled = ks_all is not None
 
@@ -282,9 +333,9 @@ def ragged_decode_attention(q, k_all, v_all, ks_all, vs_all, layer, items,
     stats = jax.ShapeDtypeStruct((B, Hkv, G, _LANES), jnp.float32)
     acc, m, l = pl.pallas_call(
         functools.partial(_kernel, block=block, sm_scale=D ** -0.5,
-                          scaled=scaled),
+                          scaled=scaled, **({"ring": M} if ring else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(1,), in_specs=in_specs,
+            num_scalar_prefetch=5 + len(ring), grid=(1,), in_specs=in_specs,
             out_specs=[full(B, Hkv, G, D), full(B, Hkv, G, _LANES),
                        full(B, Hkv, G, _LANES)],
             scratch_shapes=scratch),
@@ -295,6 +346,6 @@ def ragged_decode_attention(q, k_all, v_all, ks_all, vs_all, layer, items,
         name="ragged_decode_attention",
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), depth, row, blk, n,
-      *operands)
+      *ring, *operands)
     return (acc.reshape(B, H, D), m[..., 0].reshape(B, H),
             l[..., 0].reshape(B, H))

@@ -44,14 +44,41 @@ _STATS = 8     # HBM stats (lse/delta) keep a narrow 8-lane trailing dim:
                # 16x less HBM traffic than lane-replicated stats
 
 
+def band_first_block(qi, block_q: int, block_k: int, window: int):
+    """First key block a query block's band touches: query ``i`` sees key
+    ``j`` iff ``i - window < j <= i``, so block ``qi``'s first query sees
+    from ``qi * block_q - window + 1`` (at 0 where that is negative)."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def band_blocks(n_q: int, block_q: int, block_k: int, window: int):
+    """Key blocks each query block's band touches, on the host: from its
+    first query's oldest key's block to the diagonal's."""
+    return [(qi * block_q + block_q - 1) // block_k
+            - max(qi * block_q - window + 1, 0) // block_k + 1
+            for qi in range(n_q)]
+
+
+def band_width(n_q: int, block_q: int, block_k: int, window: int) -> int:
+    """Most key blocks any query block's band touches: the key axis of a
+    banded call's grid."""
+    return max(band_blocks(n_q, block_q, block_k, window))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
                 acc_scratch, *, scale: float, causal: bool,
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, window: Optional[int] = None):
     """Forward kernel. ``lse_ref`` is None in the forward-only (primal)
-    variant — no residual stats are written then."""
+    variant — no residual stats are written then. ``window`` (with
+    ``causal``): the key axis of the grid is the BAND's, key block
+    ``band_first_block(qi) + ki``, so a block wholly outside the band is
+    neither fetched nor computed."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
+    # the key block this step holds (the grid's own without a window)
+    kb = ki if window is None else (
+        band_first_block(qi, block_q, block_k, window) + ki)
 
     @pl.when(ki == 0)
     def _init():
@@ -61,7 +88,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
 
     # Causal: a KV block strictly above the diagonal contributes nothing —
     # skip its matmuls entirely (~2x fewer effective blocks).
-    block_live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    block_live = (not causal) or (kb * block_k <= qi * block_q + block_q - 1)
 
     @pl.when(block_live)
     def _compute():
@@ -76,9 +103,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scratch, l_scratch,
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            seen = q_pos >= k_pos
+            if window is not None:
+                seen = seen & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, _NEG_INF)
 
         m_prev = m_scratch[:]                         # [block_q, 128]
         row_max = jnp.max(s, axis=1, keepdims=True)   # [block_q, 1]
@@ -111,16 +141,34 @@ def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     scale: float, causal: bool, block_q: int, block_k: int,
     interpret: bool, with_lse: bool = True, name: Optional[str] = None,
+    window: Optional[int] = None,
 ):
     """[B,H,S,D] layout. Returns (out, lse[B,H,S,_STATS] f32) — lse is None
     when ``with_lse=False`` (forward-only: skips the residual writes).
     ``name`` names the custom call in a device trace (training's stays
-    unnamed, so its executables are the ones they were)."""
+    unnamed, so its executables are the ones they were). ``window`` (causal
+    self-attention only, ``S == T``): query ``i`` sees key ``j`` iff ``i -
+    window < j <= i``, and the grid's key axis shrinks to the band
+    (``band_width``), each query block starting at its own first block."""
     B, Hq, S, D = q.shape
     _, Hkv, T, _ = k.shape
     group = Hq // Hkv
     nq = S // block_q
     nk = T // block_k
+    if window is None:
+        def kv_at(b, h, qi, ki):
+            return (b, h // group, ki, 0)
+        extra = {}
+    else:
+        assert causal and S == T
+        nk = band_width(nq, block_q, block_k, window)
+
+        def kv_at(b, h, qi, ki):
+            # past the diagonal: the diagonal's block again (no new fetch)
+            return (b, h // group, jnp.minimum(
+                band_first_block(qi, block_q, block_k, window) + ki,
+                (qi * block_q + block_q - 1) // block_k), 0)
+        extra = {"window": window}
 
     out_shape = [jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype)]
     out_specs = [pl.BlockSpec((1, 1, block_q, D),
@@ -139,16 +187,14 @@ def _flash_forward(
     res = pl.pallas_call(
         functools.partial(
             kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k),
+            block_q=block_q, block_k=block_k, **extra),
         out_shape=out_shape,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),
                          lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, qi, ki: (b, h // group, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, qi, ki: (b, h // group, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D), kv_at),
+            pl.BlockSpec((1, 1, block_k, D), kv_at),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -514,19 +560,39 @@ def prefill_engages(t: int, cache_len: int, write_at, n_heads: int,
     return _FORCE_INTERPRET or _one_tpu_device()
 
 
-def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      window: Optional[int] = None) -> jax.Array:
     """Causal self-attention of ``[B, T, H, D]`` queries over the
     ``[B, T, Hkv, D]`` keys and values of the same positions: the training
     kernel's forward as it is (no residual statistics, no gradient), under a
     name a device trace can show. Padding past a prompt's end needs no
     length mask: a real query sees only keys at or before itself, and what
-    the padded queries compute is read by nobody."""
+    the padded queries compute is read by nobody. ``window``: a window
+    layer's band (query ``i`` sees ``i - window < j <= i``): the key blocks
+    wholly outside it are skipped (``prefill_key_blocks`` counts them),
+    under a name of its own."""
     T, D = q.shape[1], q.shape[3]
-    with jax.named_scope("admit_flash_attention"):
+    name = ("admit_flash_attention" if window is None
+            else "admit_window_attention")
+    extra = {} if window is None else {"window": window}
+    with jax.named_scope(name):
         out, _ = _flash_forward(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), scale=D ** -0.5, causal=True,
             block_q=auto_block_q(T), block_k=auto_block_k(T),
             interpret=not _one_tpu_device(), with_lse=False,
-            name="admit_flash_attention")
+            name=name, **extra)
         return out.transpose(0, 2, 1, 3)
+
+
+def prefill_key_blocks(t: int, window: int, banded: bool):
+    """``(visited, band)`` on the host: the (query block, key block) pairs a
+    window layer's admission attention computes for one row of ``t``
+    positions a head, and the pairs the band touches, both at the kernel's
+    blocks (one block where ``t`` is shorter than one). ``banded``: the
+    kernel ran with the band; otherwise every pair of the square is
+    computed (the einsum pair masks afterwards)."""
+    bq, bk = auto_block_q(t), auto_block_k(t)
+    nq, nk = max(1, t // bq), max(1, t // bk)
+    band = sum(band_blocks(nq, bq, bk, window))
+    return (band if banded else nq * nk), band

@@ -27,6 +27,11 @@ to land one row); both are kept as the oracles in
 ``tests/test_grid_write.py``. Never ``vmap`` of an update or ``.at[].set``
 with computed indices: those lower to that scatter again.
 
+A RING leaf (``[L, B, span, ...]``, position ``p`` at slot ``p % span``: what
+a window layer keeps) takes a chunk's columns modulo its span
+(``write_columns_ring``): the same row loop with two windows a row, one at
+the landing slot and one at the ring's front for the columns that wrapped.
+
 Pure data movement on every backend and mesh (the window is cut along rows
 and positions, which no serving mesh shards): what lands is bit for bit what
 the chunk, or the private cache, held.
@@ -83,6 +88,61 @@ def write_columns(grid: Dict[str, jax.Array], cols: Dict[str, jax.Array],
             keep = lands.reshape((1, 1, K) + (1,) * (leaf.ndim - 3))
             out.append(jax.lax.dynamic_update_slice(
                 leaf, jnp.where(keep, new, old), (zero, b, w) + tail))
+        return tuple(out)
+
+    leaves = jax.lax.fori_loop(
+        0, jnp.sum(count > 0, dtype=jnp.int32), row,
+        tuple(grid[n] for n in names))
+    return dict(zip(names, leaves))
+
+
+def write_columns_ring(grid: Dict[str, jax.Array],
+                       cols: Dict[str, jax.Array], start: jax.Array,
+                       count: jax.Array) -> Dict[str, jax.Array]:
+    """``grid[name][:, b, (start[b] + c) % span] = cols[name][:, b, c]`` for
+    ``c < count[b]``: ``write_columns`` over ring leaves (``span`` their
+    position axis, at least the chunk's ``K`` columns). A row's columns
+    land in a window at ``start % span`` (pulled back from the end as there)
+    and, where they pass the end, in a second window at slot 0; a row that
+    does not wrap rewrites that second window with what it held."""
+    names = tuple(grid)
+    _, B, M = grid[names[0]].shape[:3]
+    K = cols[names[0]].shape[2]
+    if K > M:
+        raise ValueError(f"a chunk of {K} columns does not fit a ring of "
+                         f"{M} positions")
+    # K empty columns on both sides: one slice picks the row and shifts its
+    # columns right (the window pulled back) or left (the wrapped part)
+    padded = tuple(
+        jnp.pad(cols[n].astype(grid[n].dtype),
+                ((0, 0), (0, 0), (K, K)) + ((0, 0),) * (grid[n].ndim - 3))
+        for n in names)
+    order = jnp.argsort(count <= 0, stable=True).astype(jnp.int32)
+    zero = jnp.int32(0)
+
+    def row(i, leaves):
+        b = order[i]
+        s, n = start[b] % M, count[b]
+        w = jnp.clip(s, 0, M - K)
+        # (window's first slot, the column its first slot takes)
+        windows = ((w, w - s), (zero, M - s))
+        out = []
+        for leaf, pad in zip(leaves, padded):
+            tail = (zero,) * (leaf.ndim - 3)
+            size = (leaf.shape[0], 1, K) + leaf.shape[3:]
+            for at, first in windows:
+                col = first + jnp.arange(K)
+                # a column lands here iff it exists and this window's slot
+                # is the one it wraps to
+                lands = ((col >= 0) & (col < n)
+                         & ((s + col) % M == at + jnp.arange(K)))
+                new = jax.lax.dynamic_slice(
+                    pad, (zero, b, K + jnp.clip(first, -K, K)) + tail, size)
+                old = jax.lax.dynamic_slice(leaf, (zero, b, at) + tail, size)
+                keep = lands.reshape((1, 1, K) + (1,) * (leaf.ndim - 3))
+                leaf = jax.lax.dynamic_update_slice(
+                    leaf, jnp.where(keep, new, old), (zero, b, at) + tail)
+            out.append(leaf)
         return tuple(out)
 
     leaves = jax.lax.fori_loop(

@@ -762,13 +762,21 @@ class DecodeEngine:
         pos_bytes = int(gauges.get("kv_position_bytes", 0))
         row_bytes = int(gauges.get("state_row_bytes", 0))
         row_state_tokens = -(-row_bytes // pos_bytes) if pos_bytes else 0
+        # a row priced by kind: layers that keep a ring hold min(depth,
+        # window) positions, each worth their bytes over the full layers'
+        window_tokens = int(gauges.get("window_positions", 0))
+        window_weight = (gauges.get("window_position_bytes", 0) / pos_bytes
+                         if pos_bytes else 0.0)
         if not budget:
             grid_blocks = (int(getattr(engine, "max_slots", 8))
-                           * kvpool.blocks_for(
-                               self._row_cap_tokens + row_state_tokens, bt))
+                           * kvpool.blocks_for(kvpool.priced_tokens(
+                               self._row_cap_tokens, row_state_tokens,
+                               window_tokens, window_weight), bt))
             budget = 2 * grid_blocks
         self._kv = kvpool.PagedKVPool(budget, bt, prefix_split,
-                                      row_state_tokens=row_state_tokens)
+                                      row_state_tokens=row_state_tokens,
+                                      window_tokens=window_tokens,
+                                      window_weight=window_weight)
         # a decoder that does not carry what this engine was configured
         # for (models/decoder.py: check_serving) says so now, by name
         self._model = getattr(engine, "model", None)

@@ -43,6 +43,7 @@ here deliberately carry no locks of their own.
 from __future__ import annotations
 
 import hashlib
+import math
 import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -635,6 +636,15 @@ def _tree_leaves(tree: Any):
 
 
 # ---------------------------------------------------------- the pool
+def priced_tokens(ctx_tokens: int, row_state_tokens: int = 0,
+                  window_tokens: int = 0, window_weight: float = 0.0) -> int:
+    """What a row of ``ctx_tokens`` pins, in positions of the layers that
+    keep every one: those, the ring layers' ``min(ctx, window)`` at their
+    weight, and the row's state."""
+    ring = math.ceil(min(ctx_tokens, window_tokens) * window_weight)
+    return ctx_tokens + ring + row_state_tokens
+
+
 class PagedKVPool:
     """The engine-facing facade: one ledger + one prefix cache +
     per-row metadata, all mutated under the engine's scheduler lock.
@@ -645,12 +655,21 @@ class PagedKVPool:
     its suffix + budget, which is the whole point. ``row_state_tokens``:
     what a row pins whatever its depth (a decoder's row-state leaves: a
     recurrent state), in the currency of positions; every row's cost and
-    reservation carries it (0 for a decoder that keeps none)."""
+    reservation carries it (0 for a decoder that keeps none).
+    ``window_tokens`` / ``window_weight``: a row is priced BY KIND of layer.
+    The currency is a position of the layers that keep every one; layers
+    that keep a ring of ``window_tokens`` positions hold ``min(ctx,
+    window_tokens)`` of them whatever the depth, each worth
+    ``window_weight`` of the currency (the ring layers' bytes a position
+    over the full layers'; 0 for a decoder with no ring)."""
 
     def __init__(self, budget_blocks: int, block_tokens: int,
                  split_rule: Optional[str] = None,
-                 row_state_tokens: int = 0):
+                 row_state_tokens: int = 0, window_tokens: int = 0,
+                 window_weight: float = 0.0):
         self.row_state_tokens = max(0, int(row_state_tokens))
+        self.window_tokens = max(0, int(window_tokens))
+        self.window_weight = max(0.0, float(window_weight))
         self.ledger = KVBlockLedger(budget_blocks, block_tokens)
         self.prefixes = PrefixCache(self.ledger)
         self.split = parse_split_rule(split_rule)
@@ -669,14 +688,18 @@ class PagedKVPool:
     def used_blocks(self) -> int:
         return self.ledger.used
 
+    def priced_tokens(self, ctx_tokens: int) -> int:
+        return priced_tokens(ctx_tokens, self.row_state_tokens,
+                             self.window_tokens, self.window_weight)
+
     def row_cost(self, ctx_tokens: int) -> int:
-        return blocks_for(ctx_tokens + self.row_state_tokens,
+        return blocks_for(self.priced_tokens(ctx_tokens),
                           self.ledger.block_tokens)
 
     def reserve_row(self, rid: int, ctx_tokens: int,
                     prefix_pid: Optional[int] = None) -> int:
         blocks = self.ledger.reserve_row(
-            rid, ctx_tokens + self.row_state_tokens)
+            rid, self.priced_tokens(ctx_tokens))
         if prefix_pid is not None:
             entry = self.prefixes._by_pid.get(prefix_pid)
             if entry is not None:

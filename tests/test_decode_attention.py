@@ -595,3 +595,156 @@ def test_hybrid_executables_compile_for_v5e_with_state_beside_kv(
     else:
         assert "gated_delta_prefill" in text
         assert "admit_flash_attention" in text
+
+
+# The fourth decoder (models/window_moe.py, SmallThinker-21BA3B widths: 28
+# query heads over 4 kv heads x 128, window 4096 on a grid of 32 x 16384, 64
+# experts of 768 top 6) compiled for the same described chip: the ragged
+# kernel over a ring with 7 query heads a kv head, the banded admission
+# kernel, and the cell's whole decode and 16384-bucket admission executables.
+@pytest.mark.parametrize("leaf", ["ring", "plane"])
+def test_ragged_kernel_compiles_for_v5e_at_seven_query_heads_a_kv_head(
+        leaf, v5e_chip):
+    """4 bfloat16 kv heads are two whole packed words a position and go in
+    as they lie (no padding: the argument bytes are the leaves'), float32
+    queries of 7 heads a kv head are sliced by row; the ring's work list
+    carries two more scalars a row."""
+    layers, b, hkv, h, d = 6, 32, 4, 28, 128
+    m = 4096 if leaf == "ring" else 16384
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def attend(q, k, v, layer, depth):
+        items = (decode_attention.ring_plan(depth, 3, m) if leaf == "ring"
+                 else decode_attention.plan(depth, m))
+        return decode_attention.ragged_decode_attention(
+            q, k, v, None, None, layer, items)
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        exe = jax.jit(attend).lower(
+            spec((b, h, d), jnp.float32),
+            spec((layers, b, m, hkv, d), jnp.bfloat16),
+            spec((layers, b, m, hkv, d), jnp.bfloat16),
+            spec((), jnp.int32), spec((b,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    text, mem = exe.as_text(), exe.memory_analysis()
+    assert text.count("tpu_custom_call") == 1
+    planes = 2 * layers * b * m * hkv * d * 2
+    assert planes <= mem.argument_size_in_bytes < planes + (1 << 20)
+    assert mem.temp_size_in_bytes < (8 << 20)
+
+
+def test_banded_admission_kernel_compiles_for_v5e_on_the_bands_grid(
+        v5e_chip):
+    """16384 positions in blocks of 1024 under the window of 4096: the key
+    axis of the grid is five blocks, not sixteen."""
+    from kubetorch_tpu.ops import flash_attention
+
+    t, h, hkv, d = 16384, 28, 4, 128
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e_chip)
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(
+            lambda q, k, v: flash_attention._flash_forward(
+                q, k, v, scale=d ** -0.5, causal=True, block_q=1024,
+                block_k=1024, interpret=False, with_lse=False,
+                name="admit_window_attention", window=4096)[0]).lower(
+            spec((1, h, t, d)), spec((1, hkv, t, d)),
+            spec((1, hkv, t, d))).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    assert text.count("tpu_custom_call") == 1
+    assert "admit_window_attention" in text
+    assert flash_attention.band_width(16, 1024, 1024, 4096) == 5
+
+
+@pytest.mark.parametrize("which", ["decode", "admit_16384", "admit_512"])
+def test_window_moe_executables_compile_for_v5e_with_rings_beside_planes(
+        which, v5e_chip, monkeypatch):
+    """The cell's decode and admission executables whole, at 32 slots x
+    16384. Weights 7.94 GB and K/V 3.76 GB (2.15 of planes, 1.61 of rings)
+    are arguments, every cache leaf stays aliased in place; the decode
+    chunk's temporaries are megabytes (no expert stack is sliced, no ring
+    unrolled); the 16384 bucket's stay under 2 GB (the experts take the
+    sequence 4096 tokens at a time: 1.37 GB read here, PR 40; 2.8 GB of
+    sorted pairs and products in one piece would not leave room), its
+    window layers attend through the banded kernel and its full layers
+    through the plain one; a bucket under 1024 takes the einsum pair."""
+    from kubetorch_tpu.models import WindowMoEConfig, window_moe
+    from kubetorch_tpu.models.rolling import RollingGenerator
+    from kubetorch_tpu.parallel.sharding import ShardingRules
+
+    cfg = WindowMoEConfig(max_seq_len=16384)
+    b, m, vocab = 32, 16384, cfg.vocab_size
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def specs(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    params = specs(jax.eval_shape(
+        lambda: window_moe.init(jax.random.key(0), cfg)))
+    cache = specs(jax.eval_shape(lambda: window_moe.init_cache(cfg, b, m)))
+    assert cache["k"].shape == (2, b, m, 4, 128)
+    assert cache["wk"].shape == (6, b, 4096, 4, 128)
+    state = (spec((b, vocab), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_))
+
+    def draw(n):
+        return (spec((n,), jnp.float32), spec((n,), jnp.float32),
+                spec((n, 64), jnp.int32), spec((2,), jnp.uint32))
+
+    rules = ShardingRules.default()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        if which == "decode":
+            exe = jax.jit(
+                lambda *a: RollingGenerator._decode_impl(
+                    *a, None, top_k=None, top_p=None, n_steps=8, cfg=cfg,
+                    rules=rules), donate_argnums=(1, 2, 3, 6)).lower(
+                params, cache, *state, *draw(b)).compile()
+        else:
+            p_pad = int(which.split("_")[1])
+            exe = jax.jit(
+                lambda *a: RollingGenerator._prefill_impl(
+                    *a, None, p_pad=p_pad, top_k=None, top_p=None, cfg=cfg,
+                    rules=rules),
+                donate_argnums=(1, 2, 3, 4, 5, 6)).lower(
+                params, cache, *state, spec((1, p_pad), jnp.int32),
+                spec((1,), jnp.int32), spec((1,), jnp.int32),
+                *draw(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    text, mem = exe.as_text(), exe.memory_analysis()
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    cache_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in cache.values())
+    assert 7.9e9 < weights < 8.0e9 and 3.75e9 < cache_bytes < 3.77e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert "moe_grouped_matmul" in text
+    if which == "decode":
+        assert "ragged_decode_attention" in text
+        assert mem.temp_size_in_bytes < 0.05e9, mem.temp_size_in_bytes
+    elif which == "admit_16384":
+        assert "admit_window_attention" in text
+        assert "admit_flash_attention" in text
+        assert mem.temp_size_in_bytes < 2.0e9, mem.temp_size_in_bytes
+        # what the chip must hold at once fits its 16 GB with room
+        assert weights + cache_bytes + mem.temp_size_in_bytes < 14.0e9
+    else:
+        assert "admit_window_attention" not in text
+        assert "admit_flash_attention" not in text
+        assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
