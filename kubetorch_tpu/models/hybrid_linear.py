@@ -35,7 +35,15 @@ row that decodes and 0 for one that does not: its state is held) and a
 prefill chunk are that one function. ``T = 1`` takes ``gated_delta.step``
 (on one TPU device its kernel, ``gated_delta.step_rows``, which works on the
 stacked state leaf in place and fetches only the rows that decode), anything
-longer the chunked scan.
+longer the chunked scan (``gated_delta.prefill_scan``). On one TPU device a
+scan its chunk of 128 divides (every bucket of an admission) is one kernel,
+``gated_delta_prefill``: it takes ``q``, ``k``, ``v`` in the compute dtype
+and the decays and steps a token, and builds a chunk's triangular inverse
+and factors in VMEM in the grid step that uses them; beside it XLA only
+lays ``q``, ``k``, ``v`` and ``o`` out a head at a time and sums ``log
+alpha`` inside each chunk. Elsewhere (the CPU, a mesh, a prefill chunk of
+another length) XLA makes the chunks' factors and a ``lax.scan`` walks them:
+the kernel's oracle.
 
 Layers of one kind are stacked (``params["linear"]``, ``params["full"]``)
 and scanned by index; cache leaves are stacked over the layers of the kind

@@ -32,19 +32,41 @@ Four forms of the one rule:
   A)^-1``, the chunk acts on the state it starts from through ``w = T (beta
   e^g k)`` and ``u = T (beta v)``: ``v' = u - w S``, ``o = (e^g q) S +
   tril(q k^T e^(g_i - g_j)) v'``, ``S <- e^(g_C) S + (e^(g_C - g) k)^T v'``.
-  Everything but those three lines is independent of the state and is made
-  for all chunks at once in XLA (``_factors``; the triangular inverse by
-  forward substitution in 16-row blocks, merged by products: a Neumann
-  series loses digits where ``beta k.k`` nears 2). The three lines, which
-  have to follow the chunks in order, are the Pallas kernel
-  ``gated_delta_prefill`` on one TPU device (the state stays in VMEM across
-  a row's chunks, ``_HEAD_BLOCK`` heads a grid step) and a ``lax.scan``
-  elsewhere, the kernel's oracle.
+  ``T`` is made by forward substitution in ``_SOLVE_BLOCK``-row diagonal
+  blocks, merged upward by products (a Neumann series loses digits where
+  ``beta k.k`` nears 2). Two carriers of the one form:
+
+  - On one TPU device, over a scan the chunk divides (every bucket of an
+    admission): the Pallas kernel ``gated_delta_prefill``, a grid step a
+    (row, block of ``_HEAD_BLOCK`` heads, chunk). The step takes the chunk's
+    ``q``, ``k``, ``v`` as the mixer hands them over (bfloat16, head sizes
+    unpadded) and builds everything above in VMEM: the decays, ``[q; k]
+    k^T``, ``A``, ``T`` (the diagonal blocks solved side by side in two
+    vregs, a column at a time; three merge levels on the rows they change),
+    then ``v' = T (beta (v - e^g k S))`` (``T`` taken out of ``u - w S``:
+    one product for three), ``o`` and the new state. The state block stays
+    in VMEM from a row's first chunk to its last. A head's steps hang on
+    each other and the heads' do not, so every line of the kernel is
+    written for all heads of the block at once (``[H, ., .]`` arrays,
+    batched products): the compiler fills one head's waits with another's
+    work, and a bucket's executable traces a sixth of the operations a
+    loop over heads would (a process traces every bucket at start-up: the
+    cell's ``setup_s``). XLA's part is the layout alone: ``q``,
+    ``k``, ``v`` a head at a time with the tokens along the lanes (``[B, H,
+    d, T]``; the kernel turns a chunk's blocks itself), ``o`` back, and the
+    per-token scalars ``g`` (a cumulative sum inside each chunk) and
+    ``beta`` (``[B, T, H]`` float32) in the two layouts the kernel reads
+    them in, along the lanes and down the rows. Nothing a (head, chunk)
+    pair in float32 (``A``, ``T``, ``p``: ``[c, c]``; ``w``, ``u``, ``qg``,
+    ``kd``: ``[c, dk | dv]``) exists outside the kernel.
+  - Elsewhere (the CPU, a mesh of more than one device, a scan the chunk
+    does not divide: the hybrid's chunked prefill): ``_factors`` makes those
+    matrices for all chunks at once in XLA and a ``lax.scan`` over the
+    chunks runs the three lines that meet the state. It is the kernel's
+    oracle in the tests, which is why it stays as it was written.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +76,7 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 128          # tokens a chunk: every matrix of the kernel lane-aligned
 _LANES = 128
 _SOLVE_BLOCK = 16    # rows solved by substitution before blocks are merged
-_HEAD_BLOCK = 6      # most heads a grid step of the kernel takes
+_HEAD_BLOCK = 6      # most heads a grid step of the prefill kernel takes
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 _STEP_VMEM = 100 << 20   # most a decode step's kernel may ask of VMEM
@@ -294,24 +316,121 @@ def _scan_chunks(f, state):
     return jnp.moveaxis(o, 0, 2), state
 
 
-def _kernel(w_ref, u_ref, qg_ref, kdt_ref, p_ref, dec_ref, s0_ref, o_ref,
-            s_ref, *, heads: int):
-    """One chunk of ``heads`` heads of one row. The state block's index does
-    not move along the chunk axis, so ``s_ref`` stays in VMEM from a row's
-    first chunk to its last and is written back once."""
+def _dot(a, b):
+    """``a[h] b[h]`` for every head ``h`` ([H, m, k] x [H, k, n]) in float32
+    at ``HIGHEST``. Two bfloat16 operands take the MXU's one native pass: a
+    product of two bfloat16 numbers is exact in float32, so that pass IS
+    the ``HIGHEST`` result of the same numbers widened (its five further
+    passes would multiply zeros)."""
+    exact = a.dtype == b.dtype == jnp.bfloat16
+    if not exact:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jax.lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))),
+                               precision=None if exact else _HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _odd_blocks(x, blk: int):
+    """The rows of each ``x[h]`` that lie in its odd ``blk``-row blocks,
+    stacked."""
+    return jnp.concatenate([x[:, i:i + blk]
+                            for i in range(blk, x.shape[1], 2 * blk)], axis=1)
+
+
+def _inv_unit_lower_vmem(a):
+    """``_inv_unit_lower`` inside the kernel: ``(I + a[h])^-1`` for the
+    strictly lower-triangular ``a`` [H, c, c] (``c`` a multiple of
+    ``_SOLVE_BLOCK``, a power of two), every step for all heads at once: a
+    head's steps hang on each other, the heads' do not, and the compiler
+    fills one's waits with another's work.
+
+    The diagonal blocks are laid side by side, ``d[h, r, blk b + s] = a[h,
+    blk b + r, blk b + s]`` ([blk, c] a head: two vregs), and solved
+    together: row ``r`` of every block's inverse is ``e_r - sum_{j<r} a[r,
+    j] x_j``, taken a column ``j`` at a time (``a[., j]`` spread over its
+    block's lanes by a lane gather). The blocks go back on the diagonal and
+    are merged upward: ``X21 = -X22 a21 X11`` for every pair of a level at
+    once, on the half of the rows a level changes (those of the odd
+    blocks), ``a21`` picked out of them by a mask."""
+    H, c, _ = a.shape
+    blk = _SOLVE_BLOCK
+    seg, off = _iota((blk, c), 1) // blk, _iota((blk, c), 1) % blk
+    d = sum(jnp.where(seg == b, a[:, b * blk:(b + 1) * blk], 0.0)
+            for b in range(c // blk))                            # [H, blk, c]
+    x = jnp.broadcast_to(jnp.where(off == _iota((blk, c), 0), 1.0, 0.0),
+                         d.shape)
+    lane = _iota((H * blk, c), 1)
+    first = lane - lane % blk           # a lane's block's first lane
+    for j in range(blk - 1):
+        # lane j of a block -> every lane of it; rows <= j of column j are 0
+        cj = jnp.take_along_axis(d.reshape(H * blk, c), first + j, axis=1)
+        x = x - cj.reshape(d.shape) * x[:, j:j + 1]
+    x = jnp.concatenate([jnp.where(seg == b, x, 0.0)
+                         for b in range(c // blk)], axis=1)      # [H, c, c]
+    half = (c // 2, c)
+    while blk < c:
+        # the odd blocks' rows: block 2p + 1 of the level sits at p here
+        left = _iota(half, 1) // blk == 2 * (_iota(half, 0) // blk)
+        m = _dot(jnp.where(left, _odd_blocks(a, blk), 0.0), x)  # a21 X11
+        zero = jnp.zeros((H, blk, c), jnp.float32)
+        m = jnp.concatenate([y for i in range(0, c // 2, blk)
+                             for y in (zero, m[:, i:i + blk])], axis=1)
+        odd = _odd_blocks(x, blk)
+        new = odd - _dot(odd, m)                                # [X21, X22]
+        x = jnp.concatenate([y for i in range(0, c // 2, blk)
+                             for y in (x[:, 2 * i:2 * i + blk],
+                                       new[:, i:i + blk])], axis=1)
+        blk *= 2
+    return x
+
+
+def _kernel(q_ref, k_ref, v_ref, g_ref, cols_ref, s0_ref, o_ref, s_ref):
+    """One chunk of the block's heads of one row, every line for all heads
+    at once ([H, ., .] arrays): the chunk's factors from its ``q``, ``k``
+    ([H, dk, c]), ``v`` ([H, dv, c]: the tokens along the lanes, turned
+    here) and per-token scalars (``g_ref`` [H, 1, c]: the running sum of
+    ``log alpha`` along the lanes; ``cols_ref`` [c, 2 H]: the same sum, then
+    ``beta``, a head a column), then the three lines that meet the state;
+    ``o_ref`` [H, dv, c]. The state block's index does not move along the
+    chunk axis, so ``s_ref`` stays in VMEM from a row's first chunk to its
+    last and is written back once."""
     @pl.when(pl.program_id(2) == 0)
     def _start():
         s_ref[...] = s0_ref[...]
 
-    def dot(a, b):
-        return jnp.dot(a, b, preferred_element_type=jnp.float32,
-                       precision=_HIGHEST)
-
-    for h in range(heads):
-        s = s_ref[h]                                            # [dk, dv]
-        vn = u_ref[h] - dot(w_ref[h], s)                        # [c, dv]
-        o_ref[h] = dot(qg_ref[h], s) + dot(p_ref[h], vn)
-        s_ref[h] = dec_ref[h] * s + dot(kdt_ref[h], vn)
+    f32 = jnp.float32
+    H, _, c = q_ref.shape
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    g_row = g_ref[...]                                          # [H, 1, c]
+    g = jnp.stack([cols_ref[:, h:h + 1] for h in range(H)])     # [H, c, 1]
+    beta = jnp.stack([cols_ref[:, H + h:H + h + 1] for h in range(H)])
+    # e^(g_i - g_j) at and below the diagonal, 0 above (masked before the
+    # exponential: above it the difference is positive, unbounded)
+    gam = jnp.where(col <= row,
+                    jnp.exp(jnp.where(col <= row, g - g_row, 0.0)), 0.0)
+    # [q; k] as it arrived (bfloat16), and [q; k] k^T from it
+    qk = jnp.concatenate([jnp.swapaxes(q_ref[...], 1, 2),
+                          jnp.swapaxes(k_ref[...], 1, 2)], axis=1)
+    qk_kk = _dot(qk, k_ref[...])                                # [H, 2c, c]
+    t = _inv_unit_lower_vmem(
+        jnp.where(col < row, beta * gam * qk_kk[:, c:], 0.0))
+    eg = jnp.exp(g)
+    s = s_ref[...]                                              # [H, dk, dv]
+    # e^g comes out of the products with the state
+    qs_ks = _dot(qk, s)                                         # [H, 2c, dv]
+    # v' = u - w S = T (beta v) - T (beta e^g k) S, with T taken out
+    v = jnp.swapaxes(v_ref[...], 1, 2).astype(f32)
+    vn = _dot(t, beta * (v - eg * qs_ks[:, c:]))                # [H, c, dv]
+    o = eg * qs_ks[:, :c] + _dot(gam * qk_kk[:, :c], vn)
+    o_ref[...] = jnp.swapaxes(o, 1, 2)
+    g_end = g_row[:, :, c - 1:]                                 # [H, 1, 1]
+    dec = jnp.exp(jnp.broadcast_to(g_end, (H, 1, s.shape[2])))
+    # (e^(g_C - g) k)^T v' = k^T (e^(g_C - g) v')
+    s_ref[...] = dec * s + _dot(k_ref[...], jnp.exp(g_end - g) * vn)
 
 
 def _pad_to(x, axis: int, n: int):
@@ -320,39 +439,58 @@ def _pad_to(x, axis: int, n: int):
     return jnp.pad(x, pad) if n != x.shape[axis] else x
 
 
-def _kernel_chunks(f, state, interpret: bool):
-    """The kernel over the factors: head sizes padded to the lane tile
-    (zeros: a padded key column meets a zero state row), ``kd`` handed over
-    transposed so that every product is a plain ``[m,k] x [k,n]``."""
-    B, H, N, c, dk = f["w"].shape
-    dv = f["u"].shape[-1]
-    dkp, dvp = -(-dk // _LANES) * _LANES, -(-dv // _LANES) * _LANES
+def _prefill_vmem_bytes(hb: int, c: int, dk: int, dv: int) -> int:
+    """What ``gated_delta_prefill`` holds in VMEM: a grid step's blocks
+    (``q``, ``k``, ``v`` in bfloat16 and ``o`` in float32 with the chunk
+    along the lanes; the state in and out, its ``dv`` rounded up to the lane
+    tile), each double-buffered, and room for the float32 matrices of the
+    block's heads that do not fit the registers (``[hb, c, c]``, ``[hb, c,
+    dv]``)."""
+    blocks = hb * (c * (2 * dk + dv) * 2 + c * dv * 4
+                   + 2 * dk * -(-dv // _LANES) * _LANES * 4)
+    return 2 * blocks + (8 << 20)
+
+
+def _kernel_chunks(q, k, v, log_alpha, beta, state, interpret: bool):
+    """The kernel over a scan of whole chunks. XLA's part: ``q``, ``k``,
+    ``v`` laid out a head at a time with the tokens along the lanes
+    (``[B, H, d, T]``: the layout XLA's own normalisation of ``q`` and ``k``
+    reduces in, so one copy a tensor is left where ``[B, H, T, d]`` takes
+    two), and the per-token scalars (the running sum of ``log alpha`` inside
+    each chunk, ``beta``) in the two layouts the kernel reads them in.
+    -> (o [B,H,dv,T], final state)."""
+    B, T, H, dk = q.shape
+    dv, c, f32 = v.shape[-1], CHUNK, jnp.float32
+    N = T // c
     hb = max(h for h in range(1, _HEAD_BLOCK + 1) if H % h == 0)
-    w, qg = _pad_to(f["w"], -1, dkp), _pad_to(f["qg"], -1, dkp)
-    kdt = _pad_to(f["kd"], -1, dkp).swapaxes(-1, -2)            # [..,dkp,c]
-    u = _pad_to(f["u"], -1, dvp)
-    dec = jnp.broadcast_to(f["dec"][..., None, None], (B, H, N, 1, dvp))
-    s0 = _pad_to(_pad_to(state, -1, dvp), -2, dkp)
+    g = jnp.cumsum(log_alpha.astype(f32).reshape(B, N, c, H), axis=2)
+    g_rows = jnp.moveaxis(g.reshape(B, N, c, H // hb, hb, 1), 2, 5)
+    cols = jnp.concatenate([g.reshape(B, T, H // hb, hb),
+                            beta.astype(f32).reshape(B, T, H // hb, hb)], -1)
 
-    def chunked(*tail):
-        return pl.BlockSpec((None, hb, None) + tail,
-                            lambda b, h, n: (b, h, n, 0, 0))
+    def tokens(d):
+        return pl.BlockSpec((None, hb, d, c), lambda b, h, n: (b, h, 0, n))
 
-    whole = pl.BlockSpec((None, hb, dkp, dvp), lambda b, h, n: (b, h, 0, 0))
-    o, s = pl.pallas_call(
-        functools.partial(_kernel, heads=hb),
+    whole = pl.BlockSpec((None, hb, dk, dv), lambda b, h, n: (b, h, 0, 0))
+    return pl.pallas_call(
+        _kernel,
         grid=(B, H // hb, N),
-        in_specs=[chunked(c, dkp), chunked(c, dvp), chunked(c, dkp),
-                  chunked(dkp, c), chunked(c, c), chunked(1, dvp), whole],
-        out_specs=[chunked(c, dvp), whole],
-        out_shape=[jax.ShapeDtypeStruct((B, H, N, c, dvp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, H, dkp, dvp), jnp.float32)],
+        in_specs=[tokens(dk), tokens(dk), tokens(dv),
+                  pl.BlockSpec((None, None, None, hb, 1, c),
+                               lambda b, h, n: (b, n, h, 0, 0, 0)),
+                  pl.BlockSpec((None, None, c, 2 * hb),
+                               lambda b, h, n: (b, h, n, 0)),
+                  whole],
+        out_specs=[tokens(dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((B, H, dv, T), f32),
+                   jax.ShapeDtypeStruct((B, H, dk, dv), f32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_prefill_vmem_bytes(hb, c, dk, dv)),
         name="gated_delta_prefill",
         interpret=interpret,
-    )(w, u, qg, kdt, f["p"], dec, s0)
-    return o[..., :dv], s[..., :dk, :dv]
+    )(*(jnp.transpose(x, (0, 2, 3, 1)) for x in (q, k, v)), g_rows,
+      jnp.moveaxis(cols, 2, 1), state)
 
 
 def prefill_scan(q, k, v, log_alpha, beta, state, kernel=None):
@@ -362,18 +500,23 @@ def prefill_scan(q, k, v, log_alpha, beta, state, kernel=None):
     with ``log_alpha = 0`` and ``beta = 0`` leaves the state untouched (its
     ``o`` is read by nobody); ``T`` need not be a multiple of the chunk.
     ``kernel``: None asks ``prefill_engages``; True / False force the Pallas
-    kernel (interpreted off the TPU) / the ``lax.scan``."""
+    kernel (interpreted off the TPU; its chunk is always ``CHUNK``, a
+    shorter scan padded up to it) / the ``lax.scan``."""
     B, T, H, _ = q.shape
     c = min(CHUNK, T)
-    Tp = -(-T // c) * c
     if kernel is None:
-        kernel = prefill_engages(Tp)
-    f = _factors(*(_pad_to(x, 1, Tp) for x in (q, k, v, log_alpha, beta)), c)
+        kernel = prefill_engages(-(-T // c) * c)
+    if kernel:
+        c = CHUNK
+    Tp = -(-T // c) * c
+    q, k, v, log_alpha, beta = (_pad_to(x, 1, Tp)
+                                for x in (q, k, v, log_alpha, beta))
     state = state.astype(jnp.float32)
     if kernel:
-        o, state = _kernel_chunks(f, state,
+        o, state = _kernel_chunks(q, k, v, log_alpha, beta, state,
                                   interpret=jax.default_backend() != "tpu")
+        o = jnp.transpose(o, (0, 3, 1, 2))                      # [B,T,H,dv]
     else:
-        o, state = _scan_chunks(f, state)
-    o = jnp.moveaxis(o, 1, 3).reshape(B, Tp, H, -1)             # [B,T,H,dv]
+        o, state = _scan_chunks(_factors(q, k, v, log_alpha, beta, c), state)
+        o = jnp.moveaxis(o, 1, 3).reshape(B, Tp, H, -1)
     return o[:, :T], state

@@ -451,14 +451,28 @@ def test_admission_of_two_rows_compiles_for_v5e_landing_in_place(
 # 12 linear + 4 full layers, 16 slots x 4096), compiled for the same
 # described chip (PR 31). Its kernels ask the backend, which is the CPU
 # here, so the tests answer for it.
+def _head_chunk_factors(text: str):
+    """Lines of a compiled module that make a float32 array a (head, chunk)
+    pair of the hybrid's 30 linear heads (``[1, 30, N, 128, .]``)."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"f32\[1,30,\d+,128,\d+\]", line)]
+
+
 @pytest.mark.level("unit")
 def test_gated_delta_kernel_compiles_for_v5e_at_the_published_widths(
         v5e_chip, monkeypatch):
     """Mosaic takes the chunked scan's kernel with 96-wide keys and 192-wide
-    values (padded to the lane tile inside the call) at a 1024 bucket."""
+    values as they are (no pad to the lane tile outside the call) at the
+    1024 and the 4096 bucket, and since PR 41 the kernel builds a chunk's
+    factors itself: XLA makes no float32 array a (head, chunk) pair
+    (``[1, 30, N, 128, 128]`` for ``a``, ``T``, ``p``; ``[.., 128, 96 | 192
+    | 256]`` for ``w``, ``u``, ``qg``, ``kd``), only the relayouts of q, k,
+    v, o and the per-token scalars, and the scan's temporaries are under
+    those of the XLA-made factors (compiled here, PR 41: 0.054 GB at 1024
+    and 0.497 GB at 4096 then, none at either now)."""
     from kubetorch_tpu.ops import gated_delta
 
-    b, t, h, dk, dv = 1, 1024, 30, 96, 192
+    b, h, dk, dv = 1, 30, 96, 192
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -467,14 +481,18 @@ def test_gated_delta_kernel_compiles_for_v5e_at_the_published_widths(
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        text = jax.jit(gated_delta.prefill_scan).lower(
-            spec((b, t, h, dk)), spec((b, t, h, dk)), spec((b, t, h, dv)),
-            spec((b, t, h), jnp.float32), spec((b, t, h), jnp.float32),
-            spec((b, h, dk, dv), jnp.float32)).compile().as_text()
+        for t, temporaries in ((1024, 0.02e9), (4096, 0.2e9)):
+            exe = jax.jit(gated_delta.prefill_scan).lower(
+                spec((b, t, h, dk)), spec((b, t, h, dk)), spec((b, t, h, dv)),
+                spec((b, t, h), jnp.float32), spec((b, t, h), jnp.float32),
+                spec((b, h, dk, dv), jnp.float32)).compile()
+            text = exe.as_text()
+            assert text.count("tpu_custom_call") == 1, t
+            assert "gated_delta_prefill" in text, t
+            assert not _head_chunk_factors(text), t
+            assert exe.memory_analysis().temp_size_in_bytes < temporaries, t
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_on)
-    assert text.count("tpu_custom_call") == 1
-    assert "gated_delta_prefill" in text
 
 
 @pytest.mark.level("unit")
@@ -595,6 +613,9 @@ def test_hybrid_executables_compile_for_v5e_with_state_beside_kv(
     else:
         assert "gated_delta_prefill" in text
         assert "admit_flash_attention" in text
+        # since PR 41 the scan's kernel builds its factors: XLA makes no
+        # float32 array a (head, chunk) pair in the admission
+        assert _head_chunk_factors(text) == []
 
 
 # The fourth decoder (models/window_moe.py, SmallThinker-21BA3B widths: 28

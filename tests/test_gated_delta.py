@@ -1,15 +1,17 @@
 """The gated delta rule (``ops/gated_delta.py``): its chunked form, as the
-``lax.scan`` over chunks and as the Pallas kernel in interpret mode, against
-the rule itself one token at a time; and the decode step's kernel over the
-stacked state leaf (``step_rows``, interpret mode) against the XLA ``step``.
+``lax.scan`` over chunks of XLA-made factors and as the Pallas kernel that
+builds a chunk's factors itself (interpret mode), against the rule itself
+one token at a time; and the decode step's kernel over the stacked state
+leaf (``step_rows``, interpret mode) against the XLA ``step``.
 
 Tolerance. All three are float32 sums of the same products in different
-orders; the chunked form also inverts a unit triangular matrix a chunk
-(forward substitution, exact in exact arithmetic). Outputs are O(1); they
-agree to ~2e-6 on these seeds, and 2e-5 is held. What the chunking could
-get wrong (a decay applied on the wrong side of a token, the diagonal left
-out of the intra-chunk product, a chunk boundary's state) moves outputs by
-1e-2 or more.
+orders; the chunked forms also invert a unit triangular matrix a chunk
+(forward substitution, exact in exact arithmetic), the ``lax.scan`` form in
+XLA and the kernel inside itself. Outputs are O(1); they agree to ~2e-6 on
+these seeds, and 2e-5 is held. What the chunking could get wrong (a decay
+applied on the wrong side of a token, the diagonal left out of the
+intra-chunk product, a chunk boundary's state) moves outputs by 1e-2 or
+more.
 """
 
 import jax
@@ -45,7 +47,8 @@ def close(a, b):
 
 
 # T: under one solve block, under one chunk, one chunk, not a multiple of
-# the chunk, several chunks
+# the chunk, several chunks (the kernel's chunk is always 128: a shorter
+# scan is padded up to it with held positions)
 @pytest.mark.parametrize("T", [5, 37, 128, 300, 384])
 @pytest.mark.parametrize("kernel", [False, True])
 def test_chunked_scan_equals_the_recurrence(T, kernel):
@@ -54,6 +57,33 @@ def test_chunked_scan_equals_the_recurrence(T, kernel):
     got_o, got_s = gd.prefill_scan(*args, kernel=kernel)
     assert got_o.shape == want_o.shape == (B, T, H, DV)
     assert float(jnp.abs(want_o).max()) > 0.5
+    assert close(got_o, want_o) and close(got_s, want_s)
+
+
+# The published head shape (30 heads of 96 keys x 192 values: five head
+# blocks, lane dims of three quarters and one and a half tiles); q, k, v in
+# bfloat16 as the mixer hands them over (the kernel then takes [q; k] k^T
+# in the MXU's one native pass); a nonzero start state carried over four
+# chunks of one row.
+SHAPES = {
+    "published_heads": dict(T=256, b=1, h=30, dk=96, dv=192),
+    "bfloat16_qkv": dict(T=256, b=1, h=6, dk=96, dv=192, dtype=jnp.bfloat16),
+    "four_chunks_from_a_state": dict(T=512, b=1),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("kernel", [False, True])
+def test_chunked_scan_equals_the_recurrence_at_the_cells_shapes(shape, kernel):
+    shape = dict(SHAPES[shape])
+    T, dtype = shape.pop("T"), shape.pop("dtype", jnp.float32)
+    q, k, v, *rest, state = draw(T, seed=T, **shape)
+    args = (*(x.astype(dtype) for x in (q, k, v)), *rest, state)
+    want_o, want_s = gd.recurrence(*args)
+    got_o, got_s = gd.prefill_scan(*args, kernel=kernel)
+    assert got_o.shape == want_o.shape == q.shape[:3] + v.shape[3:]
+    assert float(jnp.abs(want_o).max()) > 0.25
+    assert float(jnp.abs(state).max()) > 1.0        # the state it starts from
     assert close(got_o, want_o) and close(got_s, want_s)
 
 
@@ -93,6 +123,26 @@ def test_a_held_token_is_bit_for_bit_no_token():
     assert np.array_equal(np.asarray(new), np.asarray(S))
 
 
+@pytest.mark.parametrize("kernel", [False, True])
+def test_a_row_that_ends_inside_a_chunk_keeps_its_state_bit_for_bit(kernel):
+    """Three chunks, the row's real tokens end inside the middle one: the
+    wholly held third chunk hands the state on bit for bit (what a bucket's
+    padding relies on), so the scan ends where a scan of two chunks ends;
+    and the positions held inside the middle chunk change nothing a scan of
+    the real tokens alone would not give."""
+    T, n = 384, 200
+    q, k, v, la, beta, S = draw(T, seed=13, b=1)
+    live = (jnp.arange(T) < n)[None, :, None]
+    la, beta = jnp.where(live, la, 0.0), jnp.where(live, beta, 0.0)
+    _, full = gd.prefill_scan(q, k, v, la, beta, S, kernel=kernel)
+    _, two = gd.prefill_scan(*(x[:, :256] for x in (q, k, v, la, beta)), S,
+                             kernel=kernel)
+    assert np.array_equal(np.asarray(full), np.asarray(two))
+    _, want = gd.recurrence(*(x[:, :n] for x in (q, k, v, la, beta)), S)
+    assert close(full, want)
+    assert float(jnp.abs(full - S).max()) > 1e-2
+
+
 def test_triangular_inverse_where_a_series_would_lose_its_digits():
     """Keys that nearly coincide and ``beta`` near 2: ``I + A`` has entries
     near 2 all below the diagonal, its inverse stays O(1), and the powers
@@ -102,6 +152,26 @@ def test_triangular_inverse_where_a_series_would_lose_its_digits():
     inv = gd._inv_unit_lower(a[None])[0]
     err = jnp.abs(inv @ (jnp.eye(n) + a) - jnp.eye(n)).max()
     assert float(err) < 1e-3 and float(jnp.abs(inv).max()) < 4.0
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_coincident_keys_go_through_the_scan_as_through_the_rule(kernel):
+    """The same ill-conditioned inverse THROUGH ``prefill_scan``: every key
+    of a head the same unit vector, ``beta`` 1.9, one chunk of 128, so the
+    substitution and the merges inside the kernel (and those of
+    ``_inv_unit_lower`` under the ``lax.scan``) meet ``A`` = 1.9 below the
+    diagonal times the decays. ``o`` and the state stay finite and agree
+    with the rule a token at a time to 1e-3."""
+    T = 128
+    q, k, v, la, beta, S = draw(T, seed=11)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    beta, la = jnp.full_like(beta, 1.9), jnp.full_like(la, -1e-3)
+    want_o, want_s = gd.recurrence(q, k, v, la, beta, S)
+    got_o, got_s = gd.prefill_scan(q, k, v, la, beta, S, kernel=kernel)
+    assert bool(jnp.isfinite(got_o).all() & jnp.isfinite(got_s).all())
+    assert float(jnp.abs(got_o - want_o).max()) < 1e-3
+    assert float(jnp.abs(got_s - want_s).max()) < 1e-3
+    assert float(jnp.abs(want_s).max()) > 1.0
 
 
 def test_kernel_engages_only_where_its_chunk_divides_the_scan(monkeypatch):
