@@ -9,8 +9,8 @@ O(1) in depth.
 """
 
 from kubetorch_tpu.models.configs import (HybridLinearConfig,
-                                          LatentMoEConfig, LlamaConfig,
-                                          MoEConfig, ViTConfig,
+                                          IndexedMoEConfig, LatentMoEConfig,
+                                          LlamaConfig, MoEConfig, ViTConfig,
                                           WindowMoEConfig)
 from kubetorch_tpu.models import llama
 
@@ -23,7 +23,7 @@ def __getattr__(name):
 
     if name in ("generate", "quant", "rolling", "speculative", "lora",
                 "embed", "decoder", "latent_moe", "hybrid_linear",
-                "window_moe"):
+                "window_moe", "indexed_moe"):
         return importlib.import_module(f"kubetorch_tpu.models.{name}")
     if name == "LoraConfig":
         return importlib.import_module(
@@ -47,8 +47,9 @@ def __getattr__(name):
 
 
 __all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig",
-           "HybridLinearConfig", "WindowMoEConfig", "ViTConfig",
-           "decoder", "latent_moe", "hybrid_linear", "window_moe", "llama",
+           "HybridLinearConfig", "WindowMoEConfig", "IndexedMoEConfig",
+           "ViTConfig", "decoder", "latent_moe", "hybrid_linear",
+           "window_moe", "indexed_moe", "llama",
            "Generator",
            "generate", "quant", "quantize_params", "RollingGenerator",
            "SpeculativeGenerator", "speculative", "lora", "LoraConfig",

@@ -342,6 +342,74 @@ class WindowMoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexedMoEConfig:
+    """Decoder whose attention CHOOSES its keys (``models/indexed_moe.py``;
+    the layer as the ``KeyeVL2`` public config's language model writes it,
+    ``sa_config``): grouped-query attention (``n_heads`` over ``n_kv_heads``
+    of ``head_dim``, an RMSNorm with a learned weight over each query and
+    key head, rope over the whole head, halves layout) in front of which a
+    learned INDEX scores every earlier position: ``index_heads`` queries of
+    ``index_dim`` against ONE key of ``index_dim`` a position (a LayerNorm
+    with weight and bias over it, both rotated), ``I[t, s] = sum_j w[t, j]
+    relu(qI[t, j] . kI[s])`` in float32 with ``w`` a float32 projection of
+    the token; query ``t`` attends exactly the ``index_topk`` positions ``s
+    <= t`` with the largest ``I[t, s]`` (all of them while ``t + 1 <=
+    index_topk``; equal scores go to the lower position). What a row keeps
+    of a layer is therefore three leaves a position: ``k``, ``v`` and the
+    index key ``ik``. Every layer ends in ``top_k`` of ``n_experts`` SwiGLU
+    experts of ``expert_mlp_dim`` chosen by a float32 softmax router over
+    all experts, the chosen weights renormalised to sum 1. No shared
+    expert, no dense layer, no bias but the index key's norm."""
+
+    vocab_size: int = 151936
+    embed_dim: int = 2048
+    n_layers: int = 4
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    index_heads: int = 16
+    index_dim: int = 64
+    index_topk: int = 2048
+    rope_theta: float = 1e7
+    n_experts: int = 128
+    top_k: int = 8
+    expert_mlp_dim: int = 768
+    rms_eps: float = 1e-6
+    max_seq_len: int = 32768
+    dtype: str = "bfloat16"        # compute dtype
+    param_dtype: str = "bfloat16"  # storage dtype
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("n_heads must be a multiple of n_kv_heads")
+        if self.top_k > self.n_experts:
+            raise ValueError(f"top_k {self.top_k} > n_experts "
+                             f"{self.n_experts}")
+        if self.index_topk < 1 or self.head_dim % 2 or self.index_dim % 2:
+            raise ValueError("index_topk must be >= 1, head_dim and "
+                             "index_dim even")
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def storage_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+    @classmethod
+    def tiny(cls, **kw) -> "IndexedMoEConfig":
+        """CI config: two layers, an index that keeps 16 positions."""
+        base = dict(vocab_size=512, embed_dim=64, n_layers=2, n_heads=8,
+                    n_kv_heads=4, head_dim=16, index_heads=4, index_dim=8,
+                    index_topk=16, rope_theta=10000.0, n_experts=8, top_k=2,
+                    expert_mlp_dim=32, max_seq_len=64,
+                    dtype="float32", param_dtype="float32")
+        base.update(kw)
+        return cls(**base)
+
+
+@dataclasses.dataclass(frozen=True)
 class ViTConfig:
     """ViT-L/16-style image classifier (BASELINE config #4)."""
 

@@ -88,7 +88,8 @@ object it was built with (``decoder_for(cfg)``):
 functions; its executables are the ones they were), ``models/latent_moe.py``
 the second, ``models/hybrid_linear.py`` the third and the first with
 row-state leaves, ``models/window_moe.py`` the fourth and the first with
-rings.
+rings, ``models/indexed_moe.py`` the fifth and the first whose layer keeps a
+leaf (``ik``, the index's key) that attention itself never reads.
 """
 
 from __future__ import annotations
@@ -270,6 +271,7 @@ class LlamaDecoder:
 def decoder_for(cfg):
     """The decoder of a configuration object, by its type."""
     from kubetorch_tpu.models.configs import (HybridLinearConfig,
+                                              IndexedMoEConfig,
                                               LatentMoEConfig, LlamaConfig,
                                               WindowMoEConfig)
 
@@ -287,5 +289,9 @@ def decoder_for(cfg):
         from kubetorch_tpu.models.window_moe import WindowMoEDecoder
 
         return WindowMoEDecoder
+    if isinstance(cfg, IndexedMoEConfig):
+        from kubetorch_tpu.models.indexed_moe import IndexedMoEDecoder
+
+        return IndexedMoEDecoder
     raise TypeError(f"no decoder for a configuration of type "
                     f"{type(cfg).__name__}")

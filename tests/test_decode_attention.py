@@ -769,3 +769,147 @@ def test_window_moe_executables_compile_for_v5e_with_rings_beside_planes(
         assert "admit_window_attention" not in text
         assert "admit_flash_attention" not in text
         assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+
+
+# ---- the fifth decoder (models/indexed_moe.py, ops/indexed_attention.py) at
+# the published widths of keye-vl-2.0-30b-a3b-bf16-serve, 16 slots x 32768
+
+@pytest.mark.parametrize("kernel", ["index_select", "admit_attention",
+                                    "index_choice", "decode_attention"])
+def test_indexed_attention_kernels_compile_for_v5e(kernel, v5e_chip):
+    """The four kernels of the learned sparse attention at the cell's
+    largest shapes: the choice of a 32768-position admission (a VMEM scratch
+    of 128 x 32768 order keys, 16 MB, under a raised limit), its attention
+    under the int8 mask, a decode step's threshold search over 16 rows of
+    32768 + 8 order keys, and its ragged read of 16 rows of bfloat16 K/V (4
+    kv heads: two whole words a position) with the choice as a mask."""
+    from kubetorch_tpu.ops import indexed_attention
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    T, B, M, L = 32768, 16, 32768, 4
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        if kernel == "index_select":
+            exe = jax.jit(lambda *a: indexed_attention.index_select(
+                *a, topk=2048)).lower(
+                spec((1, T, 16, 64), bf16), spec((1, T, 64), bf16),
+                spec((1, T, 16), jnp.float32), spec((1,), i32)).compile()
+        elif kernel == "admit_attention":
+            exe = jax.jit(indexed_attention.admit_indexed_attention).lower(
+                spec((1, T, 32, 128), bf16), spec((1, T, 4, 128), bf16),
+                spec((1, T, 4, 128), bf16), spec((1, T, T), jnp.int8)
+            ).compile()
+        elif kernel == "index_choice":
+            exe = jax.jit(lambda keys: indexed_attention.index_choice(
+                keys, topk=2048)).lower(spec((B, M + 8), i32)).compile()
+        else:
+            exe = jax.jit(
+                lambda q, k, v, depth, keys, thr, tie:
+                indexed_attention.indexed_decode_attention(
+                    q, k, v, jnp.int32(1), decode_attention.plan(depth, M),
+                    keys, thr, tie)).lower(
+                spec((B, 32, 128), bf16), spec((L, B, M, 4, 128), bf16),
+                spec((L, B, M, 4, 128), bf16), spec((B,), i32),
+                spec((B, M), i32), spec((B,), i32), spec((B,), i32)
+            ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    text = exe.as_text()
+    assert "tpu_custom_call" in text
+    assert {"index_select": "index_select",
+            "admit_attention": "admit_indexed_attention",
+            "index_choice": "index_choice",
+            "decode_attention": "indexed_decode_attention"}[kernel] in text
+
+
+@pytest.mark.parametrize("which", ["decode", "admit_32768", "admit_2048"])
+def test_indexed_moe_executables_compile_for_v5e_with_the_index_key_beside_kv(
+        which, v5e_chip, monkeypatch):
+    """The cell's decode and admission executables whole, at 16 slots x
+    32768 and 4 layers. Weights 6.25 GB and the cache 4.56 GB (4.29 of K and
+    V, 0.27 of index keys) are arguments, every cache leaf stays aliased in
+    place; the decode chunk holds the two decode kernels and its
+    temporaries stay under 0.05 GB (5 MB read here, PR 42: the scores
+    and order keys of 16 rows); the 32768 bucket chooses and attends through
+    the two admission kernels and its temporaries (the int8 choice of one
+    layer is 1.07 GB) leave room on 16 GB; the 2048 bucket, where every
+    query sees everything, attends through the plain flash kernel and holds
+    no kernel of the index."""
+    from kubetorch_tpu.models import IndexedMoEConfig, indexed_moe
+    from kubetorch_tpu.models.rolling import RollingGenerator
+    from kubetorch_tpu.parallel.sharding import ShardingRules
+
+    cfg = IndexedMoEConfig()
+    b, m, vocab = 16, 32768, cfg.vocab_size
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def specs(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    params = specs(jax.eval_shape(
+        lambda: indexed_moe.init(jax.random.key(0), cfg)))
+    cache = specs(jax.eval_shape(lambda: indexed_moe.init_cache(cfg, b, m)))
+    assert cache["k"].shape == (4, b, m, 4, 128)
+    assert cache["ik"].shape == (4, b, m, 64)
+    state = (spec((b, vocab), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_))
+
+    def draw(n):
+        return (spec((n,), jnp.float32), spec((n,), jnp.float32),
+                spec((n, 64), jnp.int32), spec((2,), jnp.uint32))
+
+    rules = ShardingRules.default()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        if which == "decode":
+            exe = jax.jit(
+                lambda *a: RollingGenerator._decode_impl(
+                    *a, None, top_k=None, top_p=None, n_steps=8, cfg=cfg,
+                    rules=rules), donate_argnums=(1, 2, 3, 6)).lower(
+                params, cache, *state, *draw(b)).compile()
+        else:
+            p_pad = int(which.split("_")[1])
+            exe = jax.jit(
+                lambda *a: RollingGenerator._prefill_impl(
+                    *a, None, p_pad=p_pad, top_k=None, top_p=None, cfg=cfg,
+                    rules=rules),
+                donate_argnums=(1, 2, 3, 4, 5, 6)).lower(
+                params, cache, *state, spec((1, p_pad), jnp.int32),
+                spec((1,), jnp.int32), spec((1,), jnp.int32),
+                *draw(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    text, mem = exe.as_text(), exe.memory_analysis()
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    cache_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in cache.values())
+    assert 6.2e9 < weights < 6.3e9 and 4.55e9 < cache_bytes < 4.57e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert "moe_grouped_matmul" in text
+    if which == "decode":
+        assert "indexed_decode_attention" in text
+        assert "index_choice" in text
+        assert "index_select" not in text
+        assert mem.temp_size_in_bytes < 0.05e9, mem.temp_size_in_bytes
+    elif which == "admit_32768":
+        assert "index_select" in text
+        assert "admit_indexed_attention" in text
+        assert "admit_flash_attention" not in text
+        assert mem.temp_size_in_bytes < 3.5e9, mem.temp_size_in_bytes
+        # what the chip must hold at once fits its 16 GB with room
+        assert weights + cache_bytes + mem.temp_size_in_bytes < 15.0e9
+    else:
+        assert "admit_flash_attention" in text
+        assert "index_select" not in text
+        assert "admit_indexed_attention" not in text
+        assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
