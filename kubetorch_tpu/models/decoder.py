@@ -84,6 +84,14 @@ object it was built with (``decoder_for(cfg)``):
   a ``p_pad`` bucket, and those the band touches, a layer (what
   ``prefill_window_key_blocks`` / ``_band`` count).
 
+- a decoder with routed experts (``moe_rows_multiplied`` among its
+  ``counters``) also gives ``expert_admission(cfg, lens, p_pad)``: what its
+  expert layers make of one bucketed admission of rows ``lens`` long, from
+  shapes alone (``latent_moe.admission_plan``: tokens a pass, the row tile,
+  the work lists' row tiles and those of them that hold only padding; what
+  ``moe_piece_tokens_b<p_pad>``, ``moe_tile_rows_b<p_pad>``,
+  ``moe_admission_tiles`` and ``moe_padding_tiles_skipped`` say).
+
 ``models/llama.py`` is the first instance (``LlamaDecoder`` only names its
 functions; its executables are the ones they were), ``models/latent_moe.py``
 the second, ``models/hybrid_linear.py`` the third and the first with

@@ -24,7 +24,9 @@ The layer, on its input ``x`` (the float32 residual stream), at position
 4. ``m = RMSNorm(x')``; ``p = softmax(m W_r)`` over all experts in float32,
    the ``top_k`` largest renormalised to sum 1; ``x_next = x' + sum_e p_e
    W_down^e (silu(W_gate^e m) * (W_up^e m))``: dropless, the pairs sorted by
-   expert and the two products grouped (``latent_moe.routed_experts``).
+   expert and the two products grouped (``latent_moe.routed_experts``; an
+   admission's tokens in one pass where memory lets them,
+   ``latent_moe.admitted_experts``).
 
 **What a row keeps**: three positional leaves along ``max_len``, ``k`` and
 ``v`` (``[L, B, M, Hkv, D]``) and the index key ``ik`` (``[L, B, M, Di]``,
@@ -62,7 +64,7 @@ import jax.numpy as jnp
 from kubetorch_tpu.models.configs import IndexedMoEConfig
 from kubetorch_tpu.models.decoder import CacheLeaf
 from kubetorch_tpu.models.latent_moe import COUNTERS as MOE_COUNTERS
-from kubetorch_tpu.models.latent_moe import routed_experts
+from kubetorch_tpu.models.latent_moe import admission_plan, admitted_experts
 from kubetorch_tpu.ops import (decode_attention, flash_attention, grid_write,
                                indexed_attention)
 from kubetorch_tpu.ops.norms import rms_norm
@@ -84,9 +86,7 @@ COUNTERS = MOE_COUNTERS + INDEX_COUNTERS + PREFILL_COUNTERS
 # the leaves of a layer that are sliced a layer; the expert stacks are not
 _SMALL = ("attn_norm", "wqkv", "q_norm", "k_norm", "wo", "wiq", "wik",
           "ik_norm", "ik_bias", "wiw", "router", "mlp_norm")
-# tokens the expert layer takes at once (``window_moe._EXPERT_TOKENS``): a
-# 32768-position admission's 262144 pairs are 1 GB sorted
-_EXPERT_TOKENS = 4096
+
 # queries a pass of the plain-jnp admission (scores [block, T] float32)
 _QUERY_BLOCK = 512
 # what RollingGenerator can be asked for that this decoder does not carry
@@ -167,24 +167,25 @@ def route(m, router, cfg: IndexedMoEConfig):
                 top / jnp.sum(top, axis=-1, keepdims=True))
 
 
+def _held_bytes(cfg) -> int:
+    """What the attention of the LONGEST admission holds at its peak (the
+    generator's memory is laid out for that bucket; a shorter one may use
+    as much): the int8 choice beside the mask it is made under, q and the
+    attended, and the residual stream (2.95 GB at 32768, where the
+    executable compiled for v5e reads 3.12: PR 43)."""
+    L, it = cfg.max_seq_len, jnp.dtype(cfg.compute_dtype).itemsize
+    return (2 * L * L + 2 * L * cfg.n_heads * cfg.head_dim * it
+            + L * cfg.embed_dim * 4)
+
+
 def _experts(m, valid, chosen, weights, stack, i, cfg: IndexedMoEConfig):
-    """m [n,E] in the compute dtype -> (sum over each token's chosen SwiGLU
-    experts [n,E] float32, counters); more than ``_EXPERT_TOKENS`` tokens go
-    through in pieces of that many (``window_moe._experts``)."""
-    n = m.shape[0]
-
-    def some(args):
-        return routed_experts(*args, stack["we_gu"], stack["we_down"], i,
-                              cfg)
-
-    if n <= _EXPERT_TOKENS or n % _EXPERT_TOKENS:
-        return some((m, valid, chosen, weights))
-    pieces = n // _EXPERT_TOKENS
-    y, _ = jax.lax.map(some, tuple(
-        a.reshape((pieces, _EXPERT_TOKENS) + a.shape[1:])
-        for a in (m, valid, chosen, weights)))
-    # an admission's counters are counted on the host (``prefill_counters``)
-    return y.reshape(n, -1), {}
+    """m [n,E] in the compute dtype -> (sum over each token's chosen
+    experts [n,E] float32, counters): ``latent_moe.admitted_experts``, in one
+    pass where that holds no more than the admission's attention does
+    (``_held_bytes``)."""
+    return admitted_experts(m, valid, chosen, weights, stack["we_gu"],
+                            stack["we_down"], i, cfg, jax.nn.silu,
+                            _held_bytes(cfg))
 
 
 def _layer_norm(x, weight, bias, eps):
@@ -540,6 +541,12 @@ class IndexedMoEDecoder:
                 p_pad, p_pad, 0, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
         return indexed_attention.admit_engages(
             p_pad, cfg.index_topk, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+
+    @staticmethod
+    def expert_admission(cfg: IndexedMoEConfig, lens, p_pad: int):
+        """``latent_moe.admission_plan`` of this decoder's admissions."""
+        return admission_plan(cfg, cfg.embed_dim, lens, p_pad,
+                              _held_bytes(cfg), cfg.n_layers)
 
     @staticmethod
     def prefill_counters(cfg: IndexedMoEConfig, prompt_tokens: int):

@@ -52,6 +52,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from kubetorch_tpu.models.configs import LatentMoEConfig
 from kubetorch_tpu.models.decoder import CacheLeaf
@@ -61,7 +62,10 @@ from kubetorch_tpu.ops.rope import rope_angles
 
 Params = Dict[str, Any]
 COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
-            "moe_group_max")
+            "moe_group_max", "moe_rows_multiplied")
+# the largest float32 copy of a call's gathered expert rows that
+# ``routed_experts`` makes for its sum (a decode step's is 1-2 MB)
+_SUM_COPY_BYTES = 16 << 20
 # what RollingGenerator can be asked for that this decoder does not carry
 _REFUSED = {
     "kv_dtype": "an int8 latent cache (kv_dtype='int8')",
@@ -295,7 +299,7 @@ def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
     ``we_*_all`` the STACKED expert weights [Lm,X,..] and ``li`` this
     layer's index in them. ``cfg`` gives ``top_k`` and ``n_experts``;
     ``act`` is the gate's activation (SiLU here; ``models/window_moe.py``
-    hands in ReLU and its own configuration). Returns (y [n,E],
+    hands in ReLU and its own configuration). Returns (y [n,E] float32,
     counters)."""
     n, E = m.shape
     K, X = cfg.top_k, cfg.n_experts
@@ -311,15 +315,99 @@ def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
         a = (act(h[:, :half]) * h[:, half:]).astype(m.dtype)
         y = grouped_matmul.grouped_matmul(a, we_down_all, li, sizes)
         # back to token order, weighted: a gather by the inverse permutation
-        inv = jnp.argsort(order)
+        # and the float32 sum over a token's K rows. Where the float32 copy
+        # of the gathered rows would be large (an admission: 2 GB at 32768
+        # tokens) the rows are gathered one choice at a time and summed as
+        # they come; a decode step keeps the one gather, a quarter the ops
+        inv = jnp.argsort(order).reshape(n, K)
         g = jnp.where(valid[:, None], weights, 0.0)
-        out = jnp.einsum("nke,nk->ne", y[inv].reshape(n, K, E).astype(
-            jnp.float32), g)                                   # float32
+        if n * K * E * 4 <= _SUM_COPY_BYTES:
+            out = jnp.einsum("nke,nk->ne", y[inv].astype(jnp.float32), g)
+        else:
+            out = jnp.zeros((n, E), jnp.float32)
+            for c in range(K):
+                out = out + y[inv[:, c]].astype(jnp.float32) * g[:, c, None]
     counters = {"moe_assignments": K * jnp.sum(valid, dtype=jnp.int32),
                 "moe_experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32),
                 "moe_expert_slots": jnp.int32(X),
-                "moe_group_max": jnp.max(sizes)}
+                "moe_group_max": jnp.max(sizes),
+                "moe_rows_multiplied": grouped_matmul.rows_multiplied(
+                    sizes, n * K, E, h.shape[-1])}
     return out, counters
+
+
+def expert_pass_bytes(tokens: int, cfg, E: int, itemsize: int) -> int:
+    """What one pass of ``routed_experts`` over ``tokens`` tokens holds at
+    its widest, from static shapes: the sorted pairs' rows beside their
+    gate/up products ((E + 2 Mx) a pair) or the down products beside the
+    rows gathered back (2 E a pair), whichever is more, and the float32
+    sum."""
+    pairs, Mx = tokens * cfg.top_k, cfg.expert_mlp_dim
+    return pairs * max(E + 2 * Mx, 2 * E) * itemsize + tokens * E * 4
+
+
+def expert_piece(n: int, cfg, E: int, itemsize: int, held_bytes: int) -> int:
+    """Tokens of an ``n``-token admission the expert layer takes in one
+    pass: all of them where that pass (``expert_pass_bytes``) holds no more
+    than ``held_bytes``, what the caller's admission holds elsewhere at its
+    peak; else the largest half, quarter, ... that does (never under the
+    kernel's smallest row tile of pairs an expert, ``16 * n_experts /
+    top_k`` tokens: below that a piece only re-reads the experts)."""
+    piece = n
+    floor = 16 * cfg.n_experts // cfg.top_k
+    while (piece % 2 == 0 and piece // 2 >= floor
+           and expert_pass_bytes(piece, cfg, E, itemsize) > held_bytes):
+        piece //= 2
+    return piece
+
+
+def admitted_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
+                     cfg, act, held_bytes: int):
+    """``routed_experts`` for an admission of any length: every fetch of an
+    expert's weights should meet all the rows the admission has for it, so
+    the tokens go through in ONE pass where memory lets them and in the
+    largest pieces that fit where it does not (``expert_piece``), one after
+    the other. A piece reads the experts it touches again. Pieces return no
+    counters: an admission's are counted on the host
+    (``prefill_counters``)."""
+    n, E = m.shape
+    piece = expert_piece(n, cfg, E, m.dtype.itemsize, held_bytes)
+
+    def some(args):
+        return routed_experts(*args, we_gu_all, we_down_all, li, cfg,
+                              act=act)
+
+    if piece == n:
+        return some((m, valid, chosen, weights))
+    y, _ = jax.lax.map(some, tuple(
+        a.reshape((n // piece, piece) + a.shape[1:])
+        for a in (m, valid, chosen, weights)))
+    return y.reshape(n, E), {}
+
+
+def admission_plan(cfg, E: int, lens, p_pad: int,
+                   held_bytes: Optional[int], layers: int):
+    """What the expert layers make of ONE bucketed admission, on the host
+    and exact, from static shapes and the prompts' lengths (``lens``, a row
+    each, padded to ``p_pad``): ``(piece, tile, tiles, skipped)`` = tokens a
+    pass takes (``held_bytes`` None: all, the caller has no pieces), the
+    row tile's height, the row tiles of the work lists (one list a layer
+    serves both products) and those of them that hold no pair, the bucket's
+    padding, which the kernel neither fetches nor multiplies; tile 0 and no
+    tiles where the product is ``ragged_dot`` (the CPU, a mesh)."""
+    n = len(lens) * p_pad
+    it = jnp.dtype(cfg.compute_dtype).itemsize
+    piece = n if held_bytes is None else expert_piece(n, cfg, E, it,
+                                                      held_bytes)
+    m, wide = piece * cfg.top_k, 2 * cfg.expert_mlp_dim
+    if not grouped_matmul.runs_kernel(m, E, wide):
+        return piece, 0, 0, 0
+    tile = grouped_matmul.tiles_for(m, cfg.n_experts, E, wide)[0]
+    real = (np.arange(p_pad)[None, :] < np.asarray(lens)[:, None]).reshape(
+        n // piece, piece).sum(axis=1) * cfg.top_k
+    tiles = -(-m // tile) * (n // piece)
+    return (piece, tile, layers * tiles,
+            layers * int(tiles - (-(-real // tile)).sum()))
 
 
 def _feed_forward(x, valid, stack, i, kind, cfg: LatentMoEConfig):
@@ -590,6 +678,12 @@ class LatentMoEDecoder:
         computes exactly its prompt's pairs."""
         return {"moe_assignments":
                 prompt_tokens * cfg.top_k * cfg.n_moe_layers}
+
+    @staticmethod
+    def expert_admission(cfg: LatentMoEConfig, lens, p_pad: int):
+        """``admission_plan`` of this decoder: every bucket in one pass."""
+        return admission_plan(cfg, cfg.embed_dim, lens, p_pad, None,
+                              cfg.n_moe_layers)
 
     @staticmethod
     def state_rows_touched(cfg, rows: int, live: int) -> int:

@@ -414,6 +414,15 @@ class RollingGenerator:
         # chunks sum them on the device and they ride the tokens' fetch;
         # what a prefill adds is counted here, on the host
         self._model_counts = {name: 0 for name in self.model.counters}
+        # a decoder with routed experts: what its expert layers make of
+        # the bucketed admissions (``_count_experts``), by shapes and
+        # prompt lengths alone. Tokens a pass and the row tile by bucket
+        # (gauges), row tiles of the work lists and those of them that
+        # held no pair (the buckets' padding, skipped).
+        self._has_experts = "moe_rows_multiplied" in self._model_counts
+        self._expert_buckets: Dict[int, Tuple[int, int]] = {}
+        self._expert_tiles = {"moe_admission_tiles": 0,
+                              "moe_padding_tiles_skipped": 0}
         self._kv_position_bytes = position_bytes(self.model, cfg,
                                                  self.kv_quantized)
         # Row-state leaves (no position axis): what a row holds whatever its
@@ -596,7 +605,16 @@ class RollingGenerator:
         / ``_band`` (key blocks the window layers' admission attention
         computed, a layer, and those the band touches) and the gauges
         ``window_position_bytes`` (bytes a position holds over the ring
-        layers) and ``window_positions`` (the span)."""
+        layers) and ``window_positions`` (the span). For a decoder with
+        routed experts: ``moe_rows_multiplied`` beside ``moe_assignments``
+        among its device counters (rows the grouped product multiplied
+        over the decode steps against the pairs that count), and of its
+        bucketed admissions, from shapes and prompt lengths on the host:
+        ``moe_piece_tokens_b<bucket>`` and ``moe_tile_rows_b<bucket>``
+        (tokens a pass of the expert layer takes and its row tile, gauges
+        of every bucket admitted so far), ``moe_admission_tiles`` and
+        ``moe_padding_tiles_skipped`` (row tiles of the work lists, and
+        those of them that held only the bucket's padding)."""
         out = {f"decode_kv_positions_{k}": int(v)
                for k, v in self._kv_positions.items()}
         out.update(self._admissions)
@@ -606,6 +624,11 @@ class RollingGenerator:
                    for k, v in self._admit_positions.items())
         out.update(self._prefill_positions)
         out.update(self._model_counts)
+        if self._has_experts:
+            out.update(self._expert_tiles)
+            for p_pad, (piece, tile) in self._expert_buckets.items():
+                out[f"moe_piece_tokens_b{p_pad}"] = piece
+                out[f"moe_tile_rows_b{p_pad}"] = tile
         out["kv_position_bytes"] = self._kv_position_bytes
         out.update((f"decode_state_rows_{k}", int(v))
                    for k, v in self._state_rows.items())
@@ -622,6 +645,19 @@ class RollingGenerator:
         for name, n in self.model.prefill_counters(
                 self.cfg, prompt_tokens).items():
             self._model_counts[name] += n
+
+    def _count_experts(self, lens, p_pad: int) -> None:
+        """Account one bucketed admission's expert layers (a decoder that
+        has them): ``lens`` the rows' prompt lengths, a dummy row's 1.
+        Called under the generator's mesh, which the kernel's rule asks
+        for."""
+        if not self._has_experts:
+            return
+        piece, tile, tiles, skipped = self.model.expert_admission(
+            self.cfg, lens, p_pad)
+        self._expert_buckets[p_pad] = (piece, tile)
+        self._expert_tiles["moe_admission_tiles"] += tiles
+        self._expert_tiles["moe_padding_tiles_skipped"] += skipped
 
     def _count_kv_read(self) -> None:
         """Account one decode chunk, from the depths it starts at."""
@@ -1456,6 +1492,7 @@ class RollingGenerator:
         key = self._draw_key()
         with self._mesh_ctx():
             self._count_admission(n_pad, p_pad, own=prefix_id is None)
+            self._count_experts(lens, p_pad)
             state = (self.cache, self._logits, self._dpos, self._dactive,
                      self._dnt, self._dnt_valid)
             # host arrays go in as they are: the call uploads them itself,

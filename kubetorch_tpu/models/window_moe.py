@@ -21,7 +21,8 @@ The layer, on its input ``x`` (the float32 residual stream):
    m) * (W_up^e m))`` over the chosen experts: dropless, the pairs sorted by
    expert and the two products grouped (``latent_moe.routed_experts``, the
    second decoder's, with ReLU for its SiLU: ``ops/grouped_matmul.py`` reads
-   only the experts given a token).
+   only the experts given a token; an admission's tokens go through in one
+   pass where memory lets them, ``latent_moe.admitted_experts``).
 
 **What a row keeps.** A full layer keeps K and V of every position (``k``,
 ``v``: ``[L_full, B, max_len, Hkv, D]``). A window layer can never again see
@@ -67,7 +68,8 @@ import jax.numpy as jnp
 from kubetorch_tpu.models.configs import WindowMoEConfig
 from kubetorch_tpu.models.decoder import CacheLeaf
 from kubetorch_tpu.models.hybrid_linear import scan_runs
-from kubetorch_tpu.models.latent_moe import COUNTERS, routed_experts
+from kubetorch_tpu.models.latent_moe import (COUNTERS, admission_plan,
+                                             admitted_experts)
 from kubetorch_tpu.ops import decode_attention, flash_attention, grid_write
 from kubetorch_tpu.ops.norms import rms_norm
 from kubetorch_tpu.ops.rope import apply_rope, rope_angles
@@ -79,9 +81,7 @@ STACK = {FULL: "full", WINDOW: "window"}
 KV = {FULL: ("k", "v"), WINDOW: ("wk", "wv")}
 # the leaves of a layer that are sliced a layer; the expert stacks are not
 _SMALL = ("attn_norm", "wqkv", "wo", "router", "mlp_norm")
-# tokens the expert layer takes at once: a 16384-position admission's sorted
-# pairs, their two products and the float32 sum are 2.8 GB in one piece
-_EXPERT_TOKENS = 4096
+
 # what RollingGenerator can be asked for that this decoder does not carry
 _REFUSED = {
     "kv_dtype": "an int8 K/V cache (kv_dtype='int8'): a ring's scales would "
@@ -160,26 +160,27 @@ def route(x, router, cfg: WindowMoEConfig):
         return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
+def _held_bytes(cfg) -> int:
+    """What the attention of the LONGEST admission holds at its peak (the
+    generator's memory is laid out for that bucket; a shorter one may use
+    as much): the mask, the fused projection beside its float32 rotation,
+    q rotated, head-major, attended and back, and the residual stream
+    (1.36 GB at 16384, as the executable compiled for v5e reads: PR 43)."""
+    L, it = cfg.max_seq_len, jnp.dtype(cfg.compute_dtype).itemsize
+    heads = cfg.n_heads * cfg.head_dim
+    qkv = heads + 2 * cfg.n_kv_heads * cfg.head_dim
+    return (L * L + L * qkv * (it + 4) + 4 * L * heads * it
+            + L * cfg.embed_dim * 4)
+
+
 def _experts(m, valid, chosen, weights, stack, i, cfg: WindowMoEConfig):
-    """m [n,E] in the compute dtype -> (sum over each token's chosen ReGLU
-    experts [n,E] float32, counters). More than ``_EXPERT_TOKENS`` tokens
-    (a long admission) go through in pieces of that many, one after the
-    other: each piece reads the experts it touches again, and the sorted
-    pairs and their products stay a quarter of a 16384 bucket's."""
-    n = m.shape[0]
-
-    def some(args):
-        return routed_experts(*args, stack["we_gu"], stack["we_down"], i,
-                              cfg, act=jax.nn.relu)
-
-    if n <= _EXPERT_TOKENS or n % _EXPERT_TOKENS:
-        return some((m, valid, chosen, weights))
-    pieces = n // _EXPERT_TOKENS
-    y, counters = jax.lax.map(some, tuple(
-        a.reshape((pieces, _EXPERT_TOKENS) + a.shape[1:])
-        for a in (m, valid, chosen, weights)))
-    # an admission's counters are counted on the host (``prefill_counters``)
-    return y.reshape(n, -1), {}
+    """m [n,E] in the compute dtype -> (sum over each token's chosen
+    experts [n,E] float32, counters): ``latent_moe.admitted_experts``, in one
+    pass where that holds no more than the admission's attention does
+    (``_held_bytes``)."""
+    return admitted_experts(m, valid, chosen, weights, stack["we_gu"],
+                            stack["we_down"], i, cfg, jax.nn.relu,
+                            _held_bytes(cfg))
 
 
 def _qkv(h, layer, sin, cos, kind: str, cfg: WindowMoEConfig):
@@ -507,6 +508,12 @@ class WindowMoEDecoder:
             p_pad, cfg.window,
             WindowMoEDecoder.prefill_flash_engages(cfg, p_pad))
         return cfg.n_heads * visited, cfg.n_heads * band
+
+    @staticmethod
+    def expert_admission(cfg: WindowMoEConfig, lens, p_pad: int):
+        """``latent_moe.admission_plan`` of this decoder's admissions."""
+        return admission_plan(cfg, cfg.embed_dim, lens, p_pad,
+                              _held_bytes(cfg), cfg.n_layers)
 
     @staticmethod
     def prefill_counters(cfg: WindowMoEConfig, prompt_tokens: int):

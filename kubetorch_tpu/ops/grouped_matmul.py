@@ -14,9 +14,11 @@ fetched**: a decode step of a few rows reads only the experts those rows
 chose. An item multiplies its whole ``tile_m x k`` row tile by its group's
 ``k x tile_n`` block on the MXU and keeps the rows that belong to the group;
 the output tile stays in VMEM across the items of one row tile. Rows past the
-last group are one more item a tile that fetches nothing new and writes
-zeros. The number of items is a traced scalar: the grid is as long as the
-list.
+last group (an admission bucket's padding) are one more item a tile that
+fetches nothing, neither weights nor rows, multiplies nothing and writes
+zeros: **a tile with no row of any group costs no MXU pass**. The number of
+items is a traced scalar: the grid is as long as the list. The row tile's
+height follows the rows a group brings (``tiles_for``).
 
 Elsewhere (CPU, a mesh) the same product is ``jax.lax.ragged_dot`` on the
 sliced layer, which is also the kernel's oracle in the tests
@@ -35,6 +37,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Test hook, as ``decode_attention._FORCE_INTERPRET``.
 _FORCE_INTERPRET = False
+# the tall row tile and the rows a group must bring for it (``tiles_for``)
+_TALL, _TALL_ROWS = 256, 2048
 
 
 def engages(m: int, k: int, n: int) -> bool:
@@ -46,28 +50,54 @@ def engages(m: int, k: int, n: int) -> bool:
     return jax.default_backend() == "tpu" and (mesh.empty or mesh.size == 1)
 
 
-def tiles_for(m: int, n: int):
-    """(tile_m, tile_n). 128 rows fill the v5e's MXU and keep the rows an
-    item computes for nothing (those of the tile's other groups) few when
-    groups are small; fewer rows than that make one tile, rounded to the
-    bf16 sublane pack. ``tile_n``: the largest multiple of 128 up to 1024
-    that divides ``n`` (a k x tile_n bf16 block of 2048 x 1024 is 4 MB, two
-    in flight)."""
-    tile_m = 128 if m >= 128 else -(-m // 16) * 16
-    tile_n = next((t for t in range(1024, 0, -128) if n % t == 0), n)
+def runs_kernel(m: int, k: int, n: int) -> bool:
+    """``engages``, or the tests' interpret mode: whether the product walks
+    the work list (what the counters of row tiles describe)."""
+    return _FORCE_INTERPRET or engages(m, k, n)
+
+
+def vmem_bytes(tile_m: int, tile_n: int, k: int) -> int:
+    """What a grid step holds in VMEM at bf16: the row tile, the weights
+    block and the output tile, each double-buffered, and the float32
+    product."""
+    return (4 * (tile_m * k + k * tile_n + tile_m * tile_n)
+            + 4 * tile_m * tile_n)
+
+
+def tiles_for(m: int, X: int, k: int, n: int):
+    """(tile_m, tile_n) from the call's static shapes. ``tile_m``: 128 rows
+    fill the v5e's MXU and keep the rows an item multiplies for nothing
+    (those of the tile's other groups: a group of ``r`` rows touches
+    ``r / tile_m + 1`` tiles) few where groups are small, which is every
+    decode step and every bucket that gives a group a few hundred rows;
+    fewer rows than that make one tile, rounded to the bf16 sublane pack.
+    Where the rows spread evenly would give a group ``_TALL_ROWS`` or more
+    (a long admission in one pass) the tile is ``_TALL`` rows: one set of
+    weights pushed into the MXU then meets that many rows. ``tile_n``: the
+    largest multiple of 128 that divides ``n`` and keeps the step's VMEM
+    (``vmem_bytes``) under half the 64 MB the call asks for: up to ``n``
+    itself where the call has more than two row tiles (an admission: each
+    row tile is then fetched once and the list walked once; a whole 2048 x
+    1536 block beside a 256-row tile is 18 MB), up to 1024 where it has one
+    or two (a decode step, whose one row tile stays put and whose narrower
+    blocks start the stream sooner). Both from the chip (PERF.md section 6,
+    PR 43: the whole width 4-10% ahead at every size; beside it 256 rows
+    1.5-3% ahead of 128 at 2048 rows a group, level at 1024, 3-6% behind
+    at 256; 512 behind everywhere)."""
+    if m < 128:
+        tile_m = -(-m // 16) * 16
+    else:
+        tile_m = _TALL if m // X >= _TALL_ROWS else 128
+    widest = n if m > 2 * tile_m else min(n, 1024)
+    fits = [t for t in range(widest - widest % 128, 0, -128) if n % t == 0]
+    tile_n = next((t for t in fits if vmem_bytes(tile_m, t, k) <= 32 << 20),
+                  fits[-1] if fits else n)
     return tile_m, tile_n
 
 
-def plan_groups(group_sizes, m: int, tile_m: int):
-    """The kernel's work list: ``(tile, group, lo, hi, first, n_items)``,
-    int32. Item ``i`` computes rows ``[lo[i], hi[i])`` (all inside row tile
-    ``tile[i]``) with group ``group[i]``'s weights; ``first[i]`` marks a row
-    tile's first item. The rows past the last group are a pseudo-group X
-    whose items reuse the previous item's weights block (no fetch) with an
-    empty row range. ``m`` is a multiple of ``tile_m``. At most
-    ``m / tile_m + X`` items; entries at or past ``n_items`` repeat the
-    last."""
-    X = group_sizes.shape[0]
+def _tiles_of(group_sizes, m: int, tile_m: int):
+    """(sizes [X+1] with the rows past the last group as pseudo-group X,
+    starts, ends, first row tile and row tiles touched of each)."""
     sizes = jnp.concatenate([
         group_sizes.astype(jnp.int32),
         (m - jnp.sum(group_sizes, dtype=jnp.int32))[None]])       # [X+1]
@@ -75,6 +105,35 @@ def plan_groups(group_sizes, m: int, tile_m: int):
     starts = ends - sizes
     t_first = starts // tile_m
     n_tiles = jnp.where(sizes > 0, (ends - 1) // tile_m - t_first + 1, 0)
+    return sizes, starts, ends, t_first, n_tiles
+
+
+def rows_multiplied(group_sizes, m: int, k: int, n: int):
+    """Rows the MXU multiplies for ``group_sizes`` in a call of ``m`` rows:
+    the work list's items that hold a row x the tile's height where the
+    kernel runs (``engages``; 1.0 x the groups' rows is the least), the
+    groups' rows themselves under ``ragged_dot``. int32 scalar."""
+    X = group_sizes.shape[0]
+    if not runs_kernel(m, k, n):
+        return jnp.sum(group_sizes, dtype=jnp.int32)
+    tile_m, _ = tiles_for(m, X, k, n)
+    n_tiles = _tiles_of(group_sizes, -(-m // tile_m) * tile_m, tile_m)[-1]
+    return tile_m * jnp.sum(n_tiles[:X], dtype=jnp.int32)
+
+
+def plan_groups(group_sizes, m: int, tile_m: int):
+    """The kernel's work list: ``(tile, src, group, lo, hi, first,
+    n_items)``, int32. Item ``i`` computes rows ``[lo[i], hi[i])`` (all
+    inside row tile ``tile[i]``) with group ``group[i]``'s weights;
+    ``first[i]`` marks a row tile's first item. The rows past the last group
+    are a pseudo-group X whose items have an empty row range and fetch
+    nothing: their weights block is the previous item's and their rows'
+    block ``src[i]`` the last tile that holds a row (``src`` is ``tile``
+    everywhere else). ``m`` is a multiple of ``tile_m``. At most
+    ``m / tile_m + X`` items; entries at or past ``n_items`` repeat the
+    last."""
+    X = group_sizes.shape[0]
+    sizes, starts, ends, t_first, n_tiles = _tiles_of(group_sizes, m, tile_m)
     item_ends = jnp.cumsum(n_tiles)
     total = item_ends[-1]
     cap = m // tile_m + X
@@ -86,65 +145,77 @@ def plan_groups(group_sizes, m: int, tile_m: int):
     hi = jnp.minimum(ends[g], (tile + 1) * tile_m)
     tail = g == X
     hi = jnp.where(tail, lo, hi)
-    # weights block of an item: the tail reuses its predecessor's
+    # what a tail item's blocks point at: its predecessor's
     last_real = jnp.max(jnp.where(sizes[:X] > 0, jnp.arange(X), 0))
     group = jnp.where(tail, last_real, g).astype(jnp.int32)
+    src = jnp.where(tail, jnp.maximum(starts[X] - 1, 0) // tile_m, tile)
     first = jnp.concatenate([jnp.ones((1,), bool), tile[1:] != tile[:-1]])
-    return (tile.astype(jnp.int32), group, lo.astype(jnp.int32),
-            hi.astype(jnp.int32), first.astype(jnp.int32),
-            total.astype(jnp.int32)[None])
+    return (tile.astype(jnp.int32), src.astype(jnp.int32), group,
+            lo.astype(jnp.int32), hi.astype(jnp.int32),
+            first.astype(jnp.int32), total.astype(jnp.int32)[None])
 
 
-def _kernel(layer_ref, tile_ref, group_ref, lo_ref, hi_ref, first_ref,
-            lhs_ref, rhs_ref, out_ref, *, tile_m: int):
+def _kernel(layer_ref, tile_ref, src_ref, group_ref, lo_ref, hi_ref,
+            first_ref, lhs_ref, rhs_ref, out_ref, *, tile_m: int):
     i = pl.program_id(1)
-    rows = tile_ref[i] * tile_m + jax.lax.broadcasted_iota(
-        jnp.int32, (tile_m, 1), 0)
-    mine = (rows >= lo_ref[i]) & (rows < hi_ref[i])
-    acc = jax.lax.dot_general(
-        lhs_ref[...], rhs_ref[0, 0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+    lo, hi, first = lo_ref[i], hi_ref[i], first_ref[i]
 
-    @pl.when(first_ref[i] == 1)
-    def _first():
-        out_ref[...] = jnp.where(mine, acc, jnp.zeros_like(acc))
+    @pl.when(hi > lo)
+    def _rows():
+        rows = tile_ref[i] * tile_m + jax.lax.broadcasted_iota(
+            jnp.int32, (tile_m, 1), 0)
+        mine = (rows >= lo) & (rows < hi)
+        acc = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
-    @pl.when(first_ref[i] == 0)
-    def _later():
-        out_ref[...] = jnp.where(mine, acc, out_ref[...])
+        @pl.when(first == 1)
+        def _first():
+            out_ref[...] = jnp.where(mine, acc, jnp.zeros_like(acc))
+
+        @pl.when(first == 0)
+        def _later():
+            out_ref[...] = jnp.where(mine, acc, out_ref[...])
+
+    # a tile past the last group: no product, its zeros
+    @pl.when((hi <= lo) & (first == 1))
+    def _padding():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def _grouped_matmul_pallas(lhs, rhs_all, layer, group_sizes, *,
-                           interpret: bool = False):
+                           tiles=None, interpret: bool = False):
     m0, k = lhs.shape
-    n = rhs_all.shape[-1]
-    tile_m, tile_n = tiles_for(m0, n)
+    X, n = rhs_all.shape[1], rhs_all.shape[-1]
+    tile_m, tile_n = tiles or tiles_for(m0, X, k, n)
     m = -(-m0 // tile_m) * tile_m
     if m != m0:
         lhs = jnp.pad(lhs, ((0, m - m0), (0, 0)))
-    tile, group, lo, hi, first, n_items = plan_groups(group_sizes, m, tile_m)
+    tile, src, group, lo, hi, first, n_items = plan_groups(
+        group_sizes, m, tile_m)
     out = pl.pallas_call(
         functools.partial(_kernel, tile_m=tile_m),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=7,
             grid=(n // tile_n, n_items[0]),
             in_specs=[
                 pl.BlockSpec((tile_m, k),
-                             lambda j, i, ly, t, g, *_: (t[i], 0)),
+                             lambda j, i, ly, t, s, g, *_: (s[i], 0)),
                 pl.BlockSpec((1, 1, k, tile_n),
-                             lambda j, i, ly, t, g, *_: (ly[0], g[i], 0, j)),
+                             lambda j, i, ly, t, s, g, *_: (ly[0], g[i], 0,
+                                                            j)),
             ],
             out_specs=pl.BlockSpec((tile_m, tile_n),
-                                   lambda j, i, ly, t, g, *_: (t[i], j))),
+                                   lambda j, i, ly, t, *_: (t[i], j))),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         name="moe_grouped_matmul",
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tile, group, lo, hi, first,
-      lhs, rhs_all)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tile, src, group, lo, hi,
+      first, lhs, rhs_all)
     return out[:m0]
 
 
@@ -158,7 +229,7 @@ def grouped_matmul(lhs, rhs_all, layer, group_sizes,
     n = rhs_all.shape[-1]
     if interpret is None:
         interpret = _FORCE_INTERPRET
-        if not interpret and not engages(m, k, n):
+        if not runs_kernel(m, k, n):
             rhs = jax.lax.dynamic_index_in_dim(rhs_all, layer, 0, False)
             out = jax.lax.ragged_dot(
                 lhs, rhs.astype(lhs.dtype), group_sizes.astype(jnp.int32),

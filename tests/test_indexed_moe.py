@@ -244,13 +244,33 @@ def test_router_is_a_softmax_over_all_with_the_chosen_renormalised(toy):
 
 
 def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
+    """The expert layer takes an admission in one pass where that holds no
+    more than the longest admission's attention (``_held_bytes``) and in
+    pieces where it would, by shapes alone (``latent_moe.admitted_experts``):
+    a generator laid out for 8 positions against one laid out for 4096, the
+    same logits."""
+    from kubetorch_tpu.models import latent_moe
+
     d, cfg, params = toy
-    toks = jnp.asarray([tokens_of(64, seed=2)])
-    whole = np.asarray(indexed_moe.forward(params, toks, cfg))
-    monkeypatch.setattr(indexed_moe, "_EXPERT_TOKENS", 16)
+    T = 256
+    toks = jnp.asarray([tokens_of(T, seed=2)])
+    roomy = dataclasses.replace(cfg, max_seq_len=4096)
+    small = dataclasses.replace(cfg, max_seq_len=8)
+    E = cfg.embed_dim
+    assert latent_moe.expert_piece(T, roomy, E, 4,
+                                   indexed_moe._held_bytes(roomy)) == T
+    assert latent_moe.expert_piece(T, small, E, 4,
+                                   indexed_moe._held_bytes(small)) < T
+    whole = np.asarray(indexed_moe.forward(params, toks, roomy))
     monkeypatch.setattr(indexed_moe, "_QUERY_BLOCK", 16)
-    pieces = np.asarray(indexed_moe.forward(params, toks, cfg))
+    pieces = np.asarray(indexed_moe.forward(params, toks, small))
     assert np.abs(pieces - whole).max() < 1e-5
+    # the cell's shapes: one pass at every bucket, the top one too
+    real = IndexedMoEConfig()
+    for bucket in (2048, 8192, 16384, 32768):
+        assert latent_moe.expert_piece(
+            bucket, real, real.embed_dim, 2,
+            indexed_moe._held_bytes(real)) == bucket
 
 
 # ----------------------------------------------------- (ii) the cache
@@ -457,6 +477,41 @@ def test_engine_serves_interleaved_requests_each_as_alone(toy):
     assert stats["moe_expert_slots"] > 0
     assert (stats["prefill_index_pairs_scored"]
             >= stats["prefill_index_pairs_needed"])
+
+
+@pytest.mark.parametrize("p_pad, prompt, plan", [
+    # one pass, the tall tile: 262144 pairs in 1024 tiles of 256 a layer,
+    # the prompt's 172000 pairs fill 672 of them
+    (32768, 21500, (32768, 256, 4 * 1024, 4 * (1024 - 672))),
+    # 1024 rows an expert and fewer: tile 128
+    (16384, 10000, (16384, 128, 4 * 1024, 4 * (1024 - 625))),
+    (8192, 7168, (8192, 128, 4 * 512, 4 * (512 - 448))),
+    (2048, 2048, (2048, 128, 4 * 128, 0)),
+])
+def test_expert_admission_by_hand(p_pad, prompt, plan, monkeypatch):
+    """What ``stats()`` says of an admission's expert layers, at the cell's
+    widths, as where the kernel runs: tokens a pass, the row tile, the row
+    tiles of 4 layers' work lists and those that hold only padding."""
+    from kubetorch_tpu.models import latent_moe
+    from kubetorch_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "_FORCE_INTERPRET", True)
+    real = IndexedMoEConfig()
+    assert indexed_moe.IndexedMoEDecoder.expert_admission(
+        real, [prompt], p_pad) == plan
+    # a generator laid out for 4096 positions would take 32768 in pieces
+    small = dataclasses.replace(real, max_seq_len=4096)
+    piece, tile, tiles, skipped = latent_moe.admission_plan(
+        small, small.embed_dim, [prompt], p_pad,
+        indexed_moe._held_bytes(small), 4)
+    if p_pad == 32768:
+        assert (piece, tile) == (1024, 128)
+        # 20 whole pieces, one of 1020 tokens (8160 pairs: all 64 of its
+        # tiles hold one) and 11 of padding alone
+        assert (tiles, skipped) == (4 * 32 * 64, 4 * 11 * 64)
+    monkeypatch.setattr(grouped_matmul, "_FORCE_INTERPRET", False)
+    assert indexed_moe.IndexedMoEDecoder.expert_admission(
+        real, [prompt], p_pad) == (p_pad, 0, 0, 0)       # ``ragged_dot``
 
 
 def test_prefill_counters_by_hand(toy):

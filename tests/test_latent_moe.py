@@ -236,41 +236,142 @@ def test_prefill_kernel_equals_causal_attention_with_unlike_head_sizes():
 
 
 # ------------------------------------------------------------ (iv)
+def _loop(lhs, rhs, sizes):
+    want = np.zeros((lhs.shape[0], rhs.shape[-1]), np.float32)
+    at = 0
+    for g, size in enumerate(sizes):
+        want[at:at + size] = np.asarray(lhs[at:at + size]) @ np.asarray(
+            rhs[g])
+        at += size
+    return want
+
+
+# every height ``tiles_for`` can return: the call's rows rounded to the
+# sublane pack (None: the rule's own for these few rows), 128, the tall tile
+@pytest.mark.parametrize("tile_m", [None, 128, grouped_matmul._TALL])
 @pytest.mark.parametrize("sizes, m", [
     ([0, 7, 0, 20, 3, 0, 11, 0], 50),       # rows past the groups: zeros
     ([0, 0, 300, 0, 0, 0, 0, 0], 300),      # one expert gets all
     ([100, 0, 0, 150, 0, 0, 0, 30], 300),   # groups across row tiles
     ([1, 1, 1, 1, 1, 1, 1, 1], 8),
 ])
-def test_grouped_product_equals_the_loop(sizes, m):
+def test_grouped_product_equals_the_loop(sizes, m, tile_m):
     X, k, n = 8, 64, 96
     lhs = jax.random.normal(jax.random.key(0), (m, k), jnp.float32)
     rhs = jax.random.normal(jax.random.key(1), (3, X, k, n), jnp.float32)
-    want = np.zeros((m, n), np.float32)
-    at = 0
-    for g, size in enumerate(sizes):
-        want[at:at + size] = np.asarray(lhs[at:at + size]) @ np.asarray(
-            rhs[1, g])
-        at += size
+    want = _loop(lhs, rhs[1], sizes)
     sizes = jnp.asarray(sizes, jnp.int32)
-    kernel = grouped_matmul.grouped_matmul(lhs, rhs, jnp.int32(1), sizes,
-                                           interpret=True)
+    if tile_m is None:
+        kernel = grouped_matmul.grouped_matmul(lhs, rhs, jnp.int32(1), sizes,
+                                               interpret=True)
+    else:
+        kernel = grouped_matmul._grouped_matmul_pallas(
+            lhs, rhs, jnp.int32(1), sizes, tiles=(tile_m, n), interpret=True)
     plain = grouped_matmul.grouped_matmul(lhs, rhs, jnp.int32(1), sizes)
     assert np.abs(np.asarray(kernel) - want).max() < 1e-4
     assert np.abs(np.asarray(plain) - want).max() < 1e-4
 
 
+@pytest.mark.parametrize("tile_m", [16, 128, grouped_matmul._TALL])
+@pytest.mark.parametrize("sizes", [
+    [100, 0, 300, 0, 5, 0, 700, 19],        # the tail starts inside a tile
+    [0, 0, 0, 0, 0, 0, 0, 1024],            # and on a tile's edge
+    [0] * 8,                                # an all-padding piece
+])
+def test_a_tile_of_padding_is_not_multiplied(sizes, tile_m):
+    """Rows past the last group are poisoned: a product over them would
+    carry NaN into its tile. They come out zero, the groups' rows exact,
+    and the work list gives the padding's tiles no rows and no fetch."""
+    X, k, n, m = 8, 128, 256, 2048
+    total = sum(sizes)
+    lhs = jax.random.normal(jax.random.key(0), (m, k), jnp.float32)
+    lhs = lhs.at[total:].set(jnp.nan)
+    rhs = jax.random.normal(jax.random.key(1), (2, X, k, n), jnp.float32)
+    got = np.asarray(grouped_matmul._grouped_matmul_pallas(
+        lhs, rhs, jnp.int32(1), jnp.asarray(sizes, jnp.int32),
+        tiles=(tile_m, 128), interpret=True))
+    assert not np.isnan(got).any()
+    assert (got[total:] == 0).all()
+    assert np.allclose(got[:total], _loop(lhs, rhs[1], sizes)[:total],
+                       atol=1e-4)
+    tile, src, group, lo, hi, first, count = (np.asarray(a) for a in (
+        grouped_matmul.plan_groups(jnp.asarray(sizes, jnp.int32), m, tile_m)))
+    tail = hi[:count[0]] == lo[:count[0]]
+    # a tail item points at blocks already there: the last tile with a row,
+    # the last group's weights; every tile is still written once
+    assert (src[:count[0]][tail] == max(total - 1, 0) // tile_m).all()
+    assert (src[:count[0]][~tail] == tile[:count[0]][~tail]).all()
+    assert first[:count[0]].sum() == m // tile_m
+    assert int(grouped_matmul._tiles_of(
+        jnp.asarray(sizes, jnp.int32), m, tile_m)[-1][:X].sum()) == (~tail).sum()
+
+
 def test_work_list_names_no_group_without_a_row():
     sizes = jnp.asarray([0, 7, 0, 20, 3, 0, 11, 0], jnp.int32)
-    tile, group, lo, hi, first, n = grouped_matmul.plan_groups(sizes, 64, 16)
+    tile, src, group, lo, hi, first, n = grouped_matmul.plan_groups(
+        sizes, 64, 16)
     n = int(n[0])
     assert set(np.asarray(group[:n]).tolist()) <= {1, 3, 4, 6}
     rows = sum(int(h - l) for l, h in zip(lo[:n], hi[:n]))
     assert rows == 41 and int(first[:n].sum()) == 4      # 64 / 16 row tiles
 
 
-def test_routed_experts_equal_a_loop_over_tokens(toy):
+def _uniform_sizes(tokens, real, K, X, seed=0):
+    """Group sizes of ``real`` of ``tokens`` tokens routed evenly at random."""
+    rng = np.random.default_rng(seed)
+    return np.bincount(rng.integers(0, X, size=real * K), minlength=X)
+
+
+# (cell's call, tokens, prompt, K, X, E, Mx, tile_m the rule gives, rows
+# multiplied over pairs at most): the shapes the cells really call
+@pytest.mark.parametrize("tokens, real, K, X, E, Mx, tile_m, most", [
+    (16, 16, 8, 128, 2048, 768, 128, None),          # keye2 decode, m 128
+    (32, 32, 6, 64, 2560, 768, 128, None),           # smallthinker, m 192
+    (8192, 7168, 6, 128, 2048, 768, 128, 1.45),      # kanana2's tail tick
+    (16384, 15360, 6, 64, 2560, 768, None, 1.25),    # smallthinker's
+    (16384, 10000, 8, 128, 2048, 768, None, 1.45),   # keye2's median prompt
+    (32768, 30720, 8, 128, 2048, 768, None, 1.25),   # keye2's tail tick
+])
+def test_tile_and_work_list_at_the_cells_shapes(tokens, real, K, X, E, Mx,
+                                                tile_m, most):
+    m = tokens * K
+    got, tile_n = grouped_matmul.tiles_for(m, X, E, 2 * Mx)
+    # an admission takes the weights' whole width, a decode step half
+    wide = grouped_matmul.tiles_for(m, X, Mx, E)[1]
+    assert (tile_n, wide) == ((2 * Mx, E) if tokens > 32
+                              else (768, 1024 if E == 2048 else 640))
+    # both products of a layer walk one work list
+    assert grouped_matmul.tiles_for(m, X, Mx, E)[0] == got
+    if tile_m is None:                    # a long admission in one pass
+        assert got == (grouped_matmul._TALL
+                       if m // X >= grouped_matmul._TALL_ROWS else 128)
+    else:
+        assert got == tile_m
+    # VMEM: rows, weights and product double-buffered, the float32 product
+    assert grouped_matmul.vmem_bytes(got, tile_n, E) < 24 << 20
+    # a model eight times as wide gets a narrower block, not a refusal
+    wide = grouped_matmul.tiles_for(m, X, 8 * E, 2 * Mx)[1]
+    assert wide < tile_n and grouped_matmul.vmem_bytes(got, wide,
+                                                       8 * E) <= 32 << 20
+    if most is None:
+        return
+    sizes = _uniform_sizes(tokens, real, K, X)
+    n_tiles = grouped_matmul._tiles_of(jnp.asarray(sizes, jnp.int32), m,
+                                       got)[-1]
+    ratio = got * int(n_tiles[:X].sum()) / (real * K)
+    assert 1.0 <= ratio <= most, ratio
+    # the bucket's padding is no item's rows
+    assert got * int(n_tiles[:X].sum()) <= -(-real * K // got) * got + X * got
+
+
+@pytest.mark.parametrize("summed", ["one_gather", "a_choice_at_a_time"])
+def test_routed_experts_equal_a_loop_over_tokens(toy, summed, monkeypatch):
+    """Both ways ``routed_experts`` sums a token's K rows: one gather and
+    its float32 copy (a decode step), and a gather a choice with no such
+    copy (an admission, where the copy would be gigabytes)."""
     d, cfg, params = toy
+    if summed == "a_choice_at_a_time":
+        monkeypatch.setattr(latent_moe, "_SUM_COPY_BYTES", 0)
     n = 50
     m = jax.random.normal(jax.random.key(4), (n, cfg.embed_dim))
     valid = jnp.arange(n) % 7 != 3                   # some rows are no token
@@ -293,6 +394,8 @@ def test_routed_experts_equal_a_loop_over_tokens(toy):
     used = {int(e) for t in range(n) if bool(valid[t])
             for e in np.asarray(chosen[t])}
     assert int(counters["moe_experts_touched"]) == len(used)
+    # under ``ragged_dot`` (here) the rows multiplied are the pairs'
+    assert int(counters["moe_rows_multiplied"]) == int(valid.sum()) * cfg.top_k
 
 
 # ------------------------------------------------------------- (v)
@@ -480,3 +583,22 @@ def test_engine_stream_is_the_same_through_the_kernels(toy, monkeypatch):
         s_plain["decode_kv_positions_grid"]
     assert s_kernels["decode_kv_positions_read"] == 2 * 4 * 128 // 4
     assert s_kernels["moe_assignments"] == s_plain["moe_assignments"]
+    # rows the MXU multiplies, counted on the device over the decode steps:
+    # the pairs themselves under ``ragged_dot``, whole row tiles (one 16-row
+    # tile an item here: 2 slots x 2 pairs a step) through the kernel
+    decoded = 2 * cfg.top_k * cfg.n_moe_layers         # pairs, a step's
+    steps = (s_plain["moe_expert_slots"]
+             // (cfg.n_experts * cfg.n_moe_layers))
+    assert s_plain["moe_rows_multiplied"] <= steps * decoded
+    assert s_kernels["moe_rows_multiplied"] % 16 == 0
+    assert s_kernels["moe_rows_multiplied"] >= s_plain["moe_rows_multiplied"]
+    # the admission's expert layers, from shapes and the prompt's length:
+    # one pass of the 32 bucket; under ``ragged_dot`` no tile at all
+    assert (s_plain["moe_piece_tokens_b32"], s_plain["moe_tile_rows_b32"],
+            s_plain["moe_admission_tiles"]) == (32, 0, 0)
+    tile = grouped_matmul.tiles_for(32 * cfg.top_k, cfg.n_experts,
+                                    cfg.embed_dim,
+                                    2 * cfg.expert_mlp_dim)[0]
+    assert s_kernels["moe_tile_rows_b32"] == tile == 64
+    assert s_kernels["moe_admission_tiles"] == cfg.n_moe_layers
+    assert s_kernels["moe_padding_tiles_skipped"] == 0     # 29 of 32 tokens

@@ -165,19 +165,37 @@ def test_reglu_experts_equal_a_loop_over_tokens(toy):
 
 
 def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
-    """More tokens than ``_EXPERT_TOKENS`` are given to the experts a piece
-    at a time: the same sum."""
+    """An admission whose one pass would hold more than the decoder's
+    longest attention does (``_held_bytes``: here a generator laid out for
+    8 positions) goes through ``latent_moe.admitted_experts`` in pieces, by
+    shapes alone: the same sum as one pass, and no counters."""
     d, cfg, params = toy
-    n = 64
+    n = 256
     m = jax.random.normal(jax.random.key(6), (n, cfg.embed_dim))
-    valid = jnp.arange(n) < 50
+    valid = jnp.arange(n) < 200
     stack = params["full"]
     chosen, w = window_moe.route(m, stack["router"][0], cfg)
-    whole, _ = window_moe._experts(m, valid, chosen, w, stack, 0, cfg)
-    monkeypatch.setattr(window_moe, "_EXPERT_TOKENS", 16)
-    pieces, counters = window_moe._experts(m, valid, chosen, w, stack, 0, cfg)
+    roomy = dataclasses.replace(cfg, max_seq_len=4096)
+    whole, counted = window_moe._experts(m, valid, chosen, w, stack, 0, roomy)
+    assert int(counted["moe_assignments"]) == 200 * cfg.top_k
+    small = dataclasses.replace(cfg, max_seq_len=8)
+    held = window_moe._held_bytes(small)
+    piece = latent_moe.expert_piece(n, small, cfg.embed_dim, 4, held)
+    assert piece < n and n % piece == 0
+    assert latent_moe.expert_pass_bytes(piece, small, cfg.embed_dim,
+                                        4) <= held or piece == 64
+    # the pieces sum a choice at a time, as an admission's size makes them
+    monkeypatch.setattr(latent_moe, "_SUM_COPY_BYTES", 0)
+    pieces, counters = window_moe._experts(m, valid, chosen, w, stack, 0,
+                                           small)
     assert counters == {}
     assert np.abs(np.asarray(whole) - np.asarray(pieces)).max() < 1e-5
+    # the cell's shapes: one pass at every bucket, the top one too
+    real = window_moe.WindowMoEConfig(max_seq_len=16384)
+    for bucket in (512, 4096, 16384):
+        assert latent_moe.expert_piece(
+            bucket, real, real.embed_dim, 2,
+            window_moe._held_bytes(real)) == bucket
 
 
 # -------------------------------------- (ii) the cache's leaves and rings
@@ -576,8 +594,9 @@ def test_engine_prices_rows_from_the_generators_gauges(toy):
 # ----------------------------- (viii) the shared expert layer stays shared
 def _parents_routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all,
                             li, cfg):
-    """``latent_moe.routed_experts`` as it stood before it took the gate's
-    activation as an argument: the oracle of the test below."""
+    """``latent_moe.routed_experts`` with SiLU written in, as it stood
+    before it took the gate's activation as an argument (its sum and its
+    counters as PR 43 left them): the oracle of the test below."""
     from kubetorch_tpu.ops import grouped_matmul
 
     n, E = m.shape
@@ -592,14 +611,15 @@ def _parents_routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all,
         half = h.shape[-1] // 2
         a = (jax.nn.silu(h[:, :half]) * h[:, half:]).astype(m.dtype)
         y = grouped_matmul.grouped_matmul(a, we_down_all, li, sizes)
-        inv = jnp.argsort(order)
+        inv = jnp.argsort(order).reshape(n, K)
         g = jnp.where(valid[:, None], weights, 0.0)
-        out = jnp.einsum("nke,nk->ne", y[inv].reshape(n, K, E).astype(
-            jnp.float32), g)
+        out = jnp.einsum("nke,nk->ne", y[inv].astype(jnp.float32), g)
     counters = {"moe_assignments": K * jnp.sum(valid, dtype=jnp.int32),
                 "moe_experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32),
                 "moe_expert_slots": jnp.int32(X),
-                "moe_group_max": jnp.max(sizes)}
+                "moe_group_max": jnp.max(sizes),
+                "moe_rows_multiplied": grouped_matmul.rows_multiplied(
+                    sizes, n * K, E, h.shape[-1])}
     return out, counters
 
 
