@@ -22,8 +22,8 @@ def __getattr__(name):
     import importlib
 
     if name in ("generate", "quant", "rolling", "speculative", "lora",
-                "embed", "decoder", "latent_moe", "hybrid_linear",
-                "window_moe", "indexed_moe"):
+                "embed", "decoder", "experts", "latent_moe",
+                "hybrid_linear", "window_moe", "indexed_moe"):
         return importlib.import_module(f"kubetorch_tpu.models.{name}")
     if name == "LoraConfig":
         return importlib.import_module(
@@ -48,7 +48,7 @@ def __getattr__(name):
 
 __all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig",
            "HybridLinearConfig", "WindowMoEConfig", "IndexedMoEConfig",
-           "ViTConfig", "decoder", "latent_moe", "hybrid_linear",
+           "ViTConfig", "decoder", "experts", "latent_moe", "hybrid_linear",
            "window_moe", "indexed_moe", "llama",
            "Generator",
            "generate", "quant", "quantize_params", "RollingGenerator",

@@ -79,6 +79,14 @@ object it was built with (``decoder_for(cfg)``):
 - ``check_serving(cfg, **features)``: raises for a serving feature the
   decoder does not carry, naming the feature.
 
+**What has a default.** A decoder's class inherits ``Decoder`` below and
+overrides only where it differs: ``counters = ()``, ``prefill_counters`` ->
+``{}``, ``state_rows_touched`` and ``scan_positions`` -> 0 (no row-state
+leaf, no scan), ``init_chunk`` -> zeros for the chunk's columns of every leaf
+(all positional), and ``check_serving`` from the class's ``label`` and
+``refused`` (feature -> the words of the refusal; nothing refused where
+``refused`` is empty). Everything else a decoder gives itself.
+
 - a decoder with rings also gives ``window_key_blocks(cfg, p_pad)``: the
   key blocks its window layers' admission attention visits for one row of
   a ``p_pad`` bucket, and those the band touches, a layer (what
@@ -87,7 +95,7 @@ object it was built with (``decoder_for(cfg)``):
 - a decoder with routed experts (``moe_rows_multiplied`` among its
   ``counters``) also gives ``expert_admission(cfg, lens, p_pad)``: what its
   expert layers make of one bucketed admission of rows ``lens`` long, from
-  shapes alone (``latent_moe.admission_plan``: tokens a pass, the row tile,
+  shapes alone (``experts.admission_plan``: tokens a pass, the row tile,
   the work lists' row tiles and those of them that hold only padding; what
   ``moe_piece_tokens_b<p_pad>``, ``moe_tile_rows_b<p_pad>``,
   ``moe_admission_tiles`` and ``moe_padding_tiles_skipped`` say).
@@ -98,14 +106,31 @@ the second, ``models/hybrid_linear.py`` the third and the first with
 row-state leaves, ``models/window_moe.py`` the fourth and the first with
 rings, ``models/indexed_moe.py`` the fifth and the first whose layer keeps a
 leaf (``ik``, the index's key) that attention itself never reads.
+
+**What a decoder is written with, and the import rule.** A decoder is a
+file of its own equations. What two of them share lives in one of three
+places and in no decoder's name: here, what every decoder is written with
+(``scan_runs`` over a layer pattern, ``layer_at`` a stack, the stream's two
+ends ``embed`` / ``unembed``, ``refusal`` and the defaults of ``Decoder``);
+``models/experts.py``, the routed expert layer; ``ops/``, what asks only
+shapes (the cached attentions of ``ops/cached_attention.py``, the kernels and
+their tile rules). A decoder module imports ``models/configs.py``, this
+module, ``models/experts.py``, ``ops/`` and ``parallel/``, and NEVER another
+decoder, at module level or inside a function; ``ops/`` imports nothing from
+``models/`` (tests/test_decoder_interface.py holds both). This module names
+the decoders only where it finds one (``decoder_for``, ``LlamaDecoder``'s
+functions), inside the function that does.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, FrozenSet, NamedTuple, Optional, Tuple
+from typing import Any, Dict, FrozenSet, List, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+
+from kubetorch_tpu.ops.norms import rms_norm
 
 
 class CacheLeaf(NamedTuple):
@@ -180,12 +205,134 @@ def row_bytes(model, cfg, quantized: bool = False) -> int:
     return _leaf_bytes(model, cfg, quantized, False)
 
 
-class LlamaDecoder:
-    """The dense GQA decoder (``models/llama.py``): K and V planes of
-    ``n_kv_heads x head_dim`` a position, int8 with a scale a head vector
-    where the grid is quantised."""
+# ---------------------------------------- what a decoder is written with
+def _runs(kinds: Tuple[str, ...]) -> List[Tuple[str, int]]:
+    runs: List[Tuple[str, int]] = []
+    for kind in kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    return runs
+
+
+def scan_runs(layer_types: Tuple[str, ...], carry, step):
+    """Run ``step(carry, kind, first, j) -> carry`` over the layers in
+    order, ``first + j`` the layer's index among the layers of its kind (the
+    run's first and the place in the run, handed in apart: the sum is the
+    caller's to form where it reads it). The layer pattern is
+    cut into runs of one kind, the shortest repeating unit of runs is the
+    body of one ``lax.scan`` over its repeats and a run of several layers is
+    a ``lax.scan`` inside it, so each kind's layer is compiled once a place
+    in the unit, not once a layer."""
+    runs = _runs(layer_types)
+    unit = next(n for n in range(1, len(runs) + 1)
+                if len(runs) % n == 0
+                and runs == runs[:n] * (len(runs) // n))
+    per_unit = {kind: sum(c for k, c in runs[:unit] if k == kind)
+                for kind in dict.fromkeys(layer_types)}
+
+    def one_unit(carry, r):
+        first = {kind: r * per_unit[kind] for kind in per_unit}
+        for kind, count in runs[:unit]:
+            def one(carry, j, kind=kind, at=first[kind]):
+                return step(carry, kind, at, j), None
+
+            if count == 1:
+                carry, _ = one(carry, jnp.int32(0))
+            else:
+                carry, _ = jax.lax.scan(
+                    one, carry, jnp.arange(count, dtype=jnp.int32))
+            first[kind] = first[kind] + count
+        return carry, None
+
+    repeats = len(runs) // unit
+    if repeats == 1:
+        return one_unit(carry, jnp.int32(0))[0]
+    return jax.lax.scan(one_unit, carry,
+                        jnp.arange(repeats, dtype=jnp.int32))[0]
+
+
+def layer_at(stack, i):
+    """Layer ``i`` of a leaf stacked over layers (``[L, ...]`` -> ``[...]``),
+    ``i`` traced: the stacks are scanned by index, never sliced by the
+    scan."""
+    return jax.lax.dynamic_index_in_dim(stack, i, 0, False)
+
+
+def embed(params, tokens):
+    """tokens [B,T] -> the residual stream [B,T,E], float32 whatever the
+    compute dtype: every product rounds its operands to the compute dtype,
+    but the stream itself (and a router's input read from it) does not take
+    a rounding a layer. A bf16 stream moves a router's scores by ~1e-2,
+    which flips one near-tied choice in ten at 128 experts top 6 (chip run,
+    PR 27)."""
+    return params["embedding"][tokens].astype(jnp.float32)
+
+
+def unembed(x, params, cfg, unembed_positions=None):
+    """The stream's other end: x [B,T,E] -> logits [B,T,V] float32 through
+    the final norm and an untied head in the compute dtype; with
+    ``unembed_positions`` [B], at that one position a row ([B,1,V])."""
+    if unembed_positions is not None:
+        x = jnp.take_along_axis(x, unembed_positions[:, None, None], axis=1)
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(
+        cfg.compute_dtype)
+    return jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(
+        cfg.compute_dtype)).astype(jnp.float32)
+
+
+def refusal(label: str, refused: Dict[str, str], *names: str):
+    """The error for serving features a decoder does not carry: ``label``
+    names the decoder and its module, ``refused[name]`` the feature."""
+    return NotImplementedError(
+        f"{label} does not carry " + "; ".join(refused[n] for n in names))
+
+
+class Decoder:
+    """The interface's defaults (the module docstring says which members
+    have one); a decoder's class inherits this and names its own functions
+    beside them."""
 
     counters: Tuple[str, ...] = ()
+    # ``check_serving``: the decoder as a refusal names it, and what
+    # RollingGenerator can be asked for that it does not carry
+    label: str = ""
+    refused: Dict[str, str] = {}
+
+    @staticmethod
+    def init_chunk(cfg, cache, batch, cols):
+        return {name: jnp.zeros((leaf.shape[0], batch, cols)
+                                + leaf.shape[3:], leaf.dtype)
+                for name, leaf in cache.items()}
+
+    @staticmethod
+    def prefill_counters(cfg, prompt_tokens: int) -> Dict[str, int]:
+        return {}
+
+    @staticmethod
+    def state_rows_touched(cfg, rows: int, live: int) -> int:
+        return 0
+
+    @staticmethod
+    def scan_positions(cfg, rows: int, length: int) -> int:
+        return 0
+
+    @classmethod
+    def check_serving(cls, cfg, kv_dtype: str = "bf16", **features) -> None:
+        asked = [name for name, on in features.items()
+                 if on and name in cls.refused]
+        if kv_dtype != "bf16" and "kv_dtype" in cls.refused:
+            asked.insert(0, "kv_dtype")
+        if asked:
+            raise refusal(cls.label, cls.refused, *asked)
+
+
+class LlamaDecoder(Decoder):
+    """The dense GQA decoder (``models/llama.py``): K and V planes of
+    ``n_kv_heads x head_dim`` a position, int8 with a scale a head vector
+    where the grid is quantised. It carries every serving feature the
+    generator has (``refused`` is empty)."""
 
     @staticmethod
     def layer_kinds(cfg) -> Tuple[str, ...]:
@@ -258,22 +405,6 @@ class LlamaDecoder:
 
         return flash_attention.prefill_engages(
             p_pad, p_pad, 0, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
-
-    @staticmethod
-    def prefill_counters(cfg, prompt_tokens: int) -> Dict[str, int]:
-        return {}
-
-    @staticmethod
-    def state_rows_touched(cfg, rows: int, live: int) -> int:
-        return 0
-
-    @staticmethod
-    def scan_positions(cfg, rows: int, length: int) -> int:
-        return 0
-
-    @staticmethod
-    def check_serving(cfg, **features) -> None:
-        """Carries every serving feature the generator has."""
 
 
 def decoder_for(cfg):

@@ -23,9 +23,10 @@ it in the compute dtype.
   RMSNorm over the whole q and k projections, causal softmax attention, no
   rotary embedding (the config's ``rope_theta`` is null: the linear layers
   carry the order). It keeps K and V a position, as the dense decoder does,
-  and attends through the dense decoder's own paths: the flash kernel at a
-  long bucket's admission (``ops/flash_attention.py``), the ragged kernel at
-  a decode step (``ops/decode_attention.py``), the einsum pair elsewhere.
+  and attends through the same paths: the flash kernel at a long bucket's
+  admission (``ops/flash_attention.py``), the ragged kernel at a decode step
+  (``ops/decode_attention.py``), the einsum pair elsewhere
+  (``ops/cached_attention.py``).
 
 One function runs a linear layer over ``T`` tokens from a state and a tail,
 with each row's count of REAL tokens: a bucketed prefill (state and tail
@@ -48,7 +49,8 @@ the kernel's oracle.
 Layers of one kind are stacked (``params["linear"]``, ``params["full"]``)
 and scanned by index; cache leaves are stacked over the layers of the kind
 that keeps them (``k``, ``v``: ``[L_full, B, M, H', D]``, ``H'`` the heads
-rounded up to the TPU's sublane tile, ``kv_heads_stored``: the ragged
+rounded up to the TPU's sublane tile, ``decode_attention.kv_heads_stored``:
+the ragged
 kernel's DMA cuts whole tiles, and 30 heads are not; ``state``:
 ``[L_linear, B, H, dk, dv]``; ``conv``: ``[L_linear, B, conv_width - 1,
 channels]``). In chunk mode the row-state leaves ride in the chunk: the
@@ -57,15 +59,20 @@ grid is read-only there, the state is not.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from kubetorch_tpu.models.configs import HybridLinearConfig
-from kubetorch_tpu.models.decoder import CacheLeaf
+from kubetorch_tpu.models.decoder import (CacheLeaf, Decoder, embed,
+                                          layer_at, refusal, scan_runs,
+                                          unembed)
 from kubetorch_tpu.ops import (decode_attention, flash_attention,
                                gated_delta, grid_write)
+from kubetorch_tpu.ops.cached_attention import (cached_attn,
+                                                cached_attn_merged,
+                                                cached_attn_ragged)
 from kubetorch_tpu.ops.norms import rms_norm
 
 Params = Dict[str, Any]
@@ -73,6 +80,8 @@ LINEAR, FULL = "linear_attention", "full_attention"
 # the stacks' names in the parameter tree
 STACK = {LINEAR: "linear", FULL: "full"}
 ROW_LEAVES = ("state", "conv")
+_LABEL = ("the hybrid linear-attention decoder "
+          "(models/hybrid_linear.py)")
 # what RollingGenerator can be asked for that this decoder does not carry
 _REFUSED = {
     "kv_dtype": "an int8 K/V cache beside the float32 state "
@@ -86,12 +95,6 @@ _REFUSED = {
               "recurrent state",
     "handoff": "disaggregated prefill/decode handoff",
 }
-
-
-def _refuse(*names: str):
-    return NotImplementedError(
-        "the hybrid linear-attention decoder (models/hybrid_linear.py) "
-        "does not carry " + "; ".join(_REFUSED[n] for n in names))
 
 
 # ------------------------------------------------------------------ init
@@ -229,7 +232,7 @@ def _linear_mixer(x, layer, states, i, tail, counts, plan,
                 states, i, plan)
             o = o[:, None]
         else:
-            state = _at(states, i)
+            state = layer_at(states, i)
             if T == 1:
                 o, state = gated_delta.step(q[:, 0], k[:, 0], v[:, 0],
                                             log_alpha[:, 0], beta[:, 0],
@@ -278,81 +281,17 @@ def layer_kinds(cfg: HybridLinearConfig) -> Tuple[str, ...]:
     return cfg.layer_types
 
 
-def _runs(kinds: Tuple[str, ...]) -> List[Tuple[str, int]]:
-    runs: List[Tuple[str, int]] = []
-    for kind in kinds:
-        if runs and runs[-1][0] == kind:
-            runs[-1] = (kind, runs[-1][1] + 1)
-        else:
-            runs.append((kind, 1))
-    return runs
-
-
-def scan_runs(layer_types: Tuple[str, ...], carry, step):
-    """Run ``step(carry, kind, first, j) -> carry`` over the layers in
-    order, ``first + j`` the layer's index among the layers of its kind (the
-    run's first and the place in the run, handed in apart: the sum is the
-    caller's to form where it reads it). The layer pattern is
-    cut into runs of one kind, the shortest repeating unit of runs is the
-    body of one ``lax.scan`` over its repeats and a run of several layers is
-    a ``lax.scan`` inside it, so each kind's layer is compiled once a place
-    in the unit, not once a layer."""
-    runs = _runs(layer_types)
-    unit = next(n for n in range(1, len(runs) + 1)
-                if len(runs) % n == 0
-                and runs == runs[:n] * (len(runs) // n))
-    per_unit = {kind: sum(c for k, c in runs[:unit] if k == kind)
-                for kind in dict.fromkeys(layer_types)}
-
-    def one_unit(carry, r):
-        first = {kind: r * per_unit[kind] for kind in per_unit}
-        for kind, count in runs[:unit]:
-            def one(carry, j, kind=kind, at=first[kind]):
-                return step(carry, kind, at, j), None
-
-            if count == 1:
-                carry, _ = one(carry, jnp.int32(0))
-            else:
-                carry, _ = jax.lax.scan(
-                    one, carry, jnp.arange(count, dtype=jnp.int32))
-            first[kind] = first[kind] + count
-        return carry, None
-
-    repeats = len(runs) // unit
-    if repeats == 1:
-        return one_unit(carry, jnp.int32(0))[0]
-    return jax.lax.scan(one_unit, carry,
-                        jnp.arange(repeats, dtype=jnp.int32))[0]
-
-
 def _scan_layers(params, cfg: HybridLinearConfig, carry, body):
     """Run ``body(carry, layer, i, kind) -> carry`` over the layers in
     order (``scan_runs``), ``layer`` the kind's leaves at index ``i`` of its
     stack."""
-    def layer_at(kind, i):
-        return {name: jax.lax.dynamic_index_in_dim(leaf, i, 0, False)
+    def layer(kind, i):
+        return {name: layer_at(leaf, i)
                 for name, leaf in params[STACK[kind]].items()}
 
     return scan_runs(
         cfg.layer_types, carry, lambda carry, kind, at, j: body(
-            carry, layer_at(kind, at + j), at + j, kind))
-
-
-def _embed(params, tokens):
-    return params["embedding"][tokens].astype(jnp.float32)
-
-
-def _logits(x, params, cfg: HybridLinearConfig, unembed_positions=None):
-    if unembed_positions is not None:
-        x = jnp.take_along_axis(x, unembed_positions[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(
-        cfg.compute_dtype)
-    return jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(
-        cfg.compute_dtype)).astype(jnp.float32)
-
-
-def _at(stack, i):
-    return jax.lax.dynamic_index_in_dim(stack, i, 0, False)
+            carry, layer(kind, at + j), at + j, kind))
 
 
 def _put(stack, row, i):
@@ -364,7 +303,7 @@ def _linear_block(x, rows, layer, i, counts, plan, cfg: HybridLinearConfig):
     """A linear layer on the stream; ``rows`` = (state, conv) stacks."""
     state, conv = rows
     out, state, t = _linear_mixer(x.astype(cfg.compute_dtype), layer, state,
-                                  i, _at(conv, i), counts, plan, cfg)
+                                  i, layer_at(conv, i), counts, plan, cfg)
     x = _residual(x, out, layer["attn_norm"], cfg)
     return x, (state, _put(conv, t, i))
 
@@ -374,55 +313,16 @@ def _mlp_block(x, layer, cfg: HybridLinearConfig):
                                 cfg.compute_dtype), layer["mlp_norm"], cfg)
 
 
-def kv_heads_stored(cfg: HybridLinearConfig, dtype=None) -> int:
-    """Heads a position of ``k`` / ``v`` is stored at: ``n_kv_heads``
-    rounded up to the sublane tile of the cache's dtype (8 rows of 32-bit
-    words: 8 float32 heads, 16 bfloat16 ones). An array whose heads do not
-    fill whole tiles is stored padded anyway, and a kernel's DMA cannot cut
-    a ragged tile (30 heads: refused by the TPU's compiler); the padded
-    heads hold zeros and their outputs are dropped."""
-    dt = jnp.dtype(dtype) if dtype is not None else cfg.compute_dtype
-    tile = 8 * max(1, 4 // dt.itemsize)
-    return -(-cfg.n_kv_heads // tile) * tile
-
-
-# the ragged kernel double-buffers a key block of K and of V in VMEM
-_RAGGED_BUFFER_BYTES = 10 << 20
-
-
-def ragged_key_block(max_len: int, heads: int, head_dim: int,
-                     dtype) -> Optional[int]:
-    """The key block of the ragged decode kernel over this cache, or None
-    where it does not engage: the largest of ``decode_attention``'s blocks
-    that the grid's length divides by AND whose two double-buffered ``[block,
-    heads, head_dim]`` planes stay inside the kernel's VMEM (the dense
-    decoder's 512 keys x 8 heads is 4 MB; x 32 heads it is 17 MB, over the
-    16 MB a kernel may hold)."""
-    if not decode_attention.engages(1, max_len, heads, head_dim, dtype):
-        return None
-    for block in decode_attention._BLOCKS:
-        if max_len % block == 0 and (4 * block * heads * head_dim
-                                     * jnp.dtype(dtype).itemsize
-                                     <= _RAGGED_BUFFER_BYTES):
-            return block
-    return None
-
-
-def _pad_heads(x, heads: int):
-    """[B,T,H,D] -> [B,T,heads,D], zeros after the real heads."""
-    return jnp.pad(x, ((0, 0), (0, 0), (0, heads - x.shape[2]), (0, 0)))
-
-
 def init_cache(cfg: HybridLinearConfig, batch: int, max_len: int, dtype=None,
                quantized: bool = False) -> Dict[str, jax.Array]:
     """``k``, ``v`` [L_full,B,M,H',D] (the compute dtype; ``H'`` =
-    ``kv_heads_stored``), ``state`` [L_linear,B,H,dk,dv] float32 and
-    ``conv`` [L_linear,B,K-1,C]: zeros, a sequence's start."""
+    ``decode_attention.kv_heads_stored``), ``state`` [L_linear,B,H,dk,dv]
+    float32 and ``conv`` [L_linear,B,K-1,C]: zeros, a sequence's start."""
     if quantized:
-        raise _refuse("kv_dtype")
+        raise refusal(_LABEL, _REFUSED, "kv_dtype")
     dt = jnp.dtype(dtype) if dtype is not None else cfg.compute_dtype
-    kv = (cfg.n_full_layers, batch, max_len, kv_heads_stored(cfg, dt),
-          cfg.head_dim)
+    kv = (cfg.n_full_layers, batch, max_len,
+          decode_attention.kv_heads_stored(cfg.n_kv_heads, dt), cfg.head_dim)
     Ll = cfg.n_linear_layers
     return {"k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
             "state": jnp.zeros((Ll, batch, cfg.linear_heads,
@@ -478,18 +378,16 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     returned; ``grid_depth`` [B] lets one query position a row take the
     ragged kernel."""
     if lora is not None:
-        raise _refuse("adapters")
-    from kubetorch_tpu.models import llama
-
+        raise refusal(_LABEL, _REFUSED, "adapters")
     B, T = tokens.shape
     H, D = cfg.n_heads, cfg.head_dim
     dt = cfg.compute_dtype
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
 
     if chunk is None:
         M = cache["k"].shape[2]
         if not (isinstance(write_at, int) and write_at == 0 and M == T):
-            raise _refuse("prefix")
+            raise refusal(_LABEL, _REFUSED, "prefix")
         real = jnp.diagonal(mask, axis1=1, axis2=2)                 # [B,T]
         counts = jnp.sum(real, axis=1, dtype=jnp.int32)
         plan = _step_plan(T, counts, cfg)
@@ -503,12 +401,13 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
             else:
                 q, k, v = _full_qkv(x.astype(dt), layer, cfg)
                 kv = tuple(jax.lax.dynamic_update_slice(
-                    g, _pad_heads(new.astype(g.dtype), g.shape[3])[None],
+                    g, decode_attention.pad_heads(
+                        new.astype(g.dtype), g.shape[3])[None],
                     (i, 0, 0, 0, 0)) for g, new in zip(kv, (k, v)))
                 if flash:
                     attn = flash_attention.prefill_attention(q, k, v)
                 else:
-                    attn = llama._cached_attn(q, k, v, mask, cfg)
+                    attn = cached_attn(q, k, v, mask)
                 out = jnp.einsum("btn,ne->bte", attn.reshape(B, T, H * D),
                                  layer["wo"].astype(dt))
                 x = _residual(x, out, layer["attn_norm"], cfg)
@@ -517,13 +416,14 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
         x, kv, rows = _scan_layers(
             params, cfg, (x, (cache["k"], cache["v"]),
                           (cache["state"], cache["conv"])), body)
-        return (_logits(x, params, cfg, unembed_positions),
+        return (unembed(x, params, cfg, unembed_positions),
                 {"k": kv[0], "v": kv[1], "state": rows[0], "conv": rows[1]},
                 {})
 
     M = cache["k"].shape[2]
     items = None
-    block = ragged_key_block(M, cache["k"].shape[3], D, cache["k"].dtype)
+    block = decode_attention.ragged_key_block(
+        M, cache["k"].shape[3], D, cache["k"].dtype)
     if grid_depth is not None and T == 1 and block is not None:
         items = decode_attention.plan(grid_depth, M, block)
     own = jax.lax.dynamic_slice_in_dim(chunk_mask, chunk_col, T, axis=2)
@@ -538,23 +438,24 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
         else:
             q, k, v = _full_qkv(x.astype(dt), layer, cfg)
             cols = tuple(jax.lax.dynamic_update_slice(
-                c, _pad_heads(new.astype(c.dtype), c.shape[3])[None],
+                c, decode_attention.pad_heads(
+                    new.astype(c.dtype), c.shape[3])[None],
                 (i, 0, chunk_col, 0, 0)) for c, new in zip(cols, (k, v)))
-            ek, ev = _at(cols[0], i), _at(cols[1], i)
+            ek, ev = layer_at(cols[0], i), layer_at(cols[1], i)
             # the padded heads ask with zeros and are dropped after
-            q = _pad_heads(q, ek.shape[2])
+            q = decode_attention.pad_heads(q, ek.shape[2])
             if items is not None:
                 # float32 queries: the kernel's q block is [G, D] a kv head,
                 # and with one query head a kv head a bfloat16 row is half
                 # a tile, which the TPU's compiler will not slice (the
                 # kernel rounds them to its operand dtype itself)
-                attn = llama._cached_attn_ragged(
+                attn = cached_attn_ragged(
                     q.astype(jnp.float32), cache["k"], cache["v"], None,
-                    None, i, items, ek, ev, chunk_mask, cfg).astype(dt)
+                    None, i, items, ek, ev, chunk_mask).astype(dt)
             else:
-                attn = llama._cached_attn_merged(
-                    q, _at(cache["k"], i), _at(cache["v"], i), ek, ev, mask,
-                    chunk_mask, cfg)
+                attn = cached_attn_merged(
+                    q, layer_at(cache["k"], i), layer_at(cache["v"], i), ek,
+                    ev, mask, chunk_mask)
             out = jnp.einsum("btn,ne->bte",
                              attn[:, :, :H].reshape(B, T, H * D),
                              layer["wo"].astype(dt))
@@ -564,15 +465,15 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     x, cols, rows = _scan_layers(
         params, cfg, (x, (chunk["k"], chunk["v"]),
                       (chunk["state"], chunk["conv"])), body)
-    return (_logits(x, params, cfg, unembed_positions),
+    return (unembed(x, params, cfg, unembed_positions),
             {"k": cols[0], "v": cols[1], "state": rows[0], "conv": rows[1]},
             {})
 
 
-class HybridLinearDecoder:
+class HybridLinearDecoder(Decoder):
     """``models/decoder.py``'s interface over this module."""
 
-    counters: Tuple[str, ...] = ()
+    label, refused = _LABEL, _REFUSED
     layer_kinds = staticmethod(layer_kinds)
     init_cache = staticmethod(init_cache)
     merge_chunk_into_grid = staticmethod(merge_chunk_into_grid)
@@ -581,8 +482,9 @@ class HybridLinearDecoder:
     @staticmethod
     def cache_leaves(cfg: HybridLinearConfig, quantized: bool = False):
         if quantized:
-            raise _refuse("kv_dtype")
-        vec = (kv_heads_stored(cfg), cfg.head_dim)
+            raise refusal(_LABEL, _REFUSED, "kv_dtype")
+        vec = (decode_attention.kv_heads_stored(
+            cfg.n_kv_heads, cfg.compute_dtype), cfg.head_dim)
         return {
             LINEAR: (
                 CacheLeaf("state", (cfg.linear_heads, cfg.linear_key_dim,
@@ -610,17 +512,13 @@ class HybridLinearDecoder:
     def ragged_block(cfg, max_len, cache, spec: bool) -> Optional[int]:
         if spec:
             return None
-        return ragged_key_block(max_len, cache["k"].shape[3], cfg.head_dim,
-                                cache["k"].dtype)
+        return decode_attention.ragged_key_block(
+            max_len, cache["k"].shape[3], cfg.head_dim, cache["k"].dtype)
 
     @staticmethod
     def prefill_flash_engages(cfg, p_pad: int) -> bool:
         return flash_attention.prefill_engages(
             p_pad, p_pad, 0, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
-
-    @staticmethod
-    def prefill_counters(cfg, prompt_tokens: int) -> Dict[str, int]:
-        return {}
 
     @staticmethod
     def state_rows_touched(cfg, rows: int, live: int) -> int:
@@ -636,12 +534,3 @@ class HybridLinearDecoder:
         """Positions the linear layers' scan walks for ``rows`` rows of
         ``length`` (padded) tokens, a layer."""
         return rows * gated_delta.scan_positions(length)
-
-    @staticmethod
-    def check_serving(cfg, kv_dtype: str = "bf16", **features) -> None:
-        asked = [name for name, on in features.items()
-                 if on and name in _REFUSED]
-        if kv_dtype != "bf16":
-            asked.insert(0, "kv_dtype")
-        if asked:
-            raise _refuse(*asked)

@@ -24,9 +24,9 @@ The layer, on its input ``x`` (the float32 residual stream), at position
 4. ``m = RMSNorm(x')``; ``p = softmax(m W_r)`` over all experts in float32,
    the ``top_k`` largest renormalised to sum 1; ``x_next = x' + sum_e p_e
    W_down^e (silu(W_gate^e m) * (W_up^e m))``: dropless, the pairs sorted by
-   expert and the two products grouped (``latent_moe.routed_experts``; an
-   admission's tokens in one pass where memory lets them,
-   ``latent_moe.admitted_experts``).
+   expert and the two products grouped (``models/experts.py``, the shared
+   expert layer: ``routed_experts``; an admission's tokens in one pass where
+   memory lets them, ``admitted_experts``).
 
 **What a row keeps**: three positional leaves along ``max_len``, ``k`` and
 ``v`` (``[L, B, M, Hkv, D]``) and the index key ``ik`` (``[L, B, M, Di]``,
@@ -62,11 +62,13 @@ import jax
 import jax.numpy as jnp
 
 from kubetorch_tpu.models.configs import IndexedMoEConfig
-from kubetorch_tpu.models.decoder import CacheLeaf
-from kubetorch_tpu.models.latent_moe import COUNTERS as MOE_COUNTERS
-from kubetorch_tpu.models.latent_moe import admission_plan, admitted_experts
+from kubetorch_tpu.models import experts
+from kubetorch_tpu.models.decoder import (CacheLeaf, Decoder, embed,
+                                          layer_at, refusal, unembed)
 from kubetorch_tpu.ops import (decode_attention, flash_attention, grid_write,
                                indexed_attention)
+from kubetorch_tpu.ops.cached_attention import (cached_attn,
+                                                cached_attn_merged)
 from kubetorch_tpu.ops.norms import rms_norm
 from kubetorch_tpu.ops.rope import apply_rope, rope_angles
 
@@ -82,13 +84,15 @@ INDEX_COUNTERS = ("decode_index_positions_scored",
 # and of an admission, counted on the host (``prefill_counters``)
 PREFILL_COUNTERS = ("prefill_index_pairs_scored",
                     "prefill_index_pairs_needed")
-COUNTERS = MOE_COUNTERS + INDEX_COUNTERS + PREFILL_COUNTERS
+COUNTERS = experts.COUNTERS + INDEX_COUNTERS + PREFILL_COUNTERS
 # the leaves of a layer that are sliced a layer; the expert stacks are not
 _SMALL = ("attn_norm", "wqkv", "q_norm", "k_norm", "wo", "wiq", "wik",
           "ik_norm", "ik_bias", "wiw", "router", "mlp_norm")
 
 # queries a pass of the plain-jnp admission (scores [block, T] float32)
 _QUERY_BLOCK = 512
+_LABEL = ("the indexed-attention / routed-expert decoder "
+          "(models/indexed_moe.py)")
 # what RollingGenerator can be asked for that this decoder does not carry
 _REFUSED = {
     "kv_dtype": "an int8 K/V cache (kv_dtype='int8'): the index key would "
@@ -101,13 +105,6 @@ _REFUSED = {
               "and the row's own in one choice",
     "handoff": "disaggregated prefill/decode handoff tiers",
 }
-
-
-def _refuse(*names: str):
-    return NotImplementedError(
-        "the indexed-attention / routed-expert decoder "
-        "(models/indexed_moe.py) does not carry "
-        + "; ".join(_REFUSED[n] for n in names))
 
 
 # ------------------------------------------------------------------ init
@@ -178,16 +175,6 @@ def _held_bytes(cfg) -> int:
             + L * cfg.embed_dim * 4)
 
 
-def _experts(m, valid, chosen, weights, stack, i, cfg: IndexedMoEConfig):
-    """m [n,E] in the compute dtype -> (sum over each token's chosen
-    experts [n,E] float32, counters): ``latent_moe.admitted_experts``, in one
-    pass where that holds no more than the admission's attention does
-    (``_held_bytes``)."""
-    return admitted_experts(m, valid, chosen, weights, stack["we_gu"],
-                            stack["we_down"], i, cfg, jax.nn.silu,
-                            _held_bytes(cfg))
-
-
 def _layer_norm(x, weight, bias, eps):
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=-1, keepdims=True)
@@ -234,25 +221,6 @@ def layer_kinds(cfg: IndexedMoEConfig) -> Tuple[str, ...]:
     return (KIND,) * cfg.n_layers
 
 
-def _embed(params, tokens):
-    """The residual stream is float32 whatever the compute dtype
-    (``latent_moe._embed``'s reason)."""
-    return params["embedding"][tokens].astype(jnp.float32)
-
-
-def _logits(x, params, cfg: IndexedMoEConfig, unembed_positions=None):
-    if unembed_positions is not None:
-        x = jnp.take_along_axis(x, unembed_positions[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(
-        cfg.compute_dtype)
-    return jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(
-        cfg.compute_dtype)).astype(jnp.float32)
-
-
-def _at(stack, i):
-    return jax.lax.dynamic_index_in_dim(stack, i, 0, False)
-
-
 def _block(x, valid, stack, i, angles, attend, cfg: IndexedMoEConfig):
     """One layer on the stream x [B,T,E] float32. ``attend(q, k, v, qi, ki,
     w)`` -> ([B,T,H,D], the cache leaves with k, v and ki kept) is the
@@ -260,7 +228,7 @@ def _block(x, valid, stack, i, angles, attend, cfg: IndexedMoEConfig):
     layer's counters)."""
     B, T, E = x.shape
     dt = cfg.compute_dtype
-    layer = {k: _at(stack[k], i) for k in _SMALL}
+    layer = {k: layer_at(stack[k], i) for k in _SMALL}
     h = rms_norm(x, layer["attn_norm"], cfg.rms_eps).astype(dt)
     attn, kept = attend(*_project(h, layer, angles, cfg))
     x = x + jnp.einsum(
@@ -268,8 +236,9 @@ def _block(x, valid, stack, i, angles, attend, cfg: IndexedMoEConfig):
         layer["wo"].astype(dt)).astype(x.dtype)
     m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps).astype(dt)
     chosen, weights = route(m.reshape(B * T, E), layer["router"], cfg)
-    y, counters = _experts(m.reshape(B * T, E), valid.reshape(-1), chosen,
-                           weights, stack, i, cfg)
+    y, counters = experts.experts(
+        m.reshape(B * T, E), valid.reshape(-1), chosen, weights, stack, i,
+        cfg, jax.nn.silu, _held_bytes(cfg))
     return x + y.reshape(B, T, E).astype(x.dtype), kept, counters
 
 
@@ -288,7 +257,7 @@ def init_cache(cfg: IndexedMoEConfig, batch: int, max_len: int, dtype=None,
     """``k``, ``v`` [L,B,max_len,Hkv,D] and the index key ``ik``
     [L,B,max_len,Di], the compute dtype."""
     if quantized:
-        raise _refuse("kv_dtype")
+        raise refusal(_LABEL, _REFUSED, "kv_dtype")
     dt = jnp.dtype(dtype) if dtype is not None else cfg.compute_dtype
     kv = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     # a buffer each: the generator donates every leaf
@@ -317,15 +286,13 @@ def forward(params: Params, tokens: jax.Array, cfg: IndexedMoEConfig):
 def _admit_plain(q, k, v, qi, ki, w, mask, cfg: IndexedMoEConfig):
     """The choice and the attention of a whole prefill in plain ``jnp``, a
     block of queries at a time (their scores are [block, T] float32)."""
-    from kubetorch_tpu.models import llama
-
     B, T = q.shape[:2]
 
     def some(args):
         qb, qib, wb, mb = args
         keep = indexed_attention.choice_mask(qib, ki, wb, mb,
                                              cfg.index_topk)
-        return llama._cached_attn(qb, k, v, keep, cfg)
+        return cached_attn(qb, k, v, keep)
 
     block = _QUERY_BLOCK
     if T <= block or T % block:
@@ -340,7 +307,9 @@ def _admit_plain(q, k, v, qi, ki, w, mask, cfg: IndexedMoEConfig):
 def _join_chunk(q, acc_g, m_g, l_g, ek, ev, emask, grid_dtype):
     """The grid's un-normalised half (``indexed_decode_attention``) and the
     chunk's few columns under one softmax, by the log-sum-exp rule
-    (``llama._cached_attn_ragged``'s join, its operand dtypes)."""
+    (``cached_attention.cached_attn_ragged``'s join and its operand dtypes,
+    with two operations more, so it is not that function: a chunk column
+    outside ``emask`` weighs exactly 0 and the denominator is clamped)."""
     B, _, H, D = q.shape
     Hkv = ek.shape[2]
     G = H // Hkv
@@ -391,14 +360,12 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     layers; ``{}`` for a prefill (the generator counts a prefill's on the
     host)."""
     if lora is not None:
-        raise _refuse("adapters")
-    from kubetorch_tpu.models import llama
-
+        raise refusal(_LABEL, _REFUSED, "adapters")
     B, T = tokens.shape
     H, Hkv, D, topk = (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
                        cfg.index_topk)
     angles = _angles(positions, cfg)
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
 
     def keep(leaves, i, new, at):
         """The layer's k, v and ki into row ``i`` of the stacked leaves, at
@@ -410,7 +377,7 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     if chunk is None:
         M = cache["k"].shape[2]
         if not (isinstance(write_at, int) and write_at == 0 and M == T):
-            raise _refuse("prefix")
+            raise refusal(_LABEL, _REFUSED, "prefix")
         real = jnp.diagonal(mask, axis1=1, axis2=2)                 # [B,T]
         own = causal_lens is not None
         select = own and indexed_attention.admit_engages(T, topk, H, Hkv, D)
@@ -430,14 +397,14 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
                         return flash_attention.prefill_attention(
                             q, k, v), kept
                     if T <= topk:   # every query sees all it may
-                        return llama._cached_attn(q, k, v, mask, cfg), kept
+                        return cached_attn(q, k, v, mask), kept
                     return _admit_plain(q, k, v, qi, ki, w, mask, cfg), kept
 
             x, leaves, _ = _block(x, real, stack, i, angles, attend, cfg)
             return x, leaves
 
         x, leaves = _scan_layers(params, cfg, (x, dict(cache)), body)
-        return _logits(x, params, cfg, unembed_positions), leaves, {}
+        return unembed(x, params, cfg, unembed_positions), leaves, {}
 
     M, C = cache["k"].shape[2], chunk["k"].shape[2]
     depth = (grid_depth if grid_depth is not None
@@ -467,10 +434,11 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
 
         def attend(q, k, v, qi, ki, w):
             kept = keep(cols, i, (k, v, ki), chunk_col)
-            ek, ev, eik = (_at(kept[n], i) for n in LEAVES)
+            ek, ev, eik = (layer_at(kept[n], i) for n in LEAVES)
             with jax.named_scope("indexed_attention_decode"):
                 keys, echosen, v_thr, p_tie = indexed_attention.decode_choice(
-                    qi, w, _at(cache["ik"], i), eik, depth, chunk_mask, topk,
+                    qi, w, layer_at(cache["ik"], i), eik, depth, chunk_mask,
+                    topk,
                     kernel=items is not None)
                 if items is not None:
                     acc, m, l = indexed_attention.indexed_decode_attention(
@@ -479,10 +447,10 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
                         interpret=jax.default_backend() != "tpu")
                     return _join_chunk(q, acc, m, l, ek, ev, echosen,
                                        cache["k"].dtype), kept
-                return llama._cached_attn_merged(
-                    q, _at(cache["k"], i), _at(cache["v"], i), ek, ev,
-                    indexed_attention.chosen(keys, v_thr, p_tie) & mask,
-                    echosen, cfg), kept
+                return cached_attn_merged(
+                    q, layer_at(cache["k"], i), layer_at(cache["v"], i), ek,
+                    ev, indexed_attention.chosen(keys, v_thr, p_tie) & mask,
+                    echosen), kept
 
         x, cols, counters = _block(x, valid, stack, i, angles, attend, cfg)
         counters = {**counters, **step}
@@ -491,13 +459,14 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
 
     x, cols, totals = _scan_layers(params, cfg, (x, dict(chunk), totals),
                                    body)
-    return _logits(x, params, cfg, unembed_positions), cols, totals
+    return unembed(x, params, cfg, unembed_positions), cols, totals
 
 
-class IndexedMoEDecoder:
+class IndexedMoEDecoder(Decoder):
     """``models/decoder.py``'s interface over this module."""
 
     counters = COUNTERS
+    label, refused = _LABEL, _REFUSED
     layer_kinds = staticmethod(layer_kinds)
     init_cache = staticmethod(init_cache)
     merge_chunk_into_grid = staticmethod(merge_chunk_into_grid)
@@ -506,7 +475,7 @@ class IndexedMoEDecoder:
     @staticmethod
     def cache_leaves(cfg: IndexedMoEConfig, quantized: bool = False):
         if quantized:
-            raise _refuse("kv_dtype")
+            raise refusal(_LABEL, _REFUSED, "kv_dtype")
         vec, dt = (cfg.n_kv_heads, cfg.head_dim), cfg.compute_dtype
         return {KIND: (CacheLeaf("k", vec, dt), CacheLeaf("v", vec, dt),
                        CacheLeaf("ik", (cfg.index_dim,), dt))}
@@ -514,12 +483,6 @@ class IndexedMoEDecoder:
     @staticmethod
     def init_cache_like(cfg, cache, batch, max_len):
         return init_cache(cfg, batch, max_len, dtype=cache["k"].dtype)
-
-    @staticmethod
-    def init_chunk(cfg, cache, batch, cols):
-        return {name: jnp.zeros((leaf.shape[0], batch, cols)
-                                + leaf.shape[3:], leaf.dtype)
-                for name, leaf in cache.items()}
 
     @staticmethod
     def ragged_block(cfg, max_len, cache, spec: bool) -> Optional[int]:
@@ -544,14 +507,13 @@ class IndexedMoEDecoder:
 
     @staticmethod
     def expert_admission(cfg: IndexedMoEConfig, lens, p_pad: int):
-        """``latent_moe.admission_plan`` of this decoder's admissions."""
-        return admission_plan(cfg, cfg.embed_dim, lens, p_pad,
-                              _held_bytes(cfg), cfg.n_layers)
+        return experts.expert_admission(cfg, lens, p_pad, _held_bytes(cfg),
+                                        cfg.n_layers)
 
     @staticmethod
     def prefill_counters(cfg: IndexedMoEConfig, prompt_tokens: int):
-        """Padding past a prompt's end is given to no expert, so a prefill
-        computes exactly its prompt's pairs. The index's: the (query, key)
+        """The expert layers' pairs (``experts.moe_assignments``) and the
+        index's: the (query, key)
         pairs a prompt NEEDS scored (``s <= t`` of its queries at ``t >=
         index_topk``: the others choose everything) and those
         ``index_select`` scores for it at a bucketed admission (its blocks
@@ -559,24 +521,8 @@ class IndexedMoEDecoder:
         its queries), a layer."""
         n, k = prompt_tokens, cfg.index_topk
         needed = (n * (n + 1) - k * (k + 1)) // 2 if n > k else 0
-        return {"moe_assignments": n * cfg.top_k * cfg.n_layers,
+        return {"moe_assignments": experts.moe_assignments(
+                    cfg, n, cfg.n_layers),
                 "prefill_index_pairs_needed": needed * cfg.n_layers,
                 "prefill_index_pairs_scored": cfg.n_layers * (
                     indexed_attention.select_pairs(n, k) if n > k else 0)}
-
-    @staticmethod
-    def state_rows_touched(cfg, rows: int, live: int) -> int:
-        return 0
-
-    @staticmethod
-    def scan_positions(cfg, rows: int, length: int) -> int:
-        return 0
-
-    @staticmethod
-    def check_serving(cfg, kv_dtype: str = "bf16", **features) -> None:
-        asked = [name for name, on in features.items()
-                 if on and name in _REFUSED]
-        if kv_dtype != "bf16":
-            asked.insert(0, "kv_dtype")
-        if asked:
-            raise _refuse(*asked)

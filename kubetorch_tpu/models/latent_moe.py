@@ -37,7 +37,9 @@ The layer, with ``n = RMSNorm(x)`` (the DeepSeek-V3 family's public configs;
   token is dropped and no expert computes a token it was not given**: the
   step's (token, expert) pairs are sorted by expert and the gate/up and
   down products are grouped matrix products over the uneven groups
-  (``ops/grouped_matmul.py``), which read only the experts given a token.
+  (``models/experts.py``: ``routed_experts``, shared with the fourth and
+  fifth decoders, over ``ops/grouped_matmul.py``), which read only the
+  experts given a token.
 
 Layers of one kind are stacked (``params["dense"]``, ``params["moe"]``) and
 scanned by INDEX, the stacks closed over: a scanned slice handed to a Pallas
@@ -52,20 +54,17 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from kubetorch_tpu.models import experts
 from kubetorch_tpu.models.configs import LatentMoEConfig
-from kubetorch_tpu.models.decoder import CacheLeaf
-from kubetorch_tpu.ops import grid_write, grouped_matmul, latent_attention
+from kubetorch_tpu.models.decoder import (CacheLeaf, Decoder, embed,
+                                          layer_at, refusal, unembed)
+from kubetorch_tpu.ops import grid_write, latent_attention
 from kubetorch_tpu.ops.norms import rms_norm
 from kubetorch_tpu.ops.rope import rope_angles
 
 Params = Dict[str, Any]
-COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_expert_slots",
-            "moe_group_max", "moe_rows_multiplied")
-# the largest float32 copy of a call's gathered expert rows that
-# ``routed_experts`` makes for its sum (a decode step's is 1-2 MB)
-_SUM_COPY_BYTES = 16 << 20
+_LABEL = "the latent-attention decoder (models/latent_moe.py)"
 # what RollingGenerator can be asked for that this decoder does not carry
 _REFUSED = {
     "kv_dtype": "an int8 latent cache (kv_dtype='int8')",
@@ -292,124 +291,6 @@ def route(m, router, bias, cfg: LatentMoEConfig):
         return chosen.astype(jnp.int32), w * cfg.routed_scale
 
 
-def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
-                   cfg, act=jax.nn.silu):
-    """Sum over each token's chosen experts, dropless. m [n,E]; ``valid``
-    [n] bool (a padded or inactive position is given to no expert);
-    ``we_*_all`` the STACKED expert weights [Lm,X,..] and ``li`` this
-    layer's index in them. ``cfg`` gives ``top_k`` and ``n_experts``;
-    ``act`` is the gate's activation (SiLU here; ``models/window_moe.py``
-    hands in ReLU and its own configuration). Returns (y [n,E] float32,
-    counters)."""
-    n, E = m.shape
-    K, X = cfg.top_k, cfg.n_experts
-    with jax.named_scope("moe_experts"):
-        # pairs sorted by expert; pairs of no token sort past the last one
-        e_flat = jnp.where(valid[:, None], chosen, X).reshape(-1)
-        order = jnp.argsort(e_flat, stable=True)
-        sizes = jnp.sum(e_flat[:, None] == jnp.arange(X)[None, :],
-                        axis=0, dtype=jnp.int32)
-        xs = m[order // K]                                       # [n*K, E]
-        h = grouped_matmul.grouped_matmul(xs, we_gu_all, li, sizes)
-        half = h.shape[-1] // 2
-        a = (act(h[:, :half]) * h[:, half:]).astype(m.dtype)
-        y = grouped_matmul.grouped_matmul(a, we_down_all, li, sizes)
-        # back to token order, weighted: a gather by the inverse permutation
-        # and the float32 sum over a token's K rows. Where the float32 copy
-        # of the gathered rows would be large (an admission: 2 GB at 32768
-        # tokens) the rows are gathered one choice at a time and summed as
-        # they come; a decode step keeps the one gather, a quarter the ops
-        inv = jnp.argsort(order).reshape(n, K)
-        g = jnp.where(valid[:, None], weights, 0.0)
-        if n * K * E * 4 <= _SUM_COPY_BYTES:
-            out = jnp.einsum("nke,nk->ne", y[inv].astype(jnp.float32), g)
-        else:
-            out = jnp.zeros((n, E), jnp.float32)
-            for c in range(K):
-                out = out + y[inv[:, c]].astype(jnp.float32) * g[:, c, None]
-    counters = {"moe_assignments": K * jnp.sum(valid, dtype=jnp.int32),
-                "moe_experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32),
-                "moe_expert_slots": jnp.int32(X),
-                "moe_group_max": jnp.max(sizes),
-                "moe_rows_multiplied": grouped_matmul.rows_multiplied(
-                    sizes, n * K, E, h.shape[-1])}
-    return out, counters
-
-
-def expert_pass_bytes(tokens: int, cfg, E: int, itemsize: int) -> int:
-    """What one pass of ``routed_experts`` over ``tokens`` tokens holds at
-    its widest, from static shapes: the sorted pairs' rows beside their
-    gate/up products ((E + 2 Mx) a pair) or the down products beside the
-    rows gathered back (2 E a pair), whichever is more, and the float32
-    sum."""
-    pairs, Mx = tokens * cfg.top_k, cfg.expert_mlp_dim
-    return pairs * max(E + 2 * Mx, 2 * E) * itemsize + tokens * E * 4
-
-
-def expert_piece(n: int, cfg, E: int, itemsize: int, held_bytes: int) -> int:
-    """Tokens of an ``n``-token admission the expert layer takes in one
-    pass: all of them where that pass (``expert_pass_bytes``) holds no more
-    than ``held_bytes``, what the caller's admission holds elsewhere at its
-    peak; else the largest half, quarter, ... that does (never under the
-    kernel's smallest row tile of pairs an expert, ``16 * n_experts /
-    top_k`` tokens: below that a piece only re-reads the experts)."""
-    piece = n
-    floor = 16 * cfg.n_experts // cfg.top_k
-    while (piece % 2 == 0 and piece // 2 >= floor
-           and expert_pass_bytes(piece, cfg, E, itemsize) > held_bytes):
-        piece //= 2
-    return piece
-
-
-def admitted_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
-                     cfg, act, held_bytes: int):
-    """``routed_experts`` for an admission of any length: every fetch of an
-    expert's weights should meet all the rows the admission has for it, so
-    the tokens go through in ONE pass where memory lets them and in the
-    largest pieces that fit where it does not (``expert_piece``), one after
-    the other. A piece reads the experts it touches again. Pieces return no
-    counters: an admission's are counted on the host
-    (``prefill_counters``)."""
-    n, E = m.shape
-    piece = expert_piece(n, cfg, E, m.dtype.itemsize, held_bytes)
-
-    def some(args):
-        return routed_experts(*args, we_gu_all, we_down_all, li, cfg,
-                              act=act)
-
-    if piece == n:
-        return some((m, valid, chosen, weights))
-    y, _ = jax.lax.map(some, tuple(
-        a.reshape((n // piece, piece) + a.shape[1:])
-        for a in (m, valid, chosen, weights)))
-    return y.reshape(n, E), {}
-
-
-def admission_plan(cfg, E: int, lens, p_pad: int,
-                   held_bytes: Optional[int], layers: int):
-    """What the expert layers make of ONE bucketed admission, on the host
-    and exact, from static shapes and the prompts' lengths (``lens``, a row
-    each, padded to ``p_pad``): ``(piece, tile, tiles, skipped)`` = tokens a
-    pass takes (``held_bytes`` None: all, the caller has no pieces), the
-    row tile's height, the row tiles of the work lists (one list a layer
-    serves both products) and those of them that hold no pair, the bucket's
-    padding, which the kernel neither fetches nor multiplies; tile 0 and no
-    tiles where the product is ``ragged_dot`` (the CPU, a mesh)."""
-    n = len(lens) * p_pad
-    it = jnp.dtype(cfg.compute_dtype).itemsize
-    piece = n if held_bytes is None else expert_piece(n, cfg, E, it,
-                                                      held_bytes)
-    m, wide = piece * cfg.top_k, 2 * cfg.expert_mlp_dim
-    if not grouped_matmul.runs_kernel(m, E, wide):
-        return piece, 0, 0, 0
-    tile = grouped_matmul.tiles_for(m, cfg.n_experts, E, wide)[0]
-    real = (np.arange(p_pad)[None, :] < np.asarray(lens)[:, None]).reshape(
-        n // piece, piece).sum(axis=1) * cfg.top_k
-    tiles = -(-m // tile) * (n // piece)
-    return (piece, tile, layers * tiles,
-            layers * int(tiles - (-(-real // tile)).sum()))
-
-
 def _feed_forward(x, valid, stack, i, kind, cfg: LatentMoEConfig):
     """x [B,T,E] (the float32 residual stream after attention) -> (x + ffn,
     counters). ``stack`` is the kind's stacked leaves, ``i`` the layer's
@@ -418,7 +299,7 @@ def _feed_forward(x, valid, stack, i, kind, cfg: LatentMoEConfig):
     dt = cfg.compute_dtype
 
     def at(name):
-        return jax.lax.dynamic_index_in_dim(stack[name], i, 0, False)
+        return layer_at(stack[name], i)
 
     m32 = rms_norm(x, at("mlp_norm"), cfg.rms_eps)          # float32
     m = m32.astype(dt)
@@ -428,9 +309,9 @@ def _feed_forward(x, valid, stack, i, kind, cfg: LatentMoEConfig):
     B, T, E = m.shape
     chosen, weights = route(m32.reshape(B * T, E), at("router"),
                             at("router_bias"), cfg)
-    y, counters = routed_experts(m.reshape(B * T, E), valid.reshape(-1),
-                                 chosen, weights, stack["we_gu"],
-                                 stack["we_down"], i, cfg)
+    y, counters = experts.routed_experts(
+        m.reshape(B * T, E), valid.reshape(-1), chosen, weights,
+        stack["we_gu"], stack["we_down"], i, cfg)
     with jax.named_scope("moe_shared"):
         shared = _swiglu(m, at("ws_gu"), at("ws_down"), dt)
     return (x + y.reshape(B, T, E).astype(x.dtype)
@@ -447,15 +328,14 @@ _ATTN_LEAVES = ("attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo")
 
 def _attn_layer(stack, i):
     """Layer ``i``'s attention leaves out of a kind's stack."""
-    return {k: jax.lax.dynamic_index_in_dim(stack[k], i, 0, False)
-            for k in _ATTN_LEAVES}
+    return {k: layer_at(stack[k], i) for k in _ATTN_LEAVES}
 
 
 def _scan_layers(params, cfg: LatentMoEConfig, x, extra, body):
     """Run ``body(x, extra, stack, i, li, kind) -> (x, extra, counters)``
     over the layers: one ``lax.scan`` a kind over the layer's index, the
     stacks closed over. Returns (x, extra, summed counters)."""
-    totals = {name: jnp.zeros((), jnp.int32) for name in COUNTERS}
+    totals = {name: jnp.zeros((), jnp.int32) for name in experts.COUNTERS}
     first = 0
     for kind, n in (("dense", cfg.n_dense_layers),
                     ("moe", cfg.n_moe_layers)):
@@ -476,26 +356,8 @@ def _scan_layers(params, cfg: LatentMoEConfig, x, extra, body):
     return x, extra, totals
 
 
-def _embed(params, tokens):
-    """The residual stream is float32 whatever the compute dtype: every
-    product rounds its operands to the compute dtype, but the stream itself
-    (and the router's input read from it) does not take a rounding a layer.
-    A bf16 stream moves the router's scores by ~1e-2, which flips one
-    near-tied choice in ten at 128 experts top 6 (chip run, PR 27)."""
-    return params["embedding"][tokens].astype(jnp.float32)
-
-
 def _angles(positions, cfg: LatentMoEConfig):
     return rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
-
-
-def _logits(x, params, cfg: LatentMoEConfig, unembed_positions=None):
-    if unembed_positions is not None:
-        x = jnp.take_along_axis(x, unembed_positions[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(
-        cfg.compute_dtype)
-    return jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(
-        cfg.compute_dtype)).astype(jnp.float32)
 
 
 def forward(params: Params, tokens: jax.Array, cfg: LatentMoEConfig):
@@ -519,9 +381,9 @@ def forward(params: Params, tokens: jax.Array, cfg: LatentMoEConfig):
         x, counters = _feed_forward(x, valid, stack, i, kind, cfg)
         return x, extra, counters
 
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
     x, _, _ = _scan_layers(params, cfg, x, (), body)
-    return _logits(x, params, cfg)
+    return unembed(x, params, cfg)
 
 
 def init_cache(cfg: LatentMoEConfig, batch: int, max_len: int, dtype=None,
@@ -529,9 +391,7 @@ def init_cache(cfg: LatentMoEConfig, batch: int, max_len: int, dtype=None,
     """``{"ckr": [L,B,M,W]}``: the normed latent and the roped shared key
     of every position of every layer, ``[c | k_r | 0]`` (``ckr_width``)."""
     if quantized:
-        raise NotImplementedError(
-            f"the latent-attention decoder does not carry "
-            f"{_REFUSED['kv_dtype']}")
+        raise refusal(_LABEL, _REFUSED, "kv_dtype")
     dt = jnp.dtype(dtype) if dtype is not None else cfg.compute_dtype
     return {"ckr": jnp.zeros(
         (cfg.n_layers, batch, max_len, ckr_width(cfg)), dt)}
@@ -571,12 +431,11 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     chunk mask admits, summed over layers; ``{}`` for a prefill (the
     generator counts a prefill's on the host)."""
     if lora is not None:
-        raise NotImplementedError(
-            f"the latent-attention decoder does not carry "
-            f"{_REFUSED['adapters']}")
+        raise refusal(_LABEL, _REFUSED, "adapters")
     B, T = tokens.shape
     sin, cos = _angles(positions, cfg)
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
+
     def normed(x, layer):
         return rms_norm(x, layer["attn_norm"], cfg.rms_eps).astype(
             cfg.compute_dtype)
@@ -588,10 +447,8 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     if chunk is None:
         M = cache["ckr"].shape[2]
         if not (isinstance(write_at, int) and write_at == 0 and M == T):
-            raise NotImplementedError(
-                f"the latent-attention decoder prefills a private cache "
-                f"from position 0 only; it does not carry "
-                f"{_REFUSED['prefix']}")
+            # a private cache is prefilled from position 0 only
+            raise refusal(_LABEL, _REFUSED, "prefix")
         # a position no real token occupies is given to no expert
         valid = jnp.any(mask, axis=1)                               # [B,T]
 
@@ -607,7 +464,7 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
             return x, grid, {}
 
         x, grid, _ = _scan_layers(params, cfg, x, cache["ckr"], body)
-        return (_logits(x, params, cfg, unembed_positions),
+        return (unembed(x, params, cfg, unembed_positions),
                 {"ckr": grid}, {})
 
     items = None
@@ -632,14 +489,15 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
         return x, cols, counters
 
     x, cols, counters = _scan_layers(params, cfg, x, chunk["ckr"], body)
-    return (_logits(x, params, cfg, unembed_positions),
+    return (unembed(x, params, cfg, unembed_positions),
             {"ckr": cols}, counters)
 
 
-class LatentMoEDecoder:
+class LatentMoEDecoder(Decoder):
     """``models/decoder.py``'s interface over this module."""
 
-    counters = COUNTERS
+    counters = experts.COUNTERS
+    label, refused = _LABEL, _REFUSED
     layer_kinds = staticmethod(layer_kinds)
     init_cache = staticmethod(init_cache)
     forward_cached = staticmethod(forward_cached)
@@ -653,11 +511,6 @@ class LatentMoEDecoder:
     @staticmethod
     def init_cache_like(cfg, cache, batch, max_len):
         return init_cache(cfg, batch, max_len, dtype=cache["ckr"].dtype)
-
-    @staticmethod
-    def init_chunk(cfg, cache, batch, cols):
-        return {name: jnp.zeros((leaf.shape[0], batch, cols, leaf.shape[3]),
-                                leaf.dtype) for name, leaf in cache.items()}
 
     @staticmethod
     def ragged_block(cfg, max_len, cache, spec: bool) -> Optional[int]:
@@ -674,32 +527,11 @@ class LatentMoEDecoder:
 
     @staticmethod
     def prefill_counters(cfg: LatentMoEConfig, prompt_tokens: int):
-        """Padding past a prompt's end is given to no expert, so a prefill
-        computes exactly its prompt's pairs."""
-        return {"moe_assignments":
-                prompt_tokens * cfg.top_k * cfg.n_moe_layers}
+        return {"moe_assignments": experts.moe_assignments(
+            cfg, prompt_tokens, cfg.n_moe_layers)}
 
     @staticmethod
     def expert_admission(cfg: LatentMoEConfig, lens, p_pad: int):
-        """``admission_plan`` of this decoder: every bucket in one pass."""
-        return admission_plan(cfg, cfg.embed_dim, lens, p_pad, None,
-                              cfg.n_moe_layers)
-
-    @staticmethod
-    def state_rows_touched(cfg, rows: int, live: int) -> int:
-        return 0
-
-    @staticmethod
-    def scan_positions(cfg, rows: int, length: int) -> int:
-        return 0
-
-    @staticmethod
-    def check_serving(cfg, kv_dtype: str = "bf16", **features) -> None:
-        asked = [name for name, on in features.items()
-                 if on and name in _REFUSED]
-        if kv_dtype != "bf16":
-            asked.insert(0, "kv_dtype")
-        if asked:
-            raise NotImplementedError(
-                "the latent-attention decoder (models/latent_moe.py) does "
-                "not carry " + "; ".join(_REFUSED[a] for a in asked))
+        """Every bucket in one pass: this decoder has no pieces."""
+        return experts.expert_admission(cfg, lens, p_pad, None,
+                                        cfg.n_moe_layers)

@@ -31,7 +31,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.ops import apply_rope, dot_product_attention, rms_norm, rope_angles
-from kubetorch_tpu.ops import grid_write, quant_matmul
+from kubetorch_tpu.ops import decode_attention, grid_write, quant_matmul
+from kubetorch_tpu.ops.cached_attention import (
+    cached_attn, cached_attn_merged, cached_attn_merged_q, cached_attn_q,
+    cached_attn_ragged)
 from kubetorch_tpu.ops.flash_attention import (
     prefill_attention, prefill_engages)
 from kubetorch_tpu.parallel.sharding import ShardingRules, shard_constraint
@@ -572,7 +575,8 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
     1.6% overhead at D=128). Halves the KV stream AND residency; the
     dequant folds into the attention einsums exactly like the int8 weight
     path (scale is per key row, so ``scores·scale`` and ``(p·scale)·V``
-    are algebraically exact factorizations — see ``_cached_attn_q``).
+    are algebraically exact factorizations — see
+    ``ops/cached_attention.py::cached_attn_q``).
     """
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     if quantized:
@@ -592,52 +596,6 @@ def _kv_quantize(x: jax.Array):
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]),
                  -127, 127).astype(jnp.int8)
     return q, scale.astype(jnp.float32)
-
-
-def _cached_attn_q(q, ck, cv, ks, vs, mask, cfg: LlamaConfig):
-    """Quantized-KV attention: ck/cv int8 [B,M,Hkv,D], ks/vs f32
-    [B,M,Hkv]. The int8→f32 convert fuses into the einsum operand read
-    (the property the int8 weight path measured at 583 GB/s); scales
-    apply per key row AFTER the contraction (K side) and fold into the
-    probabilities BEFORE it (V side) — both exact."""
-    B, T, H, D = q.shape
-    Hkv = ck.shape[2]
-    G = H // Hkv
-    qg = q.reshape(B, T, Hkv, G, D)
-    # int8 operands converted to bf16 (not f32) with f32 accumulation:
-    # the convert then fuses into the contraction's operand read the same
-    # way the int8 weight einsums do — an f32 cast materializes a
-    # 4×-the-cache copy per step instead.
-    s = jnp.einsum("btkgd,bmkd->bkgtm", qg.astype(jnp.bfloat16),
-                   ck.astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32) * (D ** -0.5)
-    s = s * ks.transpose(0, 2, 1)[:, :, None, None, :]      # [B,Hkv,1,1,M]
-    s = jnp.where(mask[:, None, None, :, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    p = p * vs.transpose(0, 2, 1)[:, :, None, None, :]
-    out = jnp.einsum("bkgtm,bmkd->btkgd", p.astype(jnp.bfloat16),
-                     cv.astype(jnp.bfloat16),
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, T, H, D).astype(q.dtype)
-
-
-def _cached_attn(q, ck, cv, mask, cfg: LlamaConfig):
-    """q: [B,T,H,D]; ck/cv: [B,M,Hkv,D]; mask: [B,T,M] bool → [B,T,H,D].
-
-    Grouped-query einsum form — no materialized [B,M,H,D] repeat of KV.
-    T is small (prefill ≤ M, decode 1), so scores [B,Hkv,G,T,M] stay modest
-    and XLA fuses the softmax chain.
-    """
-    B, T, H, D = q.shape
-    Hkv = ck.shape[2]
-    G = H // Hkv
-    qg = q.reshape(B, T, Hkv, G, D)
-    s = jnp.einsum("btkgd,bmkd->bkgtm", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) * (D ** -0.5)
-    s = jnp.where(mask[:, None, None, :, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgtm,bmkd->btkgd", p, cv.astype(jnp.float32))
-    return out.reshape(B, T, H, D).astype(q.dtype)
 
 
 def merge_chunk_into_grid(cache: Dict[str, jax.Array],
@@ -673,123 +631,6 @@ def merge_chunk_into_grid(cache: Dict[str, jax.Array],
     return grid_write.write_columns(cache, cols, start, count)
 
 
-def _cached_attn_merged(q, gk, gv, ek, ev, gmask, emask, cfg: LlamaConfig):
-    """Attention over a read-only grid cache PLUS a small chunk cache,
-    without materializing their concatenation.
-
-    q: [B,T,H,D]; gk/gv: [B,M,Hkv,D] (grid); ek/ev: [B,K,Hkv,D] (chunk);
-    gmask: [B,T,M]; emask: [B,T,K]. Scores over both sources concatenate
-    (tiny: [B,Hkv,G,T,M+K] float32), one softmax spans them, and the two
-    value contractions sum — so the multi-GB grid is only ever *read*.
-    This is what lets rolling decode defer per-sequence cache writes to a
-    once-per-chunk merge instead of rewriting cache layers every step
-    (the one-hot write was ~2× the whole step at 8B serving scale).
-    """
-    B, T, H, D = q.shape
-    Hkv = gk.shape[2]
-    G = H // Hkv
-    qg = q.reshape(B, T, Hkv, G, D).astype(jnp.float32)
-    sg = jnp.einsum("btkgd,bmkd->bkgtm", qg,
-                    gk.astype(jnp.float32)) * (D ** -0.5)
-    se = jnp.einsum("btkgd,bmkd->bkgtm", qg,
-                    ek.astype(jnp.float32)) * (D ** -0.5)
-    sg = jnp.where(gmask[:, None, None, :, :], sg, -1e30)
-    se = jnp.where(emask[:, None, None, :, :], se, -1e30)
-    p = jax.nn.softmax(jnp.concatenate([sg, se], axis=-1), axis=-1)
-    M = gk.shape[1]
-    out = (jnp.einsum("bkgtm,bmkd->btkgd", p[..., :M],
-                      gv.astype(jnp.float32))
-           + jnp.einsum("bkgtm,bmkd->btkgd", p[..., M:],
-                        ev.astype(jnp.float32)))
-    return out.reshape(B, T, H, D).astype(q.dtype)
-
-
-def _cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask, emask,
-                          cfg: LlamaConfig):
-    """Merged grid+chunk attention over a QUANTIZED grid.
-
-    gk/gv int8 [B,M,Hkv,D] with per-vector scales gks/gvs [B,M,Hkv];
-    ek/ev bf16 chunk [B,K,Hkv,D]. Exactly `_cached_attn_merged` with the
-    int8 path's scale folding (scores·ks after the QK contraction,
-    p·vs before the PV one) applied to the grid half only — one softmax
-    spans both sources, so rolling decode can run the serving grid at
-    half the cache bytes and residency.
-
-    Which path runs when: this einsum pair (with ``_cached_attn_merged``
-    for a float grid) contracts against all ``M`` positions of every slot
-    and masks afterwards. It serves every chunk-mode forward with more
-    than one query position (chunked prefill, speculative verify), every
-    backend but the TPU, and a grid sharded over a mesh; it is also the
-    numerics ORACLE of ``_cached_attn_ragged``, which single-position
-    decode on one TPU device takes instead (tests/test_decode_attention.py
-    holds the two together)."""
-    B, T, H, D = q.shape
-    Hkv = gk.shape[2]
-    G = H // Hkv
-    qg = q.reshape(B, T, Hkv, G, D)
-    qb = qg.astype(jnp.bfloat16)
-    sg = jnp.einsum("btkgd,bmkd->bkgtm", qb, gk.astype(jnp.bfloat16),
-                    preferred_element_type=jnp.float32) * (D ** -0.5)
-    sg = sg * gks.transpose(0, 2, 1)[:, :, None, None, :]
-    se = jnp.einsum("btkgd,bmkd->bkgtm", qb, ek.astype(jnp.bfloat16),
-                    preferred_element_type=jnp.float32) * (D ** -0.5)
-    sg = jnp.where(gmask[:, None, None, :, :], sg, -1e30)
-    se = jnp.where(emask[:, None, None, :, :], se, -1e30)
-    p = jax.nn.softmax(jnp.concatenate([sg, se], axis=-1), axis=-1)
-    M = gk.shape[1]
-    pg = (p[..., :M] * gvs.transpose(0, 2, 1)[:, :, None, None, :]
-          ).astype(jnp.bfloat16)
-    out = (jnp.einsum("bkgtm,bmkd->btkgd", pg, gv.astype(jnp.bfloat16),
-                      preferred_element_type=jnp.float32)
-           + jnp.einsum("bkgtm,bmkd->btkgd",
-                        p[..., M:].astype(jnp.bfloat16),
-                        ev.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32))
-    return out.reshape(B, T, H, D).astype(q.dtype)
-
-
-def _cached_attn_ragged(q, gk_all, gv_all, gks_all, gvs_all, li, items,
-                        ek, ev, emask, cfg: LlamaConfig):
-    """Merged grid+chunk attention for ONE query position a row, the grid
-    half read only to each row's depth.
-
-    ``gk_all``/``gv_all`` are the STACKED planes [L,B,M,Hkv,D] (never a
-    sliced layer: handed to a custom call that is a copy of the layer),
-    ``gks_all``/``gvs_all`` their scales or None, ``items`` the kernel's
-    work list ``decode_attention.plan(depth, M)`` for ``depth`` [B], the
-    grid mask as a length (``m < depth[b]``; 0 for a row that is not
-    decoding). The Pallas kernel (``ops/decode_attention.py``) returns
-    the grid half un-normalised with its running max and sum; the chunk's
-    few columns are scored here in XLA and the halves join by the
-    log-sum-exp rule, so one softmax spans both exactly as in
-    ``_cached_attn_merged_q`` / ``_cached_attn_merged``, whose operand
-    dtypes this keeps (bf16 operands over an int8 or bf16 grid, f32
-    accumulation). A row at depth 0 with its chunk masked too comes out
-    finite and meaningless, as it does there."""
-    from kubetorch_tpu.ops.decode_attention import ragged_decode_attention
-
-    B, _, H, D = q.shape
-    Hkv = ek.shape[2]
-    G = H // Hkv
-    acc_g, m_g, l_g = ragged_decode_attention(
-        q[:, 0], gk_all, gv_all, gks_all, gvs_all, li, items,
-        interpret=jax.default_backend() != "tpu")
-    odt = jnp.float32 if gk_all.dtype == jnp.float32 else jnp.bfloat16
-    qg = q.reshape(B, Hkv, G, D).astype(odt)
-    se = jnp.einsum("bkgd,bckd->bkgc", qg, ek.astype(odt),
-                    preferred_element_type=jnp.float32) * (D ** -0.5)
-    se = jnp.where(emask[:, 0, None, None, :], se, -1e30)
-    m_g, l_g = m_g.reshape(B, Hkv, G), l_g.reshape(B, Hkv, G)
-    m = jnp.maximum(m_g, jnp.max(se, axis=-1))
-    pe = jnp.exp(se - m[..., None])
-    wg = jnp.exp(m_g - m)
-    out = (wg[..., None] * acc_g.reshape(B, Hkv, G, D)
-           + jnp.einsum("bkgc,bckd->bkgd", pe.astype(odt), ev.astype(odt),
-                        preferred_element_type=jnp.float32))
-    out = out / (wg * l_g + jnp.sum(pe, axis=-1))[..., None]
-    return out.reshape(B, 1, H, D).astype(q.dtype)
-
-
 def _block_cached_chunk_q(x, layer, li, sin, cos, gk_all, gv_all, gks_all,
                           gvs_all, ek_all, ev_all, col, gmask, emask,
                           cfg: LlamaConfig, rules: ShardingRules,
@@ -811,15 +652,15 @@ def _block_cached_chunk_q(x, layer, li, sin, cos, gk_all, gv_all, gks_all,
     ek = jax.lax.dynamic_index_in_dim(ek_all, li, 0, keepdims=False)
     ev = jax.lax.dynamic_index_in_dim(ev_all, li, 0, keepdims=False)
     if items is not None:
-        attn = _cached_attn_ragged(q, gk_all, gv_all, gks_all, gvs_all, li,
-                                   items, ek, ev, emask, cfg)
+        attn = cached_attn_ragged(q, gk_all, gv_all, gks_all, gvs_all, li,
+                                  items, ek, ev, emask)
     else:
         gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
         gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
         gks = jax.lax.dynamic_index_in_dim(gks_all, li, 0, keepdims=False)
         gvs = jax.lax.dynamic_index_in_dim(gvs_all, li, 0, keepdims=False)
-        attn = _cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask,
-                                     emask, cfg)
+        attn = cached_attn_merged_q(q, gk, gv, gks, gvs, ek, ev, gmask,
+                                    emask)
     attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
@@ -848,12 +689,12 @@ def _block_cached_chunk(x, layer, li, sin, cos, gk_all, gv_all, ek_all,
     ek = jax.lax.dynamic_index_in_dim(ek_all, li, 0, keepdims=False)
     ev = jax.lax.dynamic_index_in_dim(ev_all, li, 0, keepdims=False)
     if items is not None:
-        attn = _cached_attn_ragged(q, gk_all, gv_all, None, None, li, items,
-                                   ek, ev, emask, cfg)
+        attn = cached_attn_ragged(q, gk_all, gv_all, None, None, li, items,
+                                  ek, ev, emask)
     else:
         gk = jax.lax.dynamic_index_in_dim(gk_all, li, 0, keepdims=False)
         gv = jax.lax.dynamic_index_in_dim(gv_all, li, 0, keepdims=False)
-        attn = _cached_attn_merged(q, gk, gv, ek, ev, gmask, emask, cfg)
+        attn = cached_attn_merged(q, gk, gv, ek, ev, gmask, emask)
     attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
@@ -954,7 +795,7 @@ def _block_cached_q(x, layer, li, sin, cos, ck_all, cv_all, ks_all, vs_all,
         cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
         ks = jax.lax.dynamic_index_in_dim(ks_all, li, 0, keepdims=False)
         vs = jax.lax.dynamic_index_in_dim(vs_all, li, 0, keepdims=False)
-        attn = _cached_attn_q(q, ck, cv, ks, vs, mask, cfg)
+        attn = cached_attn_q(q, ck, cv, ks, vs, mask)
     attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
@@ -968,9 +809,10 @@ def _block_cached(x, layer, li, sin, cos, ck_all, cv_all, write_at, mask,
     """One decoder block in cache mode, updating the stacked ``[L, ...]``
     cache in place at layer ``li``.
 
-    Writes this step's K/V into the cache at slot ``write_at`` (scalar,
-    uniform across the batch — prompts are right-padded to a common length),
-    then attends the full cache under ``mask``.
+    Writes this step's K/V into the cache at slot ``write_at``, a SCALAR
+    (uniform across the batch: prompts are right-padded to a common length;
+    rows at depths of their own go through chunk mode and the once-a-chunk
+    merge), then attends the full cache under ``mask``.
     Returns (x, ck_all, cv_all).
 
     The stacked caches ride the layer scan's *carry*, not its xs/ys: a ys
@@ -986,43 +828,18 @@ def _block_cached(x, layer, li, sin, cos, ck_all, cv_all, write_at, mask,
     q, k, v = _qkv_proj(x, layer, sin, cos, cfg, lctx)
 
     cdt = ck_all.dtype
-    if jnp.ndim(write_at) == 0:
-        # uniform slot across the batch (Generator: right-padded prompts):
-        # a [1, B, T, Hkv, D] in-place write, no full-cache rewrite
-        ck_all = jax.lax.dynamic_update_slice(
-            ck_all, k.astype(cdt)[None], (li, 0, write_at, 0, 0))
-        cv_all = jax.lax.dynamic_update_slice(
-            cv_all, v.astype(cdt)[None], (li, 0, write_at, 0, 0))
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-    elif T == 1:
-        # per-sequence slots (rolling decode: every slot at its own depth).
-        # One-hot masked write, not a scatter — generic 2D-index scatters
-        # lower poorly on TPU (measured 15 ms vs ~2 ms per decode step on
-        # the 0.8B bench); this streams the layer's cache once at HBM speed.
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-        hit = (jnp.arange(ck.shape[1])[None, :]
-               == write_at[:, None])[:, :, None, None]        # [B, M, 1, 1]
-        ck = jnp.where(hit, k.astype(cdt), ck)
-        cv = jnp.where(hit, v.astype(cdt), cv)
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-    else:
-        # per-sequence multi-token write (rare): scatter rows
-        pos = write_at[:, None] + jnp.arange(T)[None, :]      # [B, T]
-        bidx = jnp.arange(B)[:, None]
-        ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-        ck = ck.at[bidx, pos].set(k.astype(cdt), mode="drop")
-        cv = cv.at[bidx, pos].set(v.astype(cdt), mode="drop")
-        ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-        cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
+    # a [1, B, T, Hkv, D] in-place write, no full-cache rewrite
+    ck_all = jax.lax.dynamic_update_slice(
+        ck_all, k.astype(cdt)[None], (li, 0, write_at, 0, 0))
+    cv_all = jax.lax.dynamic_update_slice(
+        cv_all, v.astype(cdt)[None], (li, 0, write_at, 0, 0))
+    ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
+    cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
 
     if own_causal:
         attn = prefill_attention(q, k, v)
     else:
-        attn = _cached_attn(q, ck, cv, mask, cfg)
+        attn = cached_attn(q, ck, cv, mask)
     attn = attn.reshape(B, T, H * D)
     x = x + _proj(attn, layer, "wo", dt) \
         + _lora_apply(attn, lctx, "wo")
@@ -1035,8 +852,7 @@ def forward_cached(
     tokens: jax.Array,        # [B, T] int32 (prefill: padded prompt; decode: 1)
     positions: jax.Array,     # [B, T] int32 RoPE positions per token
     cache: Dict[str, jax.Array],
-    write_at,                 # cache slot for tokens[:, 0]: scalar, or [B]
-                              # per-sequence slots (rolling batches)
+    write_at,                 # cache slot for tokens[:, 0]: a scalar
     mask: jax.Array,          # [B, T, max_len] bool attention mask
     cfg: LlamaConfig,
     rules: Optional[ShardingRules] = None,
@@ -1071,10 +887,10 @@ def forward_cached(
     device and a ``max_len`` a key block divides
     (``ops.decode_attention.engages``), the grid half runs in the ragged
     Pallas kernel, which reads each row's K/V only to its depth
-    (``_cached_attn_ragged``). Everything else — chunked prefill and
+    (``cached_attn_ragged``). Everything else — chunked prefill and
     speculative verify (``T > 1``), CPU, a mesh — runs the einsum pair
-    over all ``max_len`` positions (``_cached_attn_merged_q`` /
-    ``_cached_attn_merged``), which is also the kernel's oracle. The shape
+    over all ``max_len`` positions (``cached_attn_merged_q`` /
+    ``cached_attn_merged``), which is also the kernel's oracle. The shape
     decides; there is no switch.
 
     The third case is a prompt's own prefill. A caller whose ``mask`` is
@@ -1094,8 +910,9 @@ def forward_cached(
     values nobody reads, as they do under the einsum. Everything else (a
     prefix-extended admission, whose queries also see a prefix; the static
     ``Generator``, whose cache is longer than ``T``; short buckets; CPU; a
-    mesh) runs the einsum pair over the cache (``_cached_attn_q`` /
-    ``_cached_attn``), the kernel's oracle (tests/test_prefill_flash.py).
+    mesh) runs the einsum pair over the cache (``cached_attn_q`` /
+    ``cached_attn``, all five in ``ops/cached_attention.py``), the kernel's
+    oracle (tests/test_prefill_flash.py).
     """
     rules = rules or ShardingRules.default()
     dt = cfg.compute_dtype
@@ -1108,8 +925,6 @@ def forward_cached(
     # _lora_apply gathers the per-slot delta at every adapted
     # projection (select cost independent of n).
     ltree = lora["adapters"] if lora is not None else None
-    from kubetorch_tpu.ops import decode_attention
-
     # the ragged kernel's work list, made once for all layers; None: the
     # einsum pair, under ``mask``
     items = None

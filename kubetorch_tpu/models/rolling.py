@@ -2038,11 +2038,12 @@ class RollingGenerator:
         with a single query position, and it hands the grid mask down as
         a length too (``grid_depth``), so on one TPU device the grid half
         runs in the ragged Pallas kernel, which fetches each row's K/V
-        only to its depth (``llama._cached_attn_ragged``). On CPU, under
+        only to its depth (``cached_attn_ragged``). On CPU, under
         a tp mesh, or with a ``max_len`` no key block divides, the same
         call runs the einsum pair over all ``max_len`` positions
-        (``llama._cached_attn_merged_q`` / ``_cached_attn_merged``), the
-        kernel's oracle. ``stats()`` counts what was read either way.
+        (``cached_attn_merged_q`` / ``cached_attn_merged``: all three in
+        ``ops/cached_attention.py``), the kernel's oracle. ``stats()``
+        counts what was read either way.
 
         Each step draws with the engine's one sampler (``draw_tokens``:
         ``window`` [B, W] the slots' recent token ids, ``penalties`` [B]).

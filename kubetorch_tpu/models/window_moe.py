@@ -19,10 +19,11 @@ The layer, on its input ``x`` (the float32 residual stream):
    Softmax at ``head_dim ** -0.5``; ``x' = x + attn W_o``.
 3. ``m = RMSNorm(x')``; ``x_next = x' + sum_e p_e W_down^e (relu(W_gate^e
    m) * (W_up^e m))`` over the chosen experts: dropless, the pairs sorted by
-   expert and the two products grouped (``latent_moe.routed_experts``, the
-   second decoder's, with ReLU for its SiLU: ``ops/grouped_matmul.py`` reads
-   only the experts given a token; an admission's tokens go through in one
-   pass where memory lets them, ``latent_moe.admitted_experts``).
+   expert and the two products grouped (``models/experts.py``, the shared
+   expert layer, with ReLU as the gate's activation: ``routed_experts`` over
+   ``ops/grouped_matmul.py``, which reads only the experts given a token; an
+   admission's tokens go through in one pass where memory lets them,
+   ``admitted_experts``).
 
 **What a row keeps.** A full layer keeps K and V of every position (``k``,
 ``v``: ``[L_full, B, max_len, Hkv, D]``). A window layer can never again see
@@ -66,11 +67,14 @@ import jax
 import jax.numpy as jnp
 
 from kubetorch_tpu.models.configs import WindowMoEConfig
-from kubetorch_tpu.models.decoder import CacheLeaf
-from kubetorch_tpu.models.hybrid_linear import scan_runs
-from kubetorch_tpu.models.latent_moe import (COUNTERS, admission_plan,
-                                             admitted_experts)
+from kubetorch_tpu.models import experts
+from kubetorch_tpu.models.decoder import (CacheLeaf, Decoder, embed,
+                                          layer_at, refusal, scan_runs,
+                                          unembed)
 from kubetorch_tpu.ops import decode_attention, flash_attention, grid_write
+from kubetorch_tpu.ops.cached_attention import (cached_attn,
+                                                cached_attn_merged,
+                                                cached_attn_ragged)
 from kubetorch_tpu.ops.norms import rms_norm
 from kubetorch_tpu.ops.rope import apply_rope, rope_angles
 
@@ -82,6 +86,7 @@ KV = {FULL: ("k", "v"), WINDOW: ("wk", "wv")}
 # the leaves of a layer that are sliced a layer; the expert stacks are not
 _SMALL = ("attn_norm", "wqkv", "wo", "router", "mlp_norm")
 
+_LABEL = "the window / routed-expert decoder (models/window_moe.py)"
 # what RollingGenerator can be asked for that this decoder does not carry
 _REFUSED = {
     "kv_dtype": "an int8 K/V cache (kv_dtype='int8'): a ring's scales would "
@@ -95,12 +100,6 @@ _REFUSED = {
               "own to splice, and one splice would have to land mid-ring",
     "handoff": "disaggregated prefill/decode handoff tiers",
 }
-
-
-def _refuse(*names: str):
-    return NotImplementedError(
-        "the window / routed-expert decoder (models/window_moe.py) does "
-        "not carry " + "; ".join(_REFUSED[n] for n in names))
 
 
 # ------------------------------------------------------------------ init
@@ -173,16 +172,6 @@ def _held_bytes(cfg) -> int:
             + L * cfg.embed_dim * 4)
 
 
-def _experts(m, valid, chosen, weights, stack, i, cfg: WindowMoEConfig):
-    """m [n,E] in the compute dtype -> (sum over each token's chosen
-    experts [n,E] float32, counters): ``latent_moe.admitted_experts``, in one
-    pass where that holds no more than the admission's attention does
-    (``_held_bytes``)."""
-    return admitted_experts(m, valid, chosen, weights, stack["we_gu"],
-                            stack["we_down"], i, cfg, jax.nn.relu,
-                            _held_bytes(cfg))
-
-
 def _qkv(h, layer, sin, cos, kind: str, cfg: WindowMoEConfig):
     """h [B,T,E] (normed, compute dtype) -> q [B,T,H,D], k, v [B,T,Hkv,D];
     a window layer's q and k rotated by their positions."""
@@ -214,8 +203,8 @@ def layer_kinds(cfg: WindowMoEConfig) -> Tuple[str, ...]:
 def _scan_layers(params, cfg: WindowMoEConfig, carry, body):
     """Run ``body(carry, stack, i, kind) -> carry`` over the layers in
     order, ``stack`` the kind's stacked leaves and ``i`` the layer's index in
-    them (the third decoder's scheme, ``hybrid_linear.scan_runs``: each
-    kind's layer is compiled once a place in the repeating unit)."""
+    them (``decoder.scan_runs``: each kind's layer is compiled once a place
+    in the repeating unit)."""
     return scan_runs(
         cfg.layer_types, carry, lambda carry, kind, at, j: body(
             carry, params[STACK[kind]], at + j, kind))
@@ -223,28 +212,7 @@ def _scan_layers(params, cfg: WindowMoEConfig, carry, body):
 
 def _small(stack, i):
     """Layer ``i``'s leaves out of a kind's stack, less the experts'."""
-    return {k: jax.lax.dynamic_index_in_dim(stack[k], i, 0, False)
-            for k in _SMALL}
-
-
-def _embed(params, tokens):
-    """The residual stream is float32 whatever the compute dtype: the
-    router reads it as it is (``latent_moe._embed``'s reason, with the
-    router one step nearer the stream)."""
-    return params["embedding"][tokens].astype(jnp.float32)
-
-
-def _logits(x, params, cfg: WindowMoEConfig, unembed_positions=None):
-    if unembed_positions is not None:
-        x = jnp.take_along_axis(x, unembed_positions[:, None, None], axis=1)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(
-        cfg.compute_dtype)
-    return jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(
-        cfg.compute_dtype)).astype(jnp.float32)
-
-
-def _at(stack, i):
-    return jax.lax.dynamic_index_in_dim(stack, i, 0, False)
+    return {k: layer_at(stack[k], i) for k in _SMALL}
 
 
 def _block(x, valid, stack, i, kind, sin, cos, attend, cfg: WindowMoEConfig):
@@ -261,8 +229,9 @@ def _block(x, valid, stack, i, kind, sin, cos, attend, cfg: WindowMoEConfig):
         "btn,ne->bte", attn.reshape(B, T, -1).astype(dt),
         layer["wo"].astype(dt)).astype(x.dtype)
     m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps).astype(dt)
-    y, counters = _experts(m.reshape(B * T, E), valid.reshape(-1), chosen,
-                           weights, stack, i, cfg)
+    y, counters = experts.experts(
+        m.reshape(B * T, E), valid.reshape(-1), chosen, weights, stack, i,
+        cfg, jax.nn.relu, _held_bytes(cfg))
     return x + y.reshape(B, T, E).astype(x.dtype), kept, counters
 
 
@@ -274,7 +243,7 @@ def init_cache(cfg: WindowMoEConfig, batch: int, max_len: int, dtype=None,
     window, a short bucket's private one, keeps every position: slot =
     position), the compute dtype."""
     if quantized:
-        raise _refuse("kv_dtype")
+        raise refusal(_LABEL, _REFUSED, "kv_dtype")
     dt = jnp.dtype(dtype) if dtype is not None else cfg.compute_dtype
     vec = (cfg.n_kv_heads, cfg.head_dim)
     full = (cfg.n_full_layers, batch, max_len) + vec
@@ -336,14 +305,12 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     chunk mask admits, summed over layers; ``{}`` for a prefill (the
     generator counts a prefill's on the host)."""
     if lora is not None:
-        raise _refuse("adapters")
-    from kubetorch_tpu.models import llama
-
+        raise refusal(_LABEL, _REFUSED, "adapters")
     B, T = tokens.shape
     H, Hkv, D, W = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.window
     sin, cos = rope_angles(positions, D, cfg.rope_theta)
-    x = _embed(params, tokens)
-    totals = {name: jnp.zeros((), jnp.int32) for name in COUNTERS}
+    x = embed(params, tokens)
+    totals = {name: jnp.zeros((), jnp.int32) for name in experts.COUNTERS}
 
     def add(totals, counters):
         return {name: totals[name] + counters.get(name, 0)
@@ -352,7 +319,7 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
     if chunk is None:
         M = cache["k"].shape[2]
         if not (isinstance(write_at, int) and write_at == 0 and M == T):
-            raise _refuse("prefix")
+            raise refusal(_LABEL, _REFUSED, "prefix")
         real = jnp.diagonal(mask, axis1=1, axis2=2)                 # [B,T]
         flash = causal_lens is not None and flash_attention.prefill_engages(
             T, M, write_at, H, Hkv, D)
@@ -387,17 +354,17 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
                         return flash_attention.prefill_attention(
                             q, k, v, W if kind == WINDOW and T > W else None
                         ), kept
-                    return llama._cached_attn(
+                    return cached_attn(
                         q, k, v,
                         band if kind == WINDOW and band is not None
-                        else mask, cfg), kept
+                        else mask), kept
 
             x, leaves, _ = _block(x, real, stack, i, kind, sin, cos, attend,
                                   cfg)
             return x, leaves
 
         x, leaves = _scan_layers(params, cfg, (x, dict(cache)), body)
-        return _logits(x, params, cfg, unembed_positions), leaves, {}
+        return unembed(x, params, cfg, unembed_positions), leaves, {}
 
     M, span = cache["k"].shape[2], cache["wk"].shape[2]
     C = chunk["k"].shape[2]
@@ -432,21 +399,21 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
                     cols[n], new.astype(cols[n].dtype)[None],
                     (i, 0, chunk_col, 0, 0))
                 for n, new in zip(KV[kind], (k, v))}}
-            ek, ev = (_at(kept[n], i) for n in KV[kind])
+            ek, ev = (layer_at(kept[n], i) for n in KV[kind])
             with jax.named_scope("window_attention_decode"
                                  if kind == WINDOW
                                  else "full_attention_decode"):
                 if items is not None:
                     # float32 queries: 7 query heads a kv head are not a
                     # whole bfloat16 tile (``decode_attention.engages``)
-                    return llama._cached_attn_ragged(
+                    return cached_attn_ragged(
                         q.astype(jnp.float32), gk, gv, None, None, i,
-                        items[kind], ek, ev, chunk_mask, cfg
+                        items[kind], ek, ev, chunk_mask
                     ).astype(q.dtype), kept
-                return llama._cached_attn_merged(
-                    q, _at(gk, i), _at(gv, i), ek, ev,
-                    ring_mask if kind == WINDOW else mask, chunk_mask,
-                    cfg), kept
+                return cached_attn_merged(
+                    q, layer_at(gk, i), layer_at(gv, i), ek, ev,
+                    ring_mask if kind == WINDOW else mask,
+                    chunk_mask), kept
 
         x, cols, counters = _block(x, valid, stack, i, kind, sin, cos,
                                    attend, cfg)
@@ -454,13 +421,14 @@ def forward_cached(params: Params, tokens, positions, cache, write_at, mask,
 
     x, cols, totals = _scan_layers(params, cfg, (x, dict(chunk), totals),
                                    body)
-    return _logits(x, params, cfg, unembed_positions), cols, totals
+    return unembed(x, params, cfg, unembed_positions), cols, totals
 
 
-class WindowMoEDecoder:
+class WindowMoEDecoder(Decoder):
     """``models/decoder.py``'s interface over this module."""
 
-    counters = COUNTERS
+    counters = experts.COUNTERS
+    label, refused = _LABEL, _REFUSED
     layer_kinds = staticmethod(layer_kinds)
     init_cache = staticmethod(init_cache)
     merge_chunk_into_grid = staticmethod(merge_chunk_into_grid)
@@ -469,7 +437,7 @@ class WindowMoEDecoder:
     @staticmethod
     def cache_leaves(cfg: WindowMoEConfig, quantized: bool = False):
         if quantized:
-            raise _refuse("kv_dtype")
+            raise refusal(_LABEL, _REFUSED, "kv_dtype")
         vec, dt = (cfg.n_kv_heads, cfg.head_dim), cfg.compute_dtype
         return {FULL: tuple(CacheLeaf(n, vec, dt) for n in KV[FULL]),
                 WINDOW: tuple(CacheLeaf(n, vec, dt, True, cfg.window)
@@ -478,12 +446,6 @@ class WindowMoEDecoder:
     @staticmethod
     def init_cache_like(cfg, cache, batch, max_len):
         return init_cache(cfg, batch, max_len, dtype=cache["k"].dtype)
-
-    @staticmethod
-    def init_chunk(cfg, cache, batch, cols):
-        return {name: jnp.zeros((leaf.shape[0], batch, cols)
-                                + leaf.shape[3:], leaf.dtype)
-                for name, leaf in cache.items()}
 
     @staticmethod
     def ragged_block(cfg, max_len, cache, spec: bool) -> Optional[int]:
@@ -511,30 +473,10 @@ class WindowMoEDecoder:
 
     @staticmethod
     def expert_admission(cfg: WindowMoEConfig, lens, p_pad: int):
-        """``latent_moe.admission_plan`` of this decoder's admissions."""
-        return admission_plan(cfg, cfg.embed_dim, lens, p_pad,
-                              _held_bytes(cfg), cfg.n_layers)
+        return experts.expert_admission(cfg, lens, p_pad, _held_bytes(cfg),
+                                        cfg.n_layers)
 
     @staticmethod
     def prefill_counters(cfg: WindowMoEConfig, prompt_tokens: int):
-        """Padding past a prompt's end is given to no expert, so a prefill
-        computes exactly its prompt's pairs."""
-        return {"moe_assignments":
-                prompt_tokens * cfg.top_k * cfg.n_layers}
-
-    @staticmethod
-    def state_rows_touched(cfg, rows: int, live: int) -> int:
-        return 0
-
-    @staticmethod
-    def scan_positions(cfg, rows: int, length: int) -> int:
-        return 0
-
-    @staticmethod
-    def check_serving(cfg, kv_dtype: str = "bf16", **features) -> None:
-        asked = [name for name, on in features.items()
-                 if on and name in _REFUSED]
-        if kv_dtype != "bf16":
-            asked.insert(0, "kv_dtype")
-        if asked:
-            raise _refuse(*asked)
+        return {"moe_assignments": experts.moe_assignments(
+            cfg, prompt_tokens, cfg.n_layers)}

@@ -1,13 +1,14 @@
 """Pallas ragged decode attention for TPU: one query position per row over
 the READ-ONLY stacked serving grid, each row read only to its own depth.
 
-The einsum pair it stands in for (``llama._cached_attn_merged_q`` /
-``_cached_attn_merged``) contracts ``q`` against all ``max_len`` positions of
-every slot and masks afterwards, so a decode step streams the whole grid
-whatever is live. Here the grid planes stay in HBM as they are —
-``[L, B, M, Hkv, D]`` K/V and, for an int8 grid, ``[L, B, M, Hkv]`` scales —
-and one call a layer walks a work list of the live ``(row, key block)`` items
-(``plan``, made once a decode step from the rows' depths and handed in as
+The einsum pair it stands in for (``ops/cached_attention.py``:
+``cached_attn_merged_q`` / ``cached_attn_merged``) contracts ``q`` against
+all ``max_len`` positions of every slot and masks afterwards, so a decode
+step streams the whole grid whatever is live. Here the grid planes stay in
+HBM as they are — ``[L, B, M, Hkv, D]`` K/V and, for an int8 grid,
+``[L, B, M, Hkv]`` scales — and one call a layer walks a work list of the
+live ``(row, key block)`` items (``plan``, made once a decode step from the
+rows' depths and handed in as
 scalar-prefetch operands beside the layer index): each item's K/V block and
 scales are copied HBM -> VMEM by the kernel's own double-buffered DMAs, the
 next item's in flight while this one is computed. A block at or past a row's
@@ -88,9 +89,11 @@ def engages(t: int, max_len: int, n_kv_heads: int, head_dim: int,
     the queries' dtype are not whole sublane tiles (one query head a kv
     head; 7 of them) the queries go in as float32, whose rows the compiler
     slices singly (the kernel rounds them to its operand dtype itself); and
-    kv heads that do not fill a packed word are stored rounded up by the
-    decoder (``hybrid_linear.kv_heads_stored``), never here. 4 bfloat16 kv
-    heads are two whole words a position and go in as they lie."""
+    kv heads that do not fill whole tiles are stored rounded up by the
+    decoder (``kv_heads_stored`` below, the queries padded to match:
+    ``pad_heads``), never by the kernel, whose key block then has to fit
+    beside them (``ragged_key_block``). 4 bfloat16 kv heads are two whole
+    words a position and go in as they lie."""
     pack = 4 // jnp.dtype(dtype).itemsize
     if (t != 1 or block_for(max_len) is None or head_dim % 128
             or pack < 1 or n_kv_heads % pack):
@@ -99,6 +102,44 @@ def engages(t: int, max_len: int, n_kv_heads: int, head_dim: int,
         return True
     mesh = jax.sharding.get_abstract_mesh()
     return jax.default_backend() == "tpu" and (mesh.empty or mesh.size == 1)
+
+
+def kv_heads_stored(n_kv_heads: int, dtype) -> int:
+    """Heads a position of ``k`` / ``v`` is stored at: ``n_kv_heads``
+    rounded up to the sublane tile of the cache's dtype (8 rows of 32-bit
+    words: 8 float32 heads, 16 bfloat16 ones). An array whose heads do not
+    fill whole tiles is stored padded anyway, and a kernel's DMA cannot cut
+    a ragged tile (30 heads: refused by the TPU's compiler); the padded
+    heads hold zeros and their outputs are dropped."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return -(-n_kv_heads // tile) * tile
+
+
+# the ragged kernel double-buffers a key block of K and of V in VMEM
+_RAGGED_BUFFER_BYTES = 10 << 20
+
+
+def ragged_key_block(max_len: int, heads: int, head_dim: int,
+                     dtype) -> Optional[int]:
+    """The key block of the kernel over a cache of ``heads`` stored kv
+    heads, or None where it does not engage: the largest of ``_BLOCKS``
+    that the grid's length divides by AND whose two double-buffered
+    ``[block, heads, head_dim]`` planes stay inside the kernel's VMEM (the
+    dense decoder's 512 keys x 8 heads is 4 MB; x 32 heads it is 17 MB, over
+    the 16 MB a kernel may hold)."""
+    if not engages(1, max_len, heads, head_dim, dtype):
+        return None
+    for block in _BLOCKS:
+        if max_len % block == 0 and (4 * block * heads * head_dim
+                                     * jnp.dtype(dtype).itemsize
+                                     <= _RAGGED_BUFFER_BYTES):
+            return block
+    return None
+
+
+def pad_heads(x, heads: int):
+    """[B,T,H,D] -> [B,T,heads,D], zeros after the real heads."""
+    return jnp.pad(x, ((0, 0), (0, 0), (0, heads - x.shape[2]), (0, 0)))
 
 
 def _head_planes(ref, operand_dtype):

@@ -1,6 +1,7 @@
 """The ragged decode-attention kernel (ops/decode_attention.py) against the
-einsum pair it stands in for (``llama._cached_attn_merged_q`` /
-``_cached_attn_merged``), in interpret mode at toy widths.
+einsum pair it stands in for (``ops/cached_attention.py``:
+``cached_attn_merged_q`` / ``cached_attn_merged``), in interpret mode at toy
+widths.
 
 Tolerance. Both sides accumulate in f32. Over an int8 grid both feed the
 MXU bf16 operands, but they round DIFFERENT numbers to bf16 before PV: the
@@ -23,7 +24,7 @@ import numpy as np
 import pytest
 
 from kubetorch_tpu.models import llama
-from kubetorch_tpu.ops import decode_attention
+from kubetorch_tpu.ops import cached_attention, decode_attention
 
 BLOCK = 128                         # (serving picks 512 of [512, 256, 128])
 M = 4 * BLOCK                       # four key blocks
@@ -76,16 +77,16 @@ def _both(grid, q, ek, ev, pos0, active):
              & active[:, None, None])
     emask = ((jnp.arange(K)[None, None, :] <= COL) & active[:, None, None])
     if gks is not None:
-        want = llama._cached_attn_merged_q(
+        want = cached_attention.cached_attn_merged_q(
             q, gk[LAYER], gv[LAYER], gks[LAYER], gvs[LAYER], ek, ev, gmask,
-            emask, None)
+            emask)
     else:
-        want = llama._cached_attn_merged(q, gk[LAYER], gv[LAYER], ek, ev,
-                                         gmask, emask, None)
-    got = llama._cached_attn_ragged(
+        want = cached_attention.cached_attn_merged(
+            q, gk[LAYER], gv[LAYER], ek, ev, gmask, emask)
+    got = cached_attention.cached_attn_ragged(
         q, gk, gv, gks, gvs, jnp.int32(LAYER),
         decode_attention.plan(jnp.where(active, pos0, 0), M, BLOCK), ek, ev,
-        emask, None)
+        emask)
     vmax = float(max(jnp.abs(ev).max(), jnp.abs(
         gv[LAYER] * (1.0 if gvs is None else gvs[LAYER][..., None])).max()))
     return np.asarray(want), np.asarray(got), vmax
@@ -118,9 +119,9 @@ def test_dead_positions_are_not_read_into_the_result(kv):
     emask = ((jnp.arange(K)[None, None, :] <= COL) & active[:, None, None])
 
     def run(planes):
-        return np.asarray(llama._cached_attn_ragged(
+        return np.asarray(cached_attention.cached_attn_ragged(
             q, *planes, jnp.int32(LAYER),
-            decode_attention.plan(depth, M, BLOCK), ek, ev, emask, None))
+            decode_attention.plan(depth, M, BLOCK), ek, ev, emask))
 
     dead = (jnp.arange(M)[None, :] >= depth[:, None])[None, :, :, None]
     gk, gv, gks, gvs = grid

@@ -1,14 +1,23 @@
 """``models/decoder.py`` after its widening (leaves by kind; positional and
 row-state leaves): the two decoders that were there declare what they
-declared, keep no row state, and serve, export and import as they did."""
+declared, keep no row state, and serve, export and import as they did. And
+the rule between decoders: none imports another, ``ops/`` imports no model,
+and the defaults the five classes inherit are what each returned itself."""
+
+import ast
+import importlib
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubetorch_tpu.models import (HybridLinearConfig, LatentMoEConfig,
-                                  LlamaConfig, latent_moe, llama)
+from kubetorch_tpu.models import (HybridLinearConfig, IndexedMoEConfig,
+                                  LatentMoEConfig, LlamaConfig,
+                                  WindowMoEConfig, latent_moe, llama)
 from kubetorch_tpu.models.decoder import (CacheLeaf, LlamaDecoder,
                                           decoder_for, grid_dims,
                                           position_bytes, row_bytes,
@@ -127,3 +136,120 @@ def test_a_plain_export_does_not_fit_a_grid_with_row_state():
                          max_slots=2, max_len=128, steps_per_call=4)
     with pytest.raises(KVGeometryMismatch, match="row-state"):
         b.import_row(state)
+
+
+# ------------------------------------------- a decoder is a file of its own
+REPO = Path(__file__).resolve().parents[1]
+PACKAGE = "kubetorch_tpu.models"
+# module -> (its toy configuration, the kernels under ``ops/`` only it uses)
+DECODERS = {
+    "llama": (LlamaConfig.tiny, ()),
+    "latent_moe": (LatentMoEConfig.tiny, ("latent_attention",)),
+    "hybrid_linear": (HybridLinearConfig.tiny, ("gated_delta",)),
+    "window_moe": (WindowMoEConfig.tiny, ()),
+    "indexed_moe": (IndexedMoEConfig.tiny, ("indexed_attention",)),
+}
+
+
+def _imported(path: Path, package: str):
+    """Every module a file imports, at module level or inside a function,
+    by its full name (``from a.b import c`` gives ``a.b`` and ``a.b.c``)."""
+    names = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            names.update(alias.name for alias in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:      # relative: ``.`` is ``package`` itself
+                parts = package.split(".")
+                up = parts[:len(parts) - node.level + 1]
+                base = ".".join(up + ([base] if base else []))
+            names.add(base)
+            names.update(f"{base}.{alias.name}" for alias in node.names)
+    return names
+
+
+@pytest.mark.parametrize("name", sorted(DECODERS))
+def test_a_decoder_imports_no_other_decoder(name):
+    """What two decoders share lives in ``models/decoder.py``,
+    ``models/experts.py`` or ``ops/``: a decoder's file names no sibling in
+    an import, not even inside a function."""
+    seen = _imported(REPO / "kubetorch_tpu" / "models" / f"{name}.py",
+                     PACKAGE)
+    others = {f"{PACKAGE}.{other}" for other in DECODERS if other != name}
+    assert not seen & others, sorted(seen & others)
+
+
+def test_ops_import_nothing_from_models():
+    files = sorted((REPO / "kubetorch_tpu" / "ops").glob("*.py"))
+    assert len(files) > 10
+    for path in files:
+        bad = [m for m in _imported(path, "kubetorch_tpu.ops")
+               if m == PACKAGE or m.startswith(PACKAGE + ".")]
+        assert not bad, (path.name, bad)
+
+
+@pytest.mark.parametrize("name", sorted(DECODERS))
+def test_a_decoder_loads_no_other_decoder(name):
+    """A decoder enters only through ``decoder_for`` and the lazy module
+    attribute: importing the engine's path and ONE decoder loads no other
+    decoder's module and none of the kernels only another uses (``llama``
+    excepted, which ``models/__init__.py`` imports)."""
+    others = [other for other in DECODERS if other not in (name, "llama")]
+    banned = [f"{PACKAGE}.{other}" for other in others] + [
+        f"kubetorch_tpu.ops.{kernel}" for other in others
+        for kernel in DECODERS[other][1]]
+    code = ("import sys; import kubetorch_tpu.models.rolling, "
+            f"kubetorch_tpu.serving.engine, {PACKAGE}.{name}; "
+            f"bad = [m for m in {banned!r} if m in sys.modules]; "
+            "print(bad); sys.exit(1 if bad else 0)")
+    out = subprocess.run([sys.executable, "-c", code], cwd=str(REPO),
+                         capture_output=True, text=True,
+                         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
+                              "PYTHONPATH": str(REPO)})
+    assert out.returncode == 0, out.stdout + out.stderr
+
+
+@pytest.mark.parametrize("name", sorted(DECODERS))
+def test_the_inherited_defaults_are_what_each_class_returned(name):
+    """``Decoder``'s defaults against what the five classes spelled out
+    themselves before they inherited them: no state rows and no scan but
+    the hybrid's, a chunk of zeros in the grid's own dtypes for the columns
+    (the dense decoder's bfloat16 over an int8 grid and the hybrid's carried
+    row state are their own), and a refusal that names the feature and the
+    module (the dense decoder refuses nothing)."""
+    module = importlib.import_module(f"{PACKAGE}.{name}")
+    cfg = DECODERS[name][0]()
+    model = decoder_for(cfg)
+    assert model.__mro__[1].__name__ == "Decoder"
+    hybrid = name == "hybrid_linear"
+    assert model.state_rows_touched(cfg, 8, 3) == (8 if hybrid else 0)
+    assert (model.scan_positions(cfg, 2, 256) > 0) == hybrid
+    if name not in ("latent_moe", "window_moe", "indexed_moe"):
+        assert model.counters == () and model.prefill_counters(cfg, 9) == {}
+    cache = model.init_cache(cfg, 3, 64)
+    chunk = model.init_chunk(cfg, cache, 3, 8)
+    assert set(chunk) == set(cache)
+    rows = row_leaves(model, cfg)
+    for leaf, grid in cache.items():
+        if leaf in rows:
+            assert chunk[leaf] is grid
+        else:
+            assert chunk[leaf].shape == grid.shape[:2] + (8,) + grid.shape[3:]
+            assert chunk[leaf].dtype == grid.dtype
+            assert not np.asarray(chunk[leaf], np.float32).any()
+    # every feature at once: the words of each, int8 first, and the module
+    asked = dict(kv_dtype="int8", spec=True, adapters=True, mesh=True,
+                 prefix=True, handoff=True)
+    if name == "llama":
+        assert model.refused == {}
+        assert model.check_serving(cfg, **asked) is None
+        return
+    assert model.check_serving(cfg, spec=False, prefix=False) is None
+    with pytest.raises(NotImplementedError) as err:
+        model.check_serving(cfg, **asked)
+    said = str(err.value)
+    assert f"(models/{name}.py) does not carry " in said
+    assert said.endswith("; ".join(module._REFUSED[f] for f in asked))
+    with pytest.raises(NotImplementedError, match="int8"):
+        model.init_cache(cfg, 3, 64, quantized=True)

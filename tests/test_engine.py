@@ -225,9 +225,16 @@ def test_overload_sheds_typed_with_retry_after():
 
         threads = [threading.Thread(target=run, args=(k,), daemon=True)
                    for k in range(1, 4)]
-        for t in threads:
-            t.start()
+        # the one slot is taken BEFORE the two that queue are sent: three
+        # sent at once race the first admission, and the third is then the
+        # one that is shed (the backlog shows for a tick and is gone)
+        threads[0].start()
         deadline = time.time() + 5
+        while sim.active_rows < 1 and time.time() < deadline:
+            time.sleep(0.005)
+        assert sim.active_rows == 1, "the first request was never admitted"
+        for t in threads[1:]:
+            t.start()
         while sim.queued < 2 and time.time() < deadline:
             time.sleep(0.005)
         assert sim.queued >= 2, "backlog never built"

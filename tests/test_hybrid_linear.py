@@ -427,15 +427,17 @@ def test_leaves_are_declared_by_kind_and_by_sort(toy):
     big = decoder_for(real)
     assert row_bytes(big, real) == 12 * (30 * 96 * 192 * 4 + 3 * 11520 * 2)
     assert position_bytes(big, real) == 4 * 2 * 32 * 128 * 2
-    assert hybrid_linear.kv_heads_stored(real) == 32
+    assert decode_attention.kv_heads_stored(real.n_kv_heads,
+                                            real.compute_dtype) == 32
     # 512 keys x 32 heads x 128 x 2 planes x 2 buffers is over a kernel's
     # VMEM: the key block is the largest that fits
-    assert hybrid_linear.ragged_key_block(4096, 32, 128, jnp.bfloat16) is None
+    assert decode_attention.ragged_key_block(4096, 32, 128,
+                                             jnp.bfloat16) is None
     decode_attention._FORCE_INTERPRET = True
     try:
-        assert hybrid_linear.ragged_key_block(
+        assert decode_attention.ragged_key_block(
             4096, 32, 128, jnp.bfloat16) == 256
-        assert hybrid_linear.ragged_key_block(
+        assert decode_attention.ragged_key_block(
             2048, 8, 128, jnp.int8) == 512
     finally:
         decode_attention._FORCE_INTERPRET = False

@@ -35,7 +35,8 @@ from kubetorch_tpu.models.decoder import (decoder_for, grid_dims,
                                           off_grid_leaves, position_bytes,
                                           ring_leaves, row_leaves)
 from kubetorch_tpu.models.rolling import RollingGenerator
-from kubetorch_tpu.ops import decode_attention, indexed_attention
+from kubetorch_tpu.ops import (cached_attention, decode_attention,
+                               indexed_attention)
 from kubetorch_tpu.serving import kvpool
 from kubetorch_tpu.serving.engine import DecodeEngine
 
@@ -246,10 +247,10 @@ def test_router_is_a_softmax_over_all_with_the_chosen_renormalised(toy):
 def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
     """The expert layer takes an admission in one pass where that holds no
     more than the longest admission's attention (``_held_bytes``) and in
-    pieces where it would, by shapes alone (``latent_moe.admitted_experts``):
+    pieces where it would, by shapes alone (``experts.admitted_experts``):
     a generator laid out for 8 positions against one laid out for 4096, the
     same logits."""
-    from kubetorch_tpu.models import latent_moe
+    from kubetorch_tpu.models import experts
 
     d, cfg, params = toy
     T = 256
@@ -257,10 +258,10 @@ def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
     roomy = dataclasses.replace(cfg, max_seq_len=4096)
     small = dataclasses.replace(cfg, max_seq_len=8)
     E = cfg.embed_dim
-    assert latent_moe.expert_piece(T, roomy, E, 4,
-                                   indexed_moe._held_bytes(roomy)) == T
-    assert latent_moe.expert_piece(T, small, E, 4,
-                                   indexed_moe._held_bytes(small)) < T
+    assert experts.expert_piece(T, roomy, E, 4,
+                                indexed_moe._held_bytes(roomy)) == T
+    assert experts.expert_piece(T, small, E, 4,
+                                indexed_moe._held_bytes(small)) < T
     whole = np.asarray(indexed_moe.forward(params, toks, roomy))
     monkeypatch.setattr(indexed_moe, "_QUERY_BLOCK", 16)
     pieces = np.asarray(indexed_moe.forward(params, toks, small))
@@ -268,7 +269,7 @@ def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
     # the cell's shapes: one pass at every bucket, the top one too
     real = IndexedMoEConfig()
     for bucket in (2048, 8192, 16384, 32768):
-        assert latent_moe.expert_piece(
+        assert experts.expert_piece(
             bucket, real, real.embed_dim, 2,
             indexed_moe._held_bytes(real)) == bucket
 
@@ -492,7 +493,7 @@ def test_expert_admission_by_hand(p_pad, prompt, plan, monkeypatch):
     """What ``stats()`` says of an admission's expert layers, at the cell's
     widths, as where the kernel runs: tokens a pass, the row tile, the row
     tiles of 4 layers' work lists and those that hold only padding."""
-    from kubetorch_tpu.models import latent_moe
+    from kubetorch_tpu.models import experts
     from kubetorch_tpu.ops import grouped_matmul
 
     monkeypatch.setattr(grouped_matmul, "_FORCE_INTERPRET", True)
@@ -501,7 +502,7 @@ def test_expert_admission_by_hand(p_pad, prompt, plan, monkeypatch):
         real, [prompt], p_pad) == plan
     # a generator laid out for 4096 positions would take 32768 in pieces
     small = dataclasses.replace(real, max_seq_len=4096)
-    piece, tile, tiles, skipped = latent_moe.admission_plan(
+    piece, tile, tiles, skipped = experts.admission_plan(
         small, small.embed_dim, [prompt], p_pad,
         indexed_moe._held_bytes(small), 4)
     if p_pad == 32768:
@@ -647,8 +648,6 @@ def test_index_select_equals_the_plain_choice(T, topk, bq, bk, ties):
 def test_admission_kernels_equal_the_plain_attention():
     """Both admission kernels (interpreted) against the choice and the
     einsum pair in plain ``jnp``, two rows of different lengths."""
-    from kubetorch_tpu.models import llama
-
     rng = np.random.default_rng(5)
     B, T, H, Hkv, D, topk = 2, 512, 8, 4, 128, 128
     qi, ki, w = _index_case(rng, B, T, 4, 8, False)
@@ -661,7 +660,7 @@ def test_admission_kernels_equal_the_plain_attention():
         q, k, v, mask, block=256, interpret=True)
     causal = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (B, T, T))
     keep = indexed_attention.choice_mask(qi, ki, w, causal, topk)
-    want = llama._cached_attn(q, k, v, keep, None)
+    want = cached_attention.cached_attn(q, k, v, keep)
     assert float(jnp.abs(got - want)[0].max()) < 1e-5
     assert float(jnp.abs(got - want)[1, :300].max()) < 1e-5
     assert bool(jnp.isfinite(got).all())
@@ -672,8 +671,6 @@ def test_indexed_decode_kernel_equals_the_masked_einsum(dtype):
     """The ragged kernel with the choice as a mask (interpreted): rows at
     depth 0, mid-block, a whole plane and inside the first block, keys of a
     few values so that every row's threshold ties."""
-    from kubetorch_tpu.models import llama
-
     rng = np.random.default_rng(9)
     L, B, M, H, Hkv, D, k = 2, 4, 1024, 8, 4, 128, 128
     depth = jnp.asarray([0, 700, 1024, 130], jnp.int32)
@@ -689,10 +686,9 @@ def test_indexed_decode_kernel_equals_the_masked_einsum(dtype):
         keys, v_thr, p_tie, interpret=True)
     keep = indexed_attention.chosen(keys, v_thr, p_tie) & valid
     assert (np.asarray(keep.sum(-1)) == [0, k, k, k]).all()
-    want = llama._cached_attn(q[:, None].astype(jnp.float32),
-                              k_all[1].astype(jnp.float32),
-                              v_all[1].astype(jnp.float32),
-                              keep[:, None, :], None)[:, 0]
+    want = cached_attention.cached_attn(
+        q[:, None].astype(jnp.float32), k_all[1].astype(jnp.float32),
+        v_all[1].astype(jnp.float32), keep[:, None, :])[:, 0]
     got = acc / jnp.maximum(l, 1e-30)[..., None]
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     assert float(jnp.abs(got - want)[1:].max()) < tol
@@ -752,23 +748,3 @@ def test_engine_prices_rows_with_the_index_key(toy):
     finally:
         eng.close()
     assert kvpool.priced_tokens(100) == 100
-
-
-def test_the_other_decoders_do_not_import_the_fifth():
-    """The fifth decoder enters only through ``decoder_for`` and the lazy
-    module attribute: importing the engine's path and the other four
-    decoders loads neither its module nor its kernels."""
-    import subprocess
-    import sys
-
-    code = ("import sys; import kubetorch_tpu.models.rolling, "
-            "kubetorch_tpu.serving.engine, kubetorch_tpu.models.latent_moe, "
-            "kubetorch_tpu.models.hybrid_linear, "
-            "kubetorch_tpu.models.window_moe; "
-            "bad = [m for m in sys.modules if 'indexed' in m]; "
-            "print(bad); sys.exit(1 if bad else 0)")
-    out = subprocess.run([sys.executable, "-c", code], cwd=str(REPO),
-                         capture_output=True, text=True,
-                         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
-                              "PYTHONPATH": str(REPO)})
-    assert out.returncode == 0, out.stdout + out.stderr

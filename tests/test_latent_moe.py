@@ -28,7 +28,7 @@ import pytest
 
 from benchmark import weights
 from benchmark.families import latent_moe as family
-from kubetorch_tpu.models import LatentMoEConfig, latent_moe
+from kubetorch_tpu.models import LatentMoEConfig, experts, latent_moe
 from kubetorch_tpu.models.decoder import decoder_for, position_bytes
 from kubetorch_tpu.models.rolling import RollingGenerator
 from kubetorch_tpu.ops import grouped_matmul, latent_attention
@@ -371,14 +371,14 @@ def test_routed_experts_equal_a_loop_over_tokens(toy, summed, monkeypatch):
     copy (an admission, where the copy would be gigabytes)."""
     d, cfg, params = toy
     if summed == "a_choice_at_a_time":
-        monkeypatch.setattr(latent_moe, "_SUM_COPY_BYTES", 0)
+        monkeypatch.setattr(experts, "_SUM_COPY_BYTES", 0)
     n = 50
     m = jax.random.normal(jax.random.key(4), (n, cfg.embed_dim))
     valid = jnp.arange(n) % 7 != 3                   # some rows are no token
     moe = params["moe"]
     chosen, w = latent_moe.route(m, moe["router"][1], moe["router_bias"][1],
                                  cfg)
-    got, counters = latent_moe.routed_experts(
+    got, counters = experts.routed_experts(
         m, valid, chosen, w, moe["we_gu"], moe["we_down"], 1, cfg)
     want = np.zeros((n, cfg.embed_dim), np.float32)
     for t in range(n):

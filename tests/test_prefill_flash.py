@@ -1,7 +1,8 @@
 """An admission's own causal attention through the flash kernel
 (``ops/flash_attention.py::prefill_attention``, PR 30) against the einsum
-pair over the private cache that it stands in for (``llama._cached_attn_q`` /
-``_cached_attn``), in interpret mode on the CPU.
+pair over the private cache that it stands in for
+(``ops/cached_attention.py``: ``cached_attn_q`` / ``cached_attn``), in
+interpret mode on the CPU.
 
 Tolerance. Over an int8 private cache the oracle attends to K and V as the
 cache holds them (rounded to 127 levels a head vector) and the kernel to the
@@ -22,7 +23,7 @@ import pytest
 from kubetorch_tpu.models import llama
 from kubetorch_tpu.models.configs import LlamaConfig
 from kubetorch_tpu.models.rolling import RollingGenerator
-from kubetorch_tpu.ops import flash_attention
+from kubetorch_tpu.ops import cached_attention, flash_attention
 
 H, HKV, D = 32, 8, 128              # GQA 32 / 8, as the chat cell
 BUCKETS = (256, 512, 1024)
@@ -64,9 +65,9 @@ def test_flash_path_matches_einsum_pair_on_the_real_rows(kv, p_pad, which, n):
     if kv == "int8":
         kq, ks = llama._kv_quantize(k)
         vq, vs = llama._kv_quantize(v)
-        want = llama._cached_attn_q(q, kq, vq, ks, vs, mask, None)
+        want = cached_attention.cached_attn_q(q, kq, vq, ks, vs, mask)
     else:
-        want = llama._cached_attn(q, k, v, mask, None)
+        want = cached_attention.cached_attn(q, k, v, mask)
     got = flash_attention.prefill_attention(q, k, v)
     assert got.shape == want.shape and got.dtype == want.dtype
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -81,7 +82,7 @@ def test_flash_path_matches_einsum_pair_on_the_real_rows(kv, p_pad, which, n):
 def test_float32_model_differs_by_the_order_of_sums_only():
     q, k, v = _qkv(1, 256, jnp.float32, (4, 2))
     lens = jnp.asarray([200], jnp.int32)
-    want = llama._cached_attn(q, k, v, _causal_mask(256, lens), None)
+    want = cached_attention.cached_attn(q, k, v, _causal_mask(256, lens))
     got = flash_attention.prefill_attention(q, k, v)
     bound = TOL["f32"] * float(jnp.max(jnp.abs(v)))
     assert float(jnp.abs(got[0, :200] - want[0, :200]).max()) <= bound

@@ -32,7 +32,8 @@ import pytest
 from benchmark import weights
 from benchmark.families import window_moe as family
 from kubetorch_tpu.exceptions import KVGeometryMismatch
-from kubetorch_tpu.models import WindowMoEConfig, latent_moe, window_moe
+from kubetorch_tpu.models import (WindowMoEConfig, experts, latent_moe,
+                                  window_moe)
 from kubetorch_tpu.models.decoder import (decoder_for, grid_dims,
                                           off_grid_leaves, position_bytes,
                                           ring_leaves, ring_position_bytes)
@@ -142,6 +143,12 @@ def test_router_reads_the_layers_input_and_weighs_by_softmax_of_chosen(toy):
                   ).max() < 1e-6
 
 
+def _experts(m, valid, chosen, w, stack, i, cfg):
+    """The shared expert layer as ``window_moe._block`` calls it."""
+    return experts.experts(m, valid, chosen, w, stack, i, cfg, jax.nn.relu,
+                           window_moe._held_bytes(cfg))
+
+
 def test_reglu_experts_equal_a_loop_over_tokens(toy):
     d, cfg, params = toy
     n = 50
@@ -149,7 +156,7 @@ def test_reglu_experts_equal_a_loop_over_tokens(toy):
     valid = jnp.arange(n) % 7 != 3                   # some rows are no token
     stack = params["window"]
     chosen, w = window_moe.route(m, stack["router"][2], cfg)
-    got, counters = window_moe._experts(m, valid, chosen, w, stack, 2, cfg)
+    got, counters = _experts(m, valid, chosen, w, stack, 2, cfg)
     want = np.zeros((n, cfg.embed_dim), np.float32)
     for t in range(n):
         if not bool(valid[t]):
@@ -167,7 +174,7 @@ def test_reglu_experts_equal_a_loop_over_tokens(toy):
 def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
     """An admission whose one pass would hold more than the decoder's
     longest attention does (``_held_bytes``: here a generator laid out for
-    8 positions) goes through ``latent_moe.admitted_experts`` in pieces, by
+    8 positions) goes through ``experts.admitted_experts`` in pieces, by
     shapes alone: the same sum as one pass, and no counters."""
     d, cfg, params = toy
     n = 256
@@ -176,24 +183,23 @@ def test_long_admissions_go_through_the_experts_in_pieces(toy, monkeypatch):
     stack = params["full"]
     chosen, w = window_moe.route(m, stack["router"][0], cfg)
     roomy = dataclasses.replace(cfg, max_seq_len=4096)
-    whole, counted = window_moe._experts(m, valid, chosen, w, stack, 0, roomy)
+    whole, counted = _experts(m, valid, chosen, w, stack, 0, roomy)
     assert int(counted["moe_assignments"]) == 200 * cfg.top_k
     small = dataclasses.replace(cfg, max_seq_len=8)
     held = window_moe._held_bytes(small)
-    piece = latent_moe.expert_piece(n, small, cfg.embed_dim, 4, held)
+    piece = experts.expert_piece(n, small, cfg.embed_dim, 4, held)
     assert piece < n and n % piece == 0
-    assert latent_moe.expert_pass_bytes(piece, small, cfg.embed_dim,
-                                        4) <= held or piece == 64
+    assert experts.expert_pass_bytes(piece, small, cfg.embed_dim,
+                                     4) <= held or piece == 64
     # the pieces sum a choice at a time, as an admission's size makes them
-    monkeypatch.setattr(latent_moe, "_SUM_COPY_BYTES", 0)
-    pieces, counters = window_moe._experts(m, valid, chosen, w, stack, 0,
-                                           small)
+    monkeypatch.setattr(experts, "_SUM_COPY_BYTES", 0)
+    pieces, counters = _experts(m, valid, chosen, w, stack, 0, small)
     assert counters == {}
     assert np.abs(np.asarray(whole) - np.asarray(pieces)).max() < 1e-5
     # the cell's shapes: one pass at every bucket, the top one too
     real = window_moe.WindowMoEConfig(max_seq_len=16384)
     for bucket in (512, 4096, 16384):
-        assert latent_moe.expert_piece(
+        assert experts.expert_piece(
             bucket, real, real.embed_dim, 2,
             window_moe._held_bytes(real)) == bucket
 
@@ -594,7 +600,7 @@ def test_engine_prices_rows_from_the_generators_gauges(toy):
 # ----------------------------- (viii) the shared expert layer stays shared
 def _parents_routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all,
                             li, cfg):
-    """``latent_moe.routed_experts`` with SiLU written in, as it stood
+    """``experts.routed_experts`` with SiLU written in, as it stood
     before it took the gate's activation as an argument (its sum and its
     counters as PR 43 left them): the oracle of the test below."""
     from kubetorch_tpu.ops import grouped_matmul
@@ -659,5 +665,5 @@ def test_the_second_decoders_executables_lower_as_before(which, monkeypatch):
             *draw(1)).as_text()
 
     now = lower()
-    monkeypatch.setattr(latent_moe, "routed_experts", _parents_routed_experts)
+    monkeypatch.setattr(experts, "routed_experts", _parents_routed_experts)
     assert lower() == now
