@@ -8,7 +8,8 @@ model code. Layers are stacked and scanned (``lax.scan``) so compile time is
 O(1) in depth.
 """
 
-from kubetorch_tpu.models.configs import (HybridLinearConfig,
+from kubetorch_tpu.models.configs import (HybridLatentMoEConfig,
+                                          HybridLinearConfig,
                                           IndexedMoEConfig, LatentMoEConfig,
                                           LlamaConfig, MoEConfig, ViTConfig,
                                           WindowMoEConfig)
@@ -23,7 +24,8 @@ def __getattr__(name):
 
     if name in ("generate", "quant", "rolling", "speculative", "lora",
                 "embed", "decoder", "experts", "latent_moe",
-                "hybrid_linear", "window_moe", "indexed_moe"):
+                "hybrid_linear", "window_moe", "indexed_moe",
+                "hybrid_latent_moe"):
         return importlib.import_module(f"kubetorch_tpu.models.{name}")
     if name == "LoraConfig":
         return importlib.import_module(
@@ -48,8 +50,9 @@ def __getattr__(name):
 
 __all__ = ["LlamaConfig", "MoEConfig", "LatentMoEConfig",
            "HybridLinearConfig", "WindowMoEConfig", "IndexedMoEConfig",
+           "HybridLatentMoEConfig",
            "ViTConfig", "decoder", "experts", "latent_moe", "hybrid_linear",
-           "window_moe", "indexed_moe", "llama",
+           "window_moe", "indexed_moe", "hybrid_latent_moe", "llama",
            "Generator",
            "generate", "quant", "quantize_params", "RollingGenerator",
            "SpeculativeGenerator", "speculative", "lora", "LoraConfig",

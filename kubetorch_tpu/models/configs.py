@@ -410,6 +410,157 @@ class IndexedMoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class HybridLatentMoEConfig:
+    """Decoder whose mixers are of two kinds, five to one
+    (``models/hybrid_latent_moe.py``; the layer as the ``bailing_hybrid``
+    public config writes it): KDA layers keep a recurrent state of
+    ``kda_key_dim x kda_value_dim`` a head a ROW (the delta rule with a
+    decay a key CHANNEL, bounded below by ``decay_lower_bound`` a token,
+    behind a short causal convolution) and no position; MLA layers keep one
+    latent of ``kv_latent_dim`` and one rope key of ``qk_rope_dim`` a
+    position, nothing a head, and gate each head's output by one number.
+    ``layer_types`` names each layer by mixer and feed-forward: ``kda_dense``
+    | ``kda_moe`` | ``mla_moe`` (pre-norm residual blocks; the dense layers
+    lead). An expert layer's router scores all ``n_experts_routed`` experts
+    (sigmoid, a bias that only selects) in ``n_group`` groups of consecutive
+    experts, keeps the ``topk_group`` groups whose two best ``score + bias``
+    sum highest and chooses the ``top_k`` best of their experts, weights
+    renormalised over the chosen and scaled by ``routed_scale``; beside them
+    one shared SwiGLU of ``n_shared_experts * expert_mlp_dim``.
+    ``experts_held`` ``(first, count)`` is the LOCAL part of expert
+    parallelism: this program holds those consecutive experts' weights and
+    adds their terms of the sum alone; what an absent expert would add is
+    another holder's to compute. ``swiglu_limits`` (a layer each; the
+    published per-layer clamp) must be zero: its formula is not in the
+    config, so a non-zero one is refused, not guessed."""
+
+    vocab_size: int = 39296
+    embed_dim: int = 2560
+    layer_types: Tuple[str, ...] = (("kda_dense",) + ("kda_moe",) * 5
+                                    + ("mla_moe",))
+    kda_heads: int = 32
+    kda_key_dim: int = 128
+    kda_value_dim: int = 128
+    conv_width: int = 4
+    decay_lower_bound: float = -5.0
+    n_heads: int = 32
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    kv_latent_dim: int = 512
+    rope_theta: float = 6e6
+    dense_mlp_dim: int = 6144
+    n_experts_routed: int = 512
+    experts_held: Tuple[int, int] = (0, 128)
+    n_group: int = 8
+    topk_group: int = 4
+    top_k: int = 8
+    expert_mlp_dim: int = 768
+    n_shared_experts: int = 1
+    routed_scale: float = 2.5
+    norm_topk: bool = True
+    swiglu_limits: Tuple[float, ...] = ()
+    rms_eps: float = 1e-6
+    max_seq_len: int = 32768
+    dtype: str = "bfloat16"        # compute dtype
+    param_dtype: str = "bfloat16"  # storage dtype
+
+    KINDS: ClassVar[Tuple[str, ...]] = ("kda_dense", "kda_moe", "mla_moe")
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        object.__setattr__(self, "swiglu_limits", tuple(self.swiglu_limits))
+        kinds = self.layer_types
+        bad = sorted(set(kinds) - set(self.KINDS))
+        if bad or not kinds:
+            raise ValueError(f"layer_types must name {self.KINDS}, "
+                             f"got {bad or 'no layer'}")
+        dense = kinds.count("kda_dense")
+        if any(k != "kda_dense" for k in kinds[:dense]):
+            raise ValueError("the dense layers must lead the stack")
+        if any(self.swiglu_limits):
+            raise ValueError(
+                "swiglu_limits: a non-zero expert_swiglu_limit / "
+                "share_expert_swiglu_limit is not carried (the clamp's "
+                "formula is not in the public config)")
+        first, count = self.experts_held
+        size = self.n_experts_routed // max(1, self.n_group)
+        if (self.n_experts_routed % self.n_group
+                or not 1 <= self.topk_group <= self.n_group):
+            raise ValueError("n_experts_routed must split into n_group "
+                             "groups, of which topk_group are kept")
+        if (first < 0 or count < 1 or first + count > self.n_experts_routed
+                or first % size or count % size):
+            raise ValueError(
+                f"experts_held {self.experts_held} must be whole groups of "
+                f"{size} inside 0..{self.n_experts_routed}")
+        if self.top_k > self.topk_group * size or size < 2:
+            raise ValueError("top_k exceeds the kept groups' experts")
+        if self.decay_lower_bound < -5.0 or self.decay_lower_bound >= 0:
+            raise ValueError(
+                "decay_lower_bound must lie in [-5, 0): the chunked scan's "
+                "sub-blocks are safe to -5 a token (ops/kda.py)")
+        if self.qk_rope_dim % 2 or self.conv_width < 2:
+            raise ValueError("qk_rope_dim must be even, conv_width >= 2")
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def storage_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def n_kda_layers(self) -> int:
+        return self.n_layers - self.n_mla_layers
+
+    @property
+    def n_mla_layers(self) -> int:
+        return self.layer_types.count("mla_moe")
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.layer_types.count("kda_dense")
+
+    @property
+    def n_experts(self) -> int:
+        """Experts whose weights this program holds: what the expert layer
+        (``models/experts.py``) and its kernels see."""
+        return self.experts_held[1]
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def conv_channels(self) -> int:
+        """Channels the short convolution runs over: ``[q | k | v]``."""
+        return self.kda_heads * (2 * self.kda_key_dim + self.kda_value_dim)
+
+    @classmethod
+    def tiny(cls, **kw) -> "HybridLatentMoEConfig":
+        """CI config: a dense layer, a period and one more (D K K M K),
+        every expert held, float32."""
+        base = dict(vocab_size=512, embed_dim=64,
+                    layer_types=("kda_dense", "kda_moe", "kda_moe",
+                                 "mla_moe", "kda_moe"),
+                    kda_heads=4, kda_key_dim=8, kda_value_dim=16,
+                    n_heads=4, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+                    kv_latent_dim=32, rope_theta=10000.0, dense_mlp_dim=128,
+                    n_experts_routed=16, experts_held=(0, 16), n_group=4,
+                    topk_group=2, top_k=2, expert_mlp_dim=32,
+                    max_seq_len=128, dtype="float32", param_dtype="float32")
+        base.update(kw)
+        return cls(**base)
+
+
+@dataclasses.dataclass(frozen=True)
 class ViTConfig:
     """ViT-L/16-style image classifier (BASELINE config #4)."""
 

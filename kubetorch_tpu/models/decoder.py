@@ -105,7 +105,9 @@ functions; its executables are the ones they were), ``models/latent_moe.py``
 the second, ``models/hybrid_linear.py`` the third and the first with
 row-state leaves, ``models/window_moe.py`` the fourth and the first with
 rings, ``models/indexed_moe.py`` the fifth and the first whose layer keeps a
-leaf (``ik``, the index's key) that attention itself never reads.
+leaf (``ik``, the index's key) that attention itself never reads,
+``models/hybrid_latent_moe.py`` the sixth and the first with row-state
+leaves beside a LATENT plane (and the first to hold a share of its experts).
 
 **What a decoder is written with, and the import rule.** A decoder is a
 file of its own equations. What two of them share lives in one of three
@@ -409,7 +411,8 @@ class LlamaDecoder(Decoder):
 
 def decoder_for(cfg):
     """The decoder of a configuration object, by its type."""
-    from kubetorch_tpu.models.configs import (HybridLinearConfig,
+    from kubetorch_tpu.models.configs import (HybridLatentMoEConfig,
+                                              HybridLinearConfig,
                                               IndexedMoEConfig,
                                               LatentMoEConfig, LlamaConfig,
                                               WindowMoEConfig)
@@ -432,5 +435,10 @@ def decoder_for(cfg):
         from kubetorch_tpu.models.indexed_moe import IndexedMoEDecoder
 
         return IndexedMoEDecoder
+    if isinstance(cfg, HybridLatentMoEConfig):
+        from kubetorch_tpu.models.hybrid_latent_moe import \
+            HybridLatentMoEDecoder
+
+        return HybridLatentMoEDecoder
     raise TypeError(f"no decoder for a configuration of type "
                     f"{type(cfg).__name__}")

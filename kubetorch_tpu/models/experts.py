@@ -12,6 +12,9 @@ activation; a configuration object gives ``top_k``, ``n_experts``,
   admission of any length, in one pass where memory lets it
   (``expert_piece`` / ``expert_pass_bytes``); ``experts`` the layer as a
   decoder's block calls it, on its stack and layer index.
+- ``held_first`` on all three: the stack holds a SHARE of the experts the
+  router chooses among (the local part of expert parallelism); pairs whose
+  expert is absent are given to no expert. The exchange is not here.
 - ``COUNTERS``: what a pass counts on the device; ``moe_assignments`` and
   ``expert_admission`` (``admission_plan``) what an admission adds, counted
   on the host from shapes and lengths.
@@ -35,18 +38,33 @@ _SUM_COPY_BYTES = 16 << 20
 
 
 def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
-                   cfg, act=jax.nn.silu):
+                   cfg, act=jax.nn.silu, held_first: Optional[int] = None):
     """Sum over each token's chosen experts, dropless. m [n,E]; ``valid``
     [n] bool (a padded or inactive position is given to no expert);
     ``we_*_all`` the STACKED expert weights [Lm,X,..] and ``li`` this
     layer's index in them. ``cfg`` gives ``top_k`` and ``n_experts``;
     ``act`` is the gate's activation (SiLU for the second and the fifth
-    decoder, ReLU for the fourth). Returns (y [n,E] float32, counters)."""
+    decoder, ReLU for the fourth). Returns (y [n,E] float32, counters).
+
+    ``held_first`` is the LOCAL part of expert parallelism: the stack holds
+    the ``n_experts`` consecutive experts from ``held_first`` of a router
+    that chooses among more (``chosen`` names the router's); a pair whose
+    expert is absent is given to no expert and adds nothing, whatever its
+    weight (its term of the sum is its holder's), and the counters gain
+    ``moe_assignments_held``, the pairs whose expert is here."""
     n, E = m.shape
     K, X = cfg.top_k, cfg.n_experts
     with jax.named_scope("moe_experts"):
+        here = None
+        if held_first is not None:
+            chosen = chosen - held_first
+            here = (chosen >= 0) & (chosen < X)
+
+        def given():        # [n, K] or [n, 1]: the pairs given to an expert
+            return valid[:, None] if here is None else valid[:, None] & here
+
         # pairs sorted by expert; pairs of no token sort past the last one
-        e_flat = jnp.where(valid[:, None], chosen, X).reshape(-1)
+        e_flat = jnp.where(given(), chosen, X).reshape(-1)
         order = jnp.argsort(e_flat, stable=True)
         sizes = jnp.sum(e_flat[:, None] == jnp.arange(X)[None, :],
                         axis=0, dtype=jnp.int32)
@@ -61,7 +79,7 @@ def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
         # tokens) the rows are gathered one choice at a time and summed as
         # they come; a decode step keeps the one gather, a quarter the ops
         inv = jnp.argsort(order).reshape(n, K)
-        g = jnp.where(valid[:, None], weights, 0.0)
+        g = jnp.where(given(), weights, 0.0)
         if n * K * E * 4 <= _SUM_COPY_BYTES:
             out = jnp.einsum("nke,nk->ne", y[inv].astype(jnp.float32), g)
         else:
@@ -74,6 +92,8 @@ def routed_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
                 "moe_group_max": jnp.max(sizes),
                 "moe_rows_multiplied": grouped_matmul.rows_multiplied(
                     sizes, n * K, E, h.shape[-1])}
+    if held_first is not None:
+        counters["moe_assignments_held"] = jnp.sum(given(), dtype=jnp.int32)
     return out, counters
 
 
@@ -103,7 +123,8 @@ def expert_piece(n: int, cfg, E: int, itemsize: int, held_bytes: int) -> int:
 
 
 def admitted_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
-                     cfg, act, held_bytes: int):
+                     cfg, act, held_bytes: int,
+                     held_first: Optional[int] = None):
     """``routed_experts`` for an admission of any length: every fetch of an
     expert's weights should meet all the rows the admission has for it, so
     the tokens go through in ONE pass where memory lets them and in the
@@ -116,7 +137,7 @@ def admitted_experts(m, valid, chosen, weights, we_gu_all, we_down_all, li,
 
     def some(args):
         return routed_experts(*args, we_gu_all, we_down_all, li, cfg,
-                              act=act)
+                              act=act, held_first=held_first)
 
     if piece == n:
         return some((m, valid, chosen, weights))
@@ -151,14 +172,17 @@ def admission_plan(cfg, E: int, lens, p_pad: int,
             layers * int(tiles - (-(-real // tile)).sum()))
 
 
-def experts(m, valid, chosen, weights, stack, i, cfg, act, held_bytes: int):
+def experts(m, valid, chosen, weights, stack, i, cfg, act, held_bytes: int,
+            held_first: Optional[int] = None):
     """The expert layer of a decoder's block: m [n,E] in the compute dtype
     -> (sum over each token's chosen experts [n,E] float32, counters), the
     kind's stacked ``we_gu`` / ``we_down`` at layer ``i``, in one pass where
     that holds no more than ``held_bytes``, what the decoder's attention
-    holds at its longest admission."""
+    holds at its longest admission. ``held_first``: the stack holds a
+    share of the router's experts (``routed_experts``)."""
     return admitted_experts(m, valid, chosen, weights, stack["we_gu"],
-                            stack["we_down"], i, cfg, act, held_bytes)
+                            stack["we_down"], i, cfg, act, held_bytes,
+                            held_first)
 
 
 def expert_admission(cfg, lens, p_pad: int, held_bytes: Optional[int],

@@ -924,3 +924,156 @@ def test_indexed_moe_executables_compile_for_v5e_with_the_index_key_beside_kv(
         assert "index_select" not in text
         assert "admit_indexed_attention" not in text
         assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+
+
+# The sixth decoder (models/hybrid_latent_moe.py, Ling-3.0-flash widths: 32
+# KDA heads of 128 x 128 beside one latent plane of 512 + 64, 128 of 512
+# experts of 768 held, 32 slots x 32768) compiled for the same described
+# chip: its two kernels (ops/kda.py) and the cell's whole decode and
+# 32768-bucket admission executables.
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("kernel", ["kda_prefill", "kda_step"])
+def test_kda_kernels_compile_for_v5e_at_the_cells_shapes(kernel, v5e_chip,
+                                                         monkeypatch):
+    """``kda_prefill`` at the published head shape on a segment of the top
+    bucket (8192 tokens) and on the smallest bucket: ``q | k | v`` go in as
+    the mixer holds them (no transpose beside the call: the temporaries are
+    the running sums and their scan's buffers). ``kda_step`` on a row's ``(32, 128,
+    128)`` float32 block of the stacked state leaf (6 layers x 32 rows, 0.40
+    GB), the leaf the call's input and output in place."""
+    from kubetorch_tpu.ops import kda
+
+    layers, b, h, dk, dv = 6, 32, 32, 128, 128
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def step(q, k, v, a, beta, states, layer, live):
+        return kda.step_rows(q, k, v, a, beta, states, layer,
+                             kda.step_plan(live))
+
+    bf16 = jnp.bfloat16
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        if kernel == "kda_step":
+            exe = jax.jit(step, donate_argnums=(5,)).lower(
+                spec((b, h, dk), bf16), spec((b, h, dk), bf16),
+                spec((b, h, dv), bf16), spec((b, h, dk)), spec((b, h)),
+                spec((layers, b, h, dk, dv)), spec((), jnp.int32),
+                spec((b,), jnp.bool_)).compile()
+            text, mem = exe.as_text(), exe.memory_analysis()
+            assert text.count("tpu_custom_call") == 1 and kernel in text
+            assert mem.alias_size_in_bytes >= layers * b * h * dk * dv * 4
+            assert mem.temp_size_in_bytes < 1 << 20, mem.temp_size_in_bytes
+            return
+        for t in (256, 8192):
+            exe = jax.jit(kda.prefill_scan).lower(
+                spec((1, t, h, dk), bf16), spec((1, t, h, dk), bf16),
+                spec((1, t, h, dv), bf16), spec((1, t, h, dk)),
+                spec((1, t, h)), spec((1, h, dk, dv))).compile()
+            text = exe.as_text()
+            assert text.count("tpu_custom_call") == 1 and kernel in text, t
+            # the running sums (float32, a channel a token) and the
+            # cumulative sum's own buffers (0.369 GB at 8192, read here)
+            assert exe.memory_analysis().temp_size_in_bytes < 3 * (
+                t * h * dk * 4) + (1 << 20), t
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.mark.level("unit")
+@pytest.mark.parametrize("which", ["decode", "admit_32768"])
+def test_hybrid_latent_moe_executables_compile_for_v5e_with_state_beside_a_latent_plane(
+        which, v5e_chip, monkeypatch):
+    """The cell's decode and top-bucket admission executables whole, from
+    the configuration file: weights 10.48 GB (128 of 512 experts a layer, a
+    quarter of the vocabulary) + latent plane, state and tails 1.76 GB as
+    arguments, the cache aliased in place. Decode takes ``kda_step`` on the
+    state leaf, the ragged latent kernel and the grouped product, and makes
+    nothing of the leaf's shape beside it; the 32768 bucket takes
+    ``kda_prefill`` in segments of 8192 tokens, the latent flash kernel and
+    the experts in pieces, and what the chip must hold at once fits (read
+    here, PR 47: temporaries 0.17 GB and 2.81 GB)."""
+    import json
+    from pathlib import Path
+
+    from benchmark import families
+    from kubetorch_tpu.models import hybrid_latent_moe
+    from kubetorch_tpu.models.rolling import RollingGenerator
+    from kubetorch_tpu.parallel.sharding import ShardingRules
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                         / "configs" / "ling-3.0-flash-bf16-serve.json"
+                         ).read_text())
+    b, m = 32, 32768
+    cfg = families.load(config, "serve").program_config(
+        config, "serve", {"max_len": m})
+    vocab = cfg.vocab_size
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def specs(tree):
+        return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+    params = specs(jax.eval_shape(
+        lambda: hybrid_latent_moe.init(jax.random.key(0), cfg)))
+    cache = specs(jax.eval_shape(
+        lambda: hybrid_latent_moe.init_cache(cfg, b, m)))
+    assert cache["ckr"].shape == (1, b, m, 640)
+    assert cache["state"].shape == (6, b, 32, 128, 128)
+    state = (spec((b, vocab), jnp.float32), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_), spec((b,), jnp.int32),
+             spec((b,), jnp.bool_))
+
+    def draw(n):
+        return (spec((n,), jnp.float32), spec((n,), jnp.float32),
+                spec((n, 64), jnp.int32), spec((2,), jnp.uint32))
+
+    rules = ShardingRules.default()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        if which == "decode":
+            exe = jax.jit(
+                lambda *a: RollingGenerator._decode_impl(
+                    *a, None, top_k=None, top_p=None, n_steps=8, cfg=cfg,
+                    rules=rules), donate_argnums=(1, 2, 3, 6)).lower(
+                params, cache, *state, *draw(b)).compile()
+        else:
+            exe = jax.jit(
+                lambda *a: RollingGenerator._prefill_impl(
+                    *a, None, p_pad=m, top_k=None, top_p=None, cfg=cfg,
+                    rules=rules),
+                donate_argnums=(1, 2, 3, 4, 5, 6)).lower(
+                params, cache, *state, spec((1, m), jnp.int32),
+                spec((1,), jnp.int32), spec((1,), jnp.int32),
+                *draw(1)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+    text, mem = exe.as_text(), exe.memory_analysis()
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    cache_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in cache.values())
+    assert 10.4e9 < weights < 10.6e9 and 1.75e9 < cache_bytes < 1.77e9
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert "moe_grouped_matmul" in text
+    if which == "decode":
+        assert "kda_step" in text and "kda_prefill" not in text
+        assert "latent_decode_attention" in text
+        made = [line for line in text.splitlines()
+                if " = f32[6,32,32,128,128]" in line and not any(
+                    op in line for op in ("parameter(", "get-tuple-element(",
+                                          "bitcast("))]
+        assert made == [], made
+        assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
+    else:
+        assert "kda_prefill" in text and "kda_step" not in text
+        assert "latent_prefill_attention" in text
+        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        # what the chip must hold at once fits its 16.9 GB with room
+        assert weights + cache_bytes + mem.temp_size_in_bytes < 15.5e9

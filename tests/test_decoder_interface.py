@@ -2,7 +2,7 @@
 row-state leaves): the two decoders that were there declare what they
 declared, keep no row state, and serve, export and import as they did. And
 the rule between decoders: none imports another, ``ops/`` imports no model,
-and the defaults the five classes inherit are what each returned itself."""
+and the defaults the six classes inherit are what each returned itself."""
 
 import ast
 import importlib
@@ -15,9 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubetorch_tpu.models import (HybridLinearConfig, IndexedMoEConfig,
-                                  LatentMoEConfig, LlamaConfig,
-                                  WindowMoEConfig, latent_moe, llama)
+from kubetorch_tpu.models import (HybridLatentMoEConfig, HybridLinearConfig,
+                                  IndexedMoEConfig, LatentMoEConfig,
+                                  LlamaConfig, WindowMoEConfig, latent_moe,
+                                  llama)
 from kubetorch_tpu.models.decoder import (CacheLeaf, LlamaDecoder,
                                           decoder_for, grid_dims,
                                           position_bytes, row_bytes,
@@ -141,13 +142,16 @@ def test_a_plain_export_does_not_fit_a_grid_with_row_state():
 # ------------------------------------------- a decoder is a file of its own
 REPO = Path(__file__).resolve().parents[1]
 PACKAGE = "kubetorch_tpu.models"
-# module -> (its toy configuration, the kernels under ``ops/`` only it uses)
+# module -> (its toy configuration, the kernels under ``ops/`` it loads that
+# the dense decoder's path does not: the sixth shares two with its siblings)
 DECODERS = {
     "llama": (LlamaConfig.tiny, ()),
     "latent_moe": (LatentMoEConfig.tiny, ("latent_attention",)),
     "hybrid_linear": (HybridLinearConfig.tiny, ("gated_delta",)),
     "window_moe": (WindowMoEConfig.tiny, ()),
     "indexed_moe": (IndexedMoEConfig.tiny, ("indexed_attention",)),
+    "hybrid_latent_moe": (HybridLatentMoEConfig.tiny,
+                          ("kda", "latent_attention", "gated_delta")),
 }
 
 
@@ -193,12 +197,12 @@ def test_ops_import_nothing_from_models():
 def test_a_decoder_loads_no_other_decoder(name):
     """A decoder enters only through ``decoder_for`` and the lazy module
     attribute: importing the engine's path and ONE decoder loads no other
-    decoder's module and none of the kernels only another uses (``llama``
-    excepted, which ``models/__init__.py`` imports)."""
+    decoder's module and none of the kernels another uses and it does not
+    (``llama`` excepted, which ``models/__init__.py`` imports)."""
     others = [other for other in DECODERS if other not in (name, "llama")]
     banned = [f"{PACKAGE}.{other}" for other in others] + [
         f"kubetorch_tpu.ops.{kernel}" for other in others
-        for kernel in DECODERS[other][1]]
+        for kernel in DECODERS[other][1] if kernel not in DECODERS[name][1]]
     code = ("import sys; import kubetorch_tpu.models.rolling, "
             f"kubetorch_tpu.serving.engine, {PACKAGE}.{name}; "
             f"bad = [m for m in {banned!r} if m in sys.modules]; "
@@ -222,10 +226,11 @@ def test_the_inherited_defaults_are_what_each_class_returned(name):
     cfg = DECODERS[name][0]()
     model = decoder_for(cfg)
     assert model.__mro__[1].__name__ == "Decoder"
-    hybrid = name == "hybrid_linear"
+    hybrid = name in ("hybrid_linear", "hybrid_latent_moe")
     assert model.state_rows_touched(cfg, 8, 3) == (8 if hybrid else 0)
     assert (model.scan_positions(cfg, 2, 256) > 0) == hybrid
-    if name not in ("latent_moe", "window_moe", "indexed_moe"):
+    if name not in ("latent_moe", "window_moe", "indexed_moe",
+                    "hybrid_latent_moe"):
         assert model.counters == () and model.prefill_counters(cfg, 9) == {}
     cache = model.init_cache(cfg, 3, 64)
     chunk = model.init_chunk(cfg, cache, 3, 8)
